@@ -76,7 +76,6 @@ def test_llama_long_threads_block_flags(bench, monkeypatch):
                 "vs_baseline": 1}
 
     monkeypatch.setattr(bench, "bench_llama", fake_bench_llama)
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     rc = bench.main([
         "--workload", "llama-long", "--block-q", "256",
         "--block-k", "1024", "--block-q-bwd", "128",
@@ -168,7 +167,6 @@ def test_comm_mode_routes_to_bench_llama(bench, monkeypatch):
                 "vs_baseline": 1}
 
     monkeypatch.setattr(bench, "bench_llama", fake_bench_llama)
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     rc = bench.main(["--comm-mode", "bucketed_overlap"])
     assert rc == 0
     assert seen == {"comm_mode": "bucketed_overlap"}
@@ -187,18 +185,14 @@ def test_guard_mode_routes_to_bench_llama(bench, monkeypatch):
                 "vs_baseline": 1}
 
     monkeypatch.setattr(bench, "bench_llama", fake_bench_llama)
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     rc = bench.main(["--guard-mode", "skip"])
     assert rc == 0
     assert seen == {"guard_mode": "skip"}
 
 
-def test_guard_mode_on_nonconsuming_workload_is_cli_error(
-    bench, monkeypatch
-):
+def test_guard_mode_on_nonconsuming_workload_is_cli_error(bench):
     """The --comm-mode misplaced-flag discipline applies to the guard
     flag too."""
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     with pytest.raises(SystemExit) as ei:
         bench.main(["--workload", "serve", "--guard-mode", "skip"])
     assert ei.value.code == 2
@@ -257,7 +251,6 @@ def test_emitted_record_is_schema_stamped(bench, monkeypatch, capsys):
                       "value": 1, "unit": "tokens/s/chip",
                       "vs_baseline": None},
     )
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     assert bench.main(["--workload", "serve"]) == 0
     import json
 
@@ -289,7 +282,6 @@ def test_serve_mode_routes_flags(bench, monkeypatch):
                 "unit": "tokens/s/chip", "vs_baseline": None}
 
     monkeypatch.setattr(bench, "bench_serve", fake_bench_serve)
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     rc = bench.main([
         "--serve", "--serve-requests", "12", "--serve-slots", "4",
         "--serve-max-new", "7",
@@ -337,8 +329,7 @@ def test_serve_mode_routes_flags(bench, monkeypatch):
     assert seen["paged"] is True and seen["host_blocks"] == 4096
 
 
-def test_serve_alias_conflicts_with_explicit_workload(bench, monkeypatch):
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
+def test_serve_alias_conflicts_with_explicit_workload(bench):
     with pytest.raises(SystemExit):
         bench.main(["--workload", "llama", "--serve"])
 
@@ -364,7 +355,6 @@ def test_loadgen_mode_routes_flags(bench, monkeypatch):
                 "unit": "virtual_ms", "vs_baseline": None}
 
     monkeypatch.setattr(bench, "bench_loadgen", fake_bench_loadgen)
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     rc = bench.main([
         "--workload", "loadgen", "--loadgen-scenario", "bursty",
         "--serve-requests", "16", "--serve-slots", "4",
@@ -399,11 +389,10 @@ def test_loadgen_mode_routes_flags(bench, monkeypatch):
                     "--loadgen-scenario", "colocate"])
 
 
-def test_paged_flags_guarded_like_comm_mode(bench, monkeypatch):
+def test_paged_flags_guarded_like_comm_mode(bench):
     """--serve-paged on a workload that never consumes it is a CLI
     error (a slab row labeled paged would poison the bank), and the
     paged sizing flags require --serve-paged."""
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     with pytest.raises(SystemExit):
         bench.main(["--workload", "llama", "--serve-paged"])
     for flag, val in (
@@ -426,11 +415,10 @@ def test_paged_flags_guarded_like_comm_mode(bench, monkeypatch):
         bench.main(["--workload", "serve", "--serve-model", "tiny"])
 
 
-def test_spec_flags_guarded_like_comm_mode(bench, monkeypatch):
+def test_spec_flags_guarded_like_comm_mode(bench):
     """The speculative flags follow the misplaced-flag discipline: a
     spec flag on a workload (or cache layout) that cannot consume it
     is a CLI error, not a greedy row wearing a spec label."""
-    monkeypatch.setenv("TPU_HPC_BENCH_NO_PROBE", "1")
     # Non-consuming workload.
     with pytest.raises(SystemExit):
         bench.main(["--workload", "llama", "--serve-spec", "ngram"])

@@ -23,13 +23,13 @@ def test_entry_compiles_and_runs():
 
 
 def test_dryrun_multichip_in_process(devices):
-    # Under the pytest CPU-sim env jax already exposes 8 devices, so the
-    # in-process fast path runs (no subprocess).
+    # The suite asked for 8 simulated devices by name (conftest), so
+    # the dry run has them in this process.
     graft.dryrun_multichip(8)
 
 
-def test_dryrun_multichip_subprocess_path():
-    # Force the re-exec path regardless of this process's device count:
-    # ask for more devices than are visible.  The child provisions its
-    # own virtual CPU mesh of that size.
-    graft.dryrun_multichip(16)
+def test_dryrun_multichip_too_few_devices_fails(devices):
+    # More devices than this process has is a failure naming what it
+    # found -- never a re-exec onto a virtual CPU mesh.
+    with pytest.raises(RuntimeError, match="8 cpu device"):
+        graft.dryrun_multichip(16)

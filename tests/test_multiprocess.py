@@ -65,8 +65,8 @@ def _launch(rank_env) -> "list[subprocess.Popen]":
     procs = []
     for pid in (0, 1):
         env = dict(os.environ)
-        # A clean slate: the host env may carry accelerator-plugin or
-        # launcher vars that would win the detection cascade.
+        # A clean slate: the host env may carry launcher vars that
+        # would win the detection cascade.
         for v in (
             "JAX_PROCESS_ID", "JAX_NUM_PROCESSES",
             "JAX_COORDINATOR_ADDRESS", "JAX_COORDINATOR_PORT",

@@ -387,3 +387,41 @@ class TestTokenDataset:
         assert result["final_loss"] is not None
         assert np.isfinite(result["final_loss"])
         ds.close()
+
+
+def _fresh_loader(monkeypatch, src):
+    """The loader module pointed at another source file, with no
+    library loaded yet."""
+    from tpu_hpc.native import dataloader as dl
+
+    monkeypatch.setattr(dl, "_SRC", str(src))
+    monkeypatch.setattr(dl, "_lib", None)
+    monkeypatch.setattr(dl, "_build_error", None)
+    return dl
+
+
+def test_binary_is_keyed_on_source_flags_and_host(monkeypatch, tmp_path):
+    """A binary built from other source (a stale one) or on another
+    CPU (a copied tree's) has another file name, so it is never the
+    one loaded -- no modification-time test involved."""
+    from tpu_hpc.native import dataloader as dl
+
+    here = dl._lib_path()
+    assert os.path.exists(here)  # this host's build, loaded above
+    edited = tmp_path / "dataloader.cpp"
+    edited.write_text(open(dl._SRC).read() + "\n// edited\n")
+    assert _fresh_loader(monkeypatch, edited)._lib_path() != here
+    monkeypatch.undo()
+    monkeypatch.setattr(dl, "_FLAGS", dl._FLAGS + ("-DOTHER",))
+    assert dl._lib_path() != here
+
+
+def test_failed_build_raises_where_asked_by_name(monkeypatch, tmp_path):
+    """No library is an answer only for native_available(); a stream
+    asked for by name raises with the compiler's message."""
+    broken = tmp_path / "dataloader.cpp"
+    broken.write_text("this is not C++\n")
+    dl = _fresh_loader(monkeypatch, broken)
+    assert not dl.native_available()
+    with pytest.raises(RuntimeError, match="native dataloader unavailable"):
+        make_stream()

@@ -178,6 +178,13 @@ def _softmax(x, axis=-1):
     return e / np.sum(e, axis=axis, keepdims=True)
 
 
+def _rows(pages):
+    """[n, hkv, block_size, d] pages -> [n * block_size, hkv, d] token
+    rows (numpy twin of kernels.paged_attention.pages_to_tokens)."""
+    n, hkv, bs, d = pages.shape
+    return pages.transpose(0, 2, 1, 3).reshape(n * bs, hkv, d)
+
+
 def _ref_decode(q, k_pages, v_pages, tables, pos, active, block_size):
     slots, hkv, g, d = q.shape
     out = np.zeros(q.shape, np.float32)
@@ -187,8 +194,8 @@ def _ref_decode(q, k_pages, v_pages, tables, pos, active, block_size):
         length = int(pos[s]) + 1
         n = -(-length // block_size)
         ids = tables[s, :n]
-        k = k_pages[ids].reshape(n * block_size, hkv, d)[:length]
-        v = v_pages[ids].reshape(n * block_size, hkv, d)[:length]
+        k = _rows(k_pages[ids])[:length]
+        v = _rows(v_pages[ids])[:length]
         scores = np.einsum("hgd,thd->hgt", q[s], k) * d ** -0.5
         out[s] = np.einsum(
             "hgt,thd->hgd", _softmax(scores), v
@@ -200,8 +207,8 @@ def _ref_prefill(q, k_pages, v_pages, table, start, block_size):
     hkv, bucket, g, d = q.shape
     ctx = start + bucket
     n = -(-ctx // block_size)
-    k = k_pages[table[:n]].reshape(n * block_size, hkv, d)[:ctx]
-    v = v_pages[table[:n]].reshape(n * block_size, hkv, d)[:ctx]
+    k = _rows(k_pages[table[:n]])[:ctx]
+    v = _rows(v_pages[table[:n]])[:ctx]
     scores = np.einsum("hqgd,thd->hqgt", q, k) * d ** -0.5
     qpos = start + np.arange(bucket)
     causal = np.arange(ctx)[None, :] <= qpos[:, None]  # (bucket, ctx)
@@ -218,7 +225,7 @@ def _random_case(
     every dead table entry points at it, so a kernel that fails to
     redirect dead reads to scratch poisons its output."""
     pool = rng.standard_normal(
-        (num_blocks, block_size, hkv, d)
+        (num_blocks, hkv, block_size, d)
     ).astype(dtype)
     pool[SCRATCH_PAGE] = 0.0
     poison_page = num_blocks - 1
@@ -497,6 +504,12 @@ class TestEngineParity:
             PagedConfig(block_size=4, num_blocks=8, kernel="triton")
         with pytest.raises(ValueError, match="kv_quant"):
             PagedConfig(block_size=4, num_blocks=8, kv_quant="fp4")
+        # The one page size Mosaic refuses (bf16/int8 pools); every
+        # size from 2 up lowers and matched the oracle on the chip.
+        with pytest.raises(ValueError, match="block_size >= 2"):
+            PagedConfig(block_size=1, num_blocks=8, kernel="pallas")
+        PagedConfig(block_size=1, num_blocks=8)  # gather takes any
+        PagedConfig(block_size=2, num_blocks=8, kernel="pallas")
 
 
 class TestEngineInt8:

@@ -460,11 +460,13 @@ class TestPagedCompileDiscipline:
     def test_pool_layout_on_mesh(self, warm_paged, serve_mesh):
         spec = paged_kv_cache_pspec(serve_mesh, TINY.kv_heads)
         assert spec == jax.sharding.PartitionSpec(
-            None, None, None, "model", None
+            None, None, "model", None, None
         )
         assert warm_paged.ks.sharding.spec == spec
+        # Heads ahead of rows inside a page: the layout the Mosaic
+        # kernels need (kernels/paged_attention.py).
         assert warm_paged.ks.shape == (
-            TINY.n_layers, 48, 4, TINY.kv_heads, TINY.head_dim
+            TINY.n_layers, 48, TINY.kv_heads, 4, TINY.head_dim
         )
         assert warm_paged.cache_bytes == (
             2 * TINY.n_layers * 48 * 4 * TINY.kv_heads

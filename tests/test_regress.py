@@ -674,12 +674,13 @@ class TestBank:
         from tpu_hpc.obs.schema import load_records
 
         best = bank_metrics(load_records(path))
-        # The round-5 autotuned headline (HW_QUEUE_r05/bench_bk1024).
+        # Rebuilt (python -m tpu_hpc.obs.bank) from the row files the
+        # repo still holds; the training family's mark is the
+        # BENCH_EXTRA.jsonl sweep row.
         assert best["llama2_train_tokens_per_s_per_chip"] == \
-            pytest.approx(124170.6)
-        # mfu rides as a quantile-style extra where a round's tail
-        # carried the human headline line (driver capture r01). NOTE:
-        # mfu on a latency-free metric is higher-is-better, and
-        # bank_metrics treats it so.
-        assert best["llama2_train_tokens_per_s_per_chip.mfu"] == \
-            pytest.approx(0.463)
+            pytest.approx(121363.1)
+        # No driver capture is banked any more, so no row carries the
+        # lifted ``mfu`` extra.
+        assert "llama2_train_tokens_per_s_per_chip.mfu" not in best
+        # Every banked row names the row file it was lifted from.
+        assert all(r.get("source") for r in load_records(path))

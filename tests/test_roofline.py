@@ -1,10 +1,11 @@
-"""Roofline estimator: validated against the round-2 measured step.
+"""Roofline estimator: pinned against the bench model's step budget.
 
-The bench model's measured single-chip numbers (BENCH_PREOUTAGE_r02,
-docs/guide/xla_performance_notes.md step budget: 76 ms step, 50% MFU,
-pure-matmul bound ~38 ms) are the ground truth the estimator must
-bracket -- a roofline that contradicts the one real measurement we
-own is worse than none."""
+The budget in docs/guide/xla_performance_notes.md (76 ms step, 50%
+MFU, pure-matmul bound ~38 ms at the bench model on one v5e) is what
+the estimator must bracket: a lower bound above the step it bounds, or
+a ceiling below an achieved utilisation, is a broken roofline. The
+budget dates from an earlier round; it has not been re-measured on the
+chip in this one."""
 import pytest
 
 from tpu_hpc.checks import roofline
@@ -21,7 +22,7 @@ def test_single_chip_brackets_the_measured_step():
     # Matmul lower bound ~38 ms (xla_performance_notes.md budget).
     assert 35 < r.compute_s * 1e3 < 41
     assert r.bound == "compute"
-    # Measured: 76 ms -> the bound must be below it, and the measured
+    # Budget: 76 ms -> the bound must be below it, and the budget's
     # 50% MFU must not exceed the estimator's ceiling.
     assert r.step_time_lower_bound_s < 0.076
     assert r.mfu_upper_bound >= 0.50
@@ -120,7 +121,7 @@ def test_measured_chip_spec_substitutes_microbench_rates(monkeypatch):
     # The calibration path swaps in the microbench's measured matmul
     # and HBM rates, keeps spec ICI/capacity, and tags the name --
     # verified against fixed fake rates (the real microbench needs a
-    # real chip; its marginal-rate protocol is hardware-timing based).
+    # real chip).
     from tpu_hpc.checks import env_check
 
     monkeypatch.setattr(

@@ -99,12 +99,19 @@ def peak_flops_for_kind(kind: str, default=None):
     return default
 
 
-def peak_flops_for_device(device, default=None):
-    """Peak dense bf16 FLOP/s for a jax device, by device_kind prefix;
-    ``default`` for unknown kinds (CPU sim, future chips)."""
-    return peak_flops_for_kind(
-        getattr(device, "device_kind", ""), default
-    )
+def peak_flops_for_device(device):
+    """Peak dense bf16 FLOP/s for a live jax device -- the divisor of
+    bench.py's training MFU and the serving MFU. None off-TPU (the
+    simulated CPU run has no peak and reports no utilisation); a TPU
+    whose ``device_kind`` the table does not know is an error, never
+    a borrowed peak."""
+    peak = peak_flops_for_kind(device.device_kind)
+    if peak is None and device.platform == "tpu":
+        raise ValueError(
+            f"no peak FLOP/s for device_kind {device.device_kind!r} "
+            "in checks/roofline.py CHIPS; add the chip to the table"
+        )
+    return peak
 
 
 def _ring_collective_s(bytes_full: int, n: int, bw_gbps: float) -> float:

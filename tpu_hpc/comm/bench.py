@@ -27,13 +27,6 @@ The "barrier" on TPU is ``block_until_ready`` on the input (ensures
 async dispatch has drained) before starting the clock, and on the
 output before stopping it -- the same wall-clock bracketing as the
 reference's ``dist.barrier(); t0; op; synchronize; barrier; t1``.
-
-Caveat for tunneled/remote dev backends (not real pods): some proxy
-transports complete ``block_until_ready`` before device execution
-finishes, which inflates rates. On such backends trust the marginal
--rate microbench (checks/env_check.py:chip_microbench) and the
-trainer's device_get-bracketed throughput instead; on a real TPU-VM
-the bracketing here behaves like the reference's.
 """
 from __future__ import annotations
 

@@ -34,13 +34,24 @@ int8->f32 cast -- so int8 halves pool HBM *and* halves kernel read
 bytes. Quantize-on-write stays in the engine's XLA scatter; the kernels
 are read-only consumers.
 
-On CPU (tier-1) the kernels run under ``interpret=True`` -- the
-``attention.py`` ``impl="auto"`` precedent -- which lowers to plain XLA
-ops, so mesh-sharded pools partition like any other program. Parity
-contract: greedy decode through these kernels is token-exact vs the
-gather oracle for fp16/bf16 pools (same online-softmax identity, fp32
-accumulation); int8 mode is gated by a bounded-divergence oracle whose
-tolerance is pinned from the deterministic ``int8_logit_rmse`` probe.
+Page layout (the one definition; ``tokens_to_pages`` /
+``pages_to_tokens`` / ``write_tokens`` below are its only spellings):
+a page is ``[kv_heads, block_size, head_dim]``, heads AHEAD of rows, so
+the pool is ``[num_blocks, kv_heads, block_size, head_dim]`` and one
+kernel block ``(1, 1, block_size, head_dim)`` spans the full last two
+dims of the array -- the shape Mosaic's block rule accepts (its last
+two block dims must divide by the (8, 128) tile or equal the array's).
+Rows-first pages ``[block_size, kv_heads, head_dim]`` put a size-1 head
+block in the second-minor dim and are refused by the TPU lowering.
+
+The caller says whether to interpret: the serving engine compiles for
+the devices of its mesh (Mosaic on TPU, real or virtual topology; the
+Pallas interpreter on the simulated CPU mesh, which lowers to plain XLA
+ops). Parity contract: greedy decode through these kernels is
+token-exact vs the gather oracle for fp16/bf16 pools (same
+online-softmax identity, fp32 accumulation); int8 mode is gated by a
+bounded-divergence oracle whose tolerance is pinned from the
+deterministic ``int8_logit_rmse`` probe.
 """
 from __future__ import annotations
 
@@ -66,18 +77,51 @@ INT8_SCALE_FLOOR = 1e-8
 
 
 # ---------------------------------------------------------------------------
+# Page layout: [kv_heads, block_size, head_dim]
+# ---------------------------------------------------------------------------
+
+def tokens_to_pages(rows: jax.Array, block_size: int) -> jax.Array:
+    """``[n * block_size, kv_heads, head_dim]`` token rows -> ``[n,
+    kv_heads, block_size, head_dim]`` pages."""
+    t, h, d = rows.shape
+    return rows.reshape(t // block_size, block_size, h, d).transpose(
+        0, 2, 1, 3
+    )
+
+
+def pages_to_tokens(pages: jax.Array) -> jax.Array:
+    """``[..., n, kv_heads, block_size, head_dim]`` pages -> the
+    ``[..., n * block_size, kv_heads, head_dim]`` token view (what a
+    gather over a block table reads)."""
+    *lead, n, h, bs, d = pages.shape
+    return jnp.swapaxes(pages, -3, -2).reshape(*lead, n * bs, h, d)
+
+
+def write_tokens(
+    pool: jax.Array, layer, page_ids: jax.Array, offsets: jax.Array,
+    rows: jax.Array,
+) -> jax.Array:
+    """Scatter token rows ``[*idx, kv_heads, head_dim]`` into
+    ``pool[layer]`` at row ``offsets`` of pages ``page_ids`` (both
+    ``[*idx]``)."""
+    return pool.at[layer, page_ids, :, offsets].set(
+        rows.astype(pool.dtype)
+    )
+
+
+# ---------------------------------------------------------------------------
 # Per-page int8 quantization (single write-side definition)
 # ---------------------------------------------------------------------------
 
 def page_scales_int8(pages: jax.Array) -> jax.Array:
     """Per-page symmetric int8 scale: amax over the page's
-    (block_size, kv_heads, head_dim) trailing dims / 127, floored."""
+    (kv_heads, block_size, head_dim) trailing dims / 127, floored."""
     amax = jnp.max(jnp.abs(pages.astype(jnp.float32)), axis=(-3, -2, -1))
     return jnp.maximum(amax / 127.0, INT8_SCALE_FLOOR)
 
 
 def quantize_pages_int8(pages: jax.Array) -> Tuple[jax.Array, jax.Array]:
-    """Quantize ``[..., block_size, kv_heads, head_dim]`` pages to int8
+    """Quantize ``[..., kv_heads, block_size, head_dim]`` pages to int8
     with one f32 scale per page. Round-half-even, clipped to +-127
     (symmetric; -128 unused so dequant is sign-symmetric)."""
     sc = page_scales_int8(pages)
@@ -129,8 +173,8 @@ def _decode_kernel(
     @pl.when(live)
     def _step():
         q = q_ref[0, 0]                       # (g, d)
-        k = k_ref[0, :, 0, :]                 # (block_size, d)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                       # (block_size, d)
+        v = v_ref[0, 0]
         if quant:
             page = tbl_ref[s_id, j]
             k = k.astype(jnp.float32) * ksc_ref[page]
@@ -166,7 +210,7 @@ def _decode_kernel(
 
 def paged_decode_attention(
     q: jax.Array,        # (slots, kv_heads, group, head_dim)
-    k_pages: jax.Array,  # (num_blocks, block_size, kv_heads, head_dim)
+    k_pages: jax.Array,  # (num_blocks, kv_heads, block_size, head_dim)
     v_pages: jax.Array,
     tables: jax.Array,   # (slots, table_width) int32
     pos: jax.Array,      # (slots,) int32 position written this tick
@@ -198,15 +242,15 @@ def paged_decode_attention(
     def kv_map(s, h, j, tbl, pos_r, act_r, *_):
         live = jnp.logical_and(j * block_size <= pos_r[s], act_r[s] > 0)
         page = jnp.where(live, tbl[s, j], SCRATCH_PAGE)
-        return page, 0, h, 0
+        return page, h, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(slots, hkv, max_blocks),
         in_specs=[
             pl.BlockSpec((1, 1, g, d), lambda s, h, j, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, d), kv_map),
-            pl.BlockSpec((1, block_size, 1, d), kv_map),
+            pl.BlockSpec((1, 1, block_size, d), kv_map),
+            pl.BlockSpec((1, 1, block_size, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, 1, g, d), lambda s, h, j, *_: (s, h, 0, 0)),
         scratch_shapes=[
@@ -269,8 +313,8 @@ def _prefill_kernel(
     def _step():
         rows = block_q * group
         q = q_ref[0].reshape(rows, q_ref.shape[-1])  # (bq*g, d), row-major
-        k = k_ref[0, :, 0, :]                        # (block_size, d)
-        v = v_ref[0, :, 0, :]
+        k = k_ref[0, 0]                              # (block_size, d)
+        v = v_ref[0, 0]
         if quant:
             page = tbl_ref[j]
             k = k.astype(jnp.float32) * ksc_ref[page]
@@ -312,7 +356,7 @@ def _prefill_kernel(
 
 def paged_prefill_attention(
     q: jax.Array,        # (kv_heads, bucket, group, head_dim)
-    k_pages: jax.Array,  # (num_blocks, block_size, kv_heads, head_dim)
+    k_pages: jax.Array,  # (num_blocks, kv_heads, block_size, head_dim)
     v_pages: jax.Array,
     table: jax.Array,    # (table_width,) int32: one slot's table row
     start: jax.Array,    # scalar int32: chunk's first global position
@@ -349,15 +393,15 @@ def paged_prefill_attention(
     def kv_map(h, i, j, tbl, start_r, *_):
         live = j * block_size <= start_r[0] + (i + 1) * block_q - 1
         page = jnp.where(live, tbl[j], SCRATCH_PAGE)
-        return page, 0, h, 0
+        return page, h, 0, 0
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=len(scalars),
         grid=(hkv, bucket // block_q, max_blocks),
         in_specs=[
             pl.BlockSpec((1, block_q, g, d), lambda h, i, j, *_: (h, i, 0, 0)),
-            pl.BlockSpec((1, block_size, 1, d), kv_map),
-            pl.BlockSpec((1, block_size, 1, d), kv_map),
+            pl.BlockSpec((1, 1, block_size, d), kv_map),
+            pl.BlockSpec((1, 1, block_size, d), kv_map),
         ],
         out_specs=pl.BlockSpec(
             (1, block_q, g, d), lambda h, i, j, *_: (h, i, 0, 0)
@@ -383,6 +427,72 @@ def paged_prefill_attention(
         out_shape=jax.ShapeDtypeStruct((hkv, bucket, g, d), q.dtype),
         interpret=interpret,
     )(*scalars, q, k_pages, v_pages)
+
+
+# ---------------------------------------------------------------------------
+# Gather-then-dense references (the oracle the kernels are checked against)
+# ---------------------------------------------------------------------------
+
+def _gathered_view(pages, ids, scale):
+    """``pages[ids]`` as f32 token rows ``[..., T, kv_heads, d]``,
+    dequantized when the pool is int8."""
+    view = pages[ids]
+    if scale is not None:
+        view = dequantize_pages_int8(view, scale[ids])
+    return pages_to_tokens(view.astype(jnp.float32))
+
+
+def _dense_attention(q, k, v, mask, eq_qk, eq_pv):
+    """Masked softmax attention in f32 at full matmul precision (the
+    TPU default would round the f32 operands to bf16). Callers zero the
+    V rows no query may see, so a dead table entry may point at any
+    page, a NaN-poisoned one included."""
+    hi = jax.lax.Precision.HIGHEST
+    s = jnp.einsum(
+        eq_qk, q.astype(jnp.float32), k, precision=hi
+    ) * q.shape[-1] ** -0.5
+    p = jax.nn.softmax(jnp.where(mask, s, -jnp.inf), axis=-1)
+    return jnp.einsum(eq_pv, p, v, precision=hi)
+
+
+def paged_decode_reference(
+    q, k_pages, v_pages, tables, pos, active, *, max_blocks,
+    k_scale=None, v_scale=None,
+):
+    """What :func:`paged_decode_attention` must compute, the way the
+    engine's ``kernel="gather"`` path does it: gather each slot's first
+    ``max_blocks`` table entries into a dense view, then masked softmax
+    attention. Inactive slots give zeros."""
+    ids = tables[:, :max_blocks]
+    k = _gathered_view(k_pages, ids, k_scale)     # (slots, T, hkv, d)
+    v = _gathered_view(v_pages, ids, v_scale)
+    live = jnp.arange(k.shape[1])[None, :] <= pos[:, None]
+    v = jnp.where(live[:, :, None, None], v, 0.0)
+    out = _dense_attention(
+        q, k, v, live[:, None, None, :], "shgd,sthd->shgt",
+        "shgt,sthd->shgd",
+    )
+    return jnp.where(
+        active[:, None, None, None] > 0, out, 0.0
+    ).astype(q.dtype)
+
+
+def paged_prefill_reference(
+    q, k_pages, v_pages, table, start, *, max_blocks,
+    k_scale=None, v_scale=None,
+):
+    """What :func:`paged_prefill_attention` must compute: one slot's
+    table row gathered dense, global causal mask from ``start``."""
+    ids = table[:max_blocks]
+    k = _gathered_view(k_pages, ids, k_scale)     # (T, hkv, d)
+    v = _gathered_view(v_pages, ids, v_scale)
+    qpos = start + jnp.arange(q.shape[1])
+    causal = jnp.arange(k.shape[0])[None, :] <= qpos[:, None]
+    v = jnp.where(causal[-1][:, None, None], v, 0.0)
+    return _dense_attention(
+        q, k, v, causal[None, :, None, :], "hqgd,thd->hqgt",
+        "hqgt,thd->hqgd",
+    ).astype(q.dtype)
 
 
 # ---------------------------------------------------------------------------
@@ -413,9 +523,8 @@ def int8_logit_rmse(
     kq, kk = jax.random.split(jax.random.PRNGKey(seed))
     q = jax.random.normal(kq, (n_heads, head_dim), jnp.float32)
     k = jax.random.normal(kk, (seq_len, kv_heads, head_dim), jnp.float32)
-    pages = k.reshape(seq_len // block_size, block_size, kv_heads, head_dim)
-    kq8, ksc = quantize_pages_int8(pages)
-    k_hat = dequantize_pages_int8(kq8, ksc).reshape(k.shape)
+    kq8, ksc = quantize_pages_int8(tokens_to_pages(k, block_size))
+    k_hat = pages_to_tokens(dequantize_pages_int8(kq8, ksc))
     g = n_heads // kv_heads
     qg = q.reshape(kv_heads, g, head_dim)
     scale = head_dim ** -0.5

@@ -14,7 +14,9 @@ from __future__ import annotations
 
 import ctypes
 import dataclasses
+import hashlib
 import os
+import platform
 import subprocess
 import threading
 from typing import Optional, Tuple
@@ -23,38 +25,77 @@ import numpy as np
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_HERE, "src", "dataloader.cpp")
-_LIB = os.path.join(_HERE, "libtpu_hpc_data.so")
+_FLAGS = (
+    "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC", "-pthread",
+)
 _lock = threading.Lock()
 _lib: Optional[ctypes.CDLL] = None
 _build_error: Optional[str] = None
 
 
-def _build() -> None:
-    subprocess.run(
-        [
-            "g++", "-O3", "-march=native", "-std=c++17", "-shared",
-            "-fPIC", "-pthread", _SRC, "-o", _LIB,
-        ],
-        check=True,
-        capture_output=True,
-        text=True,
+def _lib_path() -> str:
+    """Where THIS host's build of THIS source lives. The name carries a
+    digest of everything the binary depends on -- the source text, the
+    compile flags and, because of ``-march=native``, the host CPU's
+    feature set -- so a binary from another machine (a copied tree) or
+    from an older source is simply not the file that gets loaded. A
+    modification-time test cannot tell either case apart."""
+    h = hashlib.sha256()
+    with open(_SRC, "rb") as f:
+        h.update(f.read())
+    h.update(" ".join(_FLAGS).encode())
+    h.update(platform.machine().encode())
+    try:
+        with open("/proc/cpuinfo") as f:
+            flags = next(
+                (l for l in f if l.startswith(("flags", "Features"))), ""
+            )
+    except OSError:
+        flags = platform.processor()
+    h.update(flags.encode())
+    return os.path.join(
+        _HERE, f"libtpu_hpc_data.{h.hexdigest()[:16]}.so"
     )
 
 
-def _load() -> Optional[ctypes.CDLL]:
+def _build(lib_path: str) -> None:
+    # Compile next to the target and rename into place: a concurrent
+    # loader (another host process) never sees a half-written library.
+    tmp = f"{lib_path}.{os.getpid()}.tmp"
+    try:
+        subprocess.run(
+            ["g++", *_FLAGS, _SRC, "-o", tmp],
+            check=True, capture_output=True, text=True,
+        )
+        os.replace(tmp, lib_path)
+    finally:
+        if os.path.exists(tmp):
+            os.remove(tmp)
+
+
+def _load() -> ctypes.CDLL:
+    """The native library, built on first use. Raises ``RuntimeError``
+    (compiler output included) when it cannot be built or loaded --
+    the stream classes below were asked for by name, and a missing
+    loader must stop them there. ``native_available`` is the one
+    caller that turns the failure into an answer."""
     global _lib, _build_error
     with _lock:
-        if _lib is not None or _build_error is not None:
+        if _lib is not None:
             return _lib
+        if _build_error is not None:
+            raise RuntimeError(_build_error)
         try:
-            if not os.path.exists(_LIB) or (
-                os.path.getmtime(_LIB) < os.path.getmtime(_SRC)
-            ):
-                _build()
-            lib = ctypes.CDLL(_LIB)
+            lib_path = _lib_path()
+            if not os.path.exists(lib_path):
+                _build(lib_path)
+            lib = ctypes.CDLL(lib_path)
         except (OSError, subprocess.CalledProcessError) as e:
-            _build_error = str(e)
-            return None
+            _build_error = (
+                f"native dataloader unavailable: {e}"
+                + (f"\n{e.stderr}" if getattr(e, "stderr", None) else "")
+            )
+            raise RuntimeError(_build_error) from e
         lib.era5_gen.argtypes = [
             ctypes.c_int64, ctypes.c_int64, ctypes.c_int64,
             ctypes.c_int64, ctypes.c_uint64, ctypes.c_int64,
@@ -126,7 +167,11 @@ def _load() -> Optional[ctypes.CDLL]:
 def native_available() -> bool:
     """True when the C++ library built (g++ present); callers fall back
     to the on-device generator otherwise."""
-    return _load() is not None
+    try:
+        _load()
+    except RuntimeError:
+        return False
+    return True
 
 
 def _fptr(a: np.ndarray):
@@ -214,12 +259,7 @@ class NativeERA5Stream(_PrefetchedStream):
     n_threads: int = 2
 
     def __post_init__(self):
-        lib = _load()
-        if lib is None:
-            raise RuntimeError(
-                f"native dataloader unavailable: {_build_error}"
-            )
-        self._lib = lib
+        lib = self._lib = _load()
         self._handle = lib.era5_prefetcher_create(
             self.batch_size, self.lat, self.lon, self.channels,
             self.seed, self.prefetch_depth, self.n_threads,
@@ -345,12 +385,7 @@ class NativeFileDataset(_PrefetchedStream):
     n_threads: int = 2
 
     def __post_init__(self):
-        lib = _load()
-        if lib is None:
-            raise RuntimeError(
-                f"native dataloader unavailable: {_build_error}"
-            )
-        self._lib = lib
+        lib = self._lib = _load()
         self._handle = lib.file_dataset_open(
             self.path.encode(), self.batch_size, self.seed,
             self.prefetch_depth, self.n_threads,
@@ -458,12 +493,7 @@ class NativeTokenDataset(_PrefetchedStream):
                 f"seq_len {self.seq_len} and batch_size "
                 f"{self.batch_size} must be positive"
             )
-        lib = _load()
-        if lib is None:
-            raise RuntimeError(
-                f"native dataloader unavailable: {_build_error}"
-            )
-        self._lib = lib
+        lib = self._lib = _load()
         self._handle = lib.token_dataset_open(
             self.path.encode(), self.batch_size, self.seq_len,
             self.seed, self.prefetch_depth, self.n_threads,

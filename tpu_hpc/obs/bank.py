@@ -1,28 +1,27 @@
-"""``python -m tpu_hpc.obs.bank BENCH_r*.json -o BENCH_HISTORY.jsonl``
+"""``python -m tpu_hpc.obs.bank ROWS.jsonl... -o BENCH_HISTORY.jsonl``
 -- normalize the banked bench history.
 
-The driver's per-round captures (``BENCH_r01.json`` ...) are ad-hoc
-``{n, cmd, rc, tail, parsed}`` wrappers: the parsed bench record when
-the round succeeded, a raw stderr tail when the backend was out. Four
-of five rounds on record are outages, and the one schema any gate can
-trust is obs/schema.py's -- so this converter lifts every capture into
-one validated ``bench``-event JSONL:
+A driver's per-round capture is an ad-hoc ``{n, cmd, rc, tail,
+parsed}`` wrapper: the parsed bench record when the round succeeded, a
+raw stderr tail when it did not. The one schema any gate can trust is
+obs/schema.py's -- so this converter lifts every capture into one
+validated ``bench``-event JSONL:
 
 * a successful round's ``parsed`` record becomes a ``bench`` event
   (metric/value/unit + whatever rode along), stamped with its round
   number, exit code and source file;
 * a failed round becomes the same failure row ``bench.py --all``
   already emits (``value: null, unit: "FAILED"``, last stderr line as
-  ``error``) -- outages are part of the trajectory, not silently
+  ``error``) -- failures are part of the trajectory, not silently
   dropped history;
 * an ``MFU <x>%`` figure in the tail (the human headline line) is
-  lifted into an ``mfu`` field so the bank keeps the number the
-  PERFORMANCE.md table quotes.
+  lifted into an ``mfu`` field.
 
-Builder-recorded row files (``BENCH_EXTRA.jsonl``,
-``HW_QUEUE_r05/bench_*.json`` single records) are accepted too: any
-input that is already a bench record (or JSONL of them) is stamped and
-passed through. The output is the ONE trusted input
+Builder-recorded row files (``BENCH_EXTRA.jsonl``, the per-PR
+``BENCH_*_rNN.jsonl``) are accepted too: any input that is already a
+bench record (or JSONL of them) is stamped and passed through. The
+committed ``BENCH_HISTORY.jsonl`` is this tool's output over the row
+files the repo holds. The output is the ONE trusted input
 ``python -m tpu_hpc.obs.regress --bank`` diffs candidates against.
 """
 from __future__ import annotations
@@ -117,8 +116,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     )
     ap.add_argument(
         "inputs", nargs="+",
-        help="driver captures (BENCH_rNN.json), bench records, or "
-        "bench-row JSONLs",
+        help="driver captures, bench records, or bench-row JSONLs",
     )
     ap.add_argument(
         "-o", "--out", default="BENCH_HISTORY.jsonl",

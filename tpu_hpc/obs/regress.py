@@ -1,10 +1,8 @@
 """``python -m tpu_hpc.obs.regress baseline.jsonl candidate.jsonl`` --
 the perf-regression gate.
 
-Every perf claim in this repo's history was a headline number, and the
-BENCH_r01..r05 trajectory (46.3% -> 57.6% MFU with four driver-bench
-outages in between) shows how easily one number lies. This gate
-replaces it: two schema-stamped run JSONLs (a training run log, a
+A perf claim that rests on one headline number is easy to get wrong
+and hard to check. This gate replaces it: two schema-stamped run JSONLs (a training run log, a
 serve replay trace, or a tpu_hpc.loadgen run) are reduced through
 ``obs.report.build_report`` to their quantile metrics -- TTFT/ITL
 p50/p95/p99, goodput, MFU, tokens/s, per-tenant loadgen quantiles,

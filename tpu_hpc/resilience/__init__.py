@@ -1,9 +1,9 @@
 """Resilience: preemption-safe, self-healing training runs.
 
 On TPU pods preemption, coordinator hangs, and flaky slices are the
-NORMAL operating regime, not the exception -- every round-5 hardware
-run was babysat by an ad-hoc shell watchdog (HW_QUEUE_r05/watchdog.log,
-the rc=3 exhausted probe window, the overwritten OOM stash log). This
+NORMAL operating regime, not the exception, and a run babysat by an
+ad-hoc shell watchdog leaves no auditable trail (a failure log
+overwritten by the next attempt is the canonical loss). This
 package moves fault handling from the queue script into the framework,
 the position "Collective Communication for 100k+ GPUs" (PAPERS.md)
 argues is mandatory at scale:

@@ -4,8 +4,7 @@ The failure mode this covers is the worst one on a shared pod: a
 training process that is neither dead nor progressing -- a wedged
 collective, a coordinator that never answers, a host read blocked on a
 dead filesystem. The allocation burns until the queue kills it, and
-the only artifact is an empty log (the round-5 ad-hoc answer was a
-shell `tail`-watching watchdog, HW_QUEUE_r05/watchdog.log).
+the only artifact is an empty log.
 
 Two cooperating pieces:
 

@@ -1,7 +1,7 @@
 """Single-host run supervisor: bounded restart-with-resume.
 
-Replaces the ad-hoc shell watchdogs every round-5 hardware run was
-babysat by (HW_QUEUE_r05/watchdog.log) with one auditable process::
+Replaces ad-hoc shell watchdogs around a training command with one
+auditable process::
 
     python -m tpu_hpc.resilience.supervisor \
         --max-restarts 3 --log-dir runs/job1 \

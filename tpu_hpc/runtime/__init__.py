@@ -1,10 +1,12 @@
 from tpu_hpc.runtime.distributed import (  # noqa: F401
     HostInfo,
     cleanup_distributed,
+    compile_cache_dir,
     get_host_info,
     init_distributed,
     is_main_host,
     print_host0,
+    require_accelerator,
 )
 from tpu_hpc.runtime.mesh import (  # noqa: F401
     MeshSpec,

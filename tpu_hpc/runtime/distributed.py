@@ -210,6 +210,59 @@ def init_distributed(
     return info
 
 
+# The persistent compile cache when JAX_COMPILATION_CACHE_DIR does not
+# name one: a fixed, git-ignored directory in the checkout. The path is
+# part of the cache key's world -- one built from a temp name, a pid or
+# a time would never hit.
+_COMPILE_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)
+    ))),
+    ".jax_cache",
+)
+
+
+def compile_cache_dir() -> str:
+    """Place JAX's persistent compilation cache and return the
+    directory in use. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
+    reads it itself and this sets nothing; otherwise the cache goes to
+    the one fixed path above. Call before the first compile."""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+
+    jax.config.update("jax_compilation_cache_dir", _COMPILE_CACHE_DIR)
+    return _COMPILE_CACHE_DIR
+
+
+def require_accelerator():
+    """THE device check of every chip-path entry point (bench.py,
+    ``python -m tpu_hpc.serve``, chip_smoke.py): bring up the runtime,
+    then exit non-zero at once, naming the platform found, unless JAX
+    came up on a TPU. A simulated run is something the caller asks for
+    by name (``TPU_HPC_SIM_DEVICES=N``), never something a measurement
+    falls into -- a CPU answering under a device metric's name is the
+    failure this exists to make impossible. On the chip it also places
+    the compile cache (:func:`compile_cache_dir`); simulated runs keep
+    JAX's default. Returns ``jax.devices()[0]``."""
+    import jax
+
+    init_distributed(verbose=False)
+    dev = jax.devices()[0]
+    if dev.platform == "tpu":
+        compile_cache_dir()
+    elif not os.environ.get("TPU_HPC_SIM_DEVICES"):
+        raise SystemExit(
+            f"tpu_hpc: no TPU: JAX came up on platform "
+            f"{dev.platform!r} ({jax.device_count()} x "
+            f"{dev.device_kind}). This entry point runs on the chip; "
+            "ask for the CPU simulation by name with "
+            "TPU_HPC_SIM_DEVICES=N."
+        )
+    return dev
+
+
 def cleanup_distributed() -> None:
     """Shut down the multi-host runtime. Parity: utils/distributed.py:161-164."""
     global _INITIALIZED

@@ -120,9 +120,10 @@ def build_mesh(
 ) -> Mesh:
     """Build a ``jax.sharding.Mesh`` from a spec.
 
-    Uses ``jax.make_mesh`` on real hardware (ICI-topology-aware axis
-    assignment); falls back to a plain reshape over the device list when
-    given an explicit device subset (tests, sub-meshes). Specs with
+    Uses ``jax.make_mesh`` on the default device set and
+    ``mesh_utils.create_device_mesh`` on explicit TPU subsets (both
+    ICI-topology-aware); a plain reshape over the device list only for
+    explicit non-TPU subsets (simulated devices have no topology). Specs with
     ``dcn_axes`` build a hybrid ICI x DCN mesh (see
     :func:`build_hybrid_mesh`).
     """
@@ -151,16 +152,11 @@ def build_mesh(
         # ICI-topology-aware layout: jax.make_mesh assigns axes onto the
         # physical torus so inner axes get the fastest links. Auto axis
         # types: the framework relies on GSPMD sharding propagation, not
-        # the newer explicit sharding-in-types mode. AxisType only
-        # exists on newer jax (>= 0.5); older runtimes are implicitly
-        # Auto, so omit the kwarg there instead of crashing every
-        # mesh construction.
-        if hasattr(jax.sharding, "AxisType"):
-            return jax.make_mesh(
-                shape, names,
-                axis_types=(jax.sharding.AxisType.Auto,) * len(names),
-            )
-        return jax.make_mesh(shape, names)
+        # the newer explicit sharding-in-types mode.
+        return jax.make_mesh(
+            shape, names,
+            axis_types=(jax.sharding.AxisType.Auto,) * len(names),
+        )
     subset = list(devices[:total])
     if all(getattr(d, "platform", None) == "tpu" for d in subset):
         # Explicit TPU device subsets (pod sub-meshes, virtual-topology
@@ -168,17 +164,13 @@ def build_mesh(
         # makes ring neighbors physically distant, which v5e's limited
         # ICI routing rejects outright for async collective-permutes
         # and which throttles any real pod. mesh_utils orders by
-        # physical coords; fall through to the flat reshape only if it
-        # cannot (e.g. an irregular subset).
+        # physical coords; a subset it cannot lay out (an irregular
+        # one) is an error here, never a silent flat reshape.
         from jax.experimental import mesh_utils
 
-        try:
-            return Mesh(
-                mesh_utils.create_device_mesh(shape, devices=subset),
-                names,
-            )
-        except Exception:
-            pass
+        return Mesh(
+            mesh_utils.create_device_mesh(shape, devices=subset), names
+        )
     arr = np.asarray(subset).reshape(shape)
     return Mesh(arr, names)
 

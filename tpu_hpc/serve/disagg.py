@@ -233,9 +233,11 @@ class DisaggEngine:
     def _rows_shape(self, bucket: int) -> Tuple[int, ...]:
         c = self.cfg
         if self.is_paged:
-            bs = self.paged.block_size
-            return (c.n_layers, bucket // bs, bs, c.kv_heads,
-                    c.head_dim)
+            # A page run in the pool's own page layout.
+            return (
+                c.n_layers, bucket // self.paged.block_size,
+                *self.prefill_engine.ks.shape[2:],
+            )
         return (c.n_layers, 1, bucket, c.kv_heads, c.head_dim)
 
     def _build_bucket_paged(self, bucket: int) -> None:
@@ -245,18 +247,12 @@ class DisaggEngine:
         block tables + referenced pages only, nothing else crosses."""
         from tpu_hpc import reshard
 
-        c = self.cfg
         pe, de = self.prefill_engine, self.decode_engine
         nb = bucket // self.paged.block_size
         rows = self._rows_shape(bucket)
-        src_sh = NamedSharding(
-            self.prefill_mesh,
-            _kv_rows_pspec(self.prefill_mesh, c.kv_heads),
-        )
-        tgt_sh = NamedSharding(
-            self.decode_mesh,
-            _kv_rows_pspec(self.decode_mesh, c.kv_heads),
-        )
+        # Page runs are slices of a pool along its block dim: each
+        # tier's rows shard exactly as its pool does.
+        src_sh, tgt_sh = pe._cache_sharding, de._cache_sharding
         cache_p = pe._cache_abstract()
         cache_d = de._cache_abstract()
         ids_p = jax.ShapeDtypeStruct((nb,), jnp.int32, sharding=pe._rep)

@@ -8,10 +8,12 @@ Memory Management for Large Language Model Serving with
 PagedAttention", PAPERS.md), rebuilt on this repo's own discipline of
 AOT executable tables and token-exact oracles:
 
-* **cache** ``[layers, num_blocks, block_size, kv_heads, head_dim]``:
-  one physical pool, KV heads sharded over the ``model`` axis (pages
-  are globally addressable, so the block dim stays unsharded -- a
-  multi-slice deployment runs one pool per data-parallel replica);
+* **cache** ``[layers, num_blocks, kv_heads, block_size, head_dim]``:
+  one physical pool, heads ahead of rows inside a page (the layout
+  kernels/paged_attention.py defines and its Mosaic kernels need), KV
+  heads sharded over the ``model`` axis (pages are globally
+  addressable, so the block dim stays unsharded -- a multi-slice
+  deployment runs one pool per data-parallel replica);
 * **BlockAllocator** (host side): LIFO free list + refcounts. A block
   is shared when several owners (request tables, the prefix trie)
   hold references; it returns to the free list only at refcount zero.
@@ -43,9 +45,10 @@ on every backend and token-exact against the no-cache forward (the
 tests/test_serve.py oracle applies verbatim) -- or ``"pallas"`` -- the
 kernels/paged_attention.py kernels dropped into the SAME program
 slots: block table walked in-kernel as a scalar-prefetch operand, one
-HBM read per page, no gathered intermediate (interpret mode off-TPU,
-token-exact vs gather by the parity suite in
-tests/test_paged_kernels.py). ``PagedConfig.kv_quant="int8"`` stores
+HBM read per page, no gathered intermediate, run under ``shard_map``
+over the serving mesh (compiled by Mosaic for TPU meshes, interpreted
+on the simulated CPU mesh; token-exact vs gather by the parity suite
+in tests/test_paged_kernels.py). ``PagedConfig.kv_quant="int8"`` stores
 the pool as per-page symmetric int8 with f32 scale side arrays
 (``k_scales``/``v_scales``, one scalar per page per layer): half the
 pool HBM, ~2x the resident context at equal bytes, gated by a
@@ -54,6 +57,7 @@ bounded-divergence oracle instead of token-exactness.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -67,7 +71,10 @@ from tpu_hpc.kernels.paged_attention import (
     dequantize_pages_int8,
     paged_decode_attention,
     paged_prefill_attention,
+    pages_to_tokens,
     quantize_pages_int8,
+    tokens_to_pages,
+    write_tokens,
 )
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.engine import (
@@ -114,11 +121,20 @@ class PagedConfig:
     (serve/tier.py; 0 = no tier). Like ``num_blocks`` it INCLUDES a
     reserved scratch slot 0, so a non-zero tier needs >= 2 slots.
     ``kernel``: how attention reads the pool -- ``"gather"`` (the XLA
-    data-indexed gather, the oracle and the CPU path) or ``"pallas"``
+    data-indexed gather, the oracle) or ``"pallas"``
     (kernels/paged_attention.py: block table walked in-kernel, one HBM
-    read per page; interpret mode off-TPU). ``kv_quant``: pool storage
-    -- ``"none"`` (cache_dtype as configured) or ``"int8"`` (per-page
-    symmetric int8 with f32 scale side arrays; half the pool bytes)."""
+    read per page). ``kv_quant``: pool storage -- ``"none"``
+    (cache_dtype as configured) or ``"int8"`` (per-page symmetric int8
+    with f32 scale side arrays; half the pool bytes).
+
+    Legal page sizes for ``kernel="pallas"``: ``block_size >= 2``. A
+    one-row page fails the Mosaic lowering for bfloat16 and int8 pools
+    (the ``(1, 128)`` page leaves the matmul a degenerate operand);
+    every size from 2 to 128 lowers, and on a v5e matches the
+    gather-then-dense oracle, for float32, bfloat16 and int8 alike
+    (PERF.md, PR 21 sweep). HBM stores a page short of a tile
+    unpadded -- XLA narrows the tiling -- so no dtype needs a larger
+    minimum; which size is FASTEST is not measured."""
 
     block_size: int = 16
     num_blocks: int = 64
@@ -162,6 +178,12 @@ class PagedConfig:
             raise ValueError(
                 f"kernel must be 'gather' or 'pallas', got "
                 f"{self.kernel!r}"
+            )
+        if self.kernel == "pallas" and self.block_size < 2:
+            raise ValueError(
+                f"kernel='pallas' needs block_size >= 2, got "
+                f"{self.block_size}: a one-row page does not lower "
+                "through Mosaic for bfloat16 and int8 pools"
             )
         if self.kv_quant not in ("none", "int8"):
             raise ValueError(
@@ -246,7 +268,45 @@ def paged_kv_cache_pspec(mesh: Mesh, kv_heads: int) -> P:
         and kv_heads % mesh.shape["model"] == 0
         else None
     )
-    return P(None, None, None, model, None)
+    return P(None, None, model, None, None)
+
+
+def _on_mesh(kernel, mesh: Mesh, kv_heads: int, heads_dim: int, **static):
+    """A paged kernel with its static arguments bound, run per KV-head
+    shard of the serving mesh. XLA has no SPMD partitioning rule for a
+    Mosaic call, so inside a GSPMD program the kernel runs under
+    ``shard_map`` (the tp.make_tp_flash_attn_fn pattern): q, the pool
+    and the output split over ``model`` on their KV-head dim exactly as
+    :func:`paged_kv_cache_pspec` lays the pool out; tables, positions
+    and scales are whole on every shard. ``heads_dim``: which dim of q
+    and of the output indexes KV heads. Interpreted when the mesh is
+    not made of TPU devices."""
+    if mesh is None:
+        raise ValueError("kernel='pallas' needs the serving mesh")
+    fn = functools.partial(
+        kernel,
+        interpret=mesh.devices.flat[0].platform != "tpu",
+        **static,
+    )
+    if mesh.size == 1:
+        return fn
+    model = paged_kv_cache_pspec(mesh, kv_heads)[2]
+    q_spec = P(*(model if i == heads_dim else None for i in range(4)))
+    pool_spec = P(None, model, None, None)
+
+    def call(q, k_pages, v_pages, *scalars, **scales):
+        # Tables/positions and the (possibly None) scales ride as two
+        # whole-on-every-shard pytrees.
+        return jax.shard_map(
+            lambda q, k, v, scalars, scales: fn(
+                q, k, v, *scalars, **scales
+            ),
+            mesh=mesh,
+            in_specs=(q_spec, pool_spec, pool_spec, P(), P()),
+            out_specs=q_spec, check_vma=False,
+        )(q, k_pages, v_pages, scalars, scales)
+
+    return call
 
 
 # ---------------------------------------------------------------------
@@ -710,6 +770,7 @@ def make_chunk_logits_fn(
     table_width: int,
     kernel: str = "gather",
     kv_quant: str = "none",
+    mesh: Optional[Mesh] = None,
 ):
     """One prefill **chunk** at a padded bucket length -- the paged
     generalisation of the slab prefill program (whole-prompt prefill
@@ -732,8 +793,8 @@ def make_chunk_logits_fn(
     of the first ``max_blocks`` table entries (the oracle);
     ``kernel="pallas"`` hands the table row to
     :func:`tpu_hpc.kernels.paged_attention.paged_prefill_attention`,
-    which walks it in-kernel (interpret mode off-TPU -- the
-    ``attention.py`` precedent). ``kv_quant="int8"`` changes the
+    which walks it in-kernel under ``shard_map`` over ``mesh`` (the
+    serving mesh; required for this kernel). ``kv_quant="int8"`` changes the
     program signature to ``(params, ks, vs, ksc, vsc, tokens, start,
     true_len, table) -> (ks, vs, ksc, vsc, next_token)``: the scatter
     quantizes whole pages (per-page f32 scale into the ``ksc``/``vsc``
@@ -749,11 +810,12 @@ def make_chunk_logits_fn(
     cache_cap = max_blocks * block_size
     quant = kv_quant == "int8"
     use_pallas = kernel == "pallas"
-    # Decided at build time, like blockwise_attention's impl="auto":
-    # off-TPU the kernel runs under the Pallas interpreter (pure XLA
-    # ops, so mesh-sharded pools partition normally).
-    interpret = jax.default_backend() != "tpu"
     groups = cfg.n_heads // cfg.kv_heads
+    if use_pallas:
+        prefill_attention = _on_mesh(
+            paged_prefill_attention, mesh, cfg.kv_heads, 0,
+            block_size=block_size, max_blocks=max_blocks,
+        )
 
     def body(params, ks, vs, ksc, vsc, tokens, start, true_len, table):
         x = _embed(params, tokens, cfg)
@@ -773,12 +835,8 @@ def make_chunk_logits_fn(
             q, k, v = _qkv(h, lp, cfg)
             q = llama2.apply_rope(q, cos, sin)
             k = llama2.apply_rope(k, cos, sin)
-            kb = k[0].reshape(
-                nb_chunk, block_size, cfg.kv_heads, cfg.head_dim
-            )
-            vb = v[0].reshape(
-                nb_chunk, block_size, cfg.kv_heads, cfg.head_dim
-            )
+            kb = tokens_to_pages(k[0], block_size)
+            vb = tokens_to_pages(v[0], block_size)
             if quant:
                 kq, k_sc = quantize_pages_int8(kb)
                 vq, v_sc = quantize_pages_int8(vb)
@@ -793,12 +851,10 @@ def make_chunk_logits_fn(
                 qp = q[0].astype(cfg.dtype).reshape(
                     bucket, cfg.kv_heads, groups, cfg.head_dim
                 ).transpose(1, 0, 2, 3)
-                ctx = paged_prefill_attention(
+                ctx = prefill_attention(
                     qp, ks[i], vs[i], table, start,
-                    block_size=block_size, max_blocks=max_blocks,
                     k_scale=ksc[i] if quant else None,
                     v_scale=vsc[i] if quant else None,
-                    interpret=interpret,
                 )
                 attn = ctx.transpose(1, 0, 2, 3).reshape(
                     1, bucket, cfg.n_heads, cfg.head_dim
@@ -813,12 +869,8 @@ def make_chunk_logits_fn(
                     v_view = dequantize_pages_int8(
                         v_view, vsc[i][view_ids]
                     )
-                k_view = k_view.reshape(
-                    1, cache_cap, cfg.kv_heads, cfg.head_dim
-                )
-                v_view = v_view.reshape(
-                    1, cache_cap, cfg.kv_heads, cfg.head_dim
-                )
+                k_view = pages_to_tokens(k_view)[None]
+                v_view = pages_to_tokens(v_view)[None]
                 attn = _grouped_attention(
                     q, k_view.astype(cfg.dtype),
                     v_view.astype(cfg.dtype), mask, cfg,
@@ -859,12 +911,13 @@ def make_chunk_prefill_fn(
     table_width: int,
     kernel: str = "gather",
     kv_quant: str = "none",
+    mesh: Optional[Mesh] = None,
 ):
     """The greedy chunk-prefill program: :func:`make_chunk_logits_fn`
     with the argmax token rule (meaningful on the final chunk only)."""
     inner = make_chunk_logits_fn(
         cfg, bucket, block_size, max_blocks, table_width,
-        kernel=kernel, kv_quant=kv_quant,
+        kernel=kernel, kv_quant=kv_quant, mesh=mesh,
     )
     if kv_quant == "int8":
         def chunk_prefill_q(params, ks, vs, ksc, vsc, tokens, start,
@@ -894,6 +947,7 @@ def make_paged_decode_fn(
     table_width: int,
     kernel: str = "gather",
     kv_quant: str = "none",
+    mesh: Optional[Mesh] = None,
 ):
     """The single-token decode program over every slot, block-table
     edition.
@@ -911,7 +965,8 @@ def make_paged_decode_fn(
 
     ``kernel="pallas"`` swaps the view gather + dense attention for
     :func:`tpu_hpc.kernels.paged_attention.paged_decode_attention`
-    (table walked in-kernel, one pool read per page). ``kv_quant=
+    (table walked in-kernel, one pool read per page, under
+    ``shard_map`` over ``mesh``). ``kv_quant=
     "int8"`` threads the scale side arrays through the signature
     (``..., ks, vs, ksc, vsc, ...``) and the token write becomes a
     page REQUANTIZE: dequantize the target page, insert the token,
@@ -924,8 +979,12 @@ def make_paged_decode_fn(
     cache_cap = max_blocks * block_size
     quant = kv_quant == "int8"
     use_pallas = kernel == "pallas"
-    interpret = jax.default_backend() != "tpu"
     groups = cfg.n_heads // cfg.kv_heads
+    if use_pallas:
+        decode_attention = _on_mesh(
+            paged_decode_attention, mesh, cfg.kv_heads, 1,
+            block_size=block_size, max_blocks=max_blocks,
+        )
 
     def body(params, ks, vs, ksc, vsc, tokens, pos, tables, active):
         slots = tokens.shape[0]
@@ -944,7 +1003,9 @@ def make_paged_decode_fn(
         )
         view_ids = tables[:, :max_blocks]
         idx = jnp.arange(block_size)
-        written = idx[None, :] <= off[:, None]  # page tail not yet live
+        # Rows of the write-target page already live, broadcast over
+        # the page's [kv_heads, block_size, head_dim].
+        written = (idx[None, :] <= off[:, None])[:, None, :, None]
         for i in range(cfg.n_layers):
             lp = params[f"layers_{i}"]
             h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
@@ -954,14 +1015,14 @@ def make_paged_decode_fn(
             if quant:
                 k_page = dequantize_pages_int8(ks[i, pb], ksc[i, pb])
                 v_page = dequantize_pages_int8(vs[i, pb], vsc[i, pb])
-                k_page = k_page.at[rows, off].set(
+                k_page = k_page.at[rows, :, off].set(
                     k[:, 0].astype(jnp.float32)
                 )
-                v_page = v_page.at[rows, off].set(
+                v_page = v_page.at[rows, :, off].set(
                     v[:, 0].astype(jnp.float32)
                 )
-                k_page = jnp.where(written[..., None, None], k_page, 0.0)
-                v_page = jnp.where(written[..., None, None], v_page, 0.0)
+                k_page = jnp.where(written, k_page, 0.0)
+                v_page = jnp.where(written, v_page, 0.0)
                 kq, k_sc = quantize_pages_int8(k_page)
                 vq, v_sc = quantize_pages_int8(v_page)
                 ks = ks.at[i, pb].set(kq)
@@ -969,18 +1030,16 @@ def make_paged_decode_fn(
                 ksc = ksc.at[i, pb].set(k_sc)
                 vsc = vsc.at[i, pb].set(v_sc)
             else:
-                ks = ks.at[i, pb, off].set(k[:, 0].astype(ks.dtype))
-                vs = vs.at[i, pb, off].set(v[:, 0].astype(vs.dtype))
+                ks = write_tokens(ks, i, pb, off, k[:, 0])
+                vs = write_tokens(vs, i, pb, off, v[:, 0])
             if use_pallas:
                 qd = q[:, 0].astype(cfg.dtype).reshape(
                     slots, cfg.kv_heads, groups, cfg.head_dim
                 )
-                ctx = paged_decode_attention(
+                ctx = decode_attention(
                     qd, ks[i], vs[i], tables, pos, active,
-                    block_size=block_size, max_blocks=max_blocks,
                     k_scale=ksc[i] if quant else None,
                     v_scale=vsc[i] if quant else None,
-                    interpret=interpret,
                 )
                 attn = ctx.reshape(
                     slots, 1, cfg.n_heads, cfg.head_dim
@@ -995,12 +1054,8 @@ def make_paged_decode_fn(
                     v_view = dequantize_pages_int8(
                         v_view, vsc[i][view_ids]
                     )
-                k_view = k_view.reshape(
-                    slots, cache_cap, cfg.kv_heads, cfg.head_dim
-                )
-                v_view = v_view.reshape(
-                    slots, cache_cap, cfg.kv_heads, cfg.head_dim
-                )
+                k_view = pages_to_tokens(k_view)
+                v_view = pages_to_tokens(v_view)
                 attn = _grouped_attention(
                     q, k_view.astype(cfg.dtype),
                     v_view.astype(cfg.dtype), mask, cfg,
@@ -1217,7 +1272,7 @@ class PagedEngine(Engine):
     def _cache_shape(self) -> Tuple[int, ...]:
         return (
             self.cfg.n_layers, self.paged.num_blocks,
-            self.paged.block_size, self.cfg.kv_heads,
+            self.cfg.kv_heads, self.paged.block_size,
             self.cfg.head_dim,
         )
 
@@ -1303,6 +1358,7 @@ class PagedEngine(Engine):
                 self.cfg, bucket, self.paged.block_size,
                 self.max_blocks_per_seq, self.table_width,
                 kernel=self.paged.kernel, kv_quant=self.paged.kv_quant,
+                mesh=self.mesh,
             )
             tokens = jax.ShapeDtypeStruct(
                 (1, bucket), jnp.int32, sharding=self._rep
@@ -1317,6 +1373,7 @@ class PagedEngine(Engine):
                 self.cfg, self.paged.block_size,
                 self.max_blocks_per_seq, self.table_width,
                 kernel=self.paged.kernel, kv_quant=self.paged.kv_quant,
+                mesh=self.mesh,
             )
             vec = jax.ShapeDtypeStruct(
                 (slots,), jnp.int32, sharding=self._rep

@@ -1,8 +1,9 @@
 """`python -m tpu_hpc.serve` -- local request-replay serving run.
 
-Brings up the engine on whatever chips are visible (simulated CPU mesh
-included: TPU_HPC_SIM_DEVICES=8 works exactly like the test suite),
-replays a deterministic synthetic request mix through the continuous
+Brings up the engine on the TPU chips of this host -- or, asked for by
+name (``--sim-devices N`` / ``TPU_HPC_SIM_DEVICES=N``), on a simulated
+CPU mesh; any other backend is refused at start-up
+(runtime.require_accelerator) -- replays a deterministic synthetic request mix through the continuous
 batcher, and emits the serving metrics record -- TTFT/ITL quantiles,
 tokens/s/chip, serving MFU -- as one JSON line on stdout plus optional
 JSONL traces. The serving analogue of bench.py's training contract.
@@ -26,17 +27,8 @@ import os
 import sys
 from typing import Optional, Sequence
 
+from tpu_hpc.checks.roofline import peak_flops_for_device
 from tpu_hpc.models import llama2
-
-
-def peak_flops_per_chip(device) -> Optional[float]:
-    """Peak dense bf16 FLOP/s from the single spec table in
-    checks/roofline.py (shared with bench.py's training MFU). None
-    for unknown kinds: a CPU-sim "serving MFU" would be meaningless
-    noise, so the summary omits it instead."""
-    from tpu_hpc.checks.roofline import peak_flops_for_device
-
-    return peak_flops_for_device(device, default=None)
 
 
 def tiny_config(vocab_size: int = 512) -> llama2.LlamaConfig:
@@ -205,9 +197,9 @@ def run_replay(
                 last[0] = now
                 heartbeat.tick(step)
 
-    batcher.run(requests, tick=tick)
+    outputs = batcher.run(requests, tick=tick)
 
-    peak = peak_flops_per_chip(jax.devices()[0])
+    peak = peak_flops_for_device(jax.devices()[0])
     summary = meter.summary(
         n_devices=jax.device_count(),
         n_params=llama2.count_params(cfg),
@@ -246,6 +238,9 @@ def run_replay(
     # Close the replay's JSONL with the registry snapshot, mirroring
     # the Trainer's run_end discipline -- one schema, two producers.
     obs.get_registry().emit_snapshot(sink=metrics_path)
+    # The generated streams ride the RETURNED summary only (callers
+    # compare engines token for token); main() prints without them.
+    summary["outputs"] = outputs
     return summary
 
 
@@ -342,7 +337,7 @@ def run_loadgen(
                 heartbeat.tick(tick)
 
     harness.drive(tick_cb=tick_cb)
-    peak = peak_flops_per_chip(jax.devices()[0])
+    peak = peak_flops_for_device(jax.devices()[0])
     # kv_layout/hit-rate evidence rides in from harness.summarize()
     # itself (the harness owns the engine's identity either way).
     extra = dict(
@@ -914,6 +909,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         from tpu_hpc.runtime import sim
 
         sim.force_sim_devices(args.sim_devices)
+    from tpu_hpc.runtime import require_accelerator
+
+    require_accelerator()
 
     if args.model == "tiny":
         cfg = tiny_config(args.vocab)
@@ -1055,6 +1053,7 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             temperature=args.temperature or 0.0,
             top_p=args.top_p if args.top_p is not None else 1.0,
         )
+        del summary["outputs"]
     print(json.dumps(summary))
     return 0
 

@@ -61,6 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_hpc.kernels.paged_attention import pages_to_tokens, write_tokens
 from tpu_hpc.models import llama2
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.engine import (
@@ -357,14 +358,10 @@ def make_spec_draft_fn(
                 q, kk, v = _qkv(h, lp, cfg)
                 q = llama2.apply_rope(q, cos, sin)
                 kk = llama2.apply_rope(kk, cos, sin)
-                ks = ks.at[i, pb, off].set(kk[:, 0].astype(ks.dtype))
-                vs = vs.at[i, pb, off].set(v[:, 0].astype(vs.dtype))
-                k_view = ks[i][view_ids].reshape(
-                    slots, cache_cap, cfg.kv_heads, cfg.head_dim
-                )
-                v_view = vs[i][view_ids].reshape(
-                    slots, cache_cap, cfg.kv_heads, cfg.head_dim
-                )
+                ks = write_tokens(ks, i, pb, off, kk[:, 0])
+                vs = write_tokens(vs, i, pb, off, v[:, 0])
+                k_view = pages_to_tokens(ks[i][view_ids])
+                v_view = pages_to_tokens(vs[i][view_ids])
                 attn = _grouped_attention(
                     q, k_view.astype(cfg.dtype),
                     v_view.astype(cfg.dtype), mask, cfg,
@@ -463,14 +460,10 @@ def make_spec_verify_fn(
             q, kk, v = _qkv(h, lp, cfg)
             q = llama2.apply_rope(q, cos, sin)
             kk = llama2.apply_rope(kk, cos, sin)
-            ks = ks.at[i, pb, off].set(kk.astype(ks.dtype))
-            vs = vs.at[i, pb, off].set(v.astype(vs.dtype))
-            k_view = ks[i][view_ids].reshape(
-                slots, cache_cap, cfg.kv_heads, cfg.head_dim
-            )
-            v_view = vs[i][view_ids].reshape(
-                slots, cache_cap, cfg.kv_heads, cfg.head_dim
-            )
+            ks = write_tokens(ks, i, pb, off, kk)
+            vs = write_tokens(vs, i, pb, off, v)
+            k_view = pages_to_tokens(ks[i][view_ids])
+            v_view = pages_to_tokens(vs[i][view_ids])
             attn = _grouped_attention(
                 q, k_view.astype(cfg.dtype), v_view.astype(cfg.dtype),
                 mask, cfg,
@@ -752,7 +745,7 @@ class SpecRunner:
         inner = make_chunk_logits_fn(
             engine.cfg, bucket, engine.paged.block_size,
             engine.max_blocks_per_seq, engine.table_width,
-            kernel=engine.paged.kernel,
+            kernel=engine.paged.kernel, mesh=engine.mesh,
         )
 
         def spec_prefill(params, ks, vs, tokens, start, true_len,

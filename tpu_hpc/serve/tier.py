@@ -41,10 +41,8 @@ from typing import Any, List, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
 
 from tpu_hpc.obs import get_bus, get_registry, span
-from tpu_hpc.serve.disagg import _kv_rows_pspec
 from tpu_hpc.serve.paging import SCRATCH_BLOCK, BlockBudgetError
 
 
@@ -52,8 +50,8 @@ class HostTier:
     """Host-memory page tier attached to one :class:`PagedEngine`.
 
     Owns the host-side K/V buffers (numpy, ``[layers, host_blocks,
-    block_size, kv_heads, head_dim]`` mirroring the device pool's page
-    layout, slot 0 scratch like the device pool's block 0) and the two
+    *page]`` in the device pool's own page layout, slot 0 scratch like
+    the device pool's block 0) and the two
     AOT programs that move page groups across the HBM/DRAM boundary.
     All *accounting* lives on the engine's :class:`BlockAllocator` and
     :class:`PrefixTrie`; this class only moves bytes and keeps the
@@ -68,14 +66,14 @@ class HostTier:
         self.engine = engine
         c = engine.cfg
         bs = engine.paged.block_size
+        page = engine.ks.shape[2:]  # the pool's page layout, verbatim
         self.host_blocks = engine.paged.host_blocks
         dtype = np.dtype(jnp.dtype(engine.ks.dtype).name)
         # One K + one V host buffer, page-granular like the device
         # pool. Plain (pageable) numpy: the pinned-buffer upgrade is a
         # jax.device_put detail the transfer path already routes
         # through, not an accounting concern.
-        shape = (c.n_layers, self.host_blocks, bs, c.kv_heads,
-                 c.head_dim)
+        shape = (c.n_layers, self.host_blocks, *page)
         self._host_k = np.zeros(shape, dtype)
         self._host_v = np.zeros(shape, dtype)
         self.host_bytes = int(self._host_k.nbytes + self._host_v.nbytes)
@@ -96,7 +94,7 @@ class HostTier:
             )
         # One page's K (or V) leaf: the transfer-group unit.
         self._page_bytes = int(
-            c.n_layers * bs * c.kv_heads * c.head_dim * dtype.itemsize
+            c.n_layers * np.prod(page) * dtype.itemsize
         )
         # Bounded streams: group pages so one hop moves about
         # max_inflight_bytes. "auto" asks the topology cost tables for
@@ -119,11 +117,10 @@ class HostTier:
         self.group = max(
             1, min(max_group, self.max_inflight_bytes // self._page_bytes)
         )
-        self._rows_shape = (c.n_layers, self.group, bs, c.kv_heads,
-                            c.head_dim)
-        self._rows_sharding = NamedSharding(
-            engine.mesh, _kv_rows_pspec(engine.mesh, c.kv_heads)
-        )
+        self._rows_shape = (c.n_layers, self.group, *page)
+        # A page group is a slice of the pool along its block dim: it
+        # shards exactly as the pool does.
+        self._rows_sharding = engine._cache_sharding
         # The gather/scatter builders register in the ENGINE's
         # executable table: _build dispatches here, the shared
         # compile counter ticks, and the zero-recompile pins cover
