@@ -28,7 +28,11 @@ Any failed check ends the run non-zero; nothing turns a failure into a
 printed line. It exits non-zero before any of this unless JAX came up
 on a TPU (``runtime.require_accelerator``), and refuses to start where
 ``TPU_HPC_SIM_DEVICES`` would force the CPU. The last line of stdout is
-one JSON summary. Rates printed on the way are information, not claims.
+one JSON object, ``{"ok": true, "device": {"platform", "kind",
+"count"}}`` as JAX reports the device, and nothing else; the run's
+details (versions, cache hits, walls, per-phase numbers) are the
+``summary`` line before it and ``chiprun_out/chip_smoke/summary.json``.
+Rates printed on the way are information, not claims.
 
     python chip_smoke.py        # one chip or one four-chip host
 """
@@ -63,6 +67,14 @@ TOL_PAGED_INT8 = 3e-2
 OUT_DIR = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "chiprun_out", "chip_smoke"
 )
+
+
+def verdict_line(device: dict) -> str:
+    """The last line of stdout: these keys and no other."""
+    return json.dumps({
+        "ok": True,
+        "device": {k: device[k] for k in ("platform", "kind", "count")},
+    })
 
 
 def log(msg: str) -> None:
@@ -515,9 +527,9 @@ def main() -> int:
         phases[name] = run()
         walls[name] = round(time.perf_counter() - t0, 1)
         log(f"{name} ok in {walls[name]} s")
-    print(json.dumps({
-        "ok": True,
-        "device": device,
+    # The run's details go on the line before last and to a file; the
+    # LAST line is the verdict alone, exactly {"ok", "device"}.
+    summary = json.dumps({
         "versions": versions,
         "compile_cache": cache,
         "wall_s": {
@@ -525,7 +537,11 @@ def main() -> int:
         },
         "phases": phases,
         "claim": None,
-    }))
+    })
+    with open(os.path.join(OUT_DIR, "summary.json"), "w") as f:
+        f.write(summary + "\n")
+    log(f"summary {summary}")
+    print(verdict_line(device), flush=True)
     return 0
 
 

@@ -42,6 +42,26 @@ def test_chip_smoke_refuses_the_simulator():
     assert "{" not in proc.stdout
 
 
+def test_chip_smoke_verdict_line_has_the_contract_keys_and_no_other():
+    # The driver refuses any other key on the last stdout line.
+    import importlib.util
+    import json
+
+    spec = importlib.util.spec_from_file_location(
+        "chip_smoke", os.path.join(REPO, "chip_smoke.py")
+    )
+    smoke = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(smoke)  # no JAX at import
+    line = smoke.verdict_line(
+        {"platform": "tpu", "kind": "TPU v5 lite", "count": 1}
+    )
+    assert "\n" not in line
+    assert json.loads(line) == {
+        "ok": True,
+        "device": {"platform": "tpu", "kind": "TPU v5 lite", "count": 1},
+    }
+
+
 def test_require_accelerator_accepts_cpu_only_when_asked_by_name(
     monkeypatch,
 ):
