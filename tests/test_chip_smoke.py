@@ -83,13 +83,20 @@ def test_compile_cache_placed_from_outside_or_at_one_fixed_path(
         jax.config, "update", lambda k, v: seen.append((k, v))
     )
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    def placed():
+        return [v for k, v in seen if k == "jax_compilation_cache_dir"]
+
     assert distributed.compile_cache_dir() == "/elsewhere/cache"
-    assert seen == []  # set from outside: code sets no other
+    assert placed() == []  # set from outside: code sets no other
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
     fixed = os.path.join(REPO, ".jax_cache")
     assert distributed.compile_cache_dir() == fixed
     assert distributed.compile_cache_dir() == fixed  # never a temp name
-    assert seen == [("jax_compilation_cache_dir", fixed)] * 2
+    assert placed() == [fixed] * 2
+    # Wherever the cache lies, its key keeps the operations' names: a
+    # trace must not show the scopes of a build from before an edit.
+    assert [kv for kv in seen if kv[0] != "jax_compilation_cache_dir"] \
+        == [("jax_compilation_cache_include_metadata_in_key", True)] * 3
     ignored = open(os.path.join(REPO, ".gitignore")).read().split()
     assert ".jax_cache/" in ignored
 

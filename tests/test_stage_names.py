@@ -1,0 +1,362 @@
+"""The program's stage names (docs/guide/observability.md, "Stage
+names"): ``jax.named_scope`` names inside the decode, chunk-prefill and
+train-step programs, ``name=`` on every ``pallas_call``, ``tpu_hpc:``
+annotations from ``obs.span``, and the stage spans inside one serve
+tick, one engine call and one train chunk. All on the CPU at a tiny
+size: names and nesting, never a time.
+"""
+import json
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import optax
+import pytest
+from jax.sharding import PartitionSpec as P
+
+from tpu_hpc import obs
+from tpu_hpc.config import TrainingConfig
+from tpu_hpc.kernels import paged_attention as pa
+from tpu_hpc.kernels.attention import blockwise_attention
+from tpu_hpc.models import datasets, llama2
+from tpu_hpc.obs import schema as schema_mod
+from tpu_hpc.obs.events import EventBus, set_bus
+from tpu_hpc.parallel import tp
+from tpu_hpc.runtime import MeshSpec, build_mesh
+from tpu_hpc.serve import (
+    ContinuousBatcher,
+    PagedConfig,
+    PagedEngine,
+    Request,
+    ServeConfig,
+    paging,
+)
+from tpu_hpc.train import Trainer, trainer as trainer_mod
+
+TINY = llama2.LlamaConfig(
+    dim=64, n_layers=2, n_heads=4, n_kv_heads=2, vocab_size=128,
+    multiple_of=16, max_seq_len=64, dtype=jnp.float32,
+)
+SERVE = ServeConfig(slots=4, max_seq_len=48, prefill_buckets=(8, 16))
+BLOCK, PER_SEQ, WIDTH = 4, 12, 16
+
+SERVE_SCOPES = (
+    "embed", "qkv", "kv_write", "kv_read", "attention", "attn_out",
+    "mlp", "head",
+)
+TRAIN_SCOPES = (
+    "embed", "qkv", "attention", "attn_out", "mlp", "head", "optimizer",
+    "sp_constrain",
+)
+
+
+def _scopes_in(lowered) -> set:
+    """Every path component of every op's name in the lowered text."""
+    text = lowered.as_text(debug_info=True)
+    return {
+        part for loc in re.findall(r'loc\("([^"]+)"', text)
+        for part in loc.split("/")
+    }
+
+
+@pytest.fixture(scope="module")
+def tiny_abstract():
+    return jax.eval_shape(
+        lambda: llama2.init_llama(jax.random.key(0), TINY)
+    )
+
+
+@pytest.fixture(scope="module")
+def program_scopes(devices, tiny_abstract):
+    """The three programs' scope names, each lowered once."""
+    i32 = jnp.int32
+    cache = jax.ShapeDtypeStruct(
+        (TINY.n_layers, 48, TINY.kv_heads, BLOCK, TINY.head_dim),
+        jnp.float32,
+    )
+    vec = jax.ShapeDtypeStruct((SERVE.slots,), i32)
+    scalar = jax.ShapeDtypeStruct((), i32)
+    decode = jax.jit(paging.make_paged_decode_fn(
+        TINY, BLOCK, PER_SEQ, WIDTH
+    )).lower(
+        tiny_abstract, cache, cache, vec, vec,
+        jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32), vec,
+    )
+    prefill = jax.jit(paging.make_chunk_prefill_fn(
+        TINY, 8, BLOCK, PER_SEQ, WIDTH
+    )).lower(
+        tiny_abstract, cache, cache,
+        jax.ShapeDtypeStruct((1, 8), i32), scalar, scalar,
+        jax.ShapeDtypeStruct((WIDTH,), i32),
+    )
+    mesh = build_mesh(MeshSpec(axes={"data": 4, "model": 2}))
+    forward = llama2.make_forward(
+        TINY, tp.sp_constrain(mesh, dp_axis="data", sp_axis="model")
+    )
+    tx = optax.adamw(1e-3)
+    step = trainer_mod.make_step_fn(forward, tx, seed=0)
+    state = jax.eval_shape(
+        lambda p: trainer_mod.TrainState(
+            step=jnp.int32(0), params=p, opt_state=tx.init(p),
+            model_state={},
+        ),
+        tiny_abstract,
+    )
+    tokens = jax.ShapeDtypeStruct((4, 16), i32)
+    train = jax.jit(step).lower(state, (tokens, tokens))
+    return {
+        "decode": _scopes_in(decode),
+        "prefill": _scopes_in(prefill),
+        "train": _scopes_in(train),
+    }
+
+
+@pytest.mark.parametrize("program,scope", [
+    *(("decode", s) for s in SERVE_SCOPES),
+    *(("prefill", s) for s in SERVE_SCOPES),
+    *(("train", s) for s in TRAIN_SCOPES),
+])
+def test_program_carries_scope(program_scopes, program, scope):
+    assert scope in program_scopes[program]
+
+
+def test_training_has_no_kv_scopes(program_scopes):
+    assert not {"kv_write", "kv_read"} & program_scopes["train"]
+
+
+def _flash_jaxpr():
+    q = jnp.ones((1, 128, 2, 64), jnp.float32)
+
+    def loss(q, k, v):
+        return blockwise_attention(
+            q, k, v, causal=True, impl="pallas_interpret", block_q=64,
+            block_k=64,
+        )[0].sum()
+
+    return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
+
+
+def _paged_jaxpr(kernel):
+    slots, hkv, d, mb = 2, 2, 16, 4
+    pool = jnp.zeros((8, hkv, BLOCK, d), jnp.float32)
+    if kernel == "paged_decode":
+        return str(jax.make_jaxpr(
+            lambda q, k, v, t, p, a: pa.paged_decode_attention(
+                q, k, v, t, p, a, block_size=BLOCK, max_blocks=mb,
+                interpret=True,
+            )
+        )(
+            jnp.zeros((slots, hkv, 1, d)), pool, pool,
+            jnp.zeros((slots, mb + 2), jnp.int32),
+            jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), jnp.int32),
+        ))
+    return str(jax.make_jaxpr(
+        lambda q, k, v, t, s: pa.paged_prefill_attention(
+            q, k, v, t, s, block_size=BLOCK, max_blocks=mb,
+            interpret=True,
+        )
+    )(
+        jnp.zeros((hkv, 8, 1, d)), pool, pool,
+        jnp.zeros((mb + 2,), jnp.int32), jnp.int32(0),
+    ))
+
+
+@pytest.mark.parametrize("kernel", [
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
+    "paged_prefill",
+])
+def test_pallas_call_carries_its_name(kernel):
+    text = _flash_jaxpr() if kernel.startswith("flash") \
+        else _paged_jaxpr(kernel)
+    assert f"name={kernel}" in text
+
+
+@pytest.fixture
+def ring():
+    """A fresh bus with no sink: spans land in its ring only."""
+    bus = EventBus(path="", ring_size=4096)
+    prev = set_bus(bus)
+    yield bus
+    set_bus(prev)
+
+
+def _spans(bus, since=0):
+    return [r for r in bus.ring()[since:] if r["event"] == "span"]
+
+
+def test_span_annotates_under_the_programs_prefix(tmp_path, ring):
+    """One bracket, two names: the profiler sees ``tpu_hpc:<name>``,
+    the JSONL record keeps ``<name>``."""
+    from jax.profiler import ProfileData
+
+    sink = str(tmp_path / "run.jsonl")
+    jax.profiler.start_trace(str(tmp_path / "trace"))
+    try:
+        with obs.span("decode", sink=sink):
+            with obs.span("decode.prep", sink=sink):
+                pass
+    finally:
+        jax.profiler.stop_trace()
+    with open(sink) as f:
+        records = [json.loads(line) for line in f]
+    assert [r["name"] for r in records] == ["decode.prep", "decode"]
+    assert records[0]["parent"] == "decode" and records[0]["depth"] == 1
+    [path] = (tmp_path / "trace").rglob("*.xplane.pb")
+    names = {
+        ev.name
+        for plane in ProfileData.from_file(str(path)).planes
+        for line in plane.lines for ev in line.events
+    }
+    assert {"tpu_hpc:decode", "tpu_hpc:decode.prep"} <= names
+    assert "decode" not in names and "decode.prep" not in names
+
+
+def test_ring_only_spans_are_stamped_when_read(ring):
+    with obs.span("tick"):
+        pass
+    [rec] = _spans(ring)
+    assert rec["name"] == "tick" and rec["run_id"] == ring.run_id
+    schema_mod.validate_record(rec)
+
+
+def test_stage_spans_are_no_phases():
+    """Phase accounting looks through the stage spans: a phase under
+    nothing but stages is top-level, a stage span never is."""
+    span = {"event": "span", "dur_s": 1.0}
+    assert schema_mod.phase_depth(
+        {**span, "name": "decode", "parent": "tick", "depth": 1}
+    ) == 0
+    assert schema_mod.phase_depth(
+        {**span, "name": "ckpt", "parent": "chunk.host", "depth": 1}
+    ) == 0
+    assert schema_mod.phase_depth(
+        {**span, "name": "kv_transfer", "parent": "prefill", "depth": 3}
+    ) == 3
+    assert schema_mod.phase_depth({**span, "name": "tick"}) == 1
+    assert schema_mod.phase_depth({**span, "name": "compute"}) == 0
+
+
+@pytest.fixture(scope="module")
+def warm_engine(devices):
+    """A paged engine on one device, warmed up, with the registry's
+    compile counter read against its own."""
+    obs.get_registry().reset()
+    mesh = build_mesh(MeshSpec(axes={"data": 1}), jax.devices()[:1])
+    engine = PagedEngine(
+        llama2.init_llama(jax.random.key(0), TINY), TINY, SERVE, mesh,
+        PagedConfig(block_size=BLOCK, num_blocks=48, prefill_chunk=8),
+    )
+    engine.warmup()
+    return engine
+
+
+def _compiles():
+    return obs.get_registry().snapshot()["counters"].get(
+        "serve_compiles_total"
+    )
+
+
+def _requests(n, new=6):
+    return [
+        Request(rid=f"r{i}", prompt=list(range(1, 10 + i)),
+                max_new_tokens=new)
+        for i in range(n)
+    ]
+
+
+def test_tick_emits_its_stages_nested_and_in_order(warm_engine, ring):
+    batcher = ContinuousBatcher(warm_engine)
+    for req in _requests(2):
+        batcher.submit(req)
+    # Two 9- and 10-token prompts at chunk 8: two prefill ticks with
+    # nobody decoding yet (the early return), then decode ticks.
+    batcher.step()
+    early = _spans(ring)
+    assert early[-1]["name"] == "tick" and early[-1]["depth"] == 0
+    assert not any(s["name"] == "tick.emit" for s in early)
+    while not any(s.decoding for s in batcher.slots):
+        batcher.step()
+    since = len(ring.ring())
+    batcher.step()
+    tick = _spans(ring, since)
+    children = [s["name"] for s in tick if s.get("parent") == "tick"]
+    assert children == [
+        "tick.admission", "tick.admit", "tick.prefill", "decode",
+        "tick.emit",
+    ]
+    assert [
+        s["name"] for s in tick if s.get("parent") == "decode"
+    ] == ["decode.prep", "decode.dispatch", "decode.fetch"]
+    assert tick[-1]["name"] == "tick" and "parent" not in tick[-1]
+    by_name = {s["name"]: s for s in tick}
+    inside = sum(
+        by_name[n]["dur_s"] for n in children
+    )
+    assert inside <= by_name["tick"]["dur_s"]
+    # Phase accounting still sees the engine's decode at the top.
+    assert schema_mod.phase_depth(by_name["decode"]) == 0
+    batcher.run()
+
+
+def test_prefill_step_emits_its_stages(warm_engine, ring):
+    batcher = ContinuousBatcher(warm_engine)
+    # A prompt no earlier test left in the prefix trie: two chunks.
+    batcher.submit(Request(
+        rid="fresh", prompt=list(range(50, 62)), max_new_tokens=2
+    ))
+    batcher.step()
+    under = [
+        s["name"] for s in _spans(ring) if s.get("parent") == "prefill"
+    ]
+    assert under == ["prefill.prep", "prefill.dispatch"]
+    batcher.step()  # the prompt's last chunk: the first token's fetch
+    under = [
+        s["name"] for s in _spans(ring) if s.get("parent") == "prefill"
+    ]
+    assert under[2:] == ["prefill.prep", "prefill.dispatch", "prefill.fetch"]
+    batcher.run()
+
+
+def test_compile_counter_is_flat_after_warmup(warm_engine, ring):
+    assert _compiles() == warm_engine.compile_count_total > 0
+    batcher = ContinuousBatcher(warm_engine)
+    for req in _requests(3, new=12):
+        batcher.submit(req)
+    before = _compiles()
+    for _ in range(10):
+        batcher.step()
+    assert _compiles() == before == warm_engine.compile_count_total
+    batcher.run()
+
+
+def test_train_chunk_emits_its_stages(mesh8, ring):
+    cfg = TrainingConfig(
+        epochs=2, steps_per_epoch=2, global_batch_size=8,
+        metrics_path="",
+    )
+    small = llama2.LlamaConfig(
+        dim=32, n_layers=1, n_heads=2, n_kv_heads=2, vocab_size=64,
+        multiple_of=16, max_seq_len=16, dtype=jnp.float32,
+    )
+    trainer = Trainer(
+        cfg, mesh8, llama2.make_forward(small),
+        llama2.init_llama(jax.random.key(0), small),
+        batch_pspec=P("data"),
+    )
+    trainer.fit(datasets.TokenStream(vocab_size=64, seq_len=16))
+    stages = [
+        s["name"] for s in _spans(ring) if s["name"].startswith("chunk.")
+    ]
+    # chunk.host is the fit outside dispatch and fetch: its start,
+    # between chunks, its end.
+    assert stages == ["chunk.host"] + [
+        "chunk.dispatch", "chunk.fetch", "chunk.host",
+    ] * 2
+    compute = [s for s in _spans(ring) if s["name"] == "compute"]
+    assert len(compute) == 2
+    assert all(s["parent"] == "chunk.host" for s in compute)
+    assert all(schema_mod.phase_depth(s) == 0 for s in compute)
+    assert np.isfinite(trainer.fit(
+        datasets.TokenStream(vocab_size=64, seq_len=16), epochs=1
+    )["epochs"][-1]["total_s"])

@@ -326,6 +326,7 @@ def _flash_forward(
             pltpu.VMEM((block_q, 1), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_fwd",
     )(qo, ko, qt, kt, vt)
     out = out.reshape(b, h, sq_p, d).transpose(0, 2, 1, 3)[:, :sq]
     lse = lse.reshape(b, h, sq_p).transpose(0, 2, 1)[:, :sq]
@@ -606,6 +607,7 @@ def _flash_backward(
         out_shape=jax.ShapeDtypeStruct((b * h, sq_p, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
         interpret=interpret,
+        name="flash_bwd_dq",
     )(qo, ko, qt, kt, vt, dot, lse_t, dm_t)
 
     dk, dv = pl.pallas_call(
@@ -629,6 +631,7 @@ def _flash_backward(
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
         interpret=interpret,
+        name="flash_bwd_dkv",
     )(qo, ko, qt, kt, vt, dot, lse_t, dm_t)
 
     unflat = lambda x, sp, s: (

@@ -271,6 +271,7 @@ def paged_decode_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((slots, hkv, g, d), q.dtype),
         interpret=interpret,
+        name="paged_decode",
     )(*scalars, q, k_pages, v_pages)
 
 
@@ -426,6 +427,7 @@ def paged_prefill_attention(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((hkv, bucket, g, d), q.dtype),
         interpret=interpret,
+        name="paged_prefill",
     )(*scalars, q, k_pages, v_pages)
 
 

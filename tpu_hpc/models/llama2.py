@@ -361,30 +361,34 @@ class Attention(nn.Module):
         groups = cfg.n_heads // n_kv
         std = 0.02
 
-        q = _dense(cfg.n_heads * hd, std, cfg, "wq")(x)
-        k = _dense(n_kv * hd, std, cfg, "wk")(x)
-        v = _dense(n_kv * hd, std, cfg, "wv")(x)
+        with jax.named_scope("qkv"):
+            q = _dense(cfg.n_heads * hd, std, cfg, "wq")(x)
+            k = _dense(n_kv * hd, std, cfg, "wk")(x)
+            v = _dense(n_kv * hd, std, cfg, "wv")(x)
 
-        cos, sin = rope_cos_sin(s, hd, positions=positions)
-        q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), cos, sin)
-        k = apply_rope(k.reshape(b, s, n_kv, hd), cos, sin)
-        v = v.reshape(b, s, n_kv, hd)
+            cos, sin = rope_cos_sin(s, hd, positions=positions)
+            q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), cos, sin)
+            k = apply_rope(k.reshape(b, s, n_kv, hd), cos, sin)
+            v = v.reshape(b, s, n_kv, hd)
 
-        if self.attn_fn is not None:
-            out = self.attn_fn(q, k, v)
-        else:
-            # scores [B, Hkv, G, S, S], fp32 softmax, causal mask; GQA
-            # via a grouped query view -- no materialised repeat_kv.
-            q = q.reshape(b, s, n_kv, groups, hd)
-            scale = hd ** -0.5
-            scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) * scale
-            scores = scores.astype(jnp.float32)
-            causal = jnp.tril(jnp.ones((s, s), dtype=bool))
-            scores = jnp.where(causal, scores, -jnp.inf)
-            probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-            out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-        out = out.reshape(b, s, cfg.n_heads * hd)
-        return _dense(cfg.dim, self.out_std, cfg, "wo")(out)
+        with jax.named_scope("attention"):
+            if self.attn_fn is not None:
+                out = self.attn_fn(q, k, v)
+            else:
+                # scores [B, Hkv, G, S, S], fp32 softmax, causal mask;
+                # GQA via a grouped query view -- no materialised
+                # repeat_kv.
+                q = q.reshape(b, s, n_kv, groups, hd)
+                scale = hd ** -0.5
+                scores = jnp.einsum("bqhgd,bkhd->bhgqk", q, k) * scale
+                scores = scores.astype(jnp.float32)
+                causal = jnp.tril(jnp.ones((s, s), dtype=bool))
+                scores = jnp.where(causal, scores, -jnp.inf)
+                probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+                out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+        with jax.named_scope("attn_out"):
+            out = out.reshape(b, s, cfg.n_heads * hd)
+            return _dense(cfg.dim, self.out_std, cfg, "wo")(out)
 
 
 class FeedForward(nn.Module):
@@ -426,17 +430,22 @@ class TransformerBlock(nn.Module):
             self.layer_id + 1 if cfg.depth_init else cfg.n_layers
         )
         out_std = 0.02 / (2 * depth) ** 0.5
+        # Stage names shared with the serving programs
+        # (serve/paging.py), read off the profiler's trace by scope.
+        with jax.named_scope("qkv"):
+            normed = RMSNorm(
+                cfg.norm_eps, cfg.param_dtype, name="attention_norm"
+            )(x)
         h = x + self.constrain(
             Attention(cfg, out_std, self.attn_fn, name="attention")(
-                RMSNorm(cfg.norm_eps, cfg.param_dtype, name="attention_norm")(x),
-                positions,
+                normed, positions
             )
         )
-        return h + self.constrain(
-            FeedForward(cfg, out_std, name="feed_forward")(
+        with jax.named_scope("mlp"):
+            ffn = FeedForward(cfg, out_std, name="feed_forward")(
                 RMSNorm(cfg.norm_eps, cfg.param_dtype, name="ffn_norm")(h)
             )
-        )
+        return h + self.constrain(ffn)
 
 
 class Llama(nn.Module):
@@ -460,16 +469,17 @@ class Llama(nn.Module):
             embedding_init=nn.initializers.normal(stddev=1.0),
             name="tok_embeddings",
         )
-        if cfg.iota_embed:
-            # Gather forward, matmul backward (no scatter, no forward
-            # one-hot); values identical to emb(tokens) up to the
-            # compute-dtype cast.
-            lookup = _make_embed_lookup(
-                cfg.vocab_size, jnp.dtype(cfg.dtype).name
-            )
-            x = lookup(emb.embedding.astype(cfg.dtype), tokens)
-        else:
-            x = emb(tokens)
+        with jax.named_scope("embed"):
+            if cfg.iota_embed:
+                # Gather forward, matmul backward (no scatter, no
+                # forward one-hot); values identical to emb(tokens) up
+                # to the compute-dtype cast.
+                lookup = _make_embed_lookup(
+                    cfg.vocab_size, jnp.dtype(cfg.dtype).name
+                )
+                x = lookup(emb.embedding.astype(cfg.dtype), tokens)
+            else:
+                x = emb(tokens)
         x = self.constrain(x)
         block = TransformerBlock
         if cfg.remat:
@@ -478,15 +488,16 @@ class Llama(nn.Module):
             x = block(
                 cfg, i, self.constrain, self.attn_fn, name=f"layers_{i}"
             )(x, positions)
-        x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")(x)
-        logits = nn.Dense(
-            cfg.vocab_size,
-            use_bias=False,
-            dtype=cfg.dtype,
-            param_dtype=cfg.param_dtype,
-            kernel_init=nn.initializers.truncated_normal(stddev=0.02),
-            name="output",
-        )(x)
+        with jax.named_scope("head"):
+            x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")(x)
+            logits = nn.Dense(
+                cfg.vocab_size,
+                use_bias=False,
+                dtype=cfg.dtype,
+                param_dtype=cfg.param_dtype,
+                kernel_init=nn.initializers.truncated_normal(stddev=0.02),
+                name="output",
+            )(x)
         # Logits stay in compute dtype: the loss upcasts INSIDE its
         # reductions (losses.cross_entropy), so XLA fuses the fp32
         # cast instead of materialising a [B, S, V] fp32 array in HBM
@@ -543,6 +554,8 @@ def make_forward(
         logits = apply_llama(
             params, inputs, cfg, constrain, attn_fn, positions
         )
-        return cross_entropy(logits, targets), model_state, {}
+        with jax.named_scope("head"):
+            loss = cross_entropy(logits, targets)
+        return loss, model_state, {}
 
     return forward

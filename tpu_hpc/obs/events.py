@@ -168,6 +168,31 @@ class EventBus:
                     f.write(line + "\n")
         return rec
 
+    def emit_ring(self, record: dict) -> dict:
+        """Ring-only emit for hot paths (a span per engine call):
+        the caller's own fresh ``record`` goes to the flight recorder
+        with its wall time and ambient trace id, and gets the
+        provenance stamp when the ring is read -- no copy, no sink.
+        Never writes a file, whatever ``path`` says: callers with a
+        sink use :meth:`emit_record`."""
+        record["time"] = time.time()
+        if "trace_id" not in record:
+            tid = current_trace_id()
+            if tid is not None:
+                record["trace_id"] = tid
+        with self._lock:
+            self._ring.append(record)
+        return record
+
+    def _stamped(self, records) -> list:
+        """Ring records as every reader expects them: fully stamped
+        (a no-op copy for those :meth:`emit_record` stamped)."""
+        host, pid = _host(), os.getpid()
+        return [
+            stamp(r, run_id=self.run_id, host=host, pid=pid)
+            for r in records
+        ]
+
     # -- flight recorder -----------------------------------------------
     def ring(
         self, lock_timeout: Optional[float] = None
@@ -184,11 +209,12 @@ class EventBus:
             acquired = self._lock.acquire(timeout=lock_timeout)
         if acquired:
             try:
-                return list(self._ring)
+                snapshot = list(self._ring)
             finally:
                 self._lock.release()
+            return self._stamped(snapshot)
         try:
-            return list(self._ring)
+            return self._stamped(list(self._ring))
         except RuntimeError:  # pragma: no cover - mutated mid-copy
             return []
 

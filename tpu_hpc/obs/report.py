@@ -38,6 +38,7 @@ from tpu_hpc.obs.schema import (  # noqa: F401
     SCHEMA_VERSION,
     SchemaError,
     load_records,
+    phase_depth,
 )
 # (load_records re-exported: the schema module owns the one
 # parse-and-validate loop; the report is just its largest consumer.)
@@ -64,7 +65,7 @@ def _phase_breakdown(records: Sequence[dict]) -> Dict[str, dict]:
         e = by.setdefault(s["name"], {"total_s": 0.0, "count": 0})
         e["total_s"] += float(s["dur_s"])
         e["count"] += 1
-        if not s.get("depth"):
+        if not phase_depth(s):
             total += float(s["dur_s"])
     for e in by.values():
         e["share"] = e["total_s"] / total if total > 0 else 0.0

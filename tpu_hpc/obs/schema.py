@@ -81,6 +81,48 @@ SPANS: Dict[str, str] = {
     "warmup": "AOT executable-table warmup",
 }
 
+# Stage spans: WHERE inside one serve tick or one train chunk the host
+# is, on the profiler's clock (``tpu_hpc:<name>`` annotations) as well
+# as in the flight ring. They are a second, finer cut of time the
+# phase spans above already account for, so phase accounting (the
+# report's shares, the critical-path analyzer) skips them and looks
+# through them: :func:`phase_depth`. One vocabulary with the device
+# side's ``jax.named_scope`` names (docs/guide/observability.md,
+# "Stage names").
+STAGE_SPANS: Dict[str, str] = {
+    "tick": "one ContinuousBatcher.step(), every way out of it",
+    "tick.admission": "admission control: shedding and class policy",
+    "tick.admit": "the admit loop: seat queued requests in free slots",
+    "tick.prefill": "advance every prefilling slot by one chunk",
+    "tick.emit": "per-slot token loop: results, meter, eviction",
+    "decode.prep": "copy-on-write guard, executable lookup, host to "
+                   "device transfers of tokens, positions, tables",
+    "decode.dispatch": "the decode executable's call (async enqueue)",
+    "decode.fetch": "wait for and copy back the step's tokens",
+    "prefill.prep": "pad the chunk, executable lookup, transfers",
+    "prefill.dispatch": "the chunk executable's call (async enqueue)",
+    "prefill.fetch": "the final chunk's first-token fetch",
+    "chunk.dispatch": "the scanned chunk's call, or the per-step loop",
+    "chunk.fetch": "the one device_get a chunk (the chunk barrier)",
+    "chunk.host": "a fit outside dispatch and fetch: from a fetch's "
+                  "return to the next dispatch (stall watermark, "
+                  "registry, heartbeat, digest, JSONL, guard, "
+                  "checkpoint), and the fit's start and end",
+}
+SPANS.update(STAGE_SPANS)
+
+
+def phase_depth(record: Mapping) -> int:
+    """A ``span`` record's depth for phase accounting: 0 for a phase
+    span with nothing but stage spans above it, its own ``depth``
+    otherwise (and never 0 for a stage span: it is no phase)."""
+    depth = int(record.get("depth") or 0)
+    if record.get("name") in STAGE_SPANS:
+        return max(depth, 1)
+    if record.get("parent") in STAGE_SPANS:
+        return 0
+    return depth
+
 
 class SchemaError(ValueError):
     """A record violates the telemetry schema."""

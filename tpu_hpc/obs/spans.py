@@ -2,11 +2,18 @@
 
 ``with span("ckpt"): ...`` measures a wall-clock duration, emits a
 ``span`` event through the bus, and (by default) opens a
-``jax.profiler.TraceAnnotation`` of the same name -- so the phase
-boundaries in a run's JSONL and the named regions in an XProf trace
-are the SAME brackets, not two instrumentation layers that drift.
-Spans nest: each event carries its ``parent`` span name and depth, so
-the report can attribute child time without double counting.
+``jax.profiler.TraceAnnotation`` named ``tpu_hpc:ckpt`` -- so the
+phase boundaries in a run's JSONL and the named regions in an XProf
+trace are the SAME brackets, not two instrumentation layers that
+drift. The ``tpu_hpc:`` prefix is the program's namespace in a trace:
+a reader keeps the events whose name starts with it and thereby tells
+the program's stages from the runtime's own host events (the JSONL
+name stays bare; ``obs/trace.py`` reads ``decode`` and ``prefill``).
+Tracing has no switch of its own: an annotation is recorded while a
+profiler session is on and costs a fraction of a microsecond while
+none is. Spans nest: each event carries its ``parent`` span name and
+depth, so the report can attribute child time without double counting.
+The stage names in use are listed in docs/guide/observability.md.
 
 Clock contract (pinned in tests/test_trace.py): **durations come from
 the monotonic clock** (``time.perf_counter``), never wall time -- an
@@ -20,15 +27,21 @@ alignment between hosts.
 For phases whose duration is measured some other way (the Trainer's
 chunk timer already brackets dispatch-to-fetch), :func:`emit_span`
 records a pre-aggregated duration without re-timing it.
+
+Cost: a span with no JSONL sink is a few microseconds (measured in
+PERF.md): the record goes to the flight ring with its wall time and
+gets its provenance stamp when the ring is read
+(``EventBus.emit_ring``), because the hot paths open several a tick.
 """
 from __future__ import annotations
 
-import contextlib
 import threading
 import time
-from typing import Iterator, Optional
+from typing import Optional
 
 from tpu_hpc.obs.events import EventBus, get_bus
+
+TRACE_PREFIX = "tpu_hpc:"
 
 _stack = threading.local()
 
@@ -38,6 +51,36 @@ def _current_stack() -> list:
     if st is None:
         st = _stack.names = []
     return st
+
+
+def _record(name: str, dur_s: float, step, fields: dict) -> dict:
+    st = _current_stack()
+    rec = {
+        "event": "span",
+        "name": name,
+        "dur_s": dur_s,
+        "t_mono": time.perf_counter(),
+        "depth": len(st),
+    }
+    if step is not None:
+        rec["step"] = step
+    if st:
+        rec["parent"] = st[-1]
+    for key, value in fields.items():
+        if value is not None:
+            rec[key] = value
+    return rec
+
+
+def _emit(rec: dict, bus: Optional[EventBus], sink, hist) -> dict:
+    if hist is not None:
+        from tpu_hpc.obs.registry import get_registry
+
+        get_registry().observe(hist, rec["dur_s"])
+    bus = bus or get_bus()
+    if sink or bus.path:
+        return bus.emit_record(rec, sink=sink)
+    return bus.emit_ring(rec)
 
 
 def emit_span(
@@ -53,61 +96,72 @@ def emit_span(
     """Emit one ``span`` record for an already-measured duration.
     ``hist`` additionally observes the duration into the global
     metrics registry under that histogram name."""
-    if hist is not None:
-        from tpu_hpc.obs.registry import get_registry
-
-        get_registry().observe(hist, dur_s)
-    st = _current_stack()
-    return (bus or get_bus()).emit(
-        "span",
-        sink=sink,
-        name=name,
-        dur_s=dur_s,
-        t_mono=time.perf_counter(),
-        step=step,
-        parent=st[-1] if st else None,
-        depth=len(st),
-        **fields,
-    )
+    return _emit(_record(name, dur_s, step, fields), bus, sink, hist)
 
 
-@contextlib.contextmanager
-def span(
-    name: str,
-    *,
-    bus: Optional[EventBus] = None,
-    sink: Optional[str] = None,
-    step: Optional[int] = None,
-    annotate: bool = True,
-    hist: Optional[str] = None,
-    **fields,
-) -> Iterator[None]:
-    """Time a block as a named span.
+# jax.profiler.TraceAnnotation, resolved on first use (importing jax
+# here would make ``import tpu_hpc.obs`` pull it in); False where jax
+# or its profiler cannot be had.
+_TraceAnnotation = None
 
-    Emits the ``span`` event in a ``finally`` (an exception inside the
-    block still records the phase and its duration -- the flight
-    recorder wants exactly the event that preceded the crash).
-    ``annotate=False`` skips the profiler annotation for spans on
-    paths where jax may not be initialized yet.
-    """
-    ann = contextlib.nullcontext()
-    if annotate:
+
+def _annotation(name: str):
+    global _TraceAnnotation
+    if _TraceAnnotation is None:
         try:
             import jax
 
-            ann = jax.profiler.TraceAnnotation(name)
+            _TraceAnnotation = jax.profiler.TraceAnnotation
         except Exception:  # pragma: no cover - profiler unavailable
-            pass
-    st = _current_stack()
-    st.append(name)
-    t0 = time.perf_counter()
-    try:
-        with ann:
-            yield
-    finally:
-        dur = time.perf_counter() - t0
-        st.pop()
-        emit_span(
-            name, dur, bus=bus, sink=sink, step=step, hist=hist,
-            **fields,
+            _TraceAnnotation = False
+    if _TraceAnnotation is False:  # pragma: no cover
+        return None
+    return _TraceAnnotation(TRACE_PREFIX + name)
+
+
+class span:
+    """Time a block as a named span (a context manager).
+
+    Emits the ``span`` event on the way out whether or not the block
+    raised (an exception inside still records the phase and its
+    duration -- the flight recorder wants exactly the event that
+    preceded the crash). ``annotate=False`` skips the profiler
+    annotation for spans on paths where jax may not be initialized
+    yet.
+    """
+
+    __slots__ = (
+        "name", "bus", "sink", "step", "hist", "fields", "_ann", "_t0",
+    )
+
+    def __init__(
+        self,
+        name: str,
+        *,
+        bus: Optional[EventBus] = None,
+        sink: Optional[str] = None,
+        step: Optional[int] = None,
+        annotate: bool = True,
+        hist: Optional[str] = None,
+        **fields,
+    ):
+        self.name, self.bus, self.sink = name, bus, sink
+        self.step, self.hist, self.fields = step, hist, fields
+        self._ann = _annotation(name) if annotate else None
+
+    def __enter__(self) -> None:
+        _current_stack().append(self.name)
+        if self._ann is not None:
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+
+    def __exit__(self, *exc) -> bool:
+        dur = time.perf_counter() - self._t0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _current_stack().pop()
+        _emit(
+            _record(self.name, dur, self.step, self.fields),
+            self.bus, self.sink, self.hist,
         )
+        return False

@@ -62,6 +62,7 @@ from tpu_hpc.obs.schema import (
     SCHEMA_VERSION,
     SchemaError,
     load_records,
+    phase_depth,
 )
 
 # Trace kinds with a meaning the analyzer knows how to reconstruct.
@@ -514,7 +515,7 @@ def build_traces(records: Sequence[dict]) -> dict:
             elif event == "span":
                 rt.spans.append((
                     r["name"], 1e3 * float(r["dur_s"]),
-                    int(r.get("depth") or 0),
+                    phase_depth(r),
                 ))
         elif kind in (KIND_STEP, KIND_TICK):
             st = steps.get(tid)
@@ -527,7 +528,7 @@ def build_traces(records: Sequence[dict]) -> dict:
             if event == "span":
                 st.spans.append((
                     r["name"], 1e3 * float(r["dur_s"]),
-                    int(r.get("depth") or 0),
+                    phase_depth(r),
                 ))
             elif event == "stall":
                 st.stalls += 1
@@ -622,7 +623,7 @@ def _analyze_requests(requests: Dict[str, RequestTrace],
             if (
                 r.get("event") == "span"
                 and r.get("name") in _DECODE_SIDE_SPANS
-                and not r.get("depth")
+                and not phase_depth(r)
             ):
                 decode_spans[r["name"]] = (
                     decode_spans.get(r["name"], 0.0)

@@ -94,7 +94,10 @@ def sp_constrain(
 
     def constrain(x: jax.Array) -> jax.Array:
         if x.ndim == 3:
-            return jax.lax.with_sharding_constraint(x, spec)
+            # The scope names the constraint's own resharding in a
+            # trace (docs/guide/observability.md, "Stage names").
+            with jax.named_scope("sp_constrain"):
+                return jax.lax.with_sharding_constraint(x, spec)
         return x
 
     return constrain

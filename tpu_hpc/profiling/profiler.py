@@ -12,8 +12,9 @@ HLO cost analysis viewable in TensorBoard/XProf or Perfetto -- the
 comm-vs-compute diagnosis workflow the reference docs prescribe
 (docs/guide/troubleshooting.md:230-239) works identically: look for
 all-reduce/all-gather ops overlapping (good) or serializing (bad) with
-the matmul stream. ``StepTraceAnnotation`` marks step boundaries so
-XProf computes per-step breakdowns.
+the matmul stream. Step boundaries and the stages inside a chunk are
+the program's own ``tpu_hpc:chunk.*`` annotations (obs/spans.py), which
+land in any trace this opens.
 """
 from __future__ import annotations
 
@@ -63,13 +64,6 @@ class TrainingProfiler:
             )
         elif self.active and step >= self.start_step + self.num_steps:
             self.stop()
-
-    def annotate(self, step: int):
-        """Step boundary marker for XProf per-step breakdowns; use as
-        ``with prof.annotate(step): train_step(...)``."""
-        if self.active:
-            return jax.profiler.StepTraceAnnotation("train", step_num=step)
-        return contextlib.nullcontext()
 
     def stop(self) -> None:
         """Close an open trace. ``active`` is cleared even when

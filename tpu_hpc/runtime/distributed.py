@@ -226,12 +226,20 @@ def compile_cache_dir() -> str:
     """Place JAX's persistent compilation cache and return the
     directory in use. Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX
     reads it itself and this sets nothing; otherwise the cache goes to
-    the one fixed path above. Call before the first compile."""
+    the one fixed path above. Call before the first compile.
+
+    Either way the cache key keeps each operation's metadata (its
+    ``jax.named_scope`` path and source line). JAX strips them by
+    default, and an executable compiled before a scope was named, or
+    by another checkout, would then be loaded with ITS names: a
+    profiler trace would show stages the program no longer has. The
+    price is a cold compile after any edit that moves a traced line."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", _COMPILE_CACHE_DIR)
     return _COMPILE_CACHE_DIR
 

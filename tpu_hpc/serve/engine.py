@@ -339,6 +339,11 @@ class Engine:
             "One batched decode step across all slots, dispatch to "
             "token fetch (s)",
         )
+        reg.describe(
+            "serve_compiles_total",
+            "Executable builds by this process's engines (flat after "
+            "warm-up, or the server is recompiling)",
+        )
 
         self._init_cache()
 
@@ -383,9 +388,16 @@ class Engine:
             self.ks.shape, self.ks.dtype, sharding=self._cache_sharding
         )
 
+    def _count_compile(self) -> None:
+        """One executable build: on the engine, and in the registry
+        (``serve_compiles_total``, every engine of the process) so an
+        operator sees a recompile on a dashboard, not in a debugger."""
+        self.compile_count += 1
+        get_registry().inc("serve_compiles_total")
+
     def _build(self, key):
         """Lower-and-compile one program shape (counted)."""
-        self.compile_count += 1
+        self._count_compile()
         cache = self._cache_abstract()
         params_abs = jax.tree.map(
             lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),

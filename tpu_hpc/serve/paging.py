@@ -818,7 +818,9 @@ def make_chunk_logits_fn(
         )
 
     def body(params, ks, vs, ksc, vsc, tokens, start, true_len, table):
-        x = _embed(params, tokens, cfg)
+        scope = jax.named_scope
+        with scope("embed"):
+            x = _embed(params, tokens, cfg)
         qpos = start + jnp.arange(bucket)
         cos, sin = llama2.rope_cos_sin(
             bucket, cfg.head_dim, positions=qpos
@@ -831,57 +833,67 @@ def make_chunk_logits_fn(
         view_ids = table[:max_blocks]
         for i in range(cfg.n_layers):
             lp = params[f"layers_{i}"]
-            h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
-            q, k, v = _qkv(h, lp, cfg)
-            q = llama2.apply_rope(q, cos, sin)
-            k = llama2.apply_rope(k, cos, sin)
-            kb = tokens_to_pages(k[0], block_size)
-            vb = tokens_to_pages(v[0], block_size)
-            if quant:
-                kq, k_sc = quantize_pages_int8(kb)
-                vq, v_sc = quantize_pages_int8(vb)
-                ks = ks.at[i, blk_ids].set(kq)
-                vs = vs.at[i, blk_ids].set(vq)
-                ksc = ksc.at[i, blk_ids].set(k_sc)
-                vsc = vsc.at[i, blk_ids].set(v_sc)
-            else:
-                ks = ks.at[i, blk_ids].set(kb.astype(ks.dtype))
-                vs = vs.at[i, blk_ids].set(vb.astype(vs.dtype))
-            if use_pallas:
-                qp = q[0].astype(cfg.dtype).reshape(
-                    bucket, cfg.kv_heads, groups, cfg.head_dim
-                ).transpose(1, 0, 2, 3)
-                ctx = prefill_attention(
-                    qp, ks[i], vs[i], table, start,
-                    k_scale=ksc[i] if quant else None,
-                    v_scale=vsc[i] if quant else None,
+            with scope("qkv"):
+                h = _rmsnorm(
+                    x, lp["attention_norm"]["scale"], cfg.norm_eps
                 )
-                attn = ctx.transpose(1, 0, 2, 3).reshape(
-                    1, bucket, cfg.n_heads, cfg.head_dim
-                )
-            else:
-                k_view = ks[i][view_ids]
-                v_view = vs[i][view_ids]
+                q, k, v = _qkv(h, lp, cfg)
+                q = llama2.apply_rope(q, cos, sin)
+                k = llama2.apply_rope(k, cos, sin)
+            with scope("kv_write"):
+                kb = tokens_to_pages(k[0], block_size)
+                vb = tokens_to_pages(v[0], block_size)
                 if quant:
-                    k_view = dequantize_pages_int8(
-                        k_view, ksc[i][view_ids]
+                    kq, k_sc = quantize_pages_int8(kb)
+                    vq, v_sc = quantize_pages_int8(vb)
+                    ks = ks.at[i, blk_ids].set(kq)
+                    vs = vs.at[i, blk_ids].set(vq)
+                    ksc = ksc.at[i, blk_ids].set(k_sc)
+                    vsc = vsc.at[i, blk_ids].set(v_sc)
+                else:
+                    ks = ks.at[i, blk_ids].set(kb.astype(ks.dtype))
+                    vs = vs.at[i, blk_ids].set(vb.astype(vs.dtype))
+            if use_pallas:
+                with scope("kv_read"):
+                    qp = q[0].astype(cfg.dtype).reshape(
+                        bucket, cfg.kv_heads, groups, cfg.head_dim
+                    ).transpose(1, 0, 2, 3)
+                    ctx = prefill_attention(
+                        qp, ks[i], vs[i], table, start,
+                        k_scale=ksc[i] if quant else None,
+                        v_scale=vsc[i] if quant else None,
                     )
-                    v_view = dequantize_pages_int8(
-                        v_view, vsc[i][view_ids]
+                    attn = ctx.transpose(1, 0, 2, 3).reshape(
+                        1, bucket, cfg.n_heads, cfg.head_dim
                     )
-                k_view = pages_to_tokens(k_view)[None]
-                v_view = pages_to_tokens(v_view)[None]
-                attn = _grouped_attention(
-                    q, k_view.astype(cfg.dtype),
-                    v_view.astype(cfg.dtype), mask, cfg,
-                )
-            x = x + _attn_out_proj(attn, lp, cfg)
-            h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-            x = x + _mlp(h, lp, cfg)
-        last = jax.lax.dynamic_slice(
-            x, (0, true_len - 1, 0), (1, 1, cfg.dim)
-        )
-        logits = _logits_head(last, params, cfg)
+            else:
+                with scope("kv_read"):
+                    k_view = ks[i][view_ids]
+                    v_view = vs[i][view_ids]
+                    if quant:
+                        k_view = dequantize_pages_int8(
+                            k_view, ksc[i][view_ids]
+                        )
+                        v_view = dequantize_pages_int8(
+                            v_view, vsc[i][view_ids]
+                        )
+                    k_view = pages_to_tokens(k_view)[None]
+                    v_view = pages_to_tokens(v_view)[None]
+                with scope("attention"):
+                    attn = _grouped_attention(
+                        q, k_view.astype(cfg.dtype),
+                        v_view.astype(cfg.dtype), mask, cfg,
+                    )
+            with scope("attn_out"):
+                x = x + _attn_out_proj(attn, lp, cfg)
+            with scope("mlp"):
+                h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
+                x = x + _mlp(h, lp, cfg)
+        with scope("head"):
+            last = jax.lax.dynamic_slice(
+                x, (0, true_len - 1, 0), (1, 1, cfg.dim)
+            )
+            logits = _logits_head(last, params, cfg)
         return ks, vs, ksc, vsc, logits[0, 0]
 
     if quant:
@@ -926,7 +938,8 @@ def make_chunk_prefill_fn(
                 params, ks, vs, ksc, vsc, tokens, start, true_len,
                 table,
             )
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            with jax.named_scope("head"):
+                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return ks, vs, ksc, vsc, tok
 
         return chunk_prefill_q
@@ -935,7 +948,9 @@ def make_chunk_prefill_fn(
         ks, vs, logits = inner(
             params, ks, vs, tokens, start, true_len, table
         )
-        return ks, vs, jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        with jax.named_scope("head"):
+            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        return ks, vs, tok
 
     return chunk_prefill
 
@@ -987,8 +1002,10 @@ def make_paged_decode_fn(
         )
 
     def body(params, ks, vs, ksc, vsc, tokens, pos, tables, active):
+        scope = jax.named_scope
         slots = tokens.shape[0]
-        x = _embed(params, tokens[:, None], cfg)
+        with scope("embed"):
+            x = _embed(params, tokens[:, None], cfg)
         cos, sin = llama2.rope_cos_sin(
             1, cfg.head_dim, positions=pos
         )
@@ -1008,63 +1025,77 @@ def make_paged_decode_fn(
         written = (idx[None, :] <= off[:, None])[:, None, :, None]
         for i in range(cfg.n_layers):
             lp = params[f"layers_{i}"]
-            h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
-            q, k, v = _qkv(h, lp, cfg)
-            q = llama2.apply_rope(q, cos, sin)
-            k = llama2.apply_rope(k, cos, sin)
-            if quant:
-                k_page = dequantize_pages_int8(ks[i, pb], ksc[i, pb])
-                v_page = dequantize_pages_int8(vs[i, pb], vsc[i, pb])
-                k_page = k_page.at[rows, :, off].set(
-                    k[:, 0].astype(jnp.float32)
+            with scope("qkv"):
+                h = _rmsnorm(
+                    x, lp["attention_norm"]["scale"], cfg.norm_eps
                 )
-                v_page = v_page.at[rows, :, off].set(
-                    v[:, 0].astype(jnp.float32)
-                )
-                k_page = jnp.where(written, k_page, 0.0)
-                v_page = jnp.where(written, v_page, 0.0)
-                kq, k_sc = quantize_pages_int8(k_page)
-                vq, v_sc = quantize_pages_int8(v_page)
-                ks = ks.at[i, pb].set(kq)
-                vs = vs.at[i, pb].set(vq)
-                ksc = ksc.at[i, pb].set(k_sc)
-                vsc = vsc.at[i, pb].set(v_sc)
-            else:
-                ks = write_tokens(ks, i, pb, off, k[:, 0])
-                vs = write_tokens(vs, i, pb, off, v[:, 0])
-            if use_pallas:
-                qd = q[:, 0].astype(cfg.dtype).reshape(
-                    slots, cfg.kv_heads, groups, cfg.head_dim
-                )
-                ctx = decode_attention(
-                    qd, ks[i], vs[i], tables, pos, active,
-                    k_scale=ksc[i] if quant else None,
-                    v_scale=vsc[i] if quant else None,
-                )
-                attn = ctx.reshape(
-                    slots, 1, cfg.n_heads, cfg.head_dim
-                )
-            else:
-                k_view = ks[i][view_ids]
-                v_view = vs[i][view_ids]
+                q, k, v = _qkv(h, lp, cfg)
+                q = llama2.apply_rope(q, cos, sin)
+                k = llama2.apply_rope(k, cos, sin)
+            with scope("kv_write"):
                 if quant:
-                    k_view = dequantize_pages_int8(
-                        k_view, ksc[i][view_ids]
+                    k_page = dequantize_pages_int8(
+                        ks[i, pb], ksc[i, pb]
                     )
-                    v_view = dequantize_pages_int8(
-                        v_view, vsc[i][view_ids]
+                    v_page = dequantize_pages_int8(
+                        vs[i, pb], vsc[i, pb]
                     )
-                k_view = pages_to_tokens(k_view)
-                v_view = pages_to_tokens(v_view)
-                attn = _grouped_attention(
-                    q, k_view.astype(cfg.dtype),
-                    v_view.astype(cfg.dtype), mask, cfg,
-                )
-            x = x + _attn_out_proj(attn, lp, cfg)
-            h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-            x = x + _mlp(h, lp, cfg)
-        logits = _logits_head(x, params, cfg)
-        tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+                    k_page = k_page.at[rows, :, off].set(
+                        k[:, 0].astype(jnp.float32)
+                    )
+                    v_page = v_page.at[rows, :, off].set(
+                        v[:, 0].astype(jnp.float32)
+                    )
+                    k_page = jnp.where(written, k_page, 0.0)
+                    v_page = jnp.where(written, v_page, 0.0)
+                    kq, k_sc = quantize_pages_int8(k_page)
+                    vq, v_sc = quantize_pages_int8(v_page)
+                    ks = ks.at[i, pb].set(kq)
+                    vs = vs.at[i, pb].set(vq)
+                    ksc = ksc.at[i, pb].set(k_sc)
+                    vsc = vsc.at[i, pb].set(v_sc)
+                else:
+                    ks = write_tokens(ks, i, pb, off, k[:, 0])
+                    vs = write_tokens(vs, i, pb, off, v[:, 0])
+            if use_pallas:
+                with scope("kv_read"):
+                    qd = q[:, 0].astype(cfg.dtype).reshape(
+                        slots, cfg.kv_heads, groups, cfg.head_dim
+                    )
+                    ctx = decode_attention(
+                        qd, ks[i], vs[i], tables, pos, active,
+                        k_scale=ksc[i] if quant else None,
+                        v_scale=vsc[i] if quant else None,
+                    )
+                    attn = ctx.reshape(
+                        slots, 1, cfg.n_heads, cfg.head_dim
+                    )
+            else:
+                with scope("kv_read"):
+                    k_view = ks[i][view_ids]
+                    v_view = vs[i][view_ids]
+                    if quant:
+                        k_view = dequantize_pages_int8(
+                            k_view, ksc[i][view_ids]
+                        )
+                        v_view = dequantize_pages_int8(
+                            v_view, vsc[i][view_ids]
+                        )
+                    k_view = pages_to_tokens(k_view)
+                    v_view = pages_to_tokens(v_view)
+                with scope("attention"):
+                    attn = _grouped_attention(
+                        q, k_view.astype(cfg.dtype),
+                        v_view.astype(cfg.dtype), mask, cfg,
+                    )
+            with scope("attn_out"):
+                x = x + _attn_out_proj(attn, lp, cfg)
+            with scope("mlp"):
+                h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
+                x = x + _mlp(h, lp, cfg)
+        with scope("head"):
+            logits = _logits_head(x, params, cfg)
+            tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
         return ks, vs, ksc, vsc, tok
 
     if quant:
@@ -1322,7 +1353,7 @@ class PagedEngine(Engine):
 
     # -- executable table ----------------------------------------------
     def _build(self, key):
-        self.compile_count += 1
+        self._count_compile()
         # Speculative programs (spec_verify / spec_draft /
         # spec_prefill) are built by the attached SpecRunner against
         # THIS engine's cache and param abstracts -- same table, same
@@ -1679,43 +1710,47 @@ class PagedEngine(Engine):
         if st.next_chunk >= len(st.plan):
             raise ValueError(f"slot {slot} has no prefill pending")
         start, run, bucket = st.plan[st.next_chunk]
-        padded = np.zeros((1, bucket), np.int32)
-        padded[0, :run] = st.prompt[start:start + run]
         quant = self.paged.kv_quant == "int8"
-        state = [self.ks, self.vs] + (
-            [self.k_scales, self.v_scales] if quant else []
-        )
-        args = [self.params, *state,
-            self._rep_arr(padded), self._rep_arr(start),
-            self._rep_arr(run),
-            self._rep_arr(self._tables[slot]),
-        ]
-        if self.spec is not None:
-            # The sampled prefill variant: same layer loop, seeded
-            # temperature/top-p first-token head (only the final
-            # chunk's token is consumed). Greedy requests (temp 0)
-            # get exactly the argmax token -- the oracle's contract.
-            exec_ = self._get_exec(("spec_prefill", bucket))
-            args += [
-                self._rep_arr(st.seed),
-                self._rep_arr(st.temperature, jnp.float32),
-                self._rep_arr(st.top_p, jnp.float32),
-            ]
-        else:
-            exec_ = self._get_exec(("prefill", bucket))
         with span("prefill", hist="serve_prefill_s", n=bucket):
-            if quant:
-                (self.ks, self.vs, self.k_scales, self.v_scales,
-                 tok) = exec_(*args)
-            else:
-                self.ks, self.vs, tok = exec_(*args)
+            with span("prefill.prep"):
+                padded = np.zeros((1, bucket), np.int32)
+                padded[0, :run] = st.prompt[start:start + run]
+                state = [self.ks, self.vs] + (
+                    [self.k_scales, self.v_scales] if quant else []
+                )
+                args = [self.params, *state,
+                    self._rep_arr(padded), self._rep_arr(start),
+                    self._rep_arr(run),
+                    self._rep_arr(self._tables[slot]),
+                ]
+                if self.spec is not None:
+                    # The sampled prefill variant: same layer loop,
+                    # seeded temperature/top-p first-token head (only
+                    # the final chunk's token is consumed). Greedy
+                    # requests (temp 0) get exactly the argmax token
+                    # -- the oracle's contract.
+                    exec_ = self._get_exec(("spec_prefill", bucket))
+                    args += [
+                        self._rep_arr(st.seed),
+                        self._rep_arr(st.temperature, jnp.float32),
+                        self._rep_arr(st.top_p, jnp.float32),
+                    ]
+                else:
+                    exec_ = self._get_exec(("prefill", bucket))
+            with span("prefill.dispatch"):
+                if quant:
+                    (self.ks, self.vs, self.k_scales, self.v_scales,
+                     tok) = exec_(*args)
+                else:
+                    self.ks, self.vs, tok = exec_(*args)
             st.next_chunk += 1
             st.forwarded += bucket
             self.prefill_forwarded_total += bucket
             self.paged_stats["prefill_chunks"] += 1
             if st.next_chunk < len(st.plan):
                 return None
-            first = int(tok)
+            with span("prefill.fetch"):
+                first = int(tok)
         if self.trie is not None:
             n_full = len(st.prompt) // self.paged.block_size
             if n_full:
@@ -1770,28 +1805,31 @@ class PagedEngine(Engine):
         still mid-chunked-prefill, must not dirty live pages)."""
         if active is None:
             active = [True] * self.serve_cfg.slots
-        for s, (is_on, pos) in enumerate(zip(active, positions)):
-            if is_on and s in self._slot_state:
-                self._cow_write_target(s, int(pos))
-        exec_ = self._get_exec(("decode",))
         quant = self.paged.kv_quant == "int8"
-        state = [self.ks, self.vs] + (
-            [self.k_scales, self.v_scales] if quant else []
-        )
         with span("decode", hist="serve_decode_s"):
-            out = exec_(
-                self.params, *state,
-                self._rep_arr(np.asarray(tokens, np.int32)),
-                self._rep_arr(np.asarray(positions, np.int32)),
-                self._tables_device(),
-                self._rep_arr(np.asarray(active, np.int32)),
-            )
-            if quant:
-                (self.ks, self.vs, self.k_scales, self.v_scales,
-                 toks) = out
-            else:
-                self.ks, self.vs, toks = out
-            return np.asarray(toks)
+            with span("decode.prep"):
+                for s, (is_on, pos) in enumerate(zip(active, positions)):
+                    if is_on and s in self._slot_state:
+                        self._cow_write_target(s, int(pos))
+                exec_ = self._get_exec(("decode",))
+                state = [self.ks, self.vs] + (
+                    [self.k_scales, self.v_scales] if quant else []
+                )
+                args = (
+                    self._rep_arr(np.asarray(tokens, np.int32)),
+                    self._rep_arr(np.asarray(positions, np.int32)),
+                    self._tables_device(),
+                    self._rep_arr(np.asarray(active, np.int32)),
+                )
+            with span("decode.dispatch"):
+                out = exec_(self.params, *state, *args)
+                if quant:
+                    (self.ks, self.vs, self.k_scales, self.v_scales,
+                     toks) = out
+                else:
+                    self.ks, self.vs, toks = out
+            with span("decode.fetch"):
+                return np.asarray(toks)
 
     def release(self, slot: int) -> None:
         """Drop the request's page references (the trie keeps its own,

@@ -52,7 +52,13 @@ from typing import Any, Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from tpu_hpc.obs import activate, emit_span, get_bus, get_registry
+from tpu_hpc.obs import (
+    activate,
+    emit_span,
+    get_bus,
+    get_registry,
+    span,
+)
 from tpu_hpc.obs.trace import (
     KIND_REQUEST,
     announce,
@@ -462,7 +468,6 @@ class ContinuousBatcher:
             trace_id=tid, slot=idx,
         )
         self.stats["admitted"] += 1
-        get_registry().inc("serve_admitted_total")
         slot.rid = req.rid
         slot.pos = len(req.prompt)
         slot.last_token = first
@@ -495,7 +500,6 @@ class ContinuousBatcher:
         forever) and count the tick as a block stall."""
         self.pending.append(req)  # _order keeps its place
         self.stats["block_stalls"] += 1
-        get_registry().inc("serve_block_stalls_total")
         get_bus().emit(
             "admission",
             sink=self._sink(),
@@ -567,7 +571,6 @@ class ContinuousBatcher:
         slot.pos = 0
         slot.remaining = req.max_new_tokens
         self.stats["admitted"] += 1
-        get_registry().inc("serve_admitted_total")
         self._set_occupancy()
         if self.meter is not None:
             self.meter.admitted(
@@ -608,18 +611,28 @@ class ContinuousBatcher:
 
     def step(self) -> None:
         """Apply admission policy, admit into free slots, advance
-        prefill chunks (paged), then one decode step for all."""
-        self._admission_control()
-        for idx, slot in enumerate(self.slots):
-            if not slot.free or not self.pending:
-                continue
-            if self._paged:
-                if not self._admit_paged(idx, slot):
-                    break
-            else:
-                self._admit_slab(idx, slot)
+        prefill chunks (paged), then one decode step for all. One
+        ``tick`` span brackets the whole of it, whichever way it
+        ends; its children (docs/guide/observability.md, "Stage
+        names") say where inside a tick the host was."""
+        with span("tick"):
+            self._tick()
+
+    def _tick(self) -> None:
+        with span("tick.admission"):
+            self._admission_control()
+        with span("tick.admit"):
+            for idx, slot in enumerate(self.slots):
+                if not slot.free or not self.pending:
+                    continue
+                if self._paged:
+                    if not self._admit_paged(idx, slot):
+                        break
+                else:
+                    self._admit_slab(idx, slot)
         if self._paged:
-            self._prefill_tick()
+            with span("tick.prefill"):
+                self._prefill_tick()
 
         if not any(s.decoding for s in self.slots):
             return
@@ -636,22 +649,22 @@ class ContinuousBatcher:
         else:
             out = self.engine.decode(tokens, positions)
         self.stats["decode_steps"] += 1
-        get_registry().inc("serve_decode_steps_total")
-        for idx, (slot, tok) in enumerate(
-            zip(self.slots, np.asarray(out))
-        ):
-            if not slot.decoding:
-                continue
-            req = self._requests[slot.rid]
-            tok = int(tok)
-            self.results[slot.rid].append(tok)
-            if self.meter is not None:
-                self.meter.token(slot.rid)
-            slot.pos += 1
-            slot.last_token = tok
-            slot.remaining -= 1
-            if slot.remaining == 0 or tok == req.eos_id:
-                self._evict(idx, slot)
+        with span("tick.emit"):
+            for idx, (slot, tok) in enumerate(
+                zip(self.slots, np.asarray(out))
+            ):
+                if not slot.decoding:
+                    continue
+                req = self._requests[slot.rid]
+                tok = int(tok)
+                self.results[slot.rid].append(tok)
+                if self.meter is not None:
+                    self.meter.token(slot.rid)
+                slot.pos += 1
+                slot.last_token = tok
+                slot.remaining -= 1
+                if slot.remaining == 0 or tok == req.eos_id:
+                    self._evict(idx, slot)
 
     def _spec_tick(self) -> None:
         """One speculative decode tick (serve/spec.py): every decoding
@@ -698,7 +711,6 @@ class ContinuousBatcher:
         )
         self.stats["decode_steps"] += 1
         reg = get_registry()
-        reg.inc("serve_decode_steps_total")
         for idx, slot in enumerate(slots):
             if not slot.decoding:
                 continue

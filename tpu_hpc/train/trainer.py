@@ -356,8 +356,11 @@ def make_step_fn(
             loss, grads = numeric_fault(
                 state.step + data_offset, loss, grads
             )
-        updates, new_opt = optimizer.update(grads, state.opt_state, state.params)
-        new_params = optax.apply_updates(state.params, updates)
+        with jax.named_scope("optimizer"):
+            updates, new_opt = optimizer.update(
+                grads, state.opt_state, state.params
+            )
+            new_params = optax.apply_updates(state.params, updates)
         new_ms_out = new_ms
         metrics = {"loss": loss, **aux}
         if health:
@@ -1173,6 +1176,18 @@ class Trainer:
         AND eval loss curve from one fit call (the convergence-run
         evidence format).
         """
+        # Stage spans (obs.schema.STAGE_SPANS): where inside a fit the
+        # host is, on the profiler's clock when a trace is on. ``host``
+        # holds the open ``chunk.host`` span: everything outside a
+        # chunk's dispatch and fetch, so it is open from here to the
+        # first dispatch, from each fetch's return to the next
+        # dispatch, and from the last fetch to the return, whichever
+        # way the fit ends.
+        with contextlib.ExitStack() as host:
+            host.enter_context(obs.span("chunk.host"))
+            return self._fit(host, dataset, epochs, eval_dataset, eval_steps)
+
+    def _fit(self, host, dataset, epochs, eval_dataset, eval_steps) -> Dict:
         cfg = self.cfg
         epochs = epochs or cfg.epochs
         if epochs != cfg.epochs and cfg.lr_schedule == "cosine":
@@ -1294,7 +1309,7 @@ class Trainer:
             ).start()
         try:
             last_metrics = self._fit_loop(
-                dataset, done, total_steps, steps_per_epoch, scanned,
+                host, dataset, done, total_steps, steps_per_epoch, scanned,
                 prof, guard, run_summaries,
                 eval_dataset=eval_dataset, eval_steps=eval_steps,
             )
@@ -1349,8 +1364,8 @@ class Trainer:
         }
 
     def _fit_loop(
-        self, dataset, done, total_steps, steps_per_epoch, scanned,
-        prof, guard, run_summaries,
+        self, host, dataset, done, total_steps, steps_per_epoch,
+        scanned, prof, guard, run_summaries,
         eval_dataset=None, eval_steps=None,
     ):
         cfg = self.cfg
@@ -1410,53 +1425,50 @@ class Trainer:
                 prof.step(done)
             self.meter.reset()
             self.meter.start_batch()
-            # Step-boundary marker for XProf per-step breakdowns; the
-            # whole chunk is one dispatch, so one annotation per chunk.
-            ann = (
-                prof.annotate(done) if prof is not None
-                else contextlib.nullcontext()
-            )
+            # The open ``chunk.host`` span (see fit) ends here.
+            host.close()
             data_s = 0.0
             health_chunk = None
-            with self.goodput.measure("productive"), ann:
-                if scanned:
-                    if self._guard_tracked:
-                        self.state, stacked = epoch_fn(
-                            self.state, self._offset_arg(off)
-                        )
-                    else:
-                        self.state, stacked = epoch_fn(self.state)
-                    last_metrics = jax.tree.map(lambda a: a[-1], stacked)
-                    if self.guard_policy is not None:
-                        # The guard's per-step evidence: the stacked
-                        # health vectors for the WHOLE chunk (a few
-                        # scalars per step), fetched in the same
-                        # device_get as the loss below.
-                        health_chunk = {
-                            k: stacked[k]
-                            for k in guard_lib.HEALTH_KEYS
-                            if k in stacked
-                        }
-                else:
-                    per_step_health = []
-                    for i in range(chunk):
-                        t_data = time.perf_counter()
-                        batch = dataset.batch_at(
-                            done + i + off, cfg.global_batch_size
-                        )
-                        data_s += time.perf_counter() - t_data
-                        last_metrics = self.train_step(batch)
+            with self.goodput.measure("productive"):
+                with obs.span("chunk.dispatch"):
+                    if scanned:
+                        if self._guard_tracked:
+                            self.state, stacked = epoch_fn(
+                                self.state, self._offset_arg(off)
+                            )
+                        else:
+                            self.state, stacked = epoch_fn(self.state)
+                        last_metrics = jax.tree.map(lambda a: a[-1], stacked)
                         if self.guard_policy is not None:
-                            per_step_health.append({
-                                k: last_metrics[k]
+                            # The guard's per-step evidence: the stacked
+                            # health vectors for the WHOLE chunk (a few
+                            # scalars per step), fetched in the same
+                            # device_get as the loss below.
+                            health_chunk = {
+                                k: stacked[k]
                                 for k in guard_lib.HEALTH_KEYS
-                                if k in last_metrics
-                            })
-                    if self.guard_policy is not None and per_step_health:
-                        health_chunk = {
-                            k: [row[k] for row in per_step_health]
-                            for k in per_step_health[0]
-                        }
+                                if k in stacked
+                            }
+                    else:
+                        per_step_health = []
+                        for i in range(chunk):
+                            t_data = time.perf_counter()
+                            batch = dataset.batch_at(
+                                done + i + off, cfg.global_batch_size
+                            )
+                            data_s += time.perf_counter() - t_data
+                            last_metrics = self.train_step(batch)
+                            if self.guard_policy is not None:
+                                per_step_health.append({
+                                    k: last_metrics[k]
+                                    for k in guard_lib.HEALTH_KEYS
+                                    if k in last_metrics
+                                })
+                        if self.guard_policy is not None and per_step_health:
+                            health_chunk = {
+                                k: [row[k] for row in per_step_health]
+                                for k in per_step_health[0]
+                            }
                 # Injected straggler delay (chaos matrix): INSIDE the
                 # metered window, so the slowness is visible to the
                 # stall watermark exactly like a degraded host's.
@@ -1470,9 +1482,11 @@ class Trainer:
                 # barrier, loss again for the log, and the health
                 # vectors separately would cost three device round
                 # trips per chunk.
-                last_metrics, health_chunk = jax.device_get(
-                    (last_metrics, health_chunk)
-                )
+                with obs.span("chunk.fetch"):
+                    last_metrics, health_chunk = jax.device_get(
+                        (last_metrics, health_chunk)
+                    )
+            host.enter_context(obs.span("chunk.host"))
             chunk_s = self.meter.end_batch(chunk * cfg.global_batch_size)
             done += chunk
             s_per_step = chunk_s / max(chunk, 1)
