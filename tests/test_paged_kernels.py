@@ -41,6 +41,7 @@ from tpu_hpc.kernels.paged_attention import (
     paged_decode_attention,
     paged_prefill_attention,
     quantize_pages_int8,
+    write_tokens,
 )
 from tpu_hpc.models import llama2
 from tpu_hpc.runtime import MeshSpec, build_mesh
@@ -442,6 +443,174 @@ class TestInt8Quantization:
                             block_size=4)
         with pytest.raises(ValueError, match="multiple of kv_heads"):
             int8_logit_rmse(head_dim=16, kv_heads=2, n_heads=3)
+
+
+# ---------------------------------------------------------------------
+# The token write (the decode programs' one spelling of it)
+# ---------------------------------------------------------------------
+
+
+def _write_tokens_oracle(pool, layer, page_ids, offsets, rows):
+    """The spelling the package had before PR 26, kept here as the
+    oracle: a token-granular scatter with the row dimension between
+    the two index arrays. Right everywhere; on the TPU the compiler
+    re-lays the whole pool round it, which is why it left the
+    package."""
+    return pool.at[layer, page_ids, :, offsets].set(
+        rows.astype(pool.dtype)
+    )
+
+
+def _bits(x):
+    return np.asarray(jax.lax.bitcast_convert_type(x, jnp.uint16))
+
+
+class TestWriteTokens:
+    @pytest.mark.parametrize("kv_heads", [32, 8])
+    def test_bit_identical_to_token_scatter_off_scratch(self, kv_heads):
+        """Distinct live pages at random rows, several slots
+        redirected to the scratch page at once (what inactive slots
+        do): every page but scratch, in every layer, comes out
+        bit-identical to the token-granular scatter, at both serve
+        cells' head counts."""
+        layers, num_blocks, bs, d, slots, dead = 2, 24, 16, 128, 12, 4
+        rng = np.random.default_rng(26 + kv_heads)
+        pool = jnp.asarray(
+            rng.standard_normal(
+                (layers, num_blocks, kv_heads, bs, d), np.float32
+            ),
+            jnp.bfloat16,
+        )
+        page_ids = rng.choice(
+            np.arange(1, num_blocks), size=slots, replace=False
+        ).astype(np.int32)
+        page_ids[rng.choice(slots, size=dead, replace=False)] = (
+            SCRATCH_BLOCK
+        )
+        offsets = rng.integers(0, bs, size=slots).astype(np.int32)
+        rows = jnp.asarray(
+            rng.standard_normal((slots, kv_heads, d), np.float32)
+        )
+        for layer in range(layers):
+            got = jax.jit(write_tokens, static_argnums=1)(
+                pool, layer, page_ids, offsets, rows
+            )
+            want = _write_tokens_oracle(
+                pool, layer, page_ids, offsets, rows
+            )
+            assert got.dtype == pool.dtype and got.shape == pool.shape
+            np.testing.assert_array_equal(
+                _bits(got)[:, 1:], _bits(want)[:, 1:]
+            )
+            # The write landed, and only in its own layer.
+            live = page_ids != SCRATCH_BLOCK
+            np.testing.assert_array_equal(
+                _bits(got)[layer, page_ids[live], :, offsets[live]],
+                _bits(rows.astype(jnp.bfloat16))[live],
+            )
+            np.testing.assert_array_equal(
+                _bits(got)[1 - layer], _bits(pool)[1 - layer]
+            )
+
+    def test_rows_of_one_page_land_in_successive_calls(self):
+        """Several rows of one slot in one page (the speculative
+        verify program's k + 1 candidate rows): one call a row, as
+        the one-writer-per-page rule asks, and every row lands."""
+        rng = np.random.default_rng(27)
+        pool = jnp.asarray(
+            rng.standard_normal((1, 9, 2, 4, 16)), jnp.float32
+        )
+        page_ids = np.array([[3, 3, 5], [7, 7, 7]], np.int32)
+        offsets = np.array([[2, 3, 0], [0, 1, 2]], np.int32)
+        rows = jnp.asarray(
+            rng.standard_normal((2, 3, 2, 16)), jnp.float32
+        )
+        got = pool
+        for j in range(page_ids.shape[1]):
+            got = write_tokens(
+                got, 0, page_ids[:, j], offsets[:, j], rows[:, j]
+            )
+        want = _write_tokens_oracle(pool, 0, page_ids, offsets, rows)
+        np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+class TestOneWriterPerPage:
+    """What every page-granular write leans on (the int8 requantize
+    always, the bf16 token write wherever it moves whole pages): in
+    one decode step no two active slots name the same write-target
+    page, and none names a page somebody else can read."""
+
+    @pytest.mark.parametrize("seed", [0, 1, 2])
+    def test_active_slots_write_distinct_private_pages(
+        self, gather_engine, monkeypatch, seed
+    ):
+        eng = gather_engine
+        bs = eng.paged.block_size
+        rng = np.random.default_rng(260 + seed)
+        # Shared prefixes of whole pages and of part of a page, exact
+        # repeats (a fully cached prompt), more requests than slots.
+        bases = [
+            rng.integers(0, TINY.vocab_size, size=14).tolist()
+            for _ in range(2)
+        ]
+        reqs = []
+        for i in range(10):
+            base = bases[int(rng.integers(2))]
+            keep = int(rng.integers(3, len(base) + 1))
+            tail = rng.integers(
+                0, TINY.vocab_size, size=int(rng.integers(0, 4))
+            ).tolist()
+            reqs.append(Request(
+                rid=f"q{seed}.{i}", prompt=base[:keep] + tail,
+                max_new_tokens=int(rng.integers(2, 9)),
+            ))
+        steps = []
+        held = []
+        decode = eng.decode
+
+        def live(slot):
+            try:
+                eng.slot_state(slot)
+            except KeyError:
+                return False
+            return True
+
+        def checked(tokens, positions, active=None):
+            on = [
+                s for s in range(len(tokens))
+                if (active is None or active[s]) and live(s)
+            ]
+            # A second owner appears on some slot's target page (a
+            # sharing policy to come; the CoW guard's case).
+            if on and rng.random() < 0.3:
+                s = on[int(rng.integers(len(on)))]
+                page = eng.slot_state(s).blocks[int(positions[s]) // bs]
+                eng.allocator.retain([page])
+                held.append(page)
+            out = decode(tokens, positions, active)
+            # ``decode`` ran the CoW guard before it dispatched, so
+            # the tables now name what the program wrote.
+            targets = [
+                eng.slot_state(s).blocks[int(positions[s]) // bs]
+                for s in on
+            ]
+            assert SCRATCH_BLOCK not in targets
+            assert len(set(targets)) == len(targets), targets
+            for page in targets:
+                assert eng.allocator.refcount(page) == 1, page
+            steps.append(len(on))
+            return out
+
+        monkeypatch.setattr(eng, "decode", checked)
+        hits0 = eng.paged_stats["prefix_hits"]
+        cows0 = eng.paged_stats["cow_copies"]
+        _, got = _drain(eng, reqs)
+        eng.allocator.release(held)
+        assert sorted(got) == sorted(r.rid for r in reqs)
+        assert max(steps) > 1  # several writers in one step
+        assert eng.paged_stats["prefix_hits"] > hits0
+        assert eng.paged_stats["cow_copies"] >= cows0 + len(held) > cows0
+        eng.allocator.check_invariant()
 
 
 # ---------------------------------------------------------------------

@@ -37,7 +37,11 @@ are read-only consumers.
 Page layout (the one definition; ``tokens_to_pages`` /
 ``pages_to_tokens`` / ``write_tokens`` below are its only spellings):
 a page is ``[kv_heads, block_size, head_dim]``, heads AHEAD of rows, so
-the pool is ``[num_blocks, kv_heads, block_size, head_dim]`` and one
+one layer's pool is ``[num_blocks, kv_heads, block_size, head_dim]``
+(what a kernel here takes; the engine stacks the layers in one array
+ahead of that and names layer and pages in ONE index,
+``pool[layer, page_ids]`` -- a ``pool[layer]`` on its own is a copy of
+a layer's pool wherever the compiler cannot fuse it away) and one
 kernel block ``(1, 1, block_size, head_dim)`` spans the full last two
 dims of the array -- the shape Mosaic's block rule accepts (its last
 two block dims must divide by the (8, 128) tile or equal the array's).
@@ -101,12 +105,35 @@ def write_tokens(
     pool: jax.Array, layer, page_ids: jax.Array, offsets: jax.Array,
     rows: jax.Array,
 ) -> jax.Array:
-    """Scatter token rows ``[*idx, kv_heads, head_dim]`` into
-    ``pool[layer]`` at row ``offsets`` of pages ``page_ids`` (both
-    ``[*idx]``)."""
-    return pool.at[layer, page_ids, :, offsets].set(
-        rows.astype(pool.dtype)
+    """Put token rows ``[slots, kv_heads, head_dim]`` into the stacked
+    pool ``[layers, num_blocks, kv_heads, block_size, head_dim]`` at row
+    ``offsets`` of pages ``page_ids`` (both ``[slots]``) of ``layer``.
+
+    Page-granular read-modify-write: gather the target pages, select
+    the row in, scatter the pages back -- two indexed operations whose
+    window is a whole page, which the TPU compiler runs on the donated
+    pool in the pool's own layout. (The token-granular
+    ``pool.at[layer, page_ids, :, offsets].set(rows)`` scatters the row
+    dimension under a ``(kv_heads, head_dim)`` window; for that the
+    compiler re-lays the WHOLE pool rows-ahead-of-heads on the way in
+    and back on the way out, 6 GiB moved a step to write 32 rows.)
+
+    ONE WRITER PER PAGE: of two entries that name the same page, one
+    page write wins and the other's row is lost. The engine holds that
+    rule for every live page -- a slot's write-target page is its own
+    (``PagedEngine._cow_write_target`` copies a shared one first) --
+    and sends every inactive slot to the scratch page, whose content
+    nothing reads. A caller with several rows for one page in one step
+    (the speculative verify program) writes them in successive calls.
+    """
+    pages = pool[layer, page_ids]
+    at_row = jnp.arange(pool.shape[-2]) == offsets[:, None]
+    pages = jnp.where(
+        at_row[:, None, :, None],
+        rows.astype(pool.dtype)[:, :, None, :],
+        pages,
     )
+    return pool.at[layer, page_ids].set(pages)
 
 
 # ---------------------------------------------------------------------------

@@ -188,6 +188,32 @@ def _grouped_attention(q, k, v, mask, cfg):
     return out.reshape(b, s_q, cfg.n_heads, cfg.head_dim)
 
 
+def _grouped_attention_paged(q, k_pages, v_pages, mask, cfg):
+    """:func:`_grouped_attention` over gathered pages as they lie in
+    the pool: ``k_pages`` / ``v_pages`` are ``[b, pages, kv_heads,
+    block_size, head_dim]`` (``pool[layer, tables]``), ``mask`` is over
+    the ``pages * block_size`` token columns. Same products, same fp32
+    softmax; the contraction runs over (page, row) where the other
+    runs over tokens, so no view is transposed to token-major first.
+    For the decode programs the views ARE the working set (every
+    slot's whole capacity) and that transpose read and wrote each of
+    them once more, 13-15 GB a step at 7B width."""
+    b, s_q = q.shape[0], q.shape[1]
+    n_kv = cfg.kv_heads
+    groups = cfg.n_heads // n_kv
+    n_pages, block_size = k_pages.shape[1], k_pages.shape[3]
+    qg = q.reshape(b, s_q, n_kv, groups, cfg.head_dim)
+    scale = cfg.head_dim ** -0.5
+    scores = jnp.einsum("bqhgd,bphkd->bhgqpk", qg, k_pages) * scale
+    scores = scores.reshape(b, n_kv, groups, s_q, n_pages * block_size)
+    scores = scores.astype(jnp.float32)
+    scores = jnp.where(mask, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    probs = probs.reshape(b, n_kv, groups, s_q, n_pages, block_size)
+    out = jnp.einsum("bhgqpk,bphkd->bqhgd", probs, v_pages)
+    return out.reshape(b, s_q, cfg.n_heads, cfg.head_dim)
+
+
 def _logits_head(x, params, cfg):
     x = _rmsnorm(x, params["norm"]["scale"], cfg.norm_eps)
     return _dense(x, params["output"]["kernel"], cfg.dtype)

@@ -39,9 +39,11 @@ AOT executable tables and token-exact oracles:
   the one-chunk case.
 
 Attention reads the logical sequence one of two ways, selected by
-``PagedConfig.kernel``: ``"gather"`` -- a gather over the block table
-(``ks[layer][table]``), the XLA-level reference formulation, correct
-on every backend and token-exact against the no-cache forward (the
+``PagedConfig.kernel``: ``"gather"`` -- ONE gather over the stacked
+pool by layer and block table (``ks[layer, table]``; never
+``ks[layer][table]``, whose per-layer slice the TPU compiler
+materialises before it gathers), the XLA-level reference formulation,
+correct on every backend and token-exact against the no-cache forward (the
 tests/test_serve.py oracle applies verbatim) -- or ``"pallas"`` -- the
 kernels/paged_attention.py kernels dropped into the SAME program
 slots: block table walked in-kernel as a scalar-prefetch operand, one
@@ -83,6 +85,7 @@ from tpu_hpc.serve.engine import (
     _dense,  # noqa: F401  (re-exported for kernel swaps)
     _embed,
     _grouped_attention,
+    _grouped_attention_paged,
     _logits_head,
     _mlp,
     _qkv,
@@ -868,15 +871,19 @@ def make_chunk_logits_fn(
                     )
             else:
                 with scope("kv_read"):
-                    k_view = ks[i][view_ids]
-                    v_view = vs[i][view_ids]
+                    k_view = ks[i, view_ids]
+                    v_view = vs[i, view_ids]
                     if quant:
                         k_view = dequantize_pages_int8(
-                            k_view, ksc[i][view_ids]
+                            k_view, ksc[i, view_ids]
                         )
                         v_view = dequantize_pages_int8(
-                            v_view, vsc[i][view_ids]
+                            v_view, vsc[i, view_ids]
                         )
+                    # Token-major for the chunk: its view is ONE slot's
+                    # pages (a hundredth of the 512-row scores), and
+                    # the chip ran this contraction 7 % faster than the
+                    # page-major one at 8 KV heads (PERF.md, PR 26).
                     k_view = pages_to_tokens(k_view)[None]
                     v_view = pages_to_tokens(v_view)[None]
                 with scope("attention"):
@@ -1072,19 +1079,17 @@ def make_paged_decode_fn(
                     )
             else:
                 with scope("kv_read"):
-                    k_view = ks[i][view_ids]
-                    v_view = vs[i][view_ids]
+                    k_view = ks[i, view_ids]
+                    v_view = vs[i, view_ids]
                     if quant:
                         k_view = dequantize_pages_int8(
-                            k_view, ksc[i][view_ids]
+                            k_view, ksc[i, view_ids]
                         )
                         v_view = dequantize_pages_int8(
-                            v_view, vsc[i][view_ids]
+                            v_view, vsc[i, view_ids]
                         )
-                    k_view = pages_to_tokens(k_view)
-                    v_view = pages_to_tokens(v_view)
                 with scope("attention"):
-                    attn = _grouped_attention(
+                    attn = _grouped_attention_paged(
                         q, k_view.astype(cfg.dtype),
                         v_view.astype(cfg.dtype), mask, cfg,
                     )

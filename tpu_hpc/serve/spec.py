@@ -61,13 +61,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_hpc.kernels.paged_attention import pages_to_tokens, write_tokens
+from tpu_hpc.kernels.paged_attention import write_tokens
 from tpu_hpc.models import llama2
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.engine import (
     _attn_out_proj,
     _embed,
-    _grouped_attention,
+    _grouped_attention_paged,
     _logits_head,
     _mlp,
     _qkv,
@@ -360,11 +360,9 @@ def make_spec_draft_fn(
                 kk = llama2.apply_rope(kk, cos, sin)
                 ks = write_tokens(ks, i, pb, off, kk[:, 0])
                 vs = write_tokens(vs, i, pb, off, v[:, 0])
-                k_view = pages_to_tokens(ks[i][view_ids])
-                v_view = pages_to_tokens(vs[i][view_ids])
-                attn = _grouped_attention(
-                    q, k_view.astype(cfg.dtype),
-                    v_view.astype(cfg.dtype), mask, cfg,
+                attn = _grouped_attention_paged(
+                    q, ks[i, view_ids].astype(cfg.dtype),
+                    vs[i, view_ids].astype(cfg.dtype), mask, cfg,
                 )
                 x = x + _attn_out_proj(attn, lp, cfg)
                 h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
@@ -460,13 +458,14 @@ def make_spec_verify_fn(
             q, kk, v = _qkv(h, lp, cfg)
             q = llama2.apply_rope(q, cos, sin)
             kk = llama2.apply_rope(kk, cos, sin)
-            ks = write_tokens(ks, i, pb, off, kk)
-            vs = write_tokens(vs, i, pb, off, v)
-            k_view = pages_to_tokens(ks[i][view_ids])
-            v_view = pages_to_tokens(vs[i][view_ids])
-            attn = _grouped_attention(
-                q, k_view.astype(cfg.dtype), v_view.astype(cfg.dtype),
-                mask, cfg,
+            # One call a candidate row: a slot's rows share pages,
+            # and write_tokens takes one writer a page.
+            for j in range(n_rows):
+                ks = write_tokens(ks, i, pb[:, j], off[:, j], kk[:, j])
+                vs = write_tokens(vs, i, pb[:, j], off[:, j], v[:, j])
+            attn = _grouped_attention_paged(
+                q, ks[i, view_ids].astype(cfg.dtype),
+                vs[i, view_ids].astype(cfg.dtype), mask, cfg,
             )
             x = x + _attn_out_proj(attn, lp, cfg)
             h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
