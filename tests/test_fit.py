@@ -652,6 +652,55 @@ class TestPagedKVTerm:
             cfg, 100, 16, cache_dtype="float32"
         ) == 2 * want
 
+    @pytest.mark.parametrize("what", [
+        "weights", "active", "pool", "token", "fits", "int8", "dense",
+    ])
+    def test_sparse_expert_cell_bytes(self, what):
+        """``serve-docqa-keye30b``'s widths, slots and capacity
+        (benchmark/workloads/): 4 of 48 layers hold 3.124B parameters =
+        5.82 GiB in bf16 while a token passes 0.55B of them; a cached
+        token is K and V and the indexer's 128 B a layer; 12 x 30720 /
+        16 + 1 pages are 2.99 GiB; weights twice (the engine's
+        construction) and the pool fit a 15.75 GiB chip."""
+        import dataclasses
+
+        from tpu_hpc.models import sparse_moe
+
+        cfg = dataclasses.replace(
+            sparse_moe.KEYE_VL2_30B_A3B, n_layers=4, max_seq_len=30720
+        )
+        counts = fit.param_counts(cfg)
+        pages = 12 * 30720 // 16 + 1
+        pool = fit.kv_paged_bytes(cfg, pages, 16)
+        if what == "weights":
+            assert counts["total"] == 3_123_858_944
+            assert round(2 * counts["total"] / 2**30, 2) == 5.82
+        elif what == "active":
+            outside = 625_381_760 - 128 * 4_718_592
+            assert counts["active"] == 4 * (outside + 8 * 4_718_592) \
+                + 311_164_928 + 2048
+            assert counts["total"] / counts["active"] > 5
+        elif what == "pool":
+            assert pages == 23041 and round(pool / 2**30, 2) == 2.99
+        elif what == "token":
+            assert pool == pages * 16 * 8704
+            assert 8704 == 4 * (2 * 4 * 128 * 2 + 64 * 2)
+        elif what == "fits":
+            assert (2 * 2 * counts["total"] + pool) / 2**30 < 15.75
+            five = fit.param_counts(dataclasses.replace(cfg, n_layers=5))
+            assert (2 * 2 * five["total"] + pool) / 2**30 > 15.75
+        elif what == "int8":
+            with pytest.raises(NotImplementedError, match="keye-vl2"):
+                fit.kv_paged_bytes(cfg, pages, 16, kv_quant="int8")
+        else:
+            dense = llama2.LlamaConfig(
+                dim=64, n_layers=3, n_heads=4, vocab_size=128,
+                multiple_of=16, max_seq_len=32,
+            )
+            both = fit.param_counts(dense)
+            assert both["total"] == both["active"] \
+                == llama2.count_params(dense)
+
     @pytest.fixture(scope="class")
     def with_paged(self, full_7b):
         # Slab 64 slots x 4096 worst-case vs a pool provisioned for

@@ -360,3 +360,90 @@ def test_train_chunk_emits_its_stages(mesh8, ring):
     assert np.isfinite(trainer.fit(
         datasets.TokenStream(vocab_size=64, seq_len=16), epochs=1
     )["epochs"][-1]["total_s"])
+
+
+# -- a sparse-expert configuration's stages and counts (PR 27) ---------
+SPARSE_SCOPES = ("indexer", "router", "experts")
+
+
+@pytest.fixture(scope="module")
+def sparse_program_scopes(devices):
+    """The decode and chunk programs of a tiny sparse-expert
+    configuration (``models/sparse_moe.py``), each lowered once."""
+    from tpu_hpc.models import sparse_moe
+
+    cfg = sparse_moe.SparseMoEConfig(
+        name="tiny-sparse", dim=64, n_layers=2, n_heads=4, n_kv_heads=2,
+        head_dim=32, vocab_size=128, max_seq_len=64, n_experts=8,
+        experts_per_token=2, expert_hidden=48, indexer_heads=2,
+        indexer_head_dim=16, indexer_rope_dim=8, indexer_topk=8,
+        dtype=jnp.float32,
+    )
+    i32 = jnp.int32
+    weights = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s, jnp.float32),
+        sparse_moe.param_shapes(cfg),
+        is_leaf=lambda x: isinstance(x, tuple),
+    )
+    cache = jax.ShapeDtypeStruct(
+        (2, 48, cfg.kv_heads, BLOCK, cfg.head_dim), jnp.float32
+    )
+    keys = jax.ShapeDtypeStruct((2, 48, BLOCK, 16), jnp.float32)
+    vec = jax.ShapeDtypeStruct((SERVE.slots,), i32)
+    scalar = jax.ShapeDtypeStruct((), i32)
+    decode = jax.jit(paging.make_paged_decode_fn(
+        cfg, BLOCK, PER_SEQ, WIDTH
+    )).lower(
+        weights, cache, cache, keys, vec, vec,
+        jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32), vec,
+    )
+    prefill = jax.jit(paging.make_chunk_prefill_fn(
+        cfg, 8, BLOCK, PER_SEQ, WIDTH
+    )).lower(
+        weights, cache, cache, keys,
+        jax.ShapeDtypeStruct((1, 8), i32), scalar, scalar,
+        jax.ShapeDtypeStruct((WIDTH,), i32),
+    )
+    return {"decode": _scopes_in(decode), "prefill": _scopes_in(prefill)}
+
+
+@pytest.mark.parametrize("program,scope", [
+    (p, s) for p in ("decode", "prefill")
+    for s in SPARSE_SCOPES + tuple(x for x in SERVE_SCOPES if x != "mlp")
+])
+def test_sparse_program_carries_scope(sparse_program_scopes, program, scope):
+    assert scope in sparse_program_scopes[program]
+
+
+def test_mlp_stays_the_dense_ffns(sparse_program_scopes, program_scopes):
+    """``mlp`` names the dense SwiGLU only: a sparse-expert program has
+    ``router`` and ``experts`` in its place, a dense one neither."""
+    for program in ("decode", "prefill"):
+        assert "mlp" not in sparse_program_scopes[program]
+        assert not set(SPARSE_SCOPES) & program_scopes[program]
+
+
+def _table_of_record():
+    import os
+
+    path = os.path.join(
+        os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+        "docs", "guide", "observability.md",
+    )
+    with open(path) as f:
+        return f.read()
+
+
+@pytest.mark.parametrize("name", [n for _, n, _ in paging.SPARSE_COUNTERS])
+def test_sparse_counter_is_described_and_in_the_table_of_record(name):
+    """Each count a sparse-expert decode step returns with its tokens
+    has HELP text in the registry (set when such an engine is built:
+    tests/test_sparse_moe.py drives them) and a row in the guide."""
+    assert f"`{name}`" in _table_of_record()
+    help_ = {n: h for _, n, h in paging.SPARSE_COUNTERS}[name]
+    assert help_ and name.startswith(("serve_moe_", "serve_sparse_"))
+
+
+@pytest.mark.parametrize("scope", SPARSE_SCOPES)
+def test_sparse_scope_is_in_the_table_of_record(scope):
+    assert f"| `{scope}` |" in _table_of_record()

@@ -40,7 +40,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import llama2
+from tpu_hpc.models import llama2, sparse_moe
 from tpu_hpc.parallel import hybrid, tp
 from tpu_hpc.parallel.plans import derived_pspecs, shardings_for
 
@@ -99,6 +99,20 @@ def kv_cache_bytes(
     )
 
 
+def param_counts(cfg: llama2.LlamaConfig) -> Dict[str, int]:
+    """``total`` parameters a chip must HOLD against ``active`` ones a
+    token passes through. One number for a dense decoder; for a
+    sparse-expert one (``models/sparse_moe.py``) memory is sized by the
+    total (every held expert) and a decode step's products by the
+    active (``experts_per_token`` experts a layer), sixteen times
+    apart at Keye-VL-2.0-30B-A3B's 8 of 128."""
+    if sparse_moe.is_sparse_moe(cfg):
+        counts = sparse_moe.count_params(cfg)
+        return {"total": counts["total"], "active": counts["active"]}
+    n = llama2.count_params(cfg)
+    return {"total": n, "active": n}
+
+
 def kv_paged_bytes(
     cfg: llama2.LlamaConfig,
     num_blocks: int,
@@ -120,7 +134,20 @@ def kv_paged_bytes(
     ``kv_quant="int8"`` (tpu_hpc.kernels.paged_attention) stores
     pages at 1 byte/element plus a per-page fp32 scale side array
     (one scale per page per layer, K and V each) -- the halved pool
-    the quantized-capacity report line budgets."""
+    the quantized-capacity report line budgets.
+
+    A sparse-expert configuration (``models/sparse_moe.py``) keeps a
+    third array under the same pages: the indexer's one key a token a
+    layer, ``indexer_head_dim`` numbers (128 B in bf16 at 64), which
+    the engine's ``cache_bytes`` counts too."""
+    if sparse_moe.is_sparse_moe(cfg):
+        if kv_quant != "none":
+            sparse_moe.refuse(
+                cfg, "an int8 page pool", "the indexer key is not quantised"
+            )
+        return num_blocks * block_size * cfg.n_layers * (
+            2 * cfg.kv_heads * cfg.head_dim + cfg.indexer_head_dim
+        ) * jnp.dtype(cache_dtype).itemsize
     if kv_quant == "int8":
         page_bytes = (
             num_blocks * block_size * cfg.n_layers * cfg.kv_heads

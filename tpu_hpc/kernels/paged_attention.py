@@ -128,6 +128,14 @@ def write_tokens(
     """
     pages = pool[layer, page_ids]
     at_row = jnp.arange(pool.shape[-2]) == offsets[:, None]
+    if pool.ndim == 4:
+        # A pool with no head axis (``[layers, num_blocks, block_size,
+        # width]``, rows ``[slots, width]``: the sparse-expert
+        # decoder's indexer keys), same rule.
+        pages = jnp.where(
+            at_row[:, :, None], rows.astype(pool.dtype)[:, None, :], pages
+        )
+        return pool.at[layer, page_ids].set(pages)
     pages = jnp.where(
         at_row[:, None, :, None],
         rows.astype(pool.dtype)[:, :, None, :],

@@ -507,9 +507,22 @@ class Llama(nn.Module):
         return logits
 
 
+def _dense_only(cfg: LlamaConfig, who: str) -> None:
+    """``Llama`` is the dense decoder: a configuration that names
+    experts (``models/sparse_moe.py``) is refused, never run as a
+    dense model of its widths."""
+    if getattr(cfg, "n_experts", 0):
+        from tpu_hpc.models import sparse_moe
+
+        sparse_moe.refuse(
+            cfg, who, "models/llama2.py builds the dense block only"
+        )
+
+
 def init_llama(
     rng: jax.Array, cfg: LlamaConfig, constrain: Constrain = _identity
 ) -> Dict:
+    _dense_only(cfg, "llama2.init_llama")
     # attn_fn never affects the param tree (the attention op itself is
     # parameter-free), so init always uses the local-attention path --
     # a mesh-bound attn_fn could not run on the tiny init sample anyway.
@@ -530,6 +543,7 @@ def apply_llama(
     loss upcasts to fp32 inside its reductions; see Llama.__call__).
     ``positions`` [S]: global RoPE position of each slot, for permuted
     token layouts (zigzag ring); None = the usual 0..S-1."""
+    _dense_only(cfg, "llama2.apply_llama")
     return Llama(cfg, constrain, attn_fn).apply(
         {"params": params}, tokens, positions
     )
@@ -548,6 +562,8 @@ def make_forward(
     under a permuted token layout; per-token mean cross-entropy is
     itself permutation-invariant."""
     from tpu_hpc.models.losses import cross_entropy
+
+    _dense_only(cfg, "llama2.make_forward (the Trainer's forward)")
 
     def forward(params, model_state, batch, step_rng):
         inputs, targets = batch

@@ -48,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import llama2
+from tpu_hpc.models import llama2, sparse_moe
 from tpu_hpc.obs import get_registry, span
 from tpu_hpc.serve.engine import Engine, ServeConfig
 
@@ -132,6 +132,10 @@ class DisaggEngine:
                 f"prefill and decode tiers share {len(shared)} "
                 "device(s); disaggregation needs disjoint tiers"
             )
+        sparse_moe.refuse(
+            cfg, "disaggregated serving (serve/disagg.py)",
+            "the cross-tier hop ships keys and values only",
+        )
         self.cfg = cfg
         self.serve_cfg = serve_cfg
         self.max_inflight_bytes = max_inflight_bytes

@@ -40,7 +40,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import llama2
+from tpu_hpc.models import llama2, sparse_moe
 from tpu_hpc.obs import get_registry, span
 
 
@@ -164,10 +164,21 @@ def _qkv(x, lp, cfg):
     q = _dense(x, lp["attention"]["wq"]["kernel"], cfg.dtype)
     k = _dense(x, lp["attention"]["wk"]["kernel"], cfg.dtype)
     v = _dense(x, lp["attention"]["wv"]["kernel"], cfg.dtype)
-    return (
-        q.reshape(b, s, cfg.n_heads, hd),
-        k.reshape(b, s, n_kv, hd),
-        v.reshape(b, s, n_kv, hd),
+    q = q.reshape(b, s, cfg.n_heads, hd)
+    k = k.reshape(b, s, n_kv, hd)
+    if getattr(cfg, "qk_norm", False):
+        # Per-head RMSNorm ahead of the rotation (sparse_moe.py).
+        q = _rmsnorm(q, lp["attention"]["q_norm"]["scale"], cfg.norm_eps)
+        k = _rmsnorm(k, lp["attention"]["k_norm"]["scale"], cfg.norm_eps)
+    return q, k, v.reshape(b, s, n_kv, hd)
+
+
+def _rope_tables(cfg, n, positions):
+    """``llama2.rope_cos_sin`` at the configuration's rotary base
+    (10000 unless it names one)."""
+    return llama2.rope_cos_sin(
+        n, cfg.head_dim, getattr(cfg, "rope_theta", 10000.0),
+        positions=positions,
     )
 
 
@@ -330,6 +341,12 @@ class Engine:
     ):
         from tpu_hpc.serve.weights import place_params, serving_pspecs
 
+        if not getattr(self, "is_paged", False):
+            sparse_moe.refuse(
+                cfg, "the slab Engine",
+                "its cache holds keys and values only, no indexer key "
+                "and no selection",
+            )
         if cfg.n_heads % cfg.kv_heads:
             raise ValueError(
                 f"n_heads {cfg.n_heads} must be a multiple of kv_heads "

@@ -60,6 +60,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -67,7 +68,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import llama2
+from tpu_hpc.models import llama2, sparse_moe
 from tpu_hpc.kernels.paged_attention import (
     INT8_SCALE_FLOOR,
     dequantize_pages_int8,
@@ -90,10 +91,36 @@ from tpu_hpc.serve.engine import (
     _mlp,
     _qkv,
     _rmsnorm,
+    _rope_tables,
     _attn_out_proj,
 )
 
 SCRATCH_BLOCK = 0
+
+# What a decode step of a sparse-expert configuration counts: (the
+# program's key for it, the registry's name, HELP), in the order the
+# program packs them behind its tokens (one device fetch a step).
+# ``*_total`` are summed over layers and steps, the one gauge is the
+# largest seen. docs/guide/observability.md has the table of record.
+SPARSE_COUNTERS: Tuple[Tuple[str, str, str], ...] = (
+    ("assignments", "serve_moe_assignments_total",
+     "Token-to-expert assignments routed by decode steps (active "
+     "slots x experts a token x layers)"),
+    ("experts_touched", "serve_moe_experts_touched_total",
+     "Distinct experts with at least one token, summed over layers "
+     "and decode steps"),
+    ("max_tokens_per_expert", "serve_moe_max_tokens_per_expert",
+     "Most tokens one expert of one layer got in one decode step"),
+    ("dropped", "serve_moe_dropped_total",
+     "Assignments to held experts the expert layer did not compute "
+     "(must stay 0)"),
+    ("selected", "serve_sparse_selected_tokens_total",
+     "Cached tokens attention read after selection (active slots x "
+     "layers, min(context, indexer_topk) each)"),
+    ("candidates", "serve_sparse_candidate_tokens_total",
+     "Cached tokens the indexer scored (active slots x layers, the "
+     "whole context each)"),
+)
 
 
 class BlockBudgetError(RuntimeError):
@@ -765,6 +792,91 @@ class PrefixTrie:
 # ---------------------------------------------------------------------
 
 
+def _with_state(body, name: str, quant: bool, sparse: bool):
+    """A program body ``(params, ks, vs, ksc, vsc, xs, *args) -> (ks,
+    vs, ksc, vsc, xs, *results)`` under the signature the pool has:
+    ``(params, ks, vs, *args)``, with ``ksc, vsc`` after ``vs`` for an
+    int8 pool and ``xs`` after those for a sparse-expert
+    configuration, in the arguments and in the results alike. ``name``
+    is the program's (the jitted module's, which the trace reports)."""
+
+    def program(params, ks, vs, *rest):
+        n = 2 * quant + sparse
+        extra, args = rest[:n], rest[n:]
+        ksc, vsc = extra[:2] if quant else (None, None)
+        xs = extra[-1] if sparse else None
+        ks, vs, ksc, vsc, xs, *out = body(
+            params, ks, vs, ksc, vsc, xs, *args
+        )
+        return (
+            ks, vs, *((ksc, vsc) if quant else ()),
+            *((xs,) if sparse else ()), *out,
+        )
+
+    program.__name__ = program.__qualname__ = name
+    return program
+
+
+def _check_read_path(cfg, kernel: str, kv_quant: str) -> None:
+    """A sparse-expert configuration reads through ``gather`` from a
+    pool in the compute dtype: the Pallas kernels walk the whole table
+    (no selection) and the int8 page write has no indexer key."""
+    if kernel != "gather" or kv_quant != "none":
+        sparse_moe.refuse(
+            cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
+            "selection runs on the gather read path over an "
+            "unquantised pool only",
+        )
+
+
+def _ffn_stage(x, lp, cfg, weight=None):
+    """The configuration's feed-forward, residual included ->
+    ``(x, counts)``: the dense SwiGLU under ``mlp`` (``counts`` None),
+    or the router and the held experts under ``router`` / ``experts``
+    (``sparse_moe.expert_ffn``'s counts; ``weight`` marks the tokens
+    that count)."""
+    scope = jax.named_scope
+    if not sparse_moe.is_sparse_moe(cfg):
+        with scope("mlp"):
+            h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
+            x = x + _mlp(h, lp, cfg)
+        return x, None
+    b, s, d = x.shape
+    with scope("router"):
+        h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
+        h = h.reshape(b * s, d)
+        gates, experts = sparse_moe.route(h, lp, cfg)
+    with scope("experts"):
+        y, counts = sparse_moe.expert_ffn(
+            h, gates, experts, lp, cfg, weight=weight
+        )
+        x = x + y.reshape(b, s, d).astype(x.dtype)
+    return x, counts
+
+
+def _select(h, lp, cfg, xs, layer, write, view_ids, valid, icos, isin):
+    """The indexer of one layer, under ``indexer``: project, put the
+    tokens' keys into the indexer pool (``write(xs, keys) -> xs``),
+    score every column of the view against each query and keep the
+    exact top ``indexer_topk`` of the columns ``valid`` allows.
+    ``h [b, s, dim]``, ``valid [b or 1, s, columns]``, ``view_ids`` the
+    view's pages (``[b, pages]``, or ``[pages]`` shared by one
+    sequence's ``s`` rows) -> ``(xs, selected [.., s, columns])``."""
+    with jax.named_scope("indexer"):
+        qi, ki, w = sparse_moe.indexer_project(h, lp, cfg, icos, isin)
+        xs = write(xs, ki)
+        keys = xs[layer, view_ids]
+        keys = keys.reshape(
+            *keys.shape[:-3], -1, cfg.indexer_head_dim
+        )
+        if view_ids.ndim == 2:          # decode: a view a slot
+            keys = keys[:, None]
+        scores = sparse_moe.indexer_scores(qi, w, keys, cfg)
+        return xs, sparse_moe.select_topk(
+            scores, valid, cfg.indexer_topk
+        )
+
+
 def make_chunk_logits_fn(
     cfg: llama2.LlamaConfig,
     bucket: int,
@@ -808,11 +920,20 @@ def make_chunk_logits_fn(
     padding, so a bucket-padded write near the capacity edge can
     never clamp (jax dynamic_slice clamps out-of-range starts, which
     would silently misalign the scatter) nor touch a real page.
+
+    A sparse-expert configuration (``models/sparse_moe.py``) takes
+    ``(params, ks, vs, xs, tokens, ...)`` and returns ``xs`` after
+    ``vs``: the chunk's indexer keys go into the pages its K/V goes
+    into, each query row keeps its own exact top ``indexer_topk`` of
+    the columns ``<= start + q``, and attention runs under that mask
+    (:func:`make_paged_decode_fn` has the stages).
     """
     nb_chunk = bucket // block_size
     cache_cap = max_blocks * block_size
     quant = kv_quant == "int8"
     use_pallas = kernel == "pallas"
+    sparse = sparse_moe.is_sparse_moe(cfg)
+    _check_read_path(cfg, kernel, kv_quant)
     groups = cfg.n_heads // cfg.kv_heads
     if use_pallas:
         prefill_attention = _on_mesh(
@@ -820,20 +941,25 @@ def make_chunk_logits_fn(
             block_size=block_size, max_blocks=max_blocks,
         )
 
-    def body(params, ks, vs, ksc, vsc, tokens, start, true_len, table):
+    def body(params, ks, vs, ksc, vsc, xs, tokens, start, true_len,
+             table):
         scope = jax.named_scope
         with scope("embed"):
             x = _embed(params, tokens, cfg)
         qpos = start + jnp.arange(bucket)
-        cos, sin = llama2.rope_cos_sin(
-            bucket, cfg.head_dim, positions=qpos
-        )
+        cos, sin = _rope_tables(cfg, bucket, qpos)
         col = jnp.arange(cache_cap)
         mask = (col[None, :] <= qpos[:, None])[None, None, None, :, :]
         blk_ids = jax.lax.dynamic_slice(
             table, (start // block_size,), (nb_chunk,)
         )
         view_ids = table[:max_blocks]
+        if sparse:
+            icos, isin = llama2.rope_cos_sin(
+                bucket, cfg.indexer_rope_dim, cfg.rope_theta,
+                positions=qpos,
+            )
+            causal = mask
         for i in range(cfg.n_layers):
             lp = params[f"layers_{i}"]
             with scope("qkv"):
@@ -856,6 +982,19 @@ def make_chunk_logits_fn(
                 else:
                     ks = ks.at[i, blk_ids].set(kb.astype(ks.dtype))
                     vs = vs.at[i, blk_ids].set(vb.astype(vs.dtype))
+            if sparse:
+                # Attention reads the selected columns only: the same
+                # view and product under the selection's mask.
+                xs, chosen = _select(
+                    h, lp, cfg, xs, i,
+                    lambda pool, keys: pool.at[i, blk_ids].set(
+                        keys[0].reshape(
+                            nb_chunk, block_size, -1
+                        ).astype(pool.dtype)
+                    ),
+                    view_ids, causal[0, 0], icos, isin,
+                )
+                mask = chosen[:, None, None]
             if use_pallas:
                 with scope("kv_read"):
                     qp = q[0].astype(cfg.dtype).reshape(
@@ -893,33 +1032,17 @@ def make_chunk_logits_fn(
                     )
             with scope("attn_out"):
                 x = x + _attn_out_proj(attn, lp, cfg)
-            with scope("mlp"):
-                h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-                x = x + _mlp(h, lp, cfg)
+            x, _ = _ffn_stage(x, lp, cfg)
         with scope("head"):
             last = jax.lax.dynamic_slice(
                 x, (0, true_len - 1, 0), (1, 1, cfg.dim)
             )
             logits = _logits_head(last, params, cfg)
-        return ks, vs, ksc, vsc, logits[0, 0]
+        return ks, vs, ksc, vsc, xs, logits[0, 0]
 
-    if quant:
-        def chunk_logits_q(params, ks, vs, ksc, vsc, tokens, start,
-                           true_len, table):
-            return body(
-                params, ks, vs, ksc, vsc, tokens, start, true_len,
-                table,
-            )
-
-        return chunk_logits_q
-
-    def chunk_logits(params, ks, vs, tokens, start, true_len, table):
-        ks, vs, _, _, logits = body(
-            params, ks, vs, None, None, tokens, start, true_len, table
-        )
-        return ks, vs, logits
-
-    return chunk_logits
+    return _with_state(
+        body, "chunk_logits_q" if quant else "chunk_logits", quant, sparse
+    )
 
 
 def make_chunk_prefill_fn(
@@ -938,27 +1061,15 @@ def make_chunk_prefill_fn(
         cfg, bucket, block_size, max_blocks, table_width,
         kernel=kernel, kv_quant=kv_quant, mesh=mesh,
     )
-    if kv_quant == "int8":
-        def chunk_prefill_q(params, ks, vs, ksc, vsc, tokens, start,
-                            true_len, table):
-            ks, vs, ksc, vsc, logits = inner(
-                params, ks, vs, ksc, vsc, tokens, start, true_len,
-                table,
-            )
-            with jax.named_scope("head"):
-                tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            return ks, vs, ksc, vsc, tok
 
-        return chunk_prefill_q
-
-    def chunk_prefill(params, ks, vs, tokens, start, true_len, table):
-        ks, vs, logits = inner(
-            params, ks, vs, tokens, start, true_len, table
-        )
+    def chunk_prefill(params, *args):
+        *state, logits = inner(params, *args)
         with jax.named_scope("head"):
             tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return ks, vs, tok
+        return (*state, tok)
 
+    if kv_quant == "int8":
+        chunk_prefill.__name__ = "chunk_prefill_q"
     return chunk_prefill
 
 
@@ -970,6 +1081,7 @@ def make_paged_decode_fn(
     kernel: str = "gather",
     kv_quant: str = "none",
     mesh: Optional[Mesh] = None,
+    probe: bool = False,
 ):
     """The single-token decode program over every slot, block-table
     edition.
@@ -997,10 +1109,29 @@ def make_paged_decode_fn(
     page's scale is monotone non-decreasing over a request's decode
     (amax only grows among live positions), so requantization drift
     of earlier tokens is bounded -- the int8 oracle's contract.
+
+    A sparse-expert configuration (``models/sparse_moe.py``) runs the
+    SAME program with two stages of its own, and ``(ks, vs)`` grows to
+    ``(ks, vs, xs)``, ``xs`` the indexer keys ``[layers, num_blocks,
+    block_size, indexer_head_dim]`` under the same page ids and rows:
+    after the K/V write the indexer (:func:`_select`) writes the
+    token's key, scores every column of the slot's view and keeps the
+    exact top ``indexer_topk`` live ones, and the read and attention
+    stages run as they are under THAT mask (the gathered pages under
+    the selection: on the v5e this read 2.3 ms a step faster than a
+    token-granular gather of the selected rows, PERF.md PR 27); the
+    feed-forward is :func:`_ffn_stage`'s router and experts. The
+    result is ``tokens ++ counts`` in one int32 vector
+    (``SPARSE_COUNTERS``' order), so the counts cost no second fetch.
+    ``probe=True`` (such configurations only) also returns each layer's
+    selection ``[layers, slots, columns]``: the benchmark's check
+    reads it, no serving path does.
     """
     cache_cap = max_blocks * block_size
     quant = kv_quant == "int8"
     use_pallas = kernel == "pallas"
+    sparse = sparse_moe.is_sparse_moe(cfg)
+    _check_read_path(cfg, kernel, kv_quant)
     groups = cfg.n_heads // cfg.kv_heads
     if use_pallas:
         decode_attention = _on_mesh(
@@ -1008,14 +1139,12 @@ def make_paged_decode_fn(
             block_size=block_size, max_blocks=max_blocks,
         )
 
-    def body(params, ks, vs, ksc, vsc, tokens, pos, tables, active):
+    def body(params, ks, vs, ksc, vsc, xs, tokens, pos, tables, active):
         scope = jax.named_scope
         slots = tokens.shape[0]
         with scope("embed"):
             x = _embed(params, tokens[:, None], cfg)
-        cos, sin = llama2.rope_cos_sin(
-            1, cfg.head_dim, positions=pos
-        )
+        cos, sin = _rope_tables(cfg, 1, pos)
         cos, sin = cos[:, None, :], sin[:, None, :]
         col = jnp.arange(cache_cap)
         mask = (col[None, :] <= pos[:, None])[:, None, None, None, :]
@@ -1030,6 +1159,20 @@ def make_paged_decode_fn(
         # Rows of the write-target page already live, broadcast over
         # the page's [kv_heads, block_size, head_dim].
         written = (idx[None, :] <= off[:, None])[:, None, :, None]
+        if sparse:
+            icos, isin = llama2.rope_cos_sin(
+                1, cfg.indexer_rope_dim, cfg.rope_theta, positions=pos
+            )
+            icos, isin = icos[:, None, :], isin[:, None, :]
+            live = mask[:, 0, 0]                  # [slots, 1, columns]
+            on = active > 0
+            picked = []
+            counts = {
+                "selected": 0,
+                "candidates": cfg.n_layers * jnp.sum(
+                    jnp.where(on, pos + 1, 0)
+                ),
+            }
         for i in range(cfg.n_layers):
             lp = params[f"layers_{i}"]
             with scope("qkv"):
@@ -1064,6 +1207,20 @@ def make_paged_decode_fn(
                 else:
                     ks = write_tokens(ks, i, pb, off, k[:, 0])
                     vs = write_tokens(vs, i, pb, off, v[:, 0])
+            if sparse:
+                xs, chosen = _select(
+                    h, lp, cfg, xs, i,
+                    lambda pool, keys: write_tokens(
+                        pool, i, pb, off, keys[:, 0]
+                    ),
+                    view_ids, live, icos, isin,
+                )
+                picked.append(chosen[:, 0])
+                mask = chosen[:, None, None]
+                with scope("indexer"):
+                    counts["selected"] += jnp.sum(
+                        jnp.where(on[:, None, None], chosen, False)
+                    )
             if use_pallas:
                 with scope("kv_read"):
                     qd = q[:, 0].astype(cfg.dtype).reshape(
@@ -1095,58 +1252,52 @@ def make_paged_decode_fn(
                     )
             with scope("attn_out"):
                 x = x + _attn_out_proj(attn, lp, cfg)
-            with scope("mlp"):
-                h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-                x = x + _mlp(h, lp, cfg)
+            x, moe = _ffn_stage(x, lp, cfg, weight=active)
+            for name, value in (moe or {}).items():
+                with scope("experts"):
+                    counts[name] = jnp.maximum(
+                        counts.get(name, 0), value
+                    ) if name.startswith("max") \
+                        else counts.get(name, 0) + value
         with scope("head"):
             logits = _logits_head(x, params, cfg)
             tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-        return ks, vs, ksc, vsc, tok
+            if sparse:
+                # The step's counts ride behind its tokens: one array,
+                # one fetch (order: SPARSE_COUNTERS).
+                tok = jnp.concatenate([tok, jnp.stack([
+                    counts[key] for key, _, _ in SPARSE_COUNTERS
+                ]).astype(jnp.int32)])
+        if probe:
+            # The selection each layer made, for the benchmark's
+            # check against the reference: [layers, slots, columns].
+            return ks, vs, ksc, vsc, xs, tok, jnp.stack(picked)
+        return ks, vs, ksc, vsc, xs, tok
 
-    if quant:
-        def decode_q(params, ks, vs, ksc, vsc, tokens, pos, tables,
-                     active):
-            return body(
-                params, ks, vs, ksc, vsc, tokens, pos, tables, active
-            )
+    return _with_state(
+        body, "decode_q" if quant else "decode", quant, sparse
+    )
 
-        return decode_q
 
-    def decode(params, ks, vs, tokens, pos, tables, active):
-        ks, vs, _, _, tok = body(
-            params, ks, vs, None, None, tokens, pos, tables, active
+def make_copy_block_fn():
+    """``(*state, src, dst)``: copy one physical page (all layers) of
+    every array the pool is made of -- the device half of
+    copy-on-write. Keys and values, an int8 pool's scale entries (a
+    copied page that kept the source's bytes but not its scale would
+    dequantize to garbage) and a sparse-expert configuration's indexer
+    keys all index pages on axis 1, so one rule moves them all."""
+
+    def copy_block(*args):
+        *state, src, dst = args
+        pages = [
+            jax.lax.dynamic_slice_in_dim(a, src, 1, axis=1) for a in state
+        ]
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(a, page, dst, axis=1)
+            for a, page in zip(state, pages)
         )
-        return ks, vs, tok
 
-    return decode
-
-
-def make_copy_block_fn(kv_quant: str = "none"):
-    """``(ks, vs, src, dst)``: copy one physical page (all layers) --
-    the device half of copy-on-write. In int8 mode the signature is
-    ``(ks, vs, ksc, vsc, src, dst)``: a page's scale entry travels
-    with its payload (a copied page that kept the source's bytes but
-    not its scale would dequantize to garbage)."""
-
-    def copy_block(ks, vs, src, dst):
-        k_page = jax.lax.dynamic_slice_in_dim(ks, src, 1, axis=1)
-        v_page = jax.lax.dynamic_slice_in_dim(vs, src, 1, axis=1)
-        ks = jax.lax.dynamic_update_slice_in_dim(ks, k_page, dst, axis=1)
-        vs = jax.lax.dynamic_update_slice_in_dim(vs, v_page, dst, axis=1)
-        return ks, vs
-
-    if kv_quant != "int8":
-        return copy_block
-
-    def copy_block_q(ks, vs, ksc, vsc, src, dst):
-        ks, vs = copy_block(ks, vs, src, dst)
-        k_sc = jax.lax.dynamic_slice_in_dim(ksc, src, 1, axis=1)
-        v_sc = jax.lax.dynamic_slice_in_dim(vsc, src, 1, axis=1)
-        ksc = jax.lax.dynamic_update_slice_in_dim(ksc, k_sc, dst, axis=1)
-        vsc = jax.lax.dynamic_update_slice_in_dim(vsc, v_sc, dst, axis=1)
-        return ks, vs, ksc, vsc
-
-    return copy_block_q
+    return copy_block
 
 
 # ---------------------------------------------------------------------
@@ -1230,6 +1381,7 @@ class PagedEngine(Engine):
                 f"cache_dtype={serve_cfg.cache_dtype!r} (the scale "
                 "side arrays are always f32)"
             )
+        _check_read_path(cfg, paged.kernel, paged.kv_quant)
         per_seq = serve_cfg.max_seq_len // bs
         # A pool SMALLER than one full-capacity sequence is legal --
         # it simply cannot serve max-length requests, and
@@ -1287,6 +1439,11 @@ class PagedEngine(Engine):
             "prefix_hit_blocks": 0, "prefill_chunks": 0,
             "cow_copies": 0, "trie_evictions": 0,
         }
+        if sparse_moe.is_sparse_moe(cfg):
+            self.paged_stats["decode_steps"] = 0
+            for _, name, help_ in SPARSE_COUNTERS:
+                self.paged_stats[name] = 0
+                get_registry().describe(name, help_)
         self._blocks_free_min = self.allocator.free_blocks
         # HELP once at construction (the ServeMeter.__init__
         # discipline); the suffix-dependent pool gauges re-describe
@@ -1322,9 +1479,24 @@ class PagedEngine(Engine):
         page -- sharding them would turn every page write into a
         collective for 4 bytes). ``cache_bytes`` counts both, which is
         what makes the fit-report capacity claim honest."""
+        self.xs = None
         if getattr(self.paged, "kv_quant", "none") != "int8":
             super()._init_cache()
             self.k_scales = self.v_scales = None
+            if sparse_moe.is_sparse_moe(self.cfg):
+                # The indexer's key: a third per-token array under the
+                # same page ids and rows (one key head, so replicated
+                # where the K/V heads are split).
+                shape = (
+                    self.cfg.n_layers, self.paged.num_blocks,
+                    self.paged.block_size, self.cfg.indexer_head_dim,
+                )
+                self.xs = jax.jit(
+                    lambda: jnp.zeros(shape, self.ks.dtype),
+                    out_shardings=self._rep,
+                )()
+                self.cache_bytes += math.prod(shape) \
+                    * self.ks.dtype.itemsize
             return
         shape = self._cache_shape()
         sc_shape = (self.cfg.n_layers, self.paged.num_blocks)
@@ -1356,6 +1528,27 @@ class PagedEngine(Engine):
             self.k_scales.shape, self.k_scales.dtype, sharding=self._rep
         )
 
+    # -- the pool's arrays, in the programs' argument order ------------
+    _STATE = ("ks", "vs", "k_scales", "v_scales", "xs")
+
+    def _state(self) -> List[Any]:
+        """Keys, values, then an int8 pool's two scale arrays, then a
+        sparse-expert configuration's indexer keys: what every paged
+        program takes after the weights, donates and returns first."""
+        return [
+            a for a in (getattr(self, n) for n in self._STATE)
+            if a is not None
+        ]
+
+    def _set_state(self, out) -> Any:
+        """Take the pool's arrays back from a program's results;
+        returns what follows them (the token)."""
+        names = [n for n in self._STATE if getattr(self, n) is not None]
+        for name, value in zip(names, out):
+            setattr(self, name, value)
+        rest = out[len(names):]
+        return rest[0] if rest else None
+
     # -- executable table ----------------------------------------------
     def _build(self, key):
         self._count_compile()
@@ -1378,16 +1571,16 @@ class PagedEngine(Engine):
         )
         scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=self._rep)
         slots = self.serve_cfg.slots
-        quant = self.paged.kv_quant == "int8"
         # int8 mode threads the f32 scale side arrays through every
-        # paged program: (ks, vs) becomes (ks, vs, ksc, vsc) in both
-        # args and results, all engine-resident and donated.
-        state = (cache, cache) + (
-            (self._scale_abstract(), self._scale_abstract())
-            if quant else ()
-        )
+        # paged program, a sparse-expert configuration its indexer
+        # keys: (ks, vs) grows in both args and results, all
+        # engine-resident and donated.
         state_shardings = (self._cache_sharding, self._cache_sharding) \
-            + ((self._rep, self._rep) if quant else ())
+            + (self._rep,) * (len(self._state()) - 2)
+        state = (cache, cache) + tuple(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=self._rep)
+            for a in self._state()[2:]
+        )
         if key[0] == "prefill":
             bucket = key[1]
             fn = make_chunk_prefill_fn(
@@ -1404,12 +1597,12 @@ class PagedEngine(Engine):
             )
             args = (params_abs,) + state + (tokens, scalar, scalar,
                                             table)
-        elif key[0] == "decode":
+        elif key[0] in ("decode", "decode_probe"):
             fn = make_paged_decode_fn(
                 self.cfg, self.paged.block_size,
                 self.max_blocks_per_seq, self.table_width,
                 kernel=self.paged.kernel, kv_quant=self.paged.kv_quant,
-                mesh=self.mesh,
+                mesh=self.mesh, probe=key[0] == "decode_probe",
             )
             vec = jax.ShapeDtypeStruct(
                 (slots,), jnp.int32, sharding=self._rep
@@ -1419,7 +1612,7 @@ class PagedEngine(Engine):
             )
             args = (params_abs,) + state + (vec, vec, tables, vec)
         else:  # ("copy_block",)
-            fn = make_copy_block_fn(kv_quant=self.paged.kv_quant)
+            fn = make_copy_block_fn()
             jitted = jax.jit(
                 fn,
                 donate_argnums=tuple(range(len(state))),
@@ -1429,7 +1622,9 @@ class PagedEngine(Engine):
         jitted = jax.jit(
             fn,
             donate_argnums=tuple(range(1, 1 + len(state))),
-            out_shardings=state_shardings + (self._rep,),
+            out_shardings=state_shardings + (self._rep,) * (
+                2 if key[0] == "decode_probe" else 1
+            ),
         )
         return jitted.lower(*args).compile()
 
@@ -1715,15 +1910,11 @@ class PagedEngine(Engine):
         if st.next_chunk >= len(st.plan):
             raise ValueError(f"slot {slot} has no prefill pending")
         start, run, bucket = st.plan[st.next_chunk]
-        quant = self.paged.kv_quant == "int8"
         with span("prefill", hist="serve_prefill_s", n=bucket):
             with span("prefill.prep"):
                 padded = np.zeros((1, bucket), np.int32)
                 padded[0, :run] = st.prompt[start:start + run]
-                state = [self.ks, self.vs] + (
-                    [self.k_scales, self.v_scales] if quant else []
-                )
-                args = [self.params, *state,
+                args = [self.params, *self._state(),
                     self._rep_arr(padded), self._rep_arr(start),
                     self._rep_arr(run),
                     self._rep_arr(self._tables[slot]),
@@ -1743,11 +1934,7 @@ class PagedEngine(Engine):
                 else:
                     exec_ = self._get_exec(("prefill", bucket))
             with span("prefill.dispatch"):
-                if quant:
-                    (self.ks, self.vs, self.k_scales, self.v_scales,
-                     tok) = exec_(*args)
-                else:
-                    self.ks, self.vs, tok = exec_(*args)
+                tok = self._set_state(exec_(*args))
             st.next_chunk += 1
             st.forwarded += bucket
             self.prefill_forwarded_total += bucket
@@ -1780,17 +1967,9 @@ class PagedEngine(Engine):
             return
         new, copied = self.allocator.cow(blk)
         if copied:
-            exec_ = self._get_exec(("copy_block",))
-            if self.paged.kv_quant == "int8":
-                self.ks, self.vs, self.k_scales, self.v_scales = exec_(
-                    self.ks, self.vs, self.k_scales, self.v_scales,
-                    self._rep_arr(blk), self._rep_arr(new),
-                )
-            else:
-                self.ks, self.vs = exec_(
-                    self.ks, self.vs, self._rep_arr(blk),
-                    self._rep_arr(new),
-                )
+            self._set_state(self._get_exec(("copy_block",))(
+                *self._state(), self._rep_arr(blk), self._rep_arr(new),
+            ))
             st.blocks[idx] = new
             self._write_table(slot, st.blocks)
             self.paged_stats["cow_copies"] += 1
@@ -1810,16 +1989,12 @@ class PagedEngine(Engine):
         still mid-chunked-prefill, must not dirty live pages)."""
         if active is None:
             active = [True] * self.serve_cfg.slots
-        quant = self.paged.kv_quant == "int8"
         with span("decode", hist="serve_decode_s"):
             with span("decode.prep"):
                 for s, (is_on, pos) in enumerate(zip(active, positions)):
                     if is_on and s in self._slot_state:
                         self._cow_write_target(s, int(pos))
                 exec_ = self._get_exec(("decode",))
-                state = [self.ks, self.vs] + (
-                    [self.k_scales, self.v_scales] if quant else []
-                )
                 args = (
                     self._rep_arr(np.asarray(tokens, np.int32)),
                     self._rep_arr(np.asarray(positions, np.int32)),
@@ -1827,14 +2002,56 @@ class PagedEngine(Engine):
                     self._rep_arr(np.asarray(active, np.int32)),
                 )
             with span("decode.dispatch"):
-                out = exec_(self.params, *state, *args)
-                if quant:
-                    (self.ks, self.vs, self.k_scales, self.v_scales,
-                     toks) = out
-                else:
-                    self.ks, self.vs, toks = out
+                toks = self._set_state(
+                    exec_(self.params, *self._state(), *args)
+                )
             with span("decode.fetch"):
-                return np.asarray(toks)
+                toks = np.asarray(toks)
+        if self.xs is None:
+            return toks
+        return self._take_counts(toks)
+
+    def probe_selection(
+        self,
+        tokens: Sequence[int],
+        positions: Sequence[int],
+        active: Sequence[bool],
+    ) -> np.ndarray:
+        """What the indexer of a sparse-expert configuration selects
+        for the decode step :meth:`decode` would run on these
+        arguments: bool ``[layers, slots, capacity]``. The decode
+        program itself, compiled once more with its per-layer masks as
+        a result (so: built on first use, outside any timed window);
+        it writes what the step writes, and a :meth:`decode` on the
+        same arguments afterwards writes the same again. No counts, no
+        token."""
+        if self.xs is None:
+            raise ValueError("probe_selection needs an indexer")
+        out = self._get_exec(("decode_probe",))(
+            self.params, *self._state(),
+            self._rep_arr(np.asarray(tokens, np.int32)),
+            self._rep_arr(np.asarray(positions, np.int32)),
+            self._tables_device(),
+            self._rep_arr(np.asarray(active, np.int32)),
+        )
+        self._set_state(out)
+        return np.asarray(out[-1])
+
+    def _take_counts(self, fetched: np.ndarray) -> np.ndarray:
+        """Split a sparse-expert decode step's fetch into its tokens
+        and its counts (``SPARSE_COUNTERS``' order); the counts go to
+        ``paged_stats`` and the registry."""
+        slots = self.serve_cfg.slots
+        reg, stats = get_registry(), self.paged_stats
+        stats["decode_steps"] += 1
+        for (_, name, _), value in zip(SPARSE_COUNTERS, fetched[slots:]):
+            if name.endswith("_total"):
+                stats[name] += int(value)
+                reg.inc(name, int(value))
+            else:
+                stats[name] = max(stats[name], int(value))
+                reg.set_gauge(name, stats[name])
+        return fetched[:slots]
 
     def release(self, slot: int) -> None:
         """Drop the request's page references (the trie keeps its own,

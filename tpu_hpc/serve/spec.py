@@ -62,7 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from tpu_hpc.kernels.paged_attention import write_tokens
-from tpu_hpc.models import llama2
+from tpu_hpc.models import llama2, sparse_moe
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.engine import (
     _attn_out_proj,
@@ -562,6 +562,12 @@ class SpecRunner:
                 "speculative decoding rides the paged engine "
                 "(serve/paging.py); slab and disagg engines are not "
                 "supported"
+            )
+        for model_cfg in (engine.cfg, draft_cfg):
+            sparse_moe.refuse(
+                model_cfg, "speculative decoding (serve/spec.py)",
+                "its draft, verify and mirrored-pool programs carry "
+                "keys and values only, and a page now has three arrays",
             )
         if cfg.k > max(engine.serve_cfg.prefill_buckets):
             raise ValueError(

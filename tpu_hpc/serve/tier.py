@@ -42,6 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from tpu_hpc.models import sparse_moe
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.paging import SCRATCH_BLOCK, BlockBudgetError
 
@@ -63,6 +64,11 @@ class HostTier:
                 "HostTier needs the prefix trie (prefix_cache=True): "
                 "parked trie pages are the only thing worth spilling"
             )
+        sparse_moe.refuse(
+            engine.cfg, "the host KV tier (serve/tier.py)",
+            "it spills and refills a page's keys and values, and a "
+            "page now has three arrays",
+        )
         self.engine = engine
         c = engine.cfg
         bs = engine.paged.block_size
