@@ -310,8 +310,9 @@ def test_every_pallas_kernel_lowers_for_v5e(v5e_2x2):
     )
     vec = sds((slots,), i32, rep)
     assert mosaic_calls(
-        program, params, pool, pool, vec, vec,
-        sds((slots, width), i32, rep), vec,
+        program, params, pool, pool, vec,
+        sds((len(paging.STEP_ROWS), slots), i32, rep),
+        sds((slots, width), i32, rep),
     ) == cfg.n_layers
 
 
@@ -375,7 +376,8 @@ def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
     if program == "decode":
         fn = paging.make_paged_decode_fn(cfg, bs, mb, width)
         vec = sds((slots,), i32)
-        args = (vec, vec, sds((slots, width), i32), vec)
+        args = (vec, sds((len(paging.STEP_ROWS), slots), i32),
+                sds((slots, width), i32))
         view_pages = slots * mb
     else:
         fn = paging.make_chunk_prefill_fn(cfg, bucket, bs, mb, width)

@@ -376,7 +376,12 @@ class TestPagedParity:
         # same tick's decode (the slab admission-tick behavior).
         batcher.step()
         assert len(batcher.results["s"]) == tokens_before + 2
-        assert len(batcher.results["l"]) == 2
+        # ... whose token is in flight for one tick more (the paged
+        # engine hands a step's tokens back with the next dispatch).
+        assert len(batcher.results["l"]) == 2 - warm_paged.decode_lag
+        batcher.step()
+        assert len(batcher.results["s"]) == tokens_before + 3
+        assert len(batcher.results["l"]) == 3 - warm_paged.decode_lag
         got = batcher.run()
         assert got["s"] == greedy_oracle(short, 8)
         assert got["l"] == greedy_oracle(long, 3)

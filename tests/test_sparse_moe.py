@@ -164,7 +164,7 @@ def test_probe_reports_the_selection_the_reference_makes(params, mesh):
     assert picked[:, 0].sum(-1).tolist() == [TOPK, TOPK]
     assert not picked[:, 0, 38:].any()
     # the probe wrote what the step writes: decoding after it is exact
-    nxt = eng.decode([first, 0, 0], [37, 0, 0], active)[0]
+    nxt = eng.decode_now([first, 0, 0], [37, 0, 0], active)[0]
     logits = _reference_logits(params, prompt, [first, int(nxt)])
     assert int(nxt) == logits[1].argmax()
 
@@ -358,23 +358,26 @@ def test_counts_come_back_with_the_tokens(params, mesh):
 # the decode and chunk-prefill programs of two dense configurations,
 # taken on the parent commit of the PR that made the stages
 # configurable (PR 27). A PR that MEANS to change a dense program
-# re-pins them and says so.
+# re-pins them and says so: PR 28 re-pinned the eight decode programs
+# (their input token is chosen between the host's and the one the step
+# before left on the device); the chunk programs are still PR 27's
+# parent's.
 DENSE_DIGESTS = {
-    "gqa-decode-gather-none": "5ea79059753ae1a5",
+    "gqa-decode-gather-none": "81408bbaba39a8c9",
     "gqa-prefill-gather-none": "8a8972db9fc5d25e",
-    "gqa-decode-gather-int8": "432805e616a972ab",
+    "gqa-decode-gather-int8": "da821b4063db53ed",
     "gqa-prefill-gather-int8": "2144b4b5b662fbda",
-    "gqa-decode-pallas-none": "98615ca699777d9b",
+    "gqa-decode-pallas-none": "667f2ad9344d9e1a",
     "gqa-prefill-pallas-none": "76d6e8f95829fbe8",
-    "gqa-decode-pallas-int8": "df25f8e7c42458c1",
+    "gqa-decode-pallas-int8": "35d63b0063e32c5b",
     "gqa-prefill-pallas-int8": "4695e884e5b48fb4",
-    "mha-decode-gather-none": "97c35e05310af789",
+    "mha-decode-gather-none": "9816f8bed450484a",
     "mha-prefill-gather-none": "141949fcab431570",
-    "mha-decode-gather-int8": "1cc4cb2daab9645d",
+    "mha-decode-gather-int8": "4ce500996804bdeb",
     "mha-prefill-gather-int8": "71101e9fc0221492",
-    "mha-decode-pallas-none": "530add4140b53c0e",
+    "mha-decode-pallas-none": "25537345de2c10ec",
     "mha-prefill-pallas-none": "0e1e073173ce7163",
-    "mha-decode-pallas-int8": "c7b2c918903d377f",
+    "mha-decode-pallas-int8": "7b0f374e3c25c107",
     "mha-prefill-pallas-int8": "bc03f9ccf4d55fa3",
 }
 
@@ -403,8 +406,9 @@ def _dense_program_text(tag, program, kernel, quant, mesh):
             cfg, block, per_seq, width, kernel=kernel, kv_quant=quant,
             mesh=mesh,
         )).lower(
-            weights, *state, vec, vec,
-            jax.ShapeDtypeStruct((slots, width), i32), vec,
+            weights, *state, vec,
+            jax.ShapeDtypeStruct((len(paging.STEP_ROWS), slots), i32),
+            jax.ShapeDtypeStruct((slots, width), i32),
         )
     else:
         lowered = jax.jit(paging.make_chunk_prefill_fn(

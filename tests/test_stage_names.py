@@ -80,8 +80,9 @@ def program_scopes(devices, tiny_abstract):
     decode = jax.jit(paging.make_paged_decode_fn(
         TINY, BLOCK, PER_SEQ, WIDTH
     )).lower(
-        tiny_abstract, cache, cache, vec, vec,
-        jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32), vec,
+        tiny_abstract, cache, cache, vec,
+        jax.ShapeDtypeStruct((len(paging.STEP_ROWS), SERVE.slots), i32),
+        jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32),
     )
     prefill = jax.jit(paging.make_chunk_prefill_fn(
         TINY, 8, BLOCK, PER_SEQ, WIDTH
@@ -394,8 +395,12 @@ def sparse_program_scopes(devices):
     decode = jax.jit(paging.make_paged_decode_fn(
         cfg, BLOCK, PER_SEQ, WIDTH
     )).lower(
-        weights, cache, cache, keys, vec, vec,
-        jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32), vec,
+        weights, cache, cache, keys,
+        jax.ShapeDtypeStruct(
+            (SERVE.slots + len(paging.SPARSE_COUNTERS),), i32
+        ),
+        jax.ShapeDtypeStruct((len(paging.STEP_ROWS), SERVE.slots), i32),
+        jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32),
     )
     prefill = jax.jit(paging.make_chunk_prefill_fn(
         cfg, 8, BLOCK, PER_SEQ, WIDTH

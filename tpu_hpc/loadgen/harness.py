@@ -347,9 +347,15 @@ class _CostModelEngine:
         self._clock.advance(cost + draft_cost)
         return out
 
+    # The modeled step costs its time when it is called, so its tokens
+    # come back in the same call whatever the engine could keep in
+    # flight: every virtual-clock count stays what it was.
+    decode_lag = 0
+
     def decode(self, tokens, positions, active=None):
         if active is not None:
-            out = self._engine.decode(tokens, positions, active)
+            step = getattr(self._engine, "decode_now", self._engine.decode)
+            out = step(tokens, positions, active)
         else:
             out = self._engine.decode(tokens, positions)
         self._clock.advance(self._decode_s)

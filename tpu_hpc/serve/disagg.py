@@ -566,7 +566,8 @@ class DisaggEngine:
         active: Optional[Sequence[bool]] = None,
     ) -> np.ndarray:
         if self.is_paged:
-            return self.decode_engine.decode(tokens, positions, active)
+            # Synchronous across the tiers: a step's own tokens.
+            return self.decode_engine.decode_now(tokens, positions, active)
         return self.decode_engine.decode(tokens, positions)
 
     def describe(self) -> dict:

@@ -97,6 +97,23 @@ from tpu_hpc.serve.engine import (
 
 SCRATCH_BLOCK = 0
 
+# Rows of the decode program's one host-made argument ``[4, slots]``:
+# a slot continues from the token the step before left on the device
+# unless ``fresh`` says the host's is the one to read.
+STEP_ROWS = ("token", "position", "active", "fresh")
+
+# What the one-step-ahead dispatch counts, beside ``serve_compiles_total``
+# (registry name, HELP); ``PagedEngine.paged_stats`` holds them too.
+DECODE_COUNTERS: Tuple[Tuple[str, str], ...] = (
+    ("serve_decode_overlapped_total",
+     "Decode steps dispatched while the step before was still "
+     "unfetched (over decode steps: how often the device had a step "
+     "queued behind the one running)"),
+    ("serve_decode_discarded_total",
+     "Slot-steps computed past an end the host saw one step late "
+     "(end of sequence) and dropped"),
+)
+
 # What a decode step of a sparse-expert configuration counts: (the
 # program's key for it, the registry's name, HELP), in the order the
 # program packs them behind its tokens (one device fetch a step).
@@ -1086,16 +1103,34 @@ def make_paged_decode_fn(
     """The single-token decode program over every slot, block-table
     edition.
 
-    ``(params, ks, vs, tokens [slots], pos [slots],
-    tables [slots, table_width], active [slots])`` ->
-    ``(ks, vs, next_tokens)``: each active slot's token K/V is
-    scattered into page ``tables[s, pos/bs]`` at offset ``pos % bs``;
-    inactive slots (free, or still prefilling their prompt) are
-    redirected to the scratch block so their garbage write cannot
-    corrupt a live page. Attention reads each slot's logical view
-    through its table and masks columns ``> pos`` -- stale pages from
-    an evicted tenant are unreachable, which is what makes page reuse
-    safe (the slab engine's slot-reuse invariant, per page).
+    ``(params, ks, vs, prev [slots], step [4, slots],
+    tables [slots, table_width])`` -> ``(ks, vs, next_tokens)``.
+    ``step``'s rows are ``STEP_ROWS``: the host's token, the position,
+    the active mask and ``fresh`` for every slot, in ONE array (one
+    transfer a step). ``prev`` is this program's own last result, left
+    on the device: a slot with ``fresh`` 0 continues from
+    ``prev[s]`` (the token the step before computed for it, which the
+    host may not have fetched yet), a slot with ``fresh`` 1 (it joins
+    this step: its first token came from its prompt's last chunk) from
+    the host's ``step[0, s]``. So step k+1 can be dispatched before
+    step k's tokens reach the host, and one step is always queued
+    behind the one running.
+
+    Each active slot's token K/V is scattered into page
+    ``tables[s, pos/bs]`` at offset ``pos % bs``; inactive slots (free,
+    or still prefilling their prompt) are redirected to the scratch
+    block so their garbage write cannot corrupt a live page. Attention
+    reads each slot's logical view through its table and masks columns
+    ``> pos`` -- stale pages from an evicted tenant are unreachable,
+    which is what makes page reuse safe (the slab engine's slot-reuse
+    invariant, per page). The same mask covers a step dispatched past
+    an end-of-sequence the host had not seen yet (it sees one a step
+    late): that step wrote row ``pos`` of a page that was still the
+    slot's own when it was dispatched (pages are reserved at admission
+    for prompt + max_new, and a step in flight holds its own copy of
+    the table), the device runs programs in dispatch order, so a later
+    tenant's chunk lands after it, and a row past a tenant's length is
+    never read.
 
     ``kernel="pallas"`` swaps the view gather + dense attention for
     :func:`tpu_hpc.kernels.paged_attention.paged_decode_attention`
@@ -1122,7 +1157,8 @@ def make_paged_decode_fn(
     token-granular gather of the selected rows, PERF.md PR 27); the
     feed-forward is :func:`_ffn_stage`'s router and experts. The
     result is ``tokens ++ counts`` in one int32 vector
-    (``SPARSE_COUNTERS``' order), so the counts cost no second fetch.
+    (``SPARSE_COUNTERS``' order), so the counts cost no second fetch
+    (and ``prev`` is that vector: its first ``slots`` entries are read).
     ``probe=True`` (such configurations only) also returns each layer's
     selection ``[layers, slots, columns]``: the benchmark's check
     reads it, no serving path does.
@@ -1139,10 +1175,12 @@ def make_paged_decode_fn(
             block_size=block_size, max_blocks=max_blocks,
         )
 
-    def body(params, ks, vs, ksc, vsc, xs, tokens, pos, tables, active):
+    def body(params, ks, vs, ksc, vsc, xs, prev, step, tables):
         scope = jax.named_scope
-        slots = tokens.shape[0]
+        host_tokens, pos, active, fresh = step
+        slots = step.shape[1]
         with scope("embed"):
+            tokens = jnp.where(fresh > 0, host_tokens, prev[:slots])
             x = _embed(params, tokens[:, None], cfg)
         cos, sin = _rope_tables(cfg, 1, pos)
         cos, sin = cos[:, None, :], sin[:, None, :]
@@ -1339,8 +1377,10 @@ class PagedEngine(Engine):
     * :meth:`prefill_step` -- run the next chunk; returns the first
       greedy token once the prompt is fully prefilled (and registers
       the prompt's full pages in the trie);
-    * :meth:`decode` -- one token for every slot, block tables and the
-      active mask riding as data;
+    * :meth:`decode` -- dispatch one token for every slot, block tables
+      and the active mask riding as data, and hand back the tokens of
+      the step BEFORE (``decode_lag``): the step's own stay on the
+      device and feed the next; :meth:`flush` takes the last;
     * :meth:`release` -- drop the request's page references (trie
       references survive, so its prompt stays hit-able).
     """
@@ -1428,6 +1468,16 @@ class PagedEngine(Engine):
         )
         self._tables_dev = None  # rebuilt lazily after table edits
         self._slot_state: Dict[int, _PagedSlot] = {}
+        # The step in flight (decode()): the decode program's last
+        # result, on the device; whether the host has yet to take it;
+        # and the slots whose NEXT input token is in it and nowhere
+        # else (active in that step and not released since).
+        self._toks = self._rep_arr(np.zeros(
+            serve_cfg.slots + len(SPARSE_COUNTERS)
+            * sparse_moe.is_sparse_moe(cfg), np.int32,
+        ))
+        self._unfetched = False
+        self._on_device = np.zeros(serve_cfg.slots, bool)
         self.prefill_forwarded_total = 0
         # Registry gauge names are process-global: a multi-pool
         # process (the disagg tiers) must suffix them or the pools
@@ -1437,13 +1487,14 @@ class PagedEngine(Engine):
         self.paged_stats = {
             "prefix_lookups": 0, "prefix_hits": 0,
             "prefix_hit_blocks": 0, "prefill_chunks": 0,
-            "cow_copies": 0, "trie_evictions": 0,
+            "cow_copies": 0, "trie_evictions": 0, "decode_steps": 0,
         }
+        counters = DECODE_COUNTERS
         if sparse_moe.is_sparse_moe(cfg):
-            self.paged_stats["decode_steps"] = 0
-            for _, name, help_ in SPARSE_COUNTERS:
-                self.paged_stats[name] = 0
-                get_registry().describe(name, help_)
+            counters += tuple(c[1:] for c in SPARSE_COUNTERS)
+        for name, help_ in counters:
+            self.paged_stats[name] = 0
+            get_registry().describe(name, help_)
         self._blocks_free_min = self.allocator.free_blocks
         # HELP once at construction (the ServeMeter.__init__
         # discipline); the suffix-dependent pool gauges re-describe
@@ -1604,13 +1655,16 @@ class PagedEngine(Engine):
                 kernel=self.paged.kernel, kv_quant=self.paged.kv_quant,
                 mesh=self.mesh, probe=key[0] == "decode_probe",
             )
-            vec = jax.ShapeDtypeStruct(
-                (slots,), jnp.int32, sharding=self._rep
+            prev = jax.ShapeDtypeStruct(
+                self._toks.shape, jnp.int32, sharding=self._rep
+            )
+            step = jax.ShapeDtypeStruct(
+                (len(STEP_ROWS), slots), jnp.int32, sharding=self._rep
             )
             tables = jax.ShapeDtypeStruct(
                 (slots, self.table_width), jnp.int32, sharding=self._rep
             )
-            args = (params_abs,) + state + (vec, vec, tables, vec)
+            args = (params_abs,) + state + (prev, step, tables)
         else:  # ("copy_block",)
             fn = make_copy_block_fn()
             jitted = jax.jit(
@@ -1978,15 +2032,52 @@ class PagedEngine(Engine):
             )
             self._set_block_gauges()
 
+    @property
+    def decode_lag(self) -> int:
+        """Steps between a :meth:`decode` call and the call that
+        returns its tokens: 1 (one step stays in flight), or 0 where
+        something reads a step's tokens on the host before the next
+        can go (speculative decoding drafts from them; the host tier
+        stays as it was measured). The scheduler emits by it."""
+        return int(self.spec is None and self.host_tier is None)
+
+    def _step_inputs(self, tokens, positions, active):
+        """What the decode program reads for a step on these
+        arguments: ``(prev, step)`` (``make_paged_decode_fn``). A slot
+        that was active in the step in flight and has not been
+        released since reads the DEVICE's token (``prev``; the host's
+        entry for it is stale by a step and ignored); every other slot
+        reads the host's ``tokens[s]``: one that joins this step (its
+        first token came from ``prefill_step``), and all of them when
+        nothing is in flight. Changes nothing, so :meth:`decode` and
+        :meth:`probe_selection` on the same arguments read the same."""
+        return self._toks, self._rep_arr(np.stack([
+            np.asarray(x, np.int32)
+            for x in (tokens, positions, active, ~self._on_device)
+        ]))
+
     def decode(
         self,
         tokens: Sequence[int],
         positions: Sequence[int],
         active: Optional[Sequence[bool]] = None,
-    ) -> np.ndarray:
-        """One decode step for every slot; ``active[s]`` False redirects
-        slot ``s``'s write to the scratch page (free slots, and slots
-        still mid-chunked-prefill, must not dirty live pages)."""
+    ) -> Optional[np.ndarray]:
+        """Dispatch one decode step for every slot and return the
+        tokens of the step BEFORE it (``decode_lag`` 1; ``None`` when
+        none was in flight), so that step k+1 is queued on the device
+        before the host waits for step k. The step's own tokens stay
+        on the device and are the next step's input
+        (:meth:`_step_inputs` says which slots read them and which
+        the host's ``tokens``); :meth:`flush` takes them. With
+        ``decode_lag`` 0 the step's own tokens come back at once.
+
+        ``active[s]`` False redirects slot ``s``'s write to the scratch
+        page (free slots, and slots still mid-chunked-prefill, must not
+        dirty live pages). An end the host can only see in a token (end
+        of sequence) is seen one step late: the slot ran one step more,
+        the caller drops that token, and :meth:`release` counts it
+        (``serve_decode_discarded_total``); ``make_paged_decode_fn``
+        says why its write harms nobody."""
         if active is None:
             active = [True] * self.serve_cfg.slots
         with span("decode", hist="serve_decode_s"):
@@ -1996,20 +2087,37 @@ class PagedEngine(Engine):
                         self._cow_write_target(s, int(pos))
                 exec_ = self._get_exec(("decode",))
                 args = (
-                    self._rep_arr(np.asarray(tokens, np.int32)),
-                    self._rep_arr(np.asarray(positions, np.int32)),
+                    *self._step_inputs(tokens, positions, active),
                     self._tables_device(),
-                    self._rep_arr(np.asarray(active, np.int32)),
                 )
             with span("decode.dispatch"):
-                toks = self._set_state(
+                before = self._toks if self._unfetched else None
+                self._toks = self._set_state(
                     exec_(self.params, *self._state(), *args)
                 )
-            with span("decode.fetch"):
-                toks = np.asarray(toks)
-        if self.xs is None:
-            return toks
-        return self._take_counts(toks)
+                self._unfetched = True
+                self._on_device = np.array(active, bool)
+                if before is not None:
+                    self._count("serve_decode_overlapped_total")
+            if not self.decode_lag:
+                return self.flush()
+            return None if before is None else self._take(before)
+
+    def flush(self) -> Optional[np.ndarray]:
+        """The tokens of the step in flight (``None`` if there is
+        none): after it the host's tokens are current, and the next
+        step reads every slot's from the host."""
+        if not self._unfetched:
+            return None
+        self._unfetched = False
+        self._on_device[:] = False
+        return self._take(self._toks)
+
+    def decode_now(self, tokens, positions, active=None) -> np.ndarray:
+        """One step, synchronously: dispatch it and take its own
+        tokens (what a caller that is not the lagged tick wants)."""
+        out = self.decode(tokens, positions, active)
+        return self.flush() if self.decode_lag else out
 
     def probe_selection(
         self,
@@ -2019,38 +2127,42 @@ class PagedEngine(Engine):
     ) -> np.ndarray:
         """What the indexer of a sparse-expert configuration selects
         for the decode step :meth:`decode` would run on these
-        arguments: bool ``[layers, slots, capacity]``. The decode
-        program itself, compiled once more with its per-layer masks as
-        a result (so: built on first use, outside any timed window);
-        it writes what the step writes, and a :meth:`decode` on the
-        same arguments afterwards writes the same again. No counts, no
-        token."""
+        arguments (its input tokens resolved by the same
+        :meth:`_step_inputs`): bool ``[layers, slots, capacity]``. The
+        decode program itself, compiled once more with its per-layer
+        masks as a result (so: built on first use, outside any timed
+        window); it writes what the step writes, and a :meth:`decode`
+        on the same arguments afterwards writes the same again. No
+        counts, no token: the step in flight stays as it is."""
         if self.xs is None:
             raise ValueError("probe_selection needs an indexer")
         out = self._get_exec(("decode_probe",))(
             self.params, *self._state(),
-            self._rep_arr(np.asarray(tokens, np.int32)),
-            self._rep_arr(np.asarray(positions, np.int32)),
+            *self._step_inputs(tokens, positions, active),
             self._tables_device(),
-            self._rep_arr(np.asarray(active, np.int32)),
         )
         self._set_state(out)
         return np.asarray(out[-1])
 
-    def _take_counts(self, fetched: np.ndarray) -> np.ndarray:
-        """Split a sparse-expert decode step's fetch into its tokens
-        and its counts (``SPARSE_COUNTERS``' order); the counts go to
-        ``paged_stats`` and the registry."""
+    def _count(self, name: str, n: int = 1) -> None:
+        self.paged_stats[name] += n
+        get_registry().inc(name, n)
+
+    def _take(self, toks) -> np.ndarray:
+        """Fetch one step's result: its tokens, and a sparse-expert
+        step's counts (``SPARSE_COUNTERS``' order) into ``paged_stats``
+        and the registry."""
+        with span("decode.fetch"):
+            fetched = np.asarray(toks)
         slots = self.serve_cfg.slots
-        reg, stats = get_registry(), self.paged_stats
+        stats = self.paged_stats
         stats["decode_steps"] += 1
         for (_, name, _), value in zip(SPARSE_COUNTERS, fetched[slots:]):
             if name.endswith("_total"):
-                stats[name] += int(value)
-                reg.inc(name, int(value))
+                self._count(name, int(value))
             else:
                 stats[name] = max(stats[name], int(value))
-                reg.set_gauge(name, stats[name])
+                get_registry().set_gauge(name, stats[name])
         return fetched[:slots]
 
     def release(self, slot: int) -> None:
@@ -2059,6 +2171,11 @@ class PagedEngine(Engine):
         st = self._slot_state.pop(slot, None)
         if st is None:
             return
+        if self._on_device[slot]:
+            # Released with its next token still in the step in
+            # flight: that slot-step ran past the request's end.
+            self._on_device[slot] = False
+            self._count("serve_decode_discarded_total")
         freed = self.allocator.release(st.blocks)
         self._write_table(slot, [])
         get_bus().emit("kv_block", action="free", n=freed, slot=slot)
@@ -2097,6 +2214,8 @@ class PagedEngine(Engine):
                 "engines)"
             )
         self._slot_state = {}
+        self._unfetched = False
+        self._on_device[:] = False
         self.allocator = BlockAllocator(
             self.paged.num_blocks, host_blocks=self.paged.host_blocks
         )

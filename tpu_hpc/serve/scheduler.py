@@ -10,9 +10,24 @@ stalls short ones behind it and a finished one never leaves its slot
 idle -- the continuous-batching property, without ever changing a
 compiled shape.
 
+One step in flight. An engine may keep a decode step's tokens on the
+device and feed the next step from there (``engine.decode_lag`` 1: the
+paged engine; every other engine, and a speculative or host-tier one,
+states 0 or nothing). The tick then DISPATCHES step k+1 before it has
+step k's tokens: ``decode`` hands back the tokens of the step before,
+and the tick emits those against the record it kept of that step
+(slot -> request). Position and the count of steps still to run
+advance at dispatch, so an end by length is known before the next
+dispatch and costs nothing; an end of sequence is seen one step late:
+the slot ran one step more and that token is dropped
+(``serve_decode_discarded_total``). A slot stays its request's until
+the request's last token is on the host. With a lag of 0 the same
+routine emits the step just dispatched.
+
 Slot invariants (pinned by tests/test_serve.py):
-  * a slot's position counter equals prompt_len + tokens generated so
-    far, resets on (re-)admission, and is what feeds RoPE in decode;
+  * a slot's position counter equals prompt_len + decode steps
+    dispatched so far, resets on (re-)admission, and is what feeds
+    RoPE in decode;
   * slot reuse is safe: the engine's per-slot length mask bounds every
     read to ``<= pos``, so a previous tenant's stale cache rows are
     unreachable;
@@ -166,7 +181,7 @@ class _Slot:
     rid: Optional[str] = None
     pos: int = 0          # next cache write position == tokens held
     last_token: int = 0   # the token the next decode step consumes
-    remaining: int = 0    # new tokens still to generate
+    remaining: int = 0    # decode steps still to dispatch
     prefilling: bool = False  # paged: prompt chunks still running
 
     @property
@@ -175,7 +190,13 @@ class _Slot:
 
     @property
     def decoding(self) -> bool:
-        return self.rid is not None and not self.prefilling
+        """In the next decode step. A slot whose steps are all
+        dispatched waits, neither decoding nor free, for its last
+        token to reach the host."""
+        return (
+            self.rid is not None and not self.prefilling
+            and self.remaining > 0
+        )
 
 
 class ContinuousBatcher:
@@ -213,6 +234,11 @@ class ContinuousBatcher:
         self.stall_signal = stall_signal
         self._paged = bool(getattr(engine, "is_paged", False))
         self._spec = getattr(engine, "spec", None) is not None
+        # 1: ``engine.decode`` returns the tokens of the step BEFORE
+        # the one it dispatches (module docstring); the step awaiting
+        # its tokens is ``_flight``: [(slot index, rid)].
+        self._lag = int(getattr(engine, "decode_lag", 0))
+        self._flight: Optional[List[tuple]] = None
         self.slots = [_Slot() for _ in range(engine.serve_cfg.slots)]
         self.pending: List[Request] = []
         self.results: Dict[str, List[int]] = {}
@@ -337,7 +363,12 @@ class ContinuousBatcher:
 
     @property
     def done(self) -> bool:
-        return not self.pending and self.active == 0
+        if self.pending or self.active:
+            return False
+        # What can still be in flight here is slot-steps past an end
+        # of sequence: take them off the engine before saying so.
+        self._flush()
+        return True
 
     def _set_occupancy(self) -> None:
         # Occupancy is THE continuous-batching health number: a low
@@ -634,7 +665,11 @@ class ContinuousBatcher:
             with span("tick.prefill"):
                 self._prefill_tick()
 
-        if not any(s.decoding for s in self.slots):
+        step = [
+            (idx, s.rid) for idx, s in enumerate(self.slots) if s.decoding
+        ]
+        if not step:
+            self._flush()
             return
         if self._spec:
             self._spec_tick()
@@ -649,21 +684,43 @@ class ContinuousBatcher:
         else:
             out = self.engine.decode(tokens, positions)
         self.stats["decode_steps"] += 1
+        for idx, _ in step:
+            self.slots[idx].pos += 1
+            self.slots[idx].remaining -= 1
+        if self._lag:
+            step, self._flight = self._flight, step
+        if step is not None:
+            self._emit(step, out)
+
+    def _flush(self) -> None:
+        """Take the step in flight off the engine and emit it: before
+        a tick with nothing to dispatch, and before ``done``."""
+        if self._flight is not None:
+            step, self._flight = self._flight, None
+            self._emit(step, self.engine.flush())
+
+    def _emit(self, step, out) -> None:
+        """Hand the tokens ``out`` of the decode step ``step``
+        ([(slot index, rid)]: the one just dispatched, or with a lag
+        the one before) to their requests; end those that are whole
+        or hit their end of sequence."""
         with span("tick.emit"):
-            for idx, (slot, tok) in enumerate(
-                zip(self.slots, np.asarray(out))
-            ):
-                if not slot.decoding:
+            out = np.asarray(out)
+            for idx, rid in step:
+                slot = self.slots[idx]
+                if slot.rid != rid:
+                    # Dispatched before the host saw the request end
+                    # (end of sequence, one step late): dropped.
                     continue
-                req = self._requests[slot.rid]
-                tok = int(tok)
-                self.results[slot.rid].append(tok)
+                req = self._requests[rid]
+                tok = int(out[idx])
+                self.results[rid].append(tok)
                 if self.meter is not None:
-                    self.meter.token(slot.rid)
-                slot.pos += 1
+                    self.meter.token(rid)
                 slot.last_token = tok
-                slot.remaining -= 1
-                if slot.remaining == 0 or tok == req.eos_id:
+                if tok == req.eos_id or (
+                    len(self.results[rid]) == req.max_new_tokens
+                ):
                     self._evict(idx, slot)
 
     def _spec_tick(self) -> None:
