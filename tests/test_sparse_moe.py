@@ -353,16 +353,21 @@ def test_counts_come_back_with_the_tokens(params, mesh):
     )
 
 
-# -- the dense programs are the parent's ----------------------------------
-# sha256[:16] of ``lowered.as_text()`` (StableHLO, no debug info) of
-# the decode and chunk-prefill programs of two dense configurations,
-# taken on the parent commit of the PR that made the stages
-# configurable (PR 27). A PR that MEANS to change a dense program
-# re-pins them and says so: PR 28 re-pinned the eight decode programs
-# (their input token is chosen between the host's and the one the step
-# before left on the device); the chunk programs are still PR 27's
-# parent's.
-DENSE_DIGESTS = {
+# -- the programs are the parent's ----------------------------------------
+# sha256[:16] of ``lowered.as_text()`` (StableHLO, no debug info: it
+# sees neither scope names nor source lines) of every serving program
+# at a tiny size. The first sixteen are the paged decode and
+# chunk-prefill programs of two dense configurations, taken on the
+# parent commit of the PR that made the stages configurable (PR 27). A
+# PR that MEANS to change a program re-pins it and says so: PR 28
+# re-pinned the eight decode programs (their input token is chosen
+# between the host's and the one the step before left on the device);
+# the chunk programs are still PR 27's parent's. The last ten (the slab
+# programs, the speculative draft and verify programs, the
+# sparse-expert decode, probe and chunk programs) were taken on the
+# parent commit of the PR that gave every program one layer loop
+# (PR 29, on 2a3e19e's code).
+PROGRAM_DIGESTS = {
     "gqa-decode-gather-none": "81408bbaba39a8c9",
     "gqa-prefill-gather-none": "8a8972db9fc5d25e",
     "gqa-decode-gather-int8": "da821b4063db53ed",
@@ -379,53 +384,108 @@ DENSE_DIGESTS = {
     "mha-prefill-pallas-none": "0e1e073173ce7163",
     "mha-decode-pallas-int8": "7b0f374e3c25c107",
     "mha-prefill-pallas-int8": "bc03f9ccf4d55fa3",
+    "gqa-slab_prefill": "18d392421c38a24a",
+    "gqa-slab_decode": "e024f76f84beab53",
+    "mha-slab_prefill": "84c81d6de834fe78",
+    "mha-slab_decode": "7d8df77d288a5f63",
+    "gqa-spec_draft": "adf0b8caaeed1392",
+    "gqa-spec_verify-onehot": "263c7331d62839f2",
+    "gqa-spec_verify-probs": "3b8616518ea90903",
+    "sparse-decode": "901f33d0d5597192",
+    "sparse-decode_probe": "9317ff59722907af",
+    "sparse-prefill": "98bb54d41635cafb",
 }
 
 
-def _dense_program_text(tag, program, kernel, quant, mesh):
-    cfg = llama2.LlamaConfig(
-        dim=64, n_layers=2, n_heads=4,
-        n_kv_heads=2 if tag == "gqa" else None, vocab_size=128,
-        multiple_of=16, max_seq_len=64, dtype=jnp.bfloat16,
-    )
-    weights = jax.eval_shape(
-        lambda: llama2.init_llama(jax.random.key(0), cfg)
-    )
-    i32 = jnp.int32
-    slots, block, per_seq, width, bucket = 4, 4, 12, 16, 8
-    dtype = jnp.int8 if quant == "int8" else jnp.bfloat16
-    cache = jax.ShapeDtypeStruct(
-        (cfg.n_layers, 48, cfg.kv_heads, block, cfg.head_dim), dtype
-    )
-    scales = jax.ShapeDtypeStruct((cfg.n_layers, 48), jnp.float32)
-    state = (cache, cache) + ((scales, scales) if quant == "int8" else ())
-    vec = jax.ShapeDtypeStruct((slots,), i32)
-    scalar = jax.ShapeDtypeStruct((), i32)
-    if program == "decode":
-        lowered = jax.jit(paging.make_paged_decode_fn(
-            cfg, block, per_seq, width, kernel=kernel, kv_quant=quant,
-            mesh=mesh,
-        )).lower(
-            weights, *state, vec,
-            jax.ShapeDtypeStruct((len(paging.STEP_ROWS), slots), i32),
-            jax.ShapeDtypeStruct((slots, width), i32),
+def _abstract(shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype)
+
+
+def _program_text(mesh, tag, program, kernel="gather", quant="none"):
+    """The lowered text of one serving program: ``tag`` names the
+    configuration (two dense ones, the file's tiny sparse-expert one),
+    ``kernel`` is the read path, or for ``spec_verify`` where the
+    draft's distributions come from."""
+    from tpu_hpc.serve import engine as slab, spec
+
+    if tag == "sparse":
+        cfg = TINY
+        weights = jax.eval_shape(
+            lambda: sparse_moe.init_sparse_moe(jax.random.key(0), cfg)
         )
     else:
-        lowered = jax.jit(paging.make_chunk_prefill_fn(
+        cfg = llama2.LlamaConfig(
+            dim=64, n_layers=2, n_heads=4,
+            n_kv_heads=2 if tag == "gqa" else None, vocab_size=128,
+            multiple_of=16, max_seq_len=64, dtype=jnp.bfloat16,
+        )
+        weights = jax.eval_shape(
+            lambda: llama2.init_llama(jax.random.key(0), cfg)
+        )
+    slots, block, per_seq, width, bucket, k = 4, 4, 12, 16, 8, 3
+    dtype = jnp.int8 if quant == "int8" else cfg.dtype
+    cache = _abstract(
+        (cfg.n_layers, 48, cfg.kv_heads, block, cfg.head_dim), dtype
+    )
+    scales = _abstract((cfg.n_layers, 48), jnp.float32)
+    state = (cache, cache) + ((scales, scales) if quant == "int8" else ())
+    vec, scalar = _abstract((slots,)), _abstract(())
+    tables = _abstract((slots, width))
+    fvec = _abstract((slots,), jnp.float32)
+    if tag == "sparse":
+        state += (_abstract(
+            (cfg.n_layers, 48, block, cfg.indexer_head_dim), cfg.dtype
+        ),)
+        vec_prev = _abstract((slots + len(paging.SPARSE_COUNTERS),))
+    else:
+        vec_prev = vec
+    if program in ("decode", "decode_probe"):
+        fn = paging.make_paged_decode_fn(
+            cfg, block, per_seq, width, kernel=kernel, kv_quant=quant,
+            mesh=mesh, probe=program == "decode_probe",
+        )
+        args = (*state, vec_prev,
+                _abstract((len(paging.STEP_ROWS), slots)), tables)
+    elif program == "prefill":
+        fn = paging.make_chunk_prefill_fn(
             cfg, bucket, block, per_seq, width, kernel=kernel,
             kv_quant=quant, mesh=mesh,
-        )).lower(
-            weights, *state, jax.ShapeDtypeStruct((1, bucket), i32),
-            scalar, scalar, jax.ShapeDtypeStruct((width,), i32),
         )
-    return lowered.as_text()
+        args = (*state, _abstract((1, bucket)), scalar, scalar,
+                _abstract((width,)))
+    elif program.startswith("slab"):
+        slab_cache = _abstract(
+            (cfg.n_layers, slots, 48, cfg.kv_heads, cfg.head_dim),
+            cfg.dtype,
+        )
+        if program == "slab_prefill":
+            fn = slab.make_prefill_fn(cfg, bucket, slots)
+            args = (slab_cache, slab_cache, _abstract((1, bucket)),
+                    scalar, scalar)
+        else:
+            fn = slab.make_decode_fn(cfg, 48)
+            args = (slab_cache, slab_cache, vec, vec)
+    elif program == "spec_draft":
+        fn = spec.make_spec_draft_fn(cfg, k, block, per_seq, width)
+        args = (*state, vec, vec, tables, vec, vec, vec, fvec, fvec)
+    else:
+        onehot = kernel == "onehot"
+        fn = spec.make_spec_verify_fn(
+            cfg, k, block, per_seq, width, onehot_q=onehot
+        )
+        probs = () if onehot else (
+            _abstract((slots, k, cfg.vocab_size), jnp.float32),
+        )
+        args = (*state, _abstract((slots, k + 1)), vec, tables, vec, vec,
+                *probs, vec, fvec, fvec)
+    return jax.jit(fn).lower(weights, *args).as_text()
 
 
-@pytest.mark.parametrize("name", sorted(DENSE_DIGESTS))
-def test_the_dense_programs_lower_to_the_parents_text(name, mesh):
-    text = _dense_program_text(*name.split("-"), mesh)
+@pytest.mark.parametrize("name", sorted(PROGRAM_DIGESTS))
+def test_the_programs_lower_to_the_parents_text(name, mesh):
+    text = _program_text(mesh, *name.split("-"))
     assert hashlib.sha256(text.encode()).hexdigest()[:16] \
-        == DENSE_DIGESTS[name]
+        == PROGRAM_DIGESTS[name]
 
 
 # -- who refuses it, by name ----------------------------------------------

@@ -1,6 +1,6 @@
 """The program's stage names (docs/guide/observability.md, "Stage
-names"): ``jax.named_scope`` names inside the decode, chunk-prefill and
-train-step programs, ``name=`` on every ``pallas_call``, ``tpu_hpc:``
+names"): ``jax.named_scope`` names inside every serving program and the
+train-step program, ``name=`` on every ``pallas_call``, ``tpu_hpc:``
 annotations from ``obs.span``, and the stage spans inside one serve
 tick, one engine call and one train chunk. All on the CPU at a tiny
 size: names and nesting, never a time.
@@ -68,35 +68,29 @@ def tiny_abstract():
 
 
 @pytest.fixture(scope="module")
-def program_scopes(devices, tiny_abstract):
-    """The three programs' scope names, each lowered once."""
-    i32 = jnp.int32
+def programs(devices, tiny_abstract):
+    """Every serving program and the train step: ``(function,
+    abstract arguments)`` by name."""
+    from tpu_hpc.serve import engine as slab, spec
+
+    i32, f32 = jnp.int32, jnp.float32
+    slots, k = SERVE.slots, 2
     cache = jax.ShapeDtypeStruct(
-        (TINY.n_layers, 48, TINY.kv_heads, BLOCK, TINY.head_dim),
-        jnp.float32,
+        (TINY.n_layers, 48, TINY.kv_heads, BLOCK, TINY.head_dim), f32
     )
-    vec = jax.ShapeDtypeStruct((SERVE.slots,), i32)
+    slab_cache = jax.ShapeDtypeStruct(
+        (TINY.n_layers, slots, 48, TINY.kv_heads, TINY.head_dim), f32
+    )
+    vec = jax.ShapeDtypeStruct((slots,), i32)
+    fvec = jax.ShapeDtypeStruct((slots,), f32)
     scalar = jax.ShapeDtypeStruct((), i32)
-    decode = jax.jit(paging.make_paged_decode_fn(
-        TINY, BLOCK, PER_SEQ, WIDTH
-    )).lower(
-        tiny_abstract, cache, cache, vec,
-        jax.ShapeDtypeStruct((len(paging.STEP_ROWS), SERVE.slots), i32),
-        jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32),
-    )
-    prefill = jax.jit(paging.make_chunk_prefill_fn(
-        TINY, 8, BLOCK, PER_SEQ, WIDTH
-    )).lower(
-        tiny_abstract, cache, cache,
-        jax.ShapeDtypeStruct((1, 8), i32), scalar, scalar,
-        jax.ShapeDtypeStruct((WIDTH,), i32),
-    )
+    tables = jax.ShapeDtypeStruct((slots, WIDTH), i32)
+    chunk = jax.ShapeDtypeStruct((1, 8), i32)
     mesh = build_mesh(MeshSpec(axes={"data": 4, "model": 2}))
     forward = llama2.make_forward(
         TINY, tp.sp_constrain(mesh, dp_axis="data", sp_axis="model")
     )
     tx = optax.adamw(1e-3)
-    step = trainer_mod.make_step_fn(forward, tx, seed=0)
     state = jax.eval_shape(
         lambda p: trainer_mod.TrainState(
             step=jnp.int32(0), params=p, opt_state=tx.init(p),
@@ -105,21 +99,74 @@ def program_scopes(devices, tiny_abstract):
         tiny_abstract,
     )
     tokens = jax.ShapeDtypeStruct((4, 16), i32)
-    train = jax.jit(step).lower(state, (tokens, tokens))
     return {
-        "decode": _scopes_in(decode),
-        "prefill": _scopes_in(prefill),
-        "train": _scopes_in(train),
+        "decode": (
+            paging.make_paged_decode_fn(TINY, BLOCK, PER_SEQ, WIDTH),
+            (tiny_abstract, cache, cache, vec,
+             jax.ShapeDtypeStruct((len(paging.STEP_ROWS), slots), i32),
+             tables),
+        ),
+        "prefill": (
+            paging.make_chunk_prefill_fn(TINY, 8, BLOCK, PER_SEQ, WIDTH),
+            (tiny_abstract, cache, cache, chunk, scalar, scalar,
+             jax.ShapeDtypeStruct((WIDTH,), i32)),
+        ),
+        "slab_prefill": (
+            slab.make_prefill_fn(TINY, 8, slots),
+            (tiny_abstract, slab_cache, slab_cache, chunk, scalar,
+             scalar),
+        ),
+        "slab_decode": (
+            slab.make_decode_fn(TINY, 48),
+            (tiny_abstract, slab_cache, slab_cache, vec, vec),
+        ),
+        "spec_draft": (
+            spec.make_spec_draft_fn(TINY, k, BLOCK, PER_SEQ, WIDTH),
+            (tiny_abstract, cache, cache, vec, vec, tables, vec, vec,
+             vec, fvec, fvec),
+        ),
+        "spec_verify": (
+            spec.make_spec_verify_fn(
+                TINY, k, BLOCK, PER_SEQ, WIDTH, onehot_q=True
+            ),
+            (tiny_abstract, cache, cache,
+             jax.ShapeDtypeStruct((slots, k + 1), i32), vec, tables,
+             vec, vec, vec, fvec, fvec),
+        ),
+        "train": (
+            trainer_mod.make_step_fn(forward, tx, seed=0),
+            (state, (tokens, tokens)),
+        ),
     }
 
 
+@pytest.fixture(scope="module")
+def program_scopes(programs):
+    return {
+        name: _scopes_in(jax.jit(fn).lower(*args))
+        for name, (fn, args) in programs.items()
+    }
+
+
+SERVE_PROGRAMS = (
+    "decode", "prefill", "slab_prefill", "slab_decode", "spec_draft",
+    "spec_verify",
+)
+
+
 @pytest.mark.parametrize("program,scope", [
-    *(("decode", s) for s in SERVE_SCOPES),
-    *(("prefill", s) for s in SERVE_SCOPES),
+    *((p, s) for p in SERVE_PROGRAMS for s in SERVE_SCOPES
+      if (p, s) != ("slab_prefill", "kv_read")),
     *(("train", s) for s in TRAIN_SCOPES),
 ])
 def test_program_carries_scope(program_scopes, program, scope):
     assert scope in program_scopes[program]
+
+
+def test_slab_prefill_reads_nothing_back(program_scopes):
+    """A slab prompt attends over the K/V it has just computed: the
+    one serving program with no ``kv_read``."""
+    assert "kv_read" not in program_scopes["slab_prefill"]
 
 
 def test_training_has_no_kv_scopes(program_scopes):
@@ -368,9 +415,10 @@ SPARSE_SCOPES = ("indexer", "router", "experts")
 
 
 @pytest.fixture(scope="module")
-def sparse_program_scopes(devices):
+def sparse_programs(devices):
     """The decode and chunk programs of a tiny sparse-expert
-    configuration (``models/sparse_moe.py``), each lowered once."""
+    configuration (``models/sparse_moe.py``): ``(function, abstract
+    arguments)`` by name."""
     from tpu_hpc.models import sparse_moe
 
     cfg = sparse_moe.SparseMoEConfig(
@@ -392,24 +440,33 @@ def sparse_program_scopes(devices):
     keys = jax.ShapeDtypeStruct((2, 48, BLOCK, 16), jnp.float32)
     vec = jax.ShapeDtypeStruct((SERVE.slots,), i32)
     scalar = jax.ShapeDtypeStruct((), i32)
-    decode = jax.jit(paging.make_paged_decode_fn(
-        cfg, BLOCK, PER_SEQ, WIDTH
-    )).lower(
-        weights, cache, cache, keys,
-        jax.ShapeDtypeStruct(
-            (SERVE.slots + len(paging.SPARSE_COUNTERS),), i32
+    return {
+        "decode": (
+            paging.make_paged_decode_fn(cfg, BLOCK, PER_SEQ, WIDTH),
+            (weights, cache, cache, keys,
+             jax.ShapeDtypeStruct(
+                 (SERVE.slots + len(paging.SPARSE_COUNTERS),), i32
+             ),
+             jax.ShapeDtypeStruct(
+                 (len(paging.STEP_ROWS), SERVE.slots), i32
+             ),
+             jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32)),
         ),
-        jax.ShapeDtypeStruct((len(paging.STEP_ROWS), SERVE.slots), i32),
-        jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32),
-    )
-    prefill = jax.jit(paging.make_chunk_prefill_fn(
-        cfg, 8, BLOCK, PER_SEQ, WIDTH
-    )).lower(
-        weights, cache, cache, keys,
-        jax.ShapeDtypeStruct((1, 8), i32), scalar, scalar,
-        jax.ShapeDtypeStruct((WIDTH,), i32),
-    )
-    return {"decode": _scopes_in(decode), "prefill": _scopes_in(prefill)}
+        "prefill": (
+            paging.make_chunk_prefill_fn(cfg, 8, BLOCK, PER_SEQ, WIDTH),
+            (weights, cache, cache, keys,
+             jax.ShapeDtypeStruct((1, 8), i32), scalar, scalar,
+             jax.ShapeDtypeStruct((WIDTH,), i32)),
+        ),
+    }
+
+
+@pytest.fixture(scope="module")
+def sparse_program_scopes(sparse_programs):
+    return {
+        name: _scopes_in(jax.jit(fn).lower(*args))
+        for name, (fn, args) in sparse_programs.items()
+    }
 
 
 @pytest.mark.parametrize("program,scope", [
@@ -426,6 +483,73 @@ def test_mlp_stays_the_dense_ffns(sparse_program_scopes, program_scopes):
     for program in ("decode", "prefill"):
         assert "mlp" not in sparse_program_scopes[program]
         assert not set(SPARSE_SCOPES) & program_scopes[program]
+
+
+def _ops_by_scope(fn, args) -> dict:
+    """The program's operations (the equations that survive dead-code
+    elimination, those of nested calls counted in their place) by the
+    LAST stage name on their path, which is how
+    ``benchmark/program_trace.py`` reads a trace; ``None`` holds those
+    under no stage."""
+    from jax.interpreters import partial_eval as pe
+
+    closed = jax.make_jaxpr(fn)(*args)
+    jaxpr, _ = pe.dce_jaxpr(
+        closed.jaxpr, [True] * len(closed.jaxpr.outvars)
+    )
+    stages = set(SERVE_SCOPES + SPARSE_SCOPES)
+    counts = {}
+
+    def walk(jaxpr, outer):
+        for eqn in jaxpr.eqns:
+            path = outer + str(eqn.source_info.name_stack).split("/")
+            inner = eqn.params.get("jaxpr")
+            if eqn.primitive.name == "jit" and inner is not None:
+                walk(inner.jaxpr, path)
+                continue
+            stage = next(
+                (p for p in reversed(path) if p in stages), None
+            )
+            counts[stage] = counts.get(stage, 0) + 1
+
+    walk(jaxpr, [])
+    return counts
+
+
+# Taken on the parent commit of the PR that gave every serving program
+# one layer loop (PR 29, on 2a3e19e's code). No digest sees a scope:
+# one that comes to swallow a neighbour's operations moves
+# ``kv_read_ms`` or ``attention_ms.serve`` while the program stays the
+# same.
+OPS_BY_SCOPE = {
+    "dense-decode": {
+        None: 61, "embed": 8, "qkv": 98, "kv_write": 116, "kv_read": 36,
+        "attention": 44, "attn_out": 6, "mlp": 32, "head": 13,
+    },
+    "dense-prefill": {
+        None: 33, "embed": 5, "qkv": 114, "kv_write": 52, "kv_read": 48,
+        "attention": 40, "attn_out": 6, "mlp": 32, "head": 16,
+    },
+    "sparse-decode": {
+        None: 83, "embed": 9, "qkv": 134, "kv_write": 116,
+        "indexer": 276, "kv_read": 36, "attention": 44, "attn_out": 6,
+        "router": 48, "experts": 94, "head": 21,
+    },
+    "sparse-prefill": {
+        None: 48, "embed": 5, "qkv": 150, "kv_write": 52,
+        "indexer": 242, "kv_read": 48, "attention": 40, "attn_out": 6,
+        "router": 48, "experts": 42, "head": 16,
+    },
+}
+
+
+@pytest.mark.parametrize("name", sorted(OPS_BY_SCOPE))
+def test_each_stage_holds_the_operations_it_held(
+    programs, sparse_programs, name
+):
+    kind, program = name.split("-")
+    fn, args = (programs if kind == "dense" else sparse_programs)[program]
+    assert _ops_by_scope(fn, args) == OPS_BY_SCOPE[name]
 
 
 def _table_of_record():

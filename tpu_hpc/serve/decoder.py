@@ -1,0 +1,215 @@
+"""The decoder layer stack of every serving program.
+
+A functional replay of ``models/llama2.py`` over the raw param dict
+(flax params are a plain dict; serving needs the K/V tensors mid-block,
+which ``nn.Module`` hides): identical dtype promotion (compute-dtype
+matmuls, fp32 RMSNorm / RoPE / softmax), identical einsum contractions,
+so greedy decode with a cache is token-exact against the no-cache
+forward pass -- the parity oracle tests/test_serve.py enforces.
+
+Every program under ``serve/`` (slab prefill and decode in engine.py,
+chunk prefill and paged decode in paging.py, speculative draft and
+verify in spec.py) embeds its tokens, runs :func:`decoder_layers`, and
+applies its own token rule to :func:`_logits_head`. What differs
+between them is where this step's K/V go and what attention reads back,
+and that is the **attention state** the program hands the loop: a
+callable ``(layer, h, lp, q, k, v) -> attended rows`` that lives next
+to the cache it knows (engine.py: slab rows; paging.py:
+``PagedAttention``, the page pool with its int8 scales, Pallas kernels
+and indexer keys). A configuration's new stage is an edit to the loop
+(a stage every cache sees the same way, as the feed-forward in
+:func:`_ffn_stage`) or to one attention state (a stage that reads or
+writes the cache), never to a program.
+
+The loop names its stages for the trace
+(docs/guide/observability.md, "Stage names"): ``qkv`` and ``attn_out``
+here, ``mlp`` or ``router`` / ``experts`` in :func:`_ffn_stage`,
+``kv_write``, ``indexer``, ``kv_read`` and ``attention`` in the
+attention state, ``embed`` and ``head`` in the program. An operation's
+stage is the LAST of these names on its path, so no stage wraps
+another.
+"""
+from __future__ import annotations
+
+from typing import Dict
+
+import jax
+import jax.numpy as jnp
+
+from tpu_hpc.models import llama2, sparse_moe
+
+
+def _dense(x: jax.Array, kernel: jax.Array, dtype) -> jax.Array:
+    """nn.Dense(use_bias=False, dtype=dtype): promote both operands to
+    the compute dtype, then contract the trailing dim."""
+    return jax.lax.dot_general(
+        x.astype(dtype), kernel.astype(dtype),
+        (((x.ndim - 1,), (0,)), ((), ())),
+    )
+
+
+def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    """RMSNorm in fp32 with a learned scale (llama2.RMSNorm)."""
+    xf = x.astype(jnp.float32)
+    normed = xf * jax.lax.rsqrt(
+        jnp.mean(xf * xf, axis=-1, keepdims=True) + eps
+    )
+    return (normed * scale.astype(jnp.float32)).astype(x.dtype)
+
+
+def _embed(params: Dict, tokens: jax.Array, cfg: llama2.LlamaConfig):
+    """Token embedding lookup in the compute dtype. Identical values to
+    both training paths (iota_embed's forward IS a plain gather)."""
+    table = params["tok_embeddings"]["embedding"].astype(cfg.dtype)
+    return jnp.take(table, tokens, axis=0)
+
+
+def _attn_out_proj(h, lp, cfg):
+    b, s = h.shape[0], h.shape[1]
+    return _dense(
+        h.reshape(b, s, cfg.n_heads * cfg.head_dim),
+        lp["attention"]["wo"]["kernel"], cfg.dtype,
+    )
+
+
+def _mlp(x, lp, cfg):
+    gate = _dense(x, lp["feed_forward"]["w1"]["kernel"], cfg.dtype)
+    up = _dense(x, lp["feed_forward"]["w3"]["kernel"], cfg.dtype)
+    return _dense(
+        jax.nn.silu(gate) * up, lp["feed_forward"]["w2"]["kernel"],
+        cfg.dtype,
+    )
+
+
+def _qkv(x, lp, cfg):
+    b, s = x.shape[0], x.shape[1]
+    hd, n_kv = cfg.head_dim, cfg.kv_heads
+    q = _dense(x, lp["attention"]["wq"]["kernel"], cfg.dtype)
+    k = _dense(x, lp["attention"]["wk"]["kernel"], cfg.dtype)
+    v = _dense(x, lp["attention"]["wv"]["kernel"], cfg.dtype)
+    q = q.reshape(b, s, cfg.n_heads, hd)
+    k = k.reshape(b, s, n_kv, hd)
+    if getattr(cfg, "qk_norm", False):
+        # Per-head RMSNorm ahead of the rotation (sparse_moe.py).
+        q = _rmsnorm(q, lp["attention"]["q_norm"]["scale"], cfg.norm_eps)
+        k = _rmsnorm(k, lp["attention"]["k_norm"]["scale"], cfg.norm_eps)
+    return q, k, v.reshape(b, s, n_kv, hd)
+
+
+def _rope_tables(cfg, n, positions=None):
+    """``llama2.rope_cos_sin`` at the configuration's rotary base
+    (10000 unless it names one): ``[n, head_dim / 2]`` for positions
+    ``0..n-1``, or a row for each of ``positions``."""
+    return llama2.rope_cos_sin(
+        n, cfg.head_dim, getattr(cfg, "rope_theta", 10000.0),
+        positions=positions,
+    )
+
+
+def _grouped_attention(q, k, v, mask, cfg):
+    """The model's einsum attention with an explicit mask: scores in
+    the compute dtype, fp32 softmax, GQA via the grouped query view
+    (llama2.Attention's no-repeat-KV contraction)."""
+    b, s_q = q.shape[0], q.shape[1]
+    n_kv = cfg.kv_heads
+    groups = cfg.n_heads // n_kv
+    qg = q.reshape(b, s_q, n_kv, groups, cfg.head_dim)
+    scale = cfg.head_dim ** -0.5
+    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
+    scores = scores.astype(jnp.float32)
+    scores = jnp.where(mask, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
+    return out.reshape(b, s_q, cfg.n_heads, cfg.head_dim)
+
+
+def _grouped_attention_paged(q, k_pages, v_pages, mask, cfg):
+    """:func:`_grouped_attention` over gathered pages as they lie in
+    the pool: ``k_pages`` / ``v_pages`` are ``[b, pages, kv_heads,
+    block_size, head_dim]`` (``pool[layer, tables]``), ``mask`` is over
+    the ``pages * block_size`` token columns. Same products, same fp32
+    softmax; the contraction runs over (page, row) where the other
+    runs over tokens, so no view is transposed to token-major first.
+    For the decode programs the views ARE the working set (every
+    slot's whole capacity) and that transpose read and wrote each of
+    them once more, 13-15 GB a step at 7B width."""
+    b, s_q = q.shape[0], q.shape[1]
+    n_kv = cfg.kv_heads
+    groups = cfg.n_heads // n_kv
+    n_pages, block_size = k_pages.shape[1], k_pages.shape[3]
+    qg = q.reshape(b, s_q, n_kv, groups, cfg.head_dim)
+    scale = cfg.head_dim ** -0.5
+    scores = jnp.einsum("bqhgd,bphkd->bhgqpk", qg, k_pages) * scale
+    scores = scores.reshape(b, n_kv, groups, s_q, n_pages * block_size)
+    scores = scores.astype(jnp.float32)
+    scores = jnp.where(mask, scores, -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    probs = probs.reshape(b, n_kv, groups, s_q, n_pages, block_size)
+    out = jnp.einsum("bhgqpk,bphkd->bqhgd", probs, v_pages)
+    return out.reshape(b, s_q, cfg.n_heads, cfg.head_dim)
+
+
+def _logits_head(x, params, cfg):
+    x = _rmsnorm(x, params["norm"]["scale"], cfg.norm_eps)
+    return _dense(x, params["output"]["kernel"], cfg.dtype)
+
+
+def _ffn_stage(x, lp, cfg, weight=None):
+    """The configuration's feed-forward, residual included ->
+    ``(x, counts)``: the dense SwiGLU under ``mlp`` (``counts`` None),
+    or the router and the held experts under ``router`` / ``experts``
+    (``sparse_moe.expert_ffn``'s counts; ``weight`` marks the tokens
+    that count)."""
+    scope = jax.named_scope
+    if not sparse_moe.is_sparse_moe(cfg):
+        with scope("mlp"):
+            h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
+            x = x + _mlp(h, lp, cfg)
+        return x, None
+    b, s, d = x.shape
+    with scope("router"):
+        h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
+        h = h.reshape(b * s, d)
+        gates, experts = sparse_moe.route(h, lp, cfg)
+    with scope("experts"):
+        y, counts = sparse_moe.expert_ffn(
+            h, gates, experts, lp, cfg, weight=weight
+        )
+        x = x + y.reshape(b, s, d).astype(x.dtype)
+    return x, counts
+
+
+def decoder_layers(params, cfg, x, cos, sin, attend, weight=None):
+    """Every layer of the decoder over ``x [b, s, dim]`` -> ``(x,
+    counts)``. A layer is: ``qkv`` (the norm, :func:`_qkv`, the rotation
+    by the program's ``cos`` / ``sin`` tables); ``attend(layer, h, lp,
+    q, k, v)``, the program's attention state, which writes this
+    step's K/V where the program keeps them and returns the attended
+    rows ``[b, s, n_heads, head_dim]`` (``h`` is the normed input, for
+    a stage of its own such as an indexer's projections); ``attn_out``
+    (the output projection and its residual); :func:`_ffn_stage`.
+    ``counts`` are the expert layers' of a sparse-expert configuration
+    over the tokens ``weight`` marks, summed over layers (``max*``: the
+    largest), and empty for a dense one."""
+    scope = jax.named_scope
+    counts = {}
+    for i in range(cfg.n_layers):
+        lp = params[f"layers_{i}"]
+        with scope("qkv"):
+            h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
+            q, k, v = _qkv(h, lp, cfg)
+            # [s, D/2] tables rotate every row alike, [b, s, D/2] each
+            # to its own position (apply_rope broadcasts either shape).
+            q = llama2.apply_rope(q, cos, sin)
+            k = llama2.apply_rope(k, cos, sin)
+        attn = attend(i, h, lp, q, k, v)
+        with scope("attn_out"):
+            x = x + _attn_out_proj(attn, lp, cfg)
+        x, moe = _ffn_stage(x, lp, cfg, weight=weight)
+        for name, value in (moe or {}).items():
+            with scope("experts"):
+                counts[name] = jnp.maximum(
+                    counts.get(name, 0), value
+                ) if name.startswith("max") \
+                    else counts.get(name, 0) + value
+    return x, counts

@@ -22,12 +22,9 @@ applied to inference. The engine dispatches ONLY from its executable
 table; any miss is counted in ``compile_count``, which the recompile
 guard in tests/test_serve.py pins to the warmup count.
 
-The model math is a functional replay of ``models/llama2.py`` over the
-same param tree (flax params are a plain dict; serving needs no module
-machinery): identical dtype promotion (compute-dtype matmuls, fp32
-RMSNorm/RoPE/softmax), identical einsum contractions, so greedy decode
-with the cache is token-exact against the no-cache forward pass -- the
-parity oracle tests/test_serve.py enforces.
+The model math is serve/decoder.py's one layer loop; this module hands
+it the attention state of a slab cache (where a prompt's and a token's
+K/V rows go, what attention reads back).
 """
 from __future__ import annotations
 
@@ -42,6 +39,13 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpu_hpc.models import llama2, sparse_moe
 from tpu_hpc.obs import get_registry, span
+from tpu_hpc.serve.decoder import (
+    _embed,
+    _grouped_attention,
+    _logits_head,
+    _rope_tables,
+    decoder_layers,
+)
 
 
 @dataclasses.dataclass(frozen=True)
@@ -107,129 +111,6 @@ def kv_cache_pspec(mesh: Mesh, slots: int, kv_heads: int) -> P:
              None)
 
 
-# ---------------------------------------------------------------------
-# Functional Llama forward over the raw param dict.
-#
-# Replays models/llama2.py's module math exactly (same promotions, same
-# contractions) -- the modules are thin wrappers over these ops, and
-# serving needs the K/V tensors mid-block, which nn.Module hides.
-# ---------------------------------------------------------------------
-
-
-def _dense(x: jax.Array, kernel: jax.Array, dtype) -> jax.Array:
-    """nn.Dense(use_bias=False, dtype=dtype): promote both operands to
-    the compute dtype, then contract the trailing dim."""
-    return jax.lax.dot_general(
-        x.astype(dtype), kernel.astype(dtype),
-        (((x.ndim - 1,), (0,)), ((), ())),
-    )
-
-
-def _rmsnorm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
-    """RMSNorm in fp32 with a learned scale (llama2.RMSNorm)."""
-    xf = x.astype(jnp.float32)
-    normed = xf * jax.lax.rsqrt(
-        jnp.mean(xf * xf, axis=-1, keepdims=True) + eps
-    )
-    return (normed * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def _embed(params: Dict, tokens: jax.Array, cfg: llama2.LlamaConfig):
-    """Token embedding lookup in the compute dtype. Identical values to
-    both training paths (iota_embed's forward IS a plain gather)."""
-    table = params["tok_embeddings"]["embedding"].astype(cfg.dtype)
-    return jnp.take(table, tokens, axis=0)
-
-
-def _attn_out_proj(h, lp, cfg):
-    b, s = h.shape[0], h.shape[1]
-    return _dense(
-        h.reshape(b, s, cfg.n_heads * cfg.head_dim),
-        lp["attention"]["wo"]["kernel"], cfg.dtype,
-    )
-
-
-def _mlp(x, lp, cfg):
-    gate = _dense(x, lp["feed_forward"]["w1"]["kernel"], cfg.dtype)
-    up = _dense(x, lp["feed_forward"]["w3"]["kernel"], cfg.dtype)
-    return _dense(
-        jax.nn.silu(gate) * up, lp["feed_forward"]["w2"]["kernel"],
-        cfg.dtype,
-    )
-
-
-def _qkv(x, lp, cfg):
-    b, s = x.shape[0], x.shape[1]
-    hd, n_kv = cfg.head_dim, cfg.kv_heads
-    q = _dense(x, lp["attention"]["wq"]["kernel"], cfg.dtype)
-    k = _dense(x, lp["attention"]["wk"]["kernel"], cfg.dtype)
-    v = _dense(x, lp["attention"]["wv"]["kernel"], cfg.dtype)
-    q = q.reshape(b, s, cfg.n_heads, hd)
-    k = k.reshape(b, s, n_kv, hd)
-    if getattr(cfg, "qk_norm", False):
-        # Per-head RMSNorm ahead of the rotation (sparse_moe.py).
-        q = _rmsnorm(q, lp["attention"]["q_norm"]["scale"], cfg.norm_eps)
-        k = _rmsnorm(k, lp["attention"]["k_norm"]["scale"], cfg.norm_eps)
-    return q, k, v.reshape(b, s, n_kv, hd)
-
-
-def _rope_tables(cfg, n, positions):
-    """``llama2.rope_cos_sin`` at the configuration's rotary base
-    (10000 unless it names one)."""
-    return llama2.rope_cos_sin(
-        n, cfg.head_dim, getattr(cfg, "rope_theta", 10000.0),
-        positions=positions,
-    )
-
-
-def _grouped_attention(q, k, v, mask, cfg):
-    """The model's einsum attention with an explicit mask: scores in
-    the compute dtype, fp32 softmax, GQA via the grouped query view
-    (llama2.Attention's no-repeat-KV contraction)."""
-    b, s_q = q.shape[0], q.shape[1]
-    n_kv = cfg.kv_heads
-    groups = cfg.n_heads // n_kv
-    qg = q.reshape(b, s_q, n_kv, groups, cfg.head_dim)
-    scale = cfg.head_dim ** -0.5
-    scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
-    scores = scores.astype(jnp.float32)
-    scores = jnp.where(mask, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-    out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, s_q, cfg.n_heads, cfg.head_dim)
-
-
-def _grouped_attention_paged(q, k_pages, v_pages, mask, cfg):
-    """:func:`_grouped_attention` over gathered pages as they lie in
-    the pool: ``k_pages`` / ``v_pages`` are ``[b, pages, kv_heads,
-    block_size, head_dim]`` (``pool[layer, tables]``), ``mask`` is over
-    the ``pages * block_size`` token columns. Same products, same fp32
-    softmax; the contraction runs over (page, row) where the other
-    runs over tokens, so no view is transposed to token-major first.
-    For the decode programs the views ARE the working set (every
-    slot's whole capacity) and that transpose read and wrote each of
-    them once more, 13-15 GB a step at 7B width."""
-    b, s_q = q.shape[0], q.shape[1]
-    n_kv = cfg.kv_heads
-    groups = cfg.n_heads // n_kv
-    n_pages, block_size = k_pages.shape[1], k_pages.shape[3]
-    qg = q.reshape(b, s_q, n_kv, groups, cfg.head_dim)
-    scale = cfg.head_dim ** -0.5
-    scores = jnp.einsum("bqhgd,bphkd->bhgqpk", qg, k_pages) * scale
-    scores = scores.reshape(b, n_kv, groups, s_q, n_pages * block_size)
-    scores = scores.astype(jnp.float32)
-    scores = jnp.where(mask, scores, -jnp.inf)
-    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
-    probs = probs.reshape(b, n_kv, groups, s_q, n_pages, block_size)
-    out = jnp.einsum("bhgqpk,bphkd->bqhgd", probs, v_pages)
-    return out.reshape(b, s_q, cfg.n_heads, cfg.head_dim)
-
-
-def _logits_head(x, params, cfg):
-    x = _rmsnorm(x, params["norm"]["scale"], cfg.norm_eps)
-    return _dense(x, params["output"]["kernel"], cfg.dtype)
-
-
 def make_prefill_fn(cfg: llama2.LlamaConfig, bucket: int, slots: int):
     """Prefill program for one padded bucket length.
 
@@ -238,40 +119,42 @@ def make_prefill_fn(cfg: llama2.LlamaConfig, bucket: int, slots: int):
     prompt, the prompt's K/V written into slot ``slot`` rows
     ``[0:bucket)`` (the padded tail is garbage the per-slot length
     mask never reads), greedy token from the logits at row
-    ``true_len - 1``.
+    ``true_len - 1``. Its attention state reads nothing back: the
+    prompt attends over the K/V it has just computed.
     """
     del slots  # shape comes from the cache operand
+    scope = jax.named_scope
 
     def prefill(params, ks, vs, tokens, true_len, slot):
-        x = _embed(params, tokens, cfg)
-        cos, sin = llama2.rope_cos_sin(bucket, cfg.head_dim)
+        with scope("embed"):
+            x = _embed(params, tokens, cfg)
+        cos, sin = _rope_tables(cfg, bucket)
         causal = jnp.tril(jnp.ones((bucket, bucket), dtype=bool))
         mask = causal[None, None, None, :, :]
-        for i in range(cfg.n_layers):
-            lp = params[f"layers_{i}"]
-            h = _rmsnorm(
-                x, lp["attention_norm"]["scale"], cfg.norm_eps
+
+        def attend(i, h, lp, q, k, v):
+            nonlocal ks, vs
+            with scope("kv_write"):
+                ks = jax.lax.dynamic_update_slice(
+                    ks, k.astype(ks.dtype)[None], (i, slot, 0, 0, 0)
+                )
+                vs = jax.lax.dynamic_update_slice(
+                    vs, v.astype(vs.dtype)[None], (i, slot, 0, 0, 0)
+                )
+            with scope("attention"):
+                return _grouped_attention(
+                    q, k.astype(cfg.dtype), v.astype(cfg.dtype), mask,
+                    cfg,
+                )
+
+        x, _ = decoder_layers(params, cfg, x, cos, sin, attend)
+        with scope("head"):
+            last = jax.lax.dynamic_slice(
+                x, (0, true_len - 1, 0), (1, 1, cfg.dim)
             )
-            q, k, v = _qkv(h, lp, cfg)
-            q = llama2.apply_rope(q, cos, sin)
-            k = llama2.apply_rope(k, cos, sin)
-            ks = jax.lax.dynamic_update_slice(
-                ks, k.astype(ks.dtype)[None], (i, slot, 0, 0, 0)
-            )
-            vs = jax.lax.dynamic_update_slice(
-                vs, v.astype(vs.dtype)[None], (i, slot, 0, 0, 0)
-            )
-            attn = _grouped_attention(
-                q, k.astype(cfg.dtype), v.astype(cfg.dtype), mask, cfg
-            )
-            x = x + _attn_out_proj(attn, lp, cfg)
-            h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-            x = x + _mlp(h, lp, cfg)
-        last = jax.lax.dynamic_slice(
-            x, (0, true_len - 1, 0), (1, 1, cfg.dim)
-        )
-        logits = _logits_head(last, params, cfg)
-        return ks, vs, jnp.argmax(logits[0, 0], axis=-1).astype(jnp.int32)
+            logits = _logits_head(last, params, cfg)
+            tok = jnp.argmax(logits[0, 0], axis=-1).astype(jnp.int32)
+        return ks, vs, tok
 
     return prefill
 
@@ -288,36 +171,34 @@ def make_decode_fn(cfg: llama2.LlamaConfig, cache_len: int):
     padded prefill tail) are masked out, which is what makes slot
     reuse safe.
     """
+    scope = jax.named_scope
 
     def decode(params, ks, vs, tokens, pos):
         slots = tokens.shape[0]
-        x = _embed(params, tokens[:, None], cfg)  # [slots, 1, dim]
-        cos, sin = llama2.rope_cos_sin(1, cfg.head_dim, positions=pos)
+        with scope("embed"):
+            x = _embed(params, tokens[:, None], cfg)  # [slots, 1, dim]
+        cos, sin = _rope_tables(cfg, 1, pos)
         cos, sin = cos[:, None, :], sin[:, None, :]  # [slots, 1, D/2]
         col = jnp.arange(cache_len)
         mask = (col[None, :] <= pos[:, None])[:, None, None, None, :]
         rows = jnp.arange(slots)
-        for i in range(cfg.n_layers):
-            lp = params[f"layers_{i}"]
-            h = _rmsnorm(
-                x, lp["attention_norm"]["scale"], cfg.norm_eps
-            )
-            q, k, v = _qkv(h, lp, cfg)
-            # Per-slot [slots, 1, D/2] tables: each slot rotates to
-            # its own position (apply_rope broadcasts either shape).
-            q = llama2.apply_rope(q, cos, sin)
-            k = llama2.apply_rope(k, cos, sin)
-            ks = ks.at[i, rows, pos].set(k[:, 0].astype(ks.dtype))
-            vs = vs.at[i, rows, pos].set(v[:, 0].astype(vs.dtype))
-            attn = _grouped_attention(
-                q, ks[i].astype(cfg.dtype), vs[i].astype(cfg.dtype),
-                mask, cfg,
-            )
-            x = x + _attn_out_proj(attn, lp, cfg)
-            h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-            x = x + _mlp(h, lp, cfg)
-        logits = _logits_head(x, params, cfg)  # [slots, 1, vocab]
-        return ks, vs, jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+
+        def attend(i, h, lp, q, k, v):
+            nonlocal ks, vs
+            with scope("kv_write"):
+                ks = ks.at[i, rows, pos].set(k[:, 0].astype(ks.dtype))
+                vs = vs.at[i, rows, pos].set(v[:, 0].astype(vs.dtype))
+            with scope("kv_read"):
+                k_all = ks[i].astype(cfg.dtype)
+                v_all = vs[i].astype(cfg.dtype)
+            with scope("attention"):
+                return _grouped_attention(q, k_all, v_all, mask, cfg)
+
+        x, _ = decoder_layers(params, cfg, x, cos, sin, attend)
+        with scope("head"):
+            logits = _logits_head(x, params, cfg)  # [slots, 1, vocab]
+            tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+        return ks, vs, tok
 
     return decode
 
@@ -431,6 +312,14 @@ class Engine:
             self.ks.shape, self.ks.dtype, sharding=self._cache_sharding
         )
 
+    def _params_abstract(self):
+        """The resident weights as shapes with their shardings: what
+        every program of this engine is lowered against."""
+        return jax.tree.map(
+            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
+            self.params, self._param_shardings,
+        )
+
     def _count_compile(self) -> None:
         """One executable build: on the engine, and in the registry
         (``serve_compiles_total``, every engine of the process) so an
@@ -442,10 +331,7 @@ class Engine:
         """Lower-and-compile one program shape (counted)."""
         self._count_compile()
         cache = self._cache_abstract()
-        params_abs = jax.tree.map(
-            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
-            self.params, self._param_shardings,
-        )
+        params_abs = self._params_abstract()
         scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=self._rep)
         if key[0] == "prefill":
             bucket = key[1]

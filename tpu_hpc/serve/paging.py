@@ -58,6 +58,7 @@ bounded-divergence oracle instead of token-exactness.
 """
 from __future__ import annotations
 
+import copy
 import dataclasses
 import functools
 import math
@@ -80,20 +81,15 @@ from tpu_hpc.kernels.paged_attention import (
     write_tokens,
 )
 from tpu_hpc.obs import get_bus, get_registry, span
-from tpu_hpc.serve.engine import (
-    Engine,
-    ServeConfig,
-    _dense,  # noqa: F401  (re-exported for kernel swaps)
+from tpu_hpc.serve.decoder import (
     _embed,
     _grouped_attention,
     _grouped_attention_paged,
     _logits_head,
-    _mlp,
-    _qkv,
-    _rmsnorm,
     _rope_tables,
-    _attn_out_proj,
+    decoder_layers,
 )
+from tpu_hpc.serve.engine import Engine, ServeConfig
 
 SCRATCH_BLOCK = 0
 
@@ -826,8 +822,7 @@ def _with_state(body, name: str, quant: bool, sparse: bool):
             params, ks, vs, ksc, vsc, xs, *args
         )
         return (
-            ks, vs, *((ksc, vsc) if quant else ()),
-            *((xs,) if sparse else ()), *out,
+            *(a for a in (ks, vs, ksc, vsc, xs) if a is not None), *out
         )
 
     program.__name__ = program.__qualname__ = name
@@ -846,52 +841,289 @@ def _check_read_path(cfg, kernel: str, kv_quant: str) -> None:
         )
 
 
-def _ffn_stage(x, lp, cfg, weight=None):
-    """The configuration's feed-forward, residual included ->
-    ``(x, counts)``: the dense SwiGLU under ``mlp`` (``counts`` None),
-    or the router and the held experts under ``router`` / ``experts``
-    (``sparse_moe.expert_ffn``'s counts; ``weight`` marks the tokens
-    that count)."""
-    scope = jax.named_scope
-    if not sparse_moe.is_sparse_moe(cfg):
-        with scope("mlp"):
-            h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-            x = x + _mlp(h, lp, cfg)
-        return x, None
-    b, s, d = x.shape
-    with scope("router"):
-        h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-        h = h.reshape(b * s, d)
-        gates, experts = sparse_moe.route(h, lp, cfg)
-    with scope("experts"):
-        y, counts = sparse_moe.expert_ffn(
-            h, gates, experts, lp, cfg, weight=weight
-        )
-        x = x + y.reshape(b, s, d).astype(x.dtype)
-    return x, counts
+class PagedAttention:
+    """The attention state of every program over the page pool: the
+    pool's arrays inside one call (``ks, vs``, an int8 pool's scales
+    ``ksc, vsc``, a sparse-expert configuration's indexer keys ``xs``)
+    and the one place that knows how a page is written and read --
+    ``kv_quant``, ``kernel`` and the indexer's selection are decided
+    here and in no program. ``serve/decoder.py``'s layer loop calls it
+    once a layer; what stays the program's is what differs between
+    programs: positions and the mask, and which pages and rows this
+    step writes.
 
+    A factory builds one for its program's kind (``chunk``: one
+    sequence's block-aligned run of tokens; else one row a slot, or a
+    slot's candidate rows); the traced body takes a copy over its
+    arrays (:meth:`on`), says what the step reads (:meth:`view`) and
+    writes (:meth:`pages` or :meth:`rows`), hands it to the loop and
+    returns :meth:`state`. A layer is then, by stage name:
 
-def _select(h, lp, cfg, xs, layer, write, view_ids, valid, icos, isin):
-    """The indexer of one layer, under ``indexer``: project, put the
-    tokens' keys into the indexer pool (``write(xs, keys) -> xs``),
-    score every column of the view against each query and keep the
-    exact top ``indexer_topk`` of the columns ``valid`` allows.
-    ``h [b, s, dim]``, ``valid [b or 1, s, columns]``, ``view_ids`` the
-    view's pages (``[b, pages]``, or ``[pages]`` shared by one
-    sequence's ``s`` rows) -> ``(xs, selected [.., s, columns])``."""
-    with jax.named_scope("indexer"):
-        qi, ki, w = sparse_moe.indexer_project(h, lp, cfg, icos, isin)
-        xs = write(xs, ki)
-        keys = xs[layer, view_ids]
-        keys = keys.reshape(
-            *keys.shape[:-3], -1, cfg.indexer_head_dim
+    * ``kv_write`` -- a chunk's K/V as whole pages into ``blk_ids``; a
+      row's into page ``pb`` at offset ``off`` through ``write_tokens``
+      (one writer a page a call, so a slot's candidate rows, which
+      share pages, go one call each). An int8 pool quantizes whole
+      pages (per-page f32 scale into ``ksc`` / ``vsc``), so its row
+      write is a page REQUANTIZE: dequantize the target page, insert
+      the row, zero the not-yet-written tail (so stale garbage cannot
+      leak into the scale), requantize with a fresh per-page amax
+      scale. The page's scale is monotone non-decreasing over a
+      request's decode (amax only grows among live positions), so
+      requantization drift of earlier tokens is bounded -- the int8
+      oracle's contract.
+    * ``indexer`` (a sparse-expert configuration only,
+      ``models/sparse_moe.py``) -- project, put the tokens' keys into
+      ``xs`` under the page ids and rows their K/V went to, score every
+      column of the view against each query and keep the exact top
+      ``indexer_topk`` of the columns the program's mask allows. The
+      read and attention stages run as they are under THAT mask (the
+      gathered pages under the selection: on the v5e this read 2.3 ms
+      a step faster than a token-granular gather of the selected rows,
+      PERF.md PR 27). A row step also keeps each layer's selection
+      (``picked``, for the benchmark's probe) and counts what the
+      indexer scored and what attention read (``counts``, by
+      ``SPARSE_COUNTERS``' keys).
+    * ``kv_read`` + ``attention`` -- ``kernel="gather"``: ONE
+      data-indexed gather of the view's pages by layer and table
+      (dequantized from an int8 pool) and the model's dense attention
+      under the mask, page-major for row steps
+      (:func:`_grouped_attention_paged`) and token-major for a chunk;
+      so a chunk attends to every previously prefilled chunk and to the
+      shared prefix pages it never computed. ``kernel="pallas"``: the
+      table handed to kernels/paged_attention.py, which walks it
+      in-kernel under ``shard_map`` over ``mesh`` (required for this
+      kernel), all of it under ``kv_read``. Both read paths dequantize,
+      so they always see the identical pool state.
+    """
+
+    def __init__(self, cfg, block_size: int, max_blocks: int,
+                 kernel: str = "gather", kv_quant: str = "none",
+                 mesh: Optional[Mesh] = None, chunk: bool = False):
+        _check_read_path(cfg, kernel, kv_quant)
+        self.cfg = cfg
+        self.block_size, self.max_blocks = block_size, max_blocks
+        self.chunk = chunk
+        self.quant = kv_quant == "int8"
+        self.sparse = sparse_moe.is_sparse_moe(cfg)
+        self.kernel = None
+        if kernel == "pallas":
+            self.kernel = _on_mesh(
+                paged_prefill_attention if chunk
+                else paged_decode_attention,
+                mesh, cfg.kv_heads, 0 if chunk else 1,
+                block_size=block_size, max_blocks=max_blocks,
+            )
+
+    def on(self, ks, vs, ksc=None, vsc=None, xs=None):
+        """This state over one call's arrays (a copy: the factory's
+        own is traced once for every shape)."""
+        pool = copy.copy(self)
+        pool.ks, pool.vs, pool.ksc, pool.vsc, pool.xs = ks, vs, ksc, vsc, xs
+        return pool
+
+    def state(self):
+        return self.ks, self.vs, self.ksc, self.vsc, self.xs
+
+    def view(self, tables, *where):
+        """What attention reads: the first ``max_blocks`` pages of each
+        slot's table (``[slots, width]``), or of one sequence's
+        (``[width]``). ``where`` is what a table-walking kernel takes
+        after the table: ``pos, active`` of a row step, ``start`` of a
+        chunk."""
+        self.tables, self.where = tables, where
+        self.view_ids = tables[..., :self.max_blocks]
+
+    def pages(self, blk_ids, qpos, mask):
+        """What a chunk writes: its tokens at positions ``qpos`` fill
+        the pages ``blk_ids``; ``mask [1, 1, 1, rows, columns]`` is
+        what each may read."""
+        self.blk_ids, self.mask = blk_ids, mask
+        if self.sparse:
+            self.icos, self.isin = self._indexer_rope(qpos)
+
+    def rows(self, pb, off, mask, slot=None):
+        """What a row step writes: page ``pb`` at offset ``off`` for
+        each slot's row (``[slots]``), or for each of a slot's
+        candidate rows (``[slots, n]``); ``mask [slots, 1, 1, rows,
+        columns]`` is what each may read. ``slot`` is
+        ``arange(slots)``, which an int8 pool's page insert indexes
+        by."""
+        cfg = self.cfg
+        self.pb, self.off, self.mask, self.slot = pb, off, mask, slot
+        if self.quant:
+            idx = jnp.arange(self.block_size)
+            # Rows of the write-target page already live, broadcast
+            # over the page's [kv_heads, block_size, head_dim].
+            self.written = (
+                idx[None, :] <= off[:, None]
+            )[:, None, :, None]
+        if self.sparse:
+            pos, active = self.where
+            icos, isin = self._indexer_rope(pos)
+            self.icos, self.isin = icos[:, None, :], isin[:, None, :]
+            self.valid = mask[:, 0, 0]            # [slots, 1, columns]
+            self.counted = active > 0
+            self.picked = []
+            self.counts = {
+                "selected": 0,
+                "candidates": cfg.n_layers * jnp.sum(
+                    jnp.where(self.counted, pos + 1, 0)
+                ),
+            }
+
+    def _indexer_rope(self, positions):
+        return llama2.rope_cos_sin(
+            1, self.cfg.indexer_rope_dim, self.cfg.rope_theta,
+            positions=positions,
         )
-        if view_ids.ndim == 2:          # decode: a view a slot
-            keys = keys[:, None]
-        scores = sparse_moe.indexer_scores(qi, w, keys, cfg)
-        return xs, sparse_moe.select_topk(
-            scores, valid, cfg.indexer_topk
-        )
+
+    def __call__(self, layer, h, lp, q, k, v):
+        self._write(layer, k, v)
+        mask = self.mask
+        if self.sparse:
+            mask = self._select(layer, h, lp)
+        return self._read(layer, q, mask)
+
+    def _target(self, j):
+        """Row ``j``'s page and offset."""
+        if self.pb.ndim == 1:
+            return self.pb, self.off
+        return self.pb[:, j], self.off[:, j]
+
+    def _write(self, layer, k, v):
+        bs = self.block_size
+        with jax.named_scope("kv_write"):
+            if self.chunk:
+                ids = self.blk_ids
+                k_pages = tokens_to_pages(k[0], bs)
+                v_pages = tokens_to_pages(v[0], bs)
+            elif self.quant:
+                ids = self.pb
+                k_pages = dequantize_pages_int8(
+                    self.ks[layer, ids], self.ksc[layer, ids]
+                )
+                v_pages = dequantize_pages_int8(
+                    self.vs[layer, ids], self.vsc[layer, ids]
+                )
+                k_pages = k_pages.at[self.slot, :, self.off].set(
+                    k[:, 0].astype(jnp.float32)
+                )
+                v_pages = v_pages.at[self.slot, :, self.off].set(
+                    v[:, 0].astype(jnp.float32)
+                )
+                k_pages = jnp.where(self.written, k_pages, 0.0)
+                v_pages = jnp.where(self.written, v_pages, 0.0)
+            else:
+                for j in range(k.shape[1]):
+                    self.ks = write_tokens(
+                        self.ks, layer, *self._target(j), k[:, j]
+                    )
+                    self.vs = write_tokens(
+                        self.vs, layer, *self._target(j), v[:, j]
+                    )
+                return
+            if self.quant:
+                kq, k_sc = quantize_pages_int8(k_pages)
+                vq, v_sc = quantize_pages_int8(v_pages)
+                self.ks = self.ks.at[layer, ids].set(kq)
+                self.vs = self.vs.at[layer, ids].set(vq)
+                self.ksc = self.ksc.at[layer, ids].set(k_sc)
+                self.vsc = self.vsc.at[layer, ids].set(v_sc)
+            else:
+                self.ks = self.ks.at[layer, ids].set(
+                    k_pages.astype(self.ks.dtype)
+                )
+                self.vs = self.vs.at[layer, ids].set(
+                    v_pages.astype(self.vs.dtype)
+                )
+
+    def _select(self, layer, h, lp):
+        """The layer's selection as attention's mask. ``h [b, s,
+        dim]``; a chunk's ``s`` rows share one view and each keeps its
+        own top ``indexer_topk`` of the columns ``<= start + q``."""
+        cfg = self.cfg
+        valid = self.mask[0, 0] if self.chunk else self.valid
+        with jax.named_scope("indexer"):
+            qi, ki, w = sparse_moe.indexer_project(
+                h, lp, cfg, self.icos, self.isin
+            )
+            if self.chunk:
+                self.xs = self.xs.at[layer, self.blk_ids].set(
+                    ki[0].reshape(
+                        self.blk_ids.shape[0], self.block_size, -1
+                    ).astype(self.xs.dtype)
+                )
+            else:
+                self.xs = write_tokens(
+                    self.xs, layer, self.pb, self.off, ki[:, 0]
+                )
+            keys = self.xs[layer, self.view_ids]
+            keys = keys.reshape(
+                *keys.shape[:-3], -1, cfg.indexer_head_dim
+            )
+            if not self.chunk:              # a view a slot
+                keys = keys[:, None]
+            scores = sparse_moe.indexer_scores(qi, w, keys, cfg)
+            chosen = sparse_moe.select_topk(
+                scores, valid, cfg.indexer_topk
+            )
+        if self.chunk:
+            return chosen[:, None, None]
+        self.picked.append(chosen[:, 0])
+        mask = chosen[:, None, None]
+        with jax.named_scope("indexer"):
+            self.counts["selected"] += jnp.sum(
+                jnp.where(self.counted[:, None, None], chosen, False)
+            )
+        return mask
+
+    def _read(self, layer, q, mask):
+        cfg, quant = self.cfg, self.quant
+        b, s = q.shape[0], q.shape[1]
+        if self.kernel is not None:
+            with jax.named_scope("kv_read"):
+                # Queries grouped by KV head: [kv_heads, rows, ...] for
+                # one sequence's chunk, [slots, kv_heads, ...] for rows.
+                qg = q[0] if self.chunk else q[:, 0]
+                qg = qg.astype(cfg.dtype).reshape(
+                    -1, cfg.kv_heads, cfg.n_heads // cfg.kv_heads,
+                    cfg.head_dim,
+                )
+                if self.chunk:
+                    qg = qg.transpose(1, 0, 2, 3)
+                ctx = self.kernel(
+                    qg, self.ks[layer], self.vs[layer], self.tables,
+                    *self.where,
+                    k_scale=self.ksc[layer] if quant else None,
+                    v_scale=self.vsc[layer] if quant else None,
+                )
+                if self.chunk:
+                    ctx = ctx.transpose(1, 0, 2, 3)
+                return ctx.reshape(b, s, cfg.n_heads, cfg.head_dim)
+        with jax.named_scope("kv_read"):
+            k_view = self.ks[layer, self.view_ids]
+            v_view = self.vs[layer, self.view_ids]
+            if quant:
+                k_view = dequantize_pages_int8(
+                    k_view, self.ksc[layer, self.view_ids]
+                )
+                v_view = dequantize_pages_int8(
+                    v_view, self.vsc[layer, self.view_ids]
+                )
+            if self.chunk:
+                # Token-major for the chunk: its view is ONE slot's
+                # pages (a hundredth of the 512-row scores), and the
+                # chip ran this contraction 7 % faster than the
+                # page-major one at 8 KV heads (PERF.md, PR 26).
+                k_view = pages_to_tokens(k_view)[None]
+                v_view = pages_to_tokens(v_view)[None]
+        with jax.named_scope("attention"):
+            attend = _grouped_attention if self.chunk \
+                else _grouped_attention_paged
+            return attend(
+                q, k_view.astype(cfg.dtype), v_view.astype(cfg.dtype),
+                mask, cfg,
+            )
 
 
 def make_chunk_logits_fn(
@@ -913,54 +1145,33 @@ def make_chunk_logits_fn(
     loop, two token rules.
 
     ``(params, ks, vs, tokens [1, bucket], start, true_len,
-    table [table_width])`` -> ``(ks, vs, next_token)``: the chunk's
-    K/V is scattered into the pages ``table[start/bs :]`` names, then
+    table [table_width])`` -> ``(ks, vs, logits)``: the chunk's
+    K/V goes into the pages ``table[start/bs :]`` names, then
     attention runs over the WHOLE logical sequence view under the
-    global causal mask ``key_pos <= start + q`` -- so a chunk attends
-    to every previously prefilled chunk and to the shared prefix pages
-    it never computed. The greedy token from row ``true_len - 1`` is
+    global causal mask ``key_pos <= start + q``
+    (:class:`PagedAttention` has the stages, and what ``kernel`` and
+    ``kv_quant`` change in them). The row at ``true_len - 1`` is
     meaningful on the final chunk only.
 
-    ``kernel="gather"`` reads the view through a data-indexed gather
-    of the first ``max_blocks`` table entries (the oracle);
-    ``kernel="pallas"`` hands the table row to
-    :func:`tpu_hpc.kernels.paged_attention.paged_prefill_attention`,
-    which walks it in-kernel under ``shard_map`` over ``mesh`` (the
-    serving mesh; required for this kernel). ``kv_quant="int8"`` changes the
-    program signature to ``(params, ks, vs, ksc, vsc, tokens, start,
-    true_len, table) -> (ks, vs, ksc, vsc, next_token)``: the scatter
-    quantizes whole pages (per-page f32 scale into the ``ksc``/``vsc``
-    side arrays) and both read paths dequantize -- so gather and
-    pallas always see the identical pool state.
+    The pool's arrays follow ``vs`` in the arguments and the results
+    alike (:func:`_with_state`): ``ksc, vsc`` for ``kv_quant="int8"``,
+    ``xs`` for a sparse-expert configuration (``models/sparse_moe.py``).
 
     ``table_width > max_blocks``: the trailing entries are scratch
     padding, so a bucket-padded write near the capacity edge can
     never clamp (jax dynamic_slice clamps out-of-range starts, which
     would silently misalign the scatter) nor touch a real page.
-
-    A sparse-expert configuration (``models/sparse_moe.py``) takes
-    ``(params, ks, vs, xs, tokens, ...)`` and returns ``xs`` after
-    ``vs``: the chunk's indexer keys go into the pages its K/V goes
-    into, each query row keeps its own exact top ``indexer_topk`` of
-    the columns ``<= start + q``, and attention runs under that mask
-    (:func:`make_paged_decode_fn` has the stages).
     """
     nb_chunk = bucket // block_size
     cache_cap = max_blocks * block_size
-    quant = kv_quant == "int8"
-    use_pallas = kernel == "pallas"
-    sparse = sparse_moe.is_sparse_moe(cfg)
-    _check_read_path(cfg, kernel, kv_quant)
-    groups = cfg.n_heads // cfg.kv_heads
-    if use_pallas:
-        prefill_attention = _on_mesh(
-            paged_prefill_attention, mesh, cfg.kv_heads, 0,
-            block_size=block_size, max_blocks=max_blocks,
-        )
+    attention = PagedAttention(
+        cfg, block_size, max_blocks, kernel, kv_quant, mesh, chunk=True
+    )
 
     def body(params, ks, vs, ksc, vsc, xs, tokens, start, true_len,
              table):
         scope = jax.named_scope
+        pool = attention.on(ks, vs, ksc, vsc, xs)
         with scope("embed"):
             x = _embed(params, tokens, cfg)
         qpos = start + jnp.arange(bucket)
@@ -970,95 +1181,19 @@ def make_chunk_logits_fn(
         blk_ids = jax.lax.dynamic_slice(
             table, (start // block_size,), (nb_chunk,)
         )
-        view_ids = table[:max_blocks]
-        if sparse:
-            icos, isin = llama2.rope_cos_sin(
-                bucket, cfg.indexer_rope_dim, cfg.rope_theta,
-                positions=qpos,
-            )
-            causal = mask
-        for i in range(cfg.n_layers):
-            lp = params[f"layers_{i}"]
-            with scope("qkv"):
-                h = _rmsnorm(
-                    x, lp["attention_norm"]["scale"], cfg.norm_eps
-                )
-                q, k, v = _qkv(h, lp, cfg)
-                q = llama2.apply_rope(q, cos, sin)
-                k = llama2.apply_rope(k, cos, sin)
-            with scope("kv_write"):
-                kb = tokens_to_pages(k[0], block_size)
-                vb = tokens_to_pages(v[0], block_size)
-                if quant:
-                    kq, k_sc = quantize_pages_int8(kb)
-                    vq, v_sc = quantize_pages_int8(vb)
-                    ks = ks.at[i, blk_ids].set(kq)
-                    vs = vs.at[i, blk_ids].set(vq)
-                    ksc = ksc.at[i, blk_ids].set(k_sc)
-                    vsc = vsc.at[i, blk_ids].set(v_sc)
-                else:
-                    ks = ks.at[i, blk_ids].set(kb.astype(ks.dtype))
-                    vs = vs.at[i, blk_ids].set(vb.astype(vs.dtype))
-            if sparse:
-                # Attention reads the selected columns only: the same
-                # view and product under the selection's mask.
-                xs, chosen = _select(
-                    h, lp, cfg, xs, i,
-                    lambda pool, keys: pool.at[i, blk_ids].set(
-                        keys[0].reshape(
-                            nb_chunk, block_size, -1
-                        ).astype(pool.dtype)
-                    ),
-                    view_ids, causal[0, 0], icos, isin,
-                )
-                mask = chosen[:, None, None]
-            if use_pallas:
-                with scope("kv_read"):
-                    qp = q[0].astype(cfg.dtype).reshape(
-                        bucket, cfg.kv_heads, groups, cfg.head_dim
-                    ).transpose(1, 0, 2, 3)
-                    ctx = prefill_attention(
-                        qp, ks[i], vs[i], table, start,
-                        k_scale=ksc[i] if quant else None,
-                        v_scale=vsc[i] if quant else None,
-                    )
-                    attn = ctx.transpose(1, 0, 2, 3).reshape(
-                        1, bucket, cfg.n_heads, cfg.head_dim
-                    )
-            else:
-                with scope("kv_read"):
-                    k_view = ks[i, view_ids]
-                    v_view = vs[i, view_ids]
-                    if quant:
-                        k_view = dequantize_pages_int8(
-                            k_view, ksc[i, view_ids]
-                        )
-                        v_view = dequantize_pages_int8(
-                            v_view, vsc[i, view_ids]
-                        )
-                    # Token-major for the chunk: its view is ONE slot's
-                    # pages (a hundredth of the 512-row scores), and
-                    # the chip ran this contraction 7 % faster than the
-                    # page-major one at 8 KV heads (PERF.md, PR 26).
-                    k_view = pages_to_tokens(k_view)[None]
-                    v_view = pages_to_tokens(v_view)[None]
-                with scope("attention"):
-                    attn = _grouped_attention(
-                        q, k_view.astype(cfg.dtype),
-                        v_view.astype(cfg.dtype), mask, cfg,
-                    )
-            with scope("attn_out"):
-                x = x + _attn_out_proj(attn, lp, cfg)
-            x, _ = _ffn_stage(x, lp, cfg)
+        pool.view(table, start)
+        pool.pages(blk_ids, qpos, mask)
+        x, _ = decoder_layers(params, cfg, x, cos, sin, pool)
         with scope("head"):
             last = jax.lax.dynamic_slice(
                 x, (0, true_len - 1, 0), (1, 1, cfg.dim)
             )
             logits = _logits_head(last, params, cfg)
-        return ks, vs, ksc, vsc, xs, logits[0, 0]
+        return *pool.state(), logits[0, 0]
 
     return _with_state(
-        body, "chunk_logits_q" if quant else "chunk_logits", quant, sparse
+        body, "chunk_logits_q" if attention.quant else "chunk_logits",
+        attention.quant, attention.sparse,
     )
 
 
@@ -1116,7 +1251,7 @@ def make_paged_decode_fn(
     step k's tokens reach the host, and one step is always queued
     behind the one running.
 
-    Each active slot's token K/V is scattered into page
+    Each active slot's token K/V goes into page
     ``tables[s, pos/bs]`` at offset ``pos % bs``; inactive slots (free,
     or still prefilling their prompt) are redirected to the scratch
     block so their garbage write cannot corrupt a live page. Attention
@@ -1132,31 +1267,13 @@ def make_paged_decode_fn(
     tenant's chunk lands after it, and a row past a tenant's length is
     never read.
 
-    ``kernel="pallas"`` swaps the view gather + dense attention for
-    :func:`tpu_hpc.kernels.paged_attention.paged_decode_attention`
-    (table walked in-kernel, one pool read per page, under
-    ``shard_map`` over ``mesh``). ``kv_quant=
-    "int8"`` threads the scale side arrays through the signature
-    (``..., ks, vs, ksc, vsc, ...``) and the token write becomes a
-    page REQUANTIZE: dequantize the target page, insert the token,
-    zero the not-yet-written tail (so stale garbage cannot leak into
-    the scale), requantize with a fresh per-page amax scale. The
-    page's scale is monotone non-decreasing over a request's decode
-    (amax only grows among live positions), so requantization drift
-    of earlier tokens is bounded -- the int8 oracle's contract.
-
-    A sparse-expert configuration (``models/sparse_moe.py``) runs the
-    SAME program with two stages of its own, and ``(ks, vs)`` grows to
-    ``(ks, vs, xs)``, ``xs`` the indexer keys ``[layers, num_blocks,
-    block_size, indexer_head_dim]`` under the same page ids and rows:
-    after the K/V write the indexer (:func:`_select`) writes the
-    token's key, scores every column of the slot's view and keeps the
-    exact top ``indexer_topk`` live ones, and the read and attention
-    stages run as they are under THAT mask (the gathered pages under
-    the selection: on the v5e this read 2.3 ms a step faster than a
-    token-granular gather of the selected rows, PERF.md PR 27); the
-    feed-forward is :func:`_ffn_stage`'s router and experts. The
-    result is ``tokens ++ counts`` in one int32 vector
+    :class:`PagedAttention` has the stages, and what ``kernel`` and
+    ``kv_quant`` change in them; the pool's arrays follow ``vs``
+    (:func:`_with_state`). A sparse-expert configuration
+    (``models/sparse_moe.py``) runs the SAME program with the
+    indexer's selection inside attention and
+    ``serve/decoder.py``'s router and experts for a feed-forward, and
+    its result is ``tokens ++ counts`` in one int32 vector
     (``SPARSE_COUNTERS``' order), so the counts cost no second fetch
     (and ``prev`` is that vector: its first ``slots`` entries are read).
     ``probe=True`` (such configurations only) also returns each layer's
@@ -1164,19 +1281,13 @@ def make_paged_decode_fn(
     reads it, no serving path does.
     """
     cache_cap = max_blocks * block_size
-    quant = kv_quant == "int8"
-    use_pallas = kernel == "pallas"
-    sparse = sparse_moe.is_sparse_moe(cfg)
-    _check_read_path(cfg, kernel, kv_quant)
-    groups = cfg.n_heads // cfg.kv_heads
-    if use_pallas:
-        decode_attention = _on_mesh(
-            paged_decode_attention, mesh, cfg.kv_heads, 1,
-            block_size=block_size, max_blocks=max_blocks,
-        )
+    attention = PagedAttention(
+        cfg, block_size, max_blocks, kernel, kv_quant, mesh
+    )
 
     def body(params, ks, vs, ksc, vsc, xs, prev, step, tables):
         scope = jax.named_scope
+        pool = attention.on(ks, vs, ksc, vsc, xs)
         host_tokens, pos, active, fresh = step
         slots = step.shape[1]
         with scope("embed"):
@@ -1192,128 +1303,30 @@ def make_paged_decode_fn(
         pb = jnp.where(
             active > 0, tables[rows, blk], SCRATCH_BLOCK
         )
-        view_ids = tables[:, :max_blocks]
-        idx = jnp.arange(block_size)
-        # Rows of the write-target page already live, broadcast over
-        # the page's [kv_heads, block_size, head_dim].
-        written = (idx[None, :] <= off[:, None])[:, None, :, None]
-        if sparse:
-            icos, isin = llama2.rope_cos_sin(
-                1, cfg.indexer_rope_dim, cfg.rope_theta, positions=pos
-            )
-            icos, isin = icos[:, None, :], isin[:, None, :]
-            live = mask[:, 0, 0]                  # [slots, 1, columns]
-            on = active > 0
-            picked = []
-            counts = {
-                "selected": 0,
-                "candidates": cfg.n_layers * jnp.sum(
-                    jnp.where(on, pos + 1, 0)
-                ),
-            }
-        for i in range(cfg.n_layers):
-            lp = params[f"layers_{i}"]
-            with scope("qkv"):
-                h = _rmsnorm(
-                    x, lp["attention_norm"]["scale"], cfg.norm_eps
-                )
-                q, k, v = _qkv(h, lp, cfg)
-                q = llama2.apply_rope(q, cos, sin)
-                k = llama2.apply_rope(k, cos, sin)
-            with scope("kv_write"):
-                if quant:
-                    k_page = dequantize_pages_int8(
-                        ks[i, pb], ksc[i, pb]
-                    )
-                    v_page = dequantize_pages_int8(
-                        vs[i, pb], vsc[i, pb]
-                    )
-                    k_page = k_page.at[rows, :, off].set(
-                        k[:, 0].astype(jnp.float32)
-                    )
-                    v_page = v_page.at[rows, :, off].set(
-                        v[:, 0].astype(jnp.float32)
-                    )
-                    k_page = jnp.where(written, k_page, 0.0)
-                    v_page = jnp.where(written, v_page, 0.0)
-                    kq, k_sc = quantize_pages_int8(k_page)
-                    vq, v_sc = quantize_pages_int8(v_page)
-                    ks = ks.at[i, pb].set(kq)
-                    vs = vs.at[i, pb].set(vq)
-                    ksc = ksc.at[i, pb].set(k_sc)
-                    vsc = vsc.at[i, pb].set(v_sc)
-                else:
-                    ks = write_tokens(ks, i, pb, off, k[:, 0])
-                    vs = write_tokens(vs, i, pb, off, v[:, 0])
-            if sparse:
-                xs, chosen = _select(
-                    h, lp, cfg, xs, i,
-                    lambda pool, keys: write_tokens(
-                        pool, i, pb, off, keys[:, 0]
-                    ),
-                    view_ids, live, icos, isin,
-                )
-                picked.append(chosen[:, 0])
-                mask = chosen[:, None, None]
-                with scope("indexer"):
-                    counts["selected"] += jnp.sum(
-                        jnp.where(on[:, None, None], chosen, False)
-                    )
-            if use_pallas:
-                with scope("kv_read"):
-                    qd = q[:, 0].astype(cfg.dtype).reshape(
-                        slots, cfg.kv_heads, groups, cfg.head_dim
-                    )
-                    ctx = decode_attention(
-                        qd, ks[i], vs[i], tables, pos, active,
-                        k_scale=ksc[i] if quant else None,
-                        v_scale=vsc[i] if quant else None,
-                    )
-                    attn = ctx.reshape(
-                        slots, 1, cfg.n_heads, cfg.head_dim
-                    )
-            else:
-                with scope("kv_read"):
-                    k_view = ks[i, view_ids]
-                    v_view = vs[i, view_ids]
-                    if quant:
-                        k_view = dequantize_pages_int8(
-                            k_view, ksc[i, view_ids]
-                        )
-                        v_view = dequantize_pages_int8(
-                            v_view, vsc[i, view_ids]
-                        )
-                with scope("attention"):
-                    attn = _grouped_attention_paged(
-                        q, k_view.astype(cfg.dtype),
-                        v_view.astype(cfg.dtype), mask, cfg,
-                    )
-            with scope("attn_out"):
-                x = x + _attn_out_proj(attn, lp, cfg)
-            x, moe = _ffn_stage(x, lp, cfg, weight=active)
-            for name, value in (moe or {}).items():
-                with scope("experts"):
-                    counts[name] = jnp.maximum(
-                        counts.get(name, 0), value
-                    ) if name.startswith("max") \
-                        else counts.get(name, 0) + value
+        pool.view(tables, pos, active)
+        pool.rows(pb, off, mask, slot=rows)
+        x, counts = decoder_layers(
+            params, cfg, x, cos, sin, pool, weight=active
+        )
         with scope("head"):
             logits = _logits_head(x, params, cfg)
             tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            if sparse:
+            if attention.sparse:
                 # The step's counts ride behind its tokens: one array,
                 # one fetch (order: SPARSE_COUNTERS).
+                counts.update(pool.counts)
                 tok = jnp.concatenate([tok, jnp.stack([
                     counts[key] for key, _, _ in SPARSE_COUNTERS
                 ]).astype(jnp.int32)])
         if probe:
             # The selection each layer made, for the benchmark's
             # check against the reference: [layers, slots, columns].
-            return ks, vs, ksc, vsc, xs, tok, jnp.stack(picked)
-        return ks, vs, ksc, vsc, xs, tok
+            return *pool.state(), tok, jnp.stack(pool.picked)
+        return *pool.state(), tok
 
     return _with_state(
-        body, "decode_q" if quant else "decode", quant, sparse
+        body, "decode_q" if attention.quant else "decode",
+        attention.quant, attention.sparse,
     )
 
 
@@ -1616,10 +1629,7 @@ class PagedEngine(Engine):
         if key[0] in self._tier_builders:
             return self._tier_builders[key[0]](key)
         cache = self._cache_abstract()
-        params_abs = jax.tree.map(
-            lambda a, s: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=s),
-            self.params, self._param_shardings,
-        )
+        params_abs = self._params_abstract()
         scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=self._rep)
         slots = self.serve_cfg.slots
         # int8 mode threads the f32 scale side arrays through every

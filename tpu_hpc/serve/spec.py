@@ -61,17 +61,18 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_hpc.kernels.paged_attention import write_tokens
 from tpu_hpc.models import llama2, sparse_moe
 from tpu_hpc.obs import get_bus, get_registry, span
-from tpu_hpc.serve.engine import (
-    _attn_out_proj,
+from tpu_hpc.serve.decoder import (
     _embed,
-    _grouped_attention_paged,
     _logits_head,
-    _mlp,
-    _qkv,
-    _rmsnorm,
+    _rope_tables,
+    decoder_layers,
+)
+from tpu_hpc.serve.paging import (
+    PagedAttention,
+    PagedEngine,
+    make_chunk_logits_fn,
 )
 
 SPEC_MODES = ("draft", "ngram")
@@ -294,12 +295,10 @@ def sample_token(
 # ---------------------------------------------------------------------
 
 
-def _rope_for(positions: jax.Array, head_dim: int):
+def _rope_for(cfg, positions: jax.Array):
     """Per-row RoPE tables for a ``[slots, n]`` position matrix."""
-    cos, sin = llama2.rope_cos_sin(
-        1, head_dim, positions=positions.reshape(-1)
-    )
-    shape = (*positions.shape, head_dim // 2)
+    cos, sin = _rope_tables(cfg, 1, positions.reshape(-1))
+    shape = (*positions.shape, cfg.head_dim // 2)
     return cos.reshape(shape), sin.reshape(shape)
 
 
@@ -327,20 +326,24 @@ def make_spec_draft_fn(
     per-step distributions ride out for the verify step's rejection
     test -- device-to-device, never fetched."""
     cache_cap = max_blocks * block_size
+    attention = PagedAttention(cfg, block_size, max_blocks)
 
     def draft(params, ks, vs, tokens, pos, tables, active, n_valid,
               seeds, temps, top_ps):
+        scope = jax.named_scope
+        pool = attention.on(ks, vs)
         slots = tokens.shape[0]
         rows = jnp.arange(slots)
         col = jnp.arange(cache_cap)
-        view_ids = tables[:, :max_blocks]
+        pool.view(tables)
         cur = tokens
         out_toks = []
         out_probs = []
         for j in range(k):
             pj = pos + j
-            x = _embed(params, cur[:, None], cfg)
-            cos, sin = _rope_for(pj[:, None], cfg.head_dim)
+            with scope("embed"):
+                x = _embed(params, cur[:, None], cfg)
+            cos, sin = _rope_for(cfg, pj[:, None])
             mask = (
                 col[None, :] <= pj[:, None]
             )[:, None, None, None, :]
@@ -349,33 +352,18 @@ def make_spec_draft_fn(
                 write_ok, tables[rows, pj // block_size],
                 scratch_block,
             )
-            off = pj % block_size
-            for i in range(cfg.n_layers):
-                lp = params[f"layers_{i}"]
-                h = _rmsnorm(
-                    x, lp["attention_norm"]["scale"], cfg.norm_eps
-                )
-                q, kk, v = _qkv(h, lp, cfg)
-                q = llama2.apply_rope(q, cos, sin)
-                kk = llama2.apply_rope(kk, cos, sin)
-                ks = write_tokens(ks, i, pb, off, kk[:, 0])
-                vs = write_tokens(vs, i, pb, off, v[:, 0])
-                attn = _grouped_attention_paged(
-                    q, ks[i, view_ids].astype(cfg.dtype),
-                    vs[i, view_ids].astype(cfg.dtype), mask, cfg,
-                )
-                x = x + _attn_out_proj(attn, lp, cfg)
-                h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-                x = x + _mlp(h, lp, cfg)
-            logits = _logits_head(x, params, cfg)  # [slots, 1, vocab]
-            p = sampling_probs(logits, temps, top_ps)[:, 0]
-            keys = _position_keys(seeds, pj, _STREAM_DRAFT)
-            tok = _categorical(keys, p).astype(jnp.int32)
+            pool.rows(pb, pj % block_size, mask)
+            x, _ = decoder_layers(params, cfg, x, cos, sin, pool)
+            with scope("head"):
+                logits = _logits_head(x, params, cfg)
+                p = sampling_probs(logits, temps, top_ps)[:, 0]
+                keys = _position_keys(seeds, pj, _STREAM_DRAFT)
+                tok = _categorical(keys, p).astype(jnp.int32)
             out_toks.append(tok)
             out_probs.append(p)
             cur = tok
         return (
-            ks, vs,
+            *pool.state()[:2],
             jnp.stack(out_toks, axis=1),
             jnp.stack(out_probs, axis=1),
         )
@@ -425,9 +413,12 @@ def make_spec_verify_fn(
     """
     cache_cap = max_blocks * block_size
     n_rows = k + 1
+    attention = PagedAttention(cfg, block_size, max_blocks)
 
     def verify(params, ks, vs, tokens, pos, tables, active, n_valid,
                *rest):
+        scope = jax.named_scope
+        pool = attention.on(ks, vs)
         if onehot_q:
             (seeds, temps, top_ps) = rest
             draft_probs = None
@@ -435,8 +426,9 @@ def make_spec_verify_fn(
             (draft_probs, seeds, temps, top_ps) = rest
         slots = tokens.shape[0]
         qpos = pos[:, None] + jnp.arange(n_rows)[None, :]
-        x = _embed(params, tokens, cfg)  # [slots, k+1, dim]
-        cos, sin = _rope_for(qpos, cfg.head_dim)
+        with scope("embed"):
+            x = _embed(params, tokens, cfg)  # [slots, k+1, dim]
+        cos, sin = _rope_for(cfg, qpos)
         col = jnp.arange(cache_cap)
         mask = (
             col[None, None, :] <= qpos[:, :, None]
@@ -450,85 +442,68 @@ def make_spec_verify_fn(
             jnp.take_along_axis(tables, qpos // block_size, axis=1),
             scratch_block,
         )
-        off = qpos % block_size
-        view_ids = tables[:, :max_blocks]
-        for i in range(cfg.n_layers):
-            lp = params[f"layers_{i}"]
-            h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
-            q, kk, v = _qkv(h, lp, cfg)
-            q = llama2.apply_rope(q, cos, sin)
-            kk = llama2.apply_rope(kk, cos, sin)
-            # One call a candidate row: a slot's rows share pages,
-            # and write_tokens takes one writer a page.
-            for j in range(n_rows):
-                ks = write_tokens(ks, i, pb[:, j], off[:, j], kk[:, j])
-                vs = write_tokens(vs, i, pb[:, j], off[:, j], v[:, j])
-            attn = _grouped_attention_paged(
-                q, ks[i, view_ids].astype(cfg.dtype),
-                vs[i, view_ids].astype(cfg.dtype), mask, cfg,
+        pool.rows(pb, qpos % block_size, mask)
+        pool.view(tables)
+        x, _ = decoder_layers(params, cfg, x, cos, sin, pool)
+        with scope("head"):
+            logits = _logits_head(x, params, cfg)  # [slots, k+1, vocab]
+            p = sampling_probs(logits, temps, top_ps)
+            drafts = tokens[:, 1:]  # [slots, k]: d_1 .. d_k
+            if onehot_q:
+                q_probs = jax.nn.one_hot(
+                    drafts, cfg.vocab_size, dtype=jnp.float32
+                )
+            else:
+                q_probs = draft_probs.astype(jnp.float32)
+            p_d = jnp.take_along_axis(
+                p[:, :k], drafts[..., None], axis=-1
+            )[..., 0]
+            q_d = jnp.take_along_axis(
+                q_probs, drafts[..., None], axis=-1
+            )[..., 0]
+            u_keys = _position_keys(
+                jnp.broadcast_to(seeds[:, None], (slots, k)),
+                qpos[:, :k], _STREAM_ACCEPT,
             )
-            x = x + _attn_out_proj(attn, lp, cfg)
-            h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-            x = x + _mlp(h, lp, cfg)
-        logits = _logits_head(x, params, cfg)  # [slots, k+1, vocab]
-        p = sampling_probs(logits, temps, top_ps)
-
-        drafts = tokens[:, 1:]  # [slots, k]: d_1 .. d_k
-        if onehot_q:
-            q_probs = jax.nn.one_hot(
-                drafts, cfg.vocab_size, dtype=jnp.float32
+            u = jax.vmap(jax.random.uniform)(u_keys).reshape(slots, k)
+            valid = jnp.arange(k)[None, :] < n_valid[:, None]
+            accept = (u * q_d < p_d) & valid
+            n_acc = jnp.sum(
+                jnp.cumprod(accept.astype(jnp.int32), axis=1), axis=1
             )
-        else:
-            q_probs = draft_probs.astype(jnp.float32)
-        p_d = jnp.take_along_axis(
-            p[:, :k], drafts[..., None], axis=-1
-        )[..., 0]
-        q_d = jnp.take_along_axis(
-            q_probs, drafts[..., None], axis=-1
-        )[..., 0]
-        u_keys = _position_keys(
-            jnp.broadcast_to(seeds[:, None], (slots, k)),
-            qpos[:, :k], _STREAM_ACCEPT,
-        )
-        u = jax.vmap(jax.random.uniform)(u_keys).reshape(slots, k)
-        valid = jnp.arange(k)[None, :] < n_valid[:, None]
-        accept = (u * q_d < p_d) & valid
-        n_acc = jnp.sum(
-            jnp.cumprod(accept.astype(jnp.int32), axis=1), axis=1
-        )
 
-        # The emitting row is n_acc in both outcomes: residual
-        # resample on a rejection, bonus draw on a clean sweep (q
-        # zeroed -> residual == p).
-        p_row = jnp.take_along_axis(
-            p, n_acc[:, None, None], axis=1
-        )[:, 0]
-        q_row = jnp.take_along_axis(
-            jnp.concatenate(
-                [q_probs,
-                 jnp.zeros((slots, 1, cfg.vocab_size), jnp.float32)],
-                axis=1,
-            ),
-            n_acc[:, None, None], axis=1,
-        )[:, 0]
-        q_row = jnp.where(
-            (n_acc == n_valid)[:, None], 0.0, q_row
-        )
-        resid = jnp.maximum(p_row - q_row, 0.0)
-        rsum = jnp.sum(resid, axis=-1, keepdims=True)
-        resid = jnp.where(rsum > 0, resid / rsum, p_row)
-        emit_keys = _position_keys(
-            seeds, pos + n_acc, _STREAM_EMIT
-        )
-        emit = _categorical(emit_keys, resid).astype(jnp.int32)
-        out = jnp.concatenate(
-            [drafts, jnp.zeros((slots, 1), jnp.int32)], axis=1
-        )
-        out = jnp.where(
-            jnp.arange(n_rows)[None, :] == n_acc[:, None],
-            emit[:, None], out,
-        )
-        return ks, vs, out, n_acc.astype(jnp.int32)
+            # The emitting row is n_acc in both outcomes: residual
+            # resample on a rejection, bonus draw on a clean sweep (q
+            # zeroed -> residual == p).
+            p_row = jnp.take_along_axis(
+                p, n_acc[:, None, None], axis=1
+            )[:, 0]
+            q_row = jnp.take_along_axis(
+                jnp.concatenate(
+                    [q_probs,
+                     jnp.zeros((slots, 1, cfg.vocab_size), jnp.float32)],
+                    axis=1,
+                ),
+                n_acc[:, None, None], axis=1,
+            )[:, 0]
+            q_row = jnp.where(
+                (n_acc == n_valid)[:, None], 0.0, q_row
+            )
+            resid = jnp.maximum(p_row - q_row, 0.0)
+            rsum = jnp.sum(resid, axis=-1, keepdims=True)
+            resid = jnp.where(rsum > 0, resid / rsum, p_row)
+            emit_keys = _position_keys(
+                seeds, pos + n_acc, _STREAM_EMIT
+            )
+            emit = _categorical(emit_keys, resid).astype(jnp.int32)
+            out = jnp.concatenate(
+                [drafts, jnp.zeros((slots, 1), jnp.int32)], axis=1
+            )
+            out = jnp.where(
+                jnp.arange(n_rows)[None, :] == n_acc[:, None],
+                emit[:, None], out,
+            )
+        return *pool.state()[:2], out, n_acc.astype(jnp.int32)
 
     return verify
 
@@ -553,8 +528,6 @@ class SpecRunner:
         draft_params: Any = None,
         draft_cfg: Optional[llama2.LlamaConfig] = None,
     ):
-        from tpu_hpc.serve.paging import PagedEngine
-
         if not getattr(engine, "is_paged", False) or not isinstance(
             engine, PagedEngine
         ):
@@ -652,12 +625,7 @@ class SpecRunner:
     # -- program builders (dispatched from the engines' _build) --------
     def _abstracts(self, engine):
         cache = engine._cache_abstract()
-        params_abs = jax.tree.map(
-            lambda a, s: jax.ShapeDtypeStruct(
-                a.shape, a.dtype, sharding=s
-            ),
-            engine.params, engine._param_shardings,
-        )
+        params_abs = engine._params_abstract()
         slots = engine.serve_cfg.slots
         rep = engine._rep
 
@@ -742,8 +710,6 @@ class SpecRunner:
         absolute position ``start + true_len - 1``, matching the
         verify program's convention, so the first generated token of
         a sampled request is part of the same deterministic stream."""
-        from tpu_hpc.serve.paging import make_chunk_logits_fn
-
         engine = self.engine
         bucket = key[1]
         cache, params_abs, slots, vec = self._abstracts(engine)
