@@ -59,7 +59,7 @@ CONFIGS = {
     ),
 }
 COUNTERS = [name for name, _ in paging.DECODE_COUNTERS]
-OVERLAPPED, DISCARDED = COUNTERS
+OVERLAPPED, DISCARDED, *VIEW_PAGES = COUNTERS
 
 
 @pytest.fixture(scope="module")
@@ -167,7 +167,8 @@ def _trace(eos=None):
 def _per_request(grown):
     """The counts that do not depend on which requests share a step."""
     return {k: v for k, v in grown.items() if k not in (
-        "decode_steps", OVERLAPPED, "serve_moe_experts_touched_total",
+        "decode_steps", OVERLAPPED, *VIEW_PAGES,
+        "serve_moe_experts_touched_total",
         "serve_moe_max_tokens_per_expert",
     )}
 

@@ -445,9 +445,11 @@ class TestPagedCompileDiscipline:
     def test_zero_recompiles_across_mix(self, warm_paged):
         """Block tables, positions and the active mask are data: a mix
         with churn, hits, chunked prompts and CoW adds NO executables
-        after warmup (buckets + decode + copy_block)."""
+        after warmup (buckets + the decode ladder: its flat rungs and
+        the rectangle + copy_block)."""
         warmed = warm_paged.compile_count
-        assert warmed == len(SERVE.prefill_buckets) + 2
+        assert len(warm_paged.decode_rungs) == 2
+        assert warmed == len(SERVE.prefill_buckets) + 2 + 2
         rng = np.random.default_rng(10)
         reqs = [
             Request(
@@ -726,8 +728,9 @@ class TestPagedReplayCLI:
         assert summary["kv_layout"] == "paged"
         assert summary["recompiles"] == 0
         assert summary["kv_block_size"] == 4
-        # bucket(8) + decode + copy_block
-        assert summary["compiled_programs"] == 3
+        # bucket(8) + decode (the flat rungs its small view has, of
+        # 2 and 3 pages, and the rectangle) + copy_block
+        assert summary["compiled_programs"] == 5
 
     def test_misplaced_paged_flags_are_cli_errors(self):
         from tpu_hpc.serve import server

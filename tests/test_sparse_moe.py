@@ -366,7 +366,9 @@ def test_counts_come_back_with_the_tokens(params, mesh):
 # programs, the speculative draft and verify programs, the
 # sparse-expert decode, probe and chunk programs) were taken on the
 # parent commit of the PR that gave every program one layer loop
-# (PR 29, on 2a3e19e's code).
+# (PR 29, on 2a3e19e's code). ``flat<pages>`` are the decode programs
+# of the flat rungs an engine of this shape holds (4 slots x 12 pages:
+# 18 and 24), as the PR that brought them left them (PR 30).
 PROGRAM_DIGESTS = {
     "gqa-decode-gather-none": "81408bbaba39a8c9",
     "gqa-prefill-gather-none": "8a8972db9fc5d25e",
@@ -394,6 +396,10 @@ PROGRAM_DIGESTS = {
     "sparse-decode": "901f33d0d5597192",
     "sparse-decode_probe": "9317ff59722907af",
     "sparse-prefill": "98bb54d41635cafb",
+    "gqa-flat18-gather-none": "ba224ec5956db4d4",
+    "gqa-flat24-gather-none": "b21373b7aa6c8bf3",
+    "mha-flat18-gather-none": "c6d1fa9b094f9fbd",
+    "mha-flat24-gather-none": "be16c8b0efdbda89",
 }
 
 
@@ -439,10 +445,11 @@ def _program_text(mesh, tag, program, kernel="gather", quant="none"):
         vec_prev = _abstract((slots + len(paging.SPARSE_COUNTERS),))
     else:
         vec_prev = vec
-    if program in ("decode", "decode_probe"):
+    if program in ("decode", "decode_probe") or program.startswith("flat"):
         fn = paging.make_paged_decode_fn(
             cfg, block, per_seq, width, kernel=kernel, kv_quant=quant,
             mesh=mesh, probe=program == "decode_probe",
+            flat_pages=int(program[4:]) if program[:4] == "flat" else None,
         )
         args = (*state, vec_prev,
                 _abstract((len(paging.STEP_ROWS), slots)), tables)

@@ -106,6 +106,14 @@ def programs(devices, tiny_abstract):
              jax.ShapeDtypeStruct((len(paging.STEP_ROWS), slots), i32),
              tables),
         ),
+        "decode_flat": (
+            paging.make_paged_decode_fn(
+                TINY, BLOCK, PER_SEQ, WIDTH, flat_pages=slots * PER_SEQ // 2
+            ),
+            (tiny_abstract, cache, cache, vec,
+             jax.ShapeDtypeStruct((len(paging.STEP_ROWS), slots), i32),
+             tables),
+        ),
         "prefill": (
             paging.make_chunk_prefill_fn(TINY, 8, BLOCK, PER_SEQ, WIDTH),
             (tiny_abstract, cache, cache, chunk, scalar, scalar,
@@ -149,8 +157,8 @@ def program_scopes(programs):
 
 
 SERVE_PROGRAMS = (
-    "decode", "prefill", "slab_prefill", "slab_decode", "spec_draft",
-    "spec_verify",
+    "decode", "decode_flat", "prefill", "slab_prefill", "slab_decode",
+    "spec_draft", "spec_verify",
 )
 
 
@@ -525,6 +533,14 @@ OPS_BY_SCOPE = {
     "dense-decode": {
         None: 61, "embed": 8, "qkv": 98, "kv_write": 116, "kv_read": 36,
         "attention": 44, "attn_out": 6, "mlp": 32, "head": 13,
+    },
+    # A flat rung (PR 30, as it came): the list of live pages is
+    # derived under ``kv_read`` (60 operations, once a step), the owner
+    # products and the combine run under ``attention``, and nothing
+    # joins the unscoped ones (the rectangle's mask leaves them).
+    "dense-decode_flat": {
+        None: 55, "embed": 8, "qkv": 98, "kv_write": 116, "kv_read": 96,
+        "attention": 80, "attn_out": 6, "mlp": 32, "head": 13,
     },
     "dense-prefill": {
         None: 33, "embed": 5, "qkv": 114, "kv_write": 52, "kv_read": 48,

@@ -149,6 +149,54 @@ def _grouped_attention_paged(q, k_pages, v_pages, mask, cfg):
     return out.reshape(b, s_q, cfg.n_heads, cfg.head_dim)
 
 
+def _grouped_attention_flat(q, k_pages, v_pages, own, mask, cfg):
+    """:func:`_grouped_attention_paged` over a FLAT list of pages, the
+    live pages of all slots end to end: ``k_pages`` / ``v_pages`` are
+    ``[pages, kv_heads, block_size, head_dim]`` (``pool[layer, ids]``),
+    ``own [pages, slots]`` says whose each page is (one slot, or
+    nobody's) and ``mask [pages, block_size]`` which of its rows that
+    slot's query may read; ``q`` is ``[slots, 1, n_heads, head_dim]``.
+    Same products in the compute dtype, same fp32 softmax, another
+    summation order: a page is scored against its owner's query, the
+    softmax's max and sum run over a slot's pages, and the weighted V
+    pages are summed by owner. Every move between slots and pages is a
+    product with ``own`` (one term a row, so exact) or a masked
+    reduction over ``[pages, slots]``, never a scatter (a TPU scatter
+    walks its indices one by one: PERF.md, PR 26). A slot that owns no
+    page (inactive) comes out 0, not 0/0."""
+    slots = q.shape[0]
+    n_kv = cfg.kv_heads
+    groups = cfg.n_heads // n_kv
+    n_pages = k_pages.shape[0]
+    exact = jax.lax.Precision.HIGHEST
+    scale = cfg.head_dim ** -0.5
+    own_f = own.astype(jnp.float32)
+    q_of = jnp.einsum(
+        "ps,sf->pf", own.astype(q.dtype), q.reshape(slots, -1)
+    ).reshape(n_pages, n_kv, groups, cfg.head_dim)
+    scores = jnp.einsum("phgd,phkd->phgk", q_of, k_pages) * scale
+    scores = scores.astype(jnp.float32)
+    scores = jnp.where(mask[:, None, None, :], scores, -jnp.inf)
+    top = jnp.max(jnp.where(
+        own[:, :, None, None], jnp.max(scores, axis=-1)[:, None], -jnp.inf
+    ), axis=0)                                    # [slots, kv, groups]
+    top = jnp.where(jnp.isfinite(top), top, 0.0)
+    top_of = jnp.einsum("ps,shg->phg", own_f, top, precision=exact)
+    weights = jnp.exp(scores - top_of[..., None])
+    total = jnp.einsum(
+        "ps,phg->shg", own_f, jnp.sum(weights, axis=-1), precision=exact
+    )
+    out = jnp.einsum(
+        "phgk,phkd->phgd", weights.astype(cfg.dtype), v_pages,
+        preferred_element_type=jnp.float32,
+    )
+    out = jnp.einsum("ps,phgd->shgd", own_f, out, precision=exact)
+    out = out / jnp.where(total > 0, total, 1.0)[..., None]
+    return out.astype(cfg.dtype).reshape(
+        slots, 1, cfg.n_heads, cfg.head_dim
+    )
+
+
 def _logits_head(x, params, cfg):
     x = _rmsnorm(x, params["norm"]["scale"], cfg.norm_eps)
     return _dense(x, params["output"]["kernel"], cfg.dtype)

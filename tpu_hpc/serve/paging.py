@@ -84,6 +84,7 @@ from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.decoder import (
     _embed,
     _grouped_attention,
+    _grouped_attention_flat,
     _grouped_attention_paged,
     _logits_head,
     _rope_tables,
@@ -108,7 +109,24 @@ DECODE_COUNTERS: Tuple[Tuple[str, str], ...] = (
     ("serve_decode_discarded_total",
      "Slot-steps computed past an end the host saw one step late "
      "(end of sequence) and dropped"),
+    ("serve_decode_view_pages_read_total",
+     "KV pages the dispatched decode programs gathered a layer: the "
+     "flat rung's size, or slots x pages a slot on the rectangle"),
+    ("serve_decode_view_pages_total",
+     "KV pages the rectangle would have gathered a layer (slots x "
+     "pages a slot, a decode step); read over this is how much of "
+     "every slot's capacity the steps read"),
 )
+
+# The flat decode rungs, as shares of slots x pages a slot: a step
+# whose live pages fit one reads that many pages and no more; above the
+# last the rectangle reads every slot's whole capacity (at half of it
+# the two cost the same a page on the v5e). Two, because each is a
+# program to trace and an executable to load at start-up, 0.65 s on the
+# v5e's host, and a lower one (1/8 made a step at a tenth of the
+# capacity twice as fast again) waits for cheaper tracing (PERF.md,
+# PR 30).
+FLAT_RUNGS = (3 / 8, 1 / 2)
 
 # What a decode step of a sparse-expert configuration counts: (the
 # program's key for it, the registry's name, HELP), in the order the
@@ -889,7 +907,11 @@ class PagedAttention:
       under the mask, page-major for row steps
       (:func:`_grouped_attention_paged`) and token-major for a chunk;
       so a chunk attends to every previously prefilled chunk and to the
-      shared prefix pages it never computed. ``kernel="pallas"``: the
+      shared prefix pages it never computed. A dense one-row step
+      whose caller knows how many pages are live gathers only those,
+      as one flat list over all slots (:meth:`live_pages`,
+      :func:`_grouped_attention_flat`), in place of every slot's whole
+      capacity. ``kernel="pallas"``: the
       table handed to kernels/paged_attention.py, which walks it
       in-kernel under ``shard_map`` over ``mesh`` (required for this
       kernel), all of it under ``kv_read``. Both read paths dequantize,
@@ -903,6 +925,7 @@ class PagedAttention:
         self.cfg = cfg
         self.block_size, self.max_blocks = block_size, max_blocks
         self.chunk = chunk
+        self.own = None                 # set by live_pages()
         self.quant = kv_quant == "int8"
         self.sparse = sparse_moe.is_sparse_moe(cfg)
         self.kernel = None
@@ -932,6 +955,42 @@ class PagedAttention:
         chunk."""
         self.tables, self.where = tables, where
         self.view_ids = tables[..., :self.max_blocks]
+
+    def live_pages(self, tables, pos, active, n_pages):
+        """What a one-row step reads in place of :meth:`view` when its
+        caller knows that the live pages of all slots fit ``n_pages``:
+        a flat list of them, slot after slot. Slot ``s`` has ``pos[s]
+        // block_size + 1`` live pages if ``active[s]``, else none;
+        entry ``j`` is page ``j - start[s]`` of the slot ``s`` whose
+        run ``[start[s], end[s])`` of the cumulative count holds ``j``
+        (``own [n_pages, slots]``: one slot a row, none past the
+        total, whose entries name the scratch page and are read by
+        nobody). Derived once a step from what the step is handed
+        anyway, under ``kv_read``; the layers gather by ``flat_ids``
+        and attend under ``flat_mask [n_pages, block_size]`` (a page's
+        rows at positions ``<= pos`` of its owner)."""
+        bs = self.block_size
+        with jax.named_scope("kv_read"):
+            n_live = jnp.where(active > 0, pos // bs + 1, 0)
+            end = jnp.cumsum(n_live)
+            start = end - n_live
+            j = jnp.arange(n_pages)[:, None]
+            self.own = (j >= start) & (j < end)
+            owner = jnp.argmax(self.own, axis=1)
+            page = jnp.sum(jnp.where(self.own, j - start, 0), axis=1)
+            owned = jnp.any(self.own, axis=1)
+            # The owner's position; -1 where there is none, so that no
+            # row of the entry is read.
+            last = jnp.where(
+                owned, jnp.sum(jnp.where(self.own, pos, 0), axis=1), -1
+            )
+            self.flat_ids = jnp.where(
+                owned, tables[owner, page], SCRATCH_BLOCK
+            )
+            self.flat_mask = (
+                page[:, None] * bs + jnp.arange(bs)[None, :]
+                <= last[:, None]
+            )
 
     def pages(self, blk_ids, qpos, mask):
         """What a chunk writes: its tokens at positions ``qpos`` fill
@@ -1100,15 +1159,16 @@ class PagedAttention:
                 if self.chunk:
                     ctx = ctx.transpose(1, 0, 2, 3)
                 return ctx.reshape(b, s, cfg.n_heads, cfg.head_dim)
+        ids = self.view_ids if self.own is None else self.flat_ids
         with jax.named_scope("kv_read"):
-            k_view = self.ks[layer, self.view_ids]
-            v_view = self.vs[layer, self.view_ids]
+            k_view = self.ks[layer, ids]
+            v_view = self.vs[layer, ids]
             if quant:
                 k_view = dequantize_pages_int8(
-                    k_view, self.ksc[layer, self.view_ids]
+                    k_view, self.ksc[layer, ids]
                 )
                 v_view = dequantize_pages_int8(
-                    v_view, self.vsc[layer, self.view_ids]
+                    v_view, self.vsc[layer, ids]
                 )
             if self.chunk:
                 # Token-major for the chunk: its view is ONE slot's
@@ -1118,6 +1178,11 @@ class PagedAttention:
                 k_view = pages_to_tokens(k_view)[None]
                 v_view = pages_to_tokens(v_view)[None]
         with jax.named_scope("attention"):
+            if self.own is not None:
+                return _grouped_attention_flat(
+                    q, k_view.astype(cfg.dtype), v_view.astype(cfg.dtype),
+                    self.own, self.flat_mask, cfg,
+                )
             attend = _grouped_attention if self.chunk \
                 else _grouped_attention_paged
             return attend(
@@ -1234,6 +1299,7 @@ def make_paged_decode_fn(
     kv_quant: str = "none",
     mesh: Optional[Mesh] = None,
     probe: bool = False,
+    flat_pages: Optional[int] = None,
 ):
     """The single-token decode program over every slot, block-table
     edition.
@@ -1279,11 +1345,28 @@ def make_paged_decode_fn(
     ``probe=True`` (such configurations only) also returns each layer's
     selection ``[layers, slots, columns]``: the benchmark's check
     reads it, no serving path does.
+
+    ``flat_pages`` (a dense configuration through ``gather`` only):
+    the same program reading ``flat_pages`` pages in all, the live
+    pages of every slot end to end (:meth:`PagedAttention.live_pages`),
+    where the plain one reads ``max_blocks`` pages of every slot
+    whatever is live. Same arguments, same results; the caller
+    promises that the step's live pages fit (``PagedEngine.decode``
+    counts them from the positions it hands over and picks the
+    program by them).
     """
     cache_cap = max_blocks * block_size
     attention = PagedAttention(
         cfg, block_size, max_blocks, kernel, kv_quant, mesh
     )
+    if flat_pages is not None and (
+        attention.sparse or attention.kernel is not None
+    ):
+        raise ValueError(
+            "flat_pages is the gather read of a dense configuration: an "
+            "indexer ranks the columns of each slot's own view and a "
+            "table-walking kernel reads no view at all"
+        )
 
     def body(params, ks, vs, ksc, vsc, xs, prev, step, tables):
         scope = jax.named_scope
@@ -1295,15 +1378,20 @@ def make_paged_decode_fn(
             x = _embed(params, tokens[:, None], cfg)
         cos, sin = _rope_tables(cfg, 1, pos)
         cos, sin = cos[:, None, :], sin[:, None, :]
-        col = jnp.arange(cache_cap)
-        mask = (col[None, :] <= pos[:, None])[:, None, None, None, :]
+        mask = None
+        if flat_pages is None:
+            col = jnp.arange(cache_cap)
+            mask = (col[None, :] <= pos[:, None])[:, None, None, None, :]
         rows = jnp.arange(slots)
         blk = pos // block_size
         off = pos % block_size
         pb = jnp.where(
             active > 0, tables[rows, blk], SCRATCH_BLOCK
         )
-        pool.view(tables, pos, active)
+        if flat_pages is None:
+            pool.view(tables, pos, active)
+        else:
+            pool.live_pages(tables, pos, active, flat_pages)
         pool.rows(pb, off, mask, slot=rows)
         x, counts = decoder_layers(
             params, cfg, x, cos, sin, pool, weight=active
@@ -1451,6 +1539,14 @@ class PagedEngine(Engine):
         # bucket-padded chunk write at the capacity edge stays
         # in-range (see make_chunk_prefill_fn).
         self.table_width = per_seq + max(serve_cfg.prefill_buckets) // bs
+        # What the rectangle gathers a layer, and the flat rungs below
+        # it (``decode_rungs``).
+        self.view_pages = serve_cfg.slots * per_seq
+        self._flat_rungs: Tuple[int, ...] = ()
+        if paged.kernel == "gather" and not sparse_moe.is_sparse_moe(cfg):
+            self._flat_rungs = tuple(sorted(
+                {int(self.view_pages * r) for r in FLAT_RUNGS} - {0}
+            ))
         super().__init__(params, cfg, serve_cfg, mesh, param_pspecs)
 
         # Speculative decoding (serve/spec.py): attach_spec sets the
@@ -1659,11 +1755,15 @@ class PagedEngine(Engine):
             args = (params_abs,) + state + (tokens, scalar, scalar,
                                             table)
         elif key[0] in ("decode", "decode_probe"):
+            # ("decode",) is the rectangle, ("decode", pages) a flat
+            # rung: one name in the trace, so the per-scope readers
+            # average over whichever ran.
             fn = make_paged_decode_fn(
                 self.cfg, self.paged.block_size,
                 self.max_blocks_per_seq, self.table_width,
                 kernel=self.paged.kernel, kv_quant=self.paged.kv_quant,
                 mesh=self.mesh, probe=key[0] == "decode_probe",
+                flat_pages=key[1] if len(key) > 1 else None,
             )
             prev = jax.ShapeDtypeStruct(
                 self._toks.shape, jnp.int32, sharding=self._rep
@@ -1710,11 +1810,23 @@ class PagedEngine(Engine):
             return self.compile_count_total
         for b in self.serve_cfg.prefill_buckets:
             self._get_exec(("prefill", b))
+        for pages in self.decode_rungs:
+            self._get_exec(("decode", pages))
         self._get_exec(("decode",))
         self._get_exec(("copy_block",))
         if self.host_tier is not None:
             self.host_tier.warmup()
         return self.compile_count
+
+    @property
+    def decode_rungs(self) -> Tuple[int, ...]:
+        """The flat decode programs this engine holds below the
+        rectangle, by the pages each reads (``FLAT_RUNGS`` of ``slots x
+        pages a slot``), smallest first. None where the read is not the
+        dense gather's: an indexer ranks each slot's own view, a
+        table-walking kernel reads no view, and a speculative engine's
+        step is the verify program."""
+        return () if self.spec is not None else self._flat_rungs
 
     @property
     def compile_count_total(self) -> int:
@@ -2081,6 +2193,13 @@ class PagedEngine(Engine):
         the host's ``tokens``); :meth:`flush` takes them. With
         ``decode_lag`` 0 the step's own tokens come back at once.
 
+        The step runs the smallest program of the ladder that holds
+        its live pages (:attr:`decode_rungs`; the rectangle above the
+        last), counted here from ``positions`` and ``active`` as the
+        program counts them: what it reads follows the occupancy, and
+        ``serve_decode_view_pages_read_total`` over ``_total`` says how
+        far.
+
         ``active[s]`` False redirects slot ``s``'s write to the scratch
         page (free slots, and slots still mid-chunked-prefill, must not
         dirty live pages). An end the host can only see in a token (end
@@ -2095,7 +2214,19 @@ class PagedEngine(Engine):
                 for s, (is_on, pos) in enumerate(zip(active, positions)):
                     if is_on and s in self._slot_state:
                         self._cow_write_target(s, int(pos))
-                exec_ = self._get_exec(("decode",))
+                # The smallest rung that holds the step's live pages
+                # (exactly what the program will count from the same
+                # positions), else the rectangle.
+                live = int(np.sum(np.where(
+                    np.asarray(active, bool),
+                    np.asarray(positions) // self.paged.block_size + 1, 0,
+                )))
+                pages = next(
+                    (p for p in self.decode_rungs if p >= live), None
+                )
+                exec_ = self._get_exec(
+                    ("decode",) if pages is None else ("decode", pages)
+                )
                 args = (
                     *self._step_inputs(tokens, positions, active),
                     self._tables_device(),
@@ -2109,6 +2240,13 @@ class PagedEngine(Engine):
                 self._on_device = np.array(active, bool)
                 if before is not None:
                     self._count("serve_decode_overlapped_total")
+                self._count(
+                    "serve_decode_view_pages_read_total",
+                    pages or self.view_pages,
+                )
+                self._count(
+                    "serve_decode_view_pages_total", self.view_pages
+                )
             if not self.decode_lag:
                 return self.flush()
             return None if before is None else self._take(before)
