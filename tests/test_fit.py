@@ -5,6 +5,7 @@ import fractions
 import re
 
 import jax
+import numpy as np
 import pytest
 
 from tpu_hpc.checks import fit
@@ -458,6 +459,110 @@ def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
     assert temp < 2 * view_pages * page_bytes + pool_bytes // 2, temp
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_latent_programs_address_the_pool_in_place(v5e_2x2, program):
+    """``serve-docqa-joyai-flash``'s decode and chunk programs,
+    compiled for the chip at the cell's shape (2 of its 7 layers):
+    every pool goes in and out in its own layout with no copy of it,
+    no per-layer slice is materialised, and the decode program makes
+    no copy of a gathered view (with no head axis inside a page the
+    view's rows ARE its tokens: a page-major contraction made the
+    compiler transpose all 566 MB of it a layer) and builds no per-head
+    key or value of the cached tokens."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_hpc.models import latent_moe
+    from tpu_hpc.serve import paging
+
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    slots, capacity, bs, bucket = 16, 30720, 16, 512
+    cfg = dataclasses.replace(
+        latent_moe.JOYAI_LLM_FLASH, n_layers=2, max_seq_len=capacity,
+        held_experts=tuple(range(64)), dtype=jnp.bfloat16,
+        param_dtype=jnp.bfloat16,
+    )
+    mb = capacity // bs
+    width = mb + bucket // bs
+    num_blocks = slots * mb + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: latent_moe.init_latent_moe(jax.random.key(0), cfg)
+        ),
+    )
+    # The latents a token a row; the rotary keys two tokens a row of
+    # 128 lanes (a 64-wide row is laid out pages-minor by the runtime,
+    # and the program then copies the whole pool in and out: this test
+    # failed on exactly that).
+    pack = paging.rope_pack(cfg, bs)
+    assert pack * cfg.rope_dim == 128
+    pool_shapes = [
+        (cfg.n_layers, num_blocks, bs, cfg.kv_lora_rank),
+        (cfg.n_layers, num_blocks, bs // pack, pack * cfg.rope_dim),
+    ]
+    pools = [sds(shape, jnp.bfloat16) for shape in pool_shapes]
+    i32 = jnp.int32
+    if program == "decode":
+        fn = paging.make_paged_decode_fn(cfg, bs, mb, width)
+        args = (sds((slots + len(paging.LATENT_COUNTERS),), i32),
+                sds((len(paging.STEP_ROWS), slots), i32),
+                sds((slots, width), i32))
+        view_tokens = slots * capacity
+    else:
+        fn = paging.make_chunk_prefill_fn(cfg, bucket, bs, mb, width)
+        args = (sds((1, bucket), i32), sds((), i32), sds((), i32),
+                sds((width,), i32))
+        view_tokens = capacity
+    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
+        params, *pools, *args
+    ).compile()
+
+    def spelled(shape):
+        return "bf16[" + ",".join(map(str, shape)) + "]"
+
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    pool_results = 0
+    for result, opcode in _HLO_INSTRUCTION.findall(entry):
+        for shape in pool_shapes:
+            if spelled(shape[1:]) in result:
+                assert opcode == "parameter", (
+                    f"{opcode} materialises a per-layer slice: {result}"
+                )
+            if spelled(shape) in result:
+                pool_results += 1
+                assert opcode != "copy", f"whole-pool copy: {result}"
+        if program == "decode" and opcode == "copy":
+            # (the rotary keys' view IS unpacked, 64 numbers a token:
+            # cheaper on the chip than scoring the packs as they lie)
+            w = cfg.kv_lora_rank
+            for view in ((slots, mb, bs, w), (slots, capacity, w),
+                         (slots * mb, bs, w)):
+                assert spelled(view) not in result, (
+                    f"copy of a gathered latent view: {result}"
+                )
+        if program == "decode":
+            # no key or value a head of the cached tokens
+            for w in (cfg.qk_head_dim, cfg.qk_nope_head_dim):
+                assert f"{capacity},{cfg.n_heads},{w}]" not in result
+    assert pool_results >= 4
+    # The gathered views (and a chunk's expanded keys and values) are
+    # the only large temporaries: no pool-sized copy besides.
+    row = 2 * cfg.latent_dim
+    pool_bytes = cfg.n_layers * num_blocks * bs * row
+    expanded = 0 if program == "decode" else 2 * capacity * cfg.n_heads * (
+        cfg.qk_head_dim + cfg.v_head_dim
+    )
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2 * view_tokens * row + 2 * expanded + pool_bytes // 2, temp
+
+
 class TestCPLayout:
     """--layout cp / --cp: the long-context fit model (FSDP over data
     x ring attention over context)."""
@@ -734,6 +839,74 @@ class TestPagedKVTerm:
             both = fit.param_counts(dense)
             assert both["total"] == both["active"] \
                 == llama2.count_params(dense)
+
+    @pytest.mark.parametrize("what", [
+        "weights", "by_kind", "active", "pool", "token", "fits", "all_256",
+        "int8", "slab",
+    ])
+    def test_latent_cell_bytes(self, what):
+        """``serve-docqa-joyai-flash``'s widths, slots and capacity
+        (benchmark/workloads/): 1 dense + 6 expert layers with 64 of
+        each layer's 256 routed experts hold 2.601B parameters = 4.85
+        GiB in bf16; a cached token is ONE row of 512 + 64 numbers a
+        layer, 1152 B, nothing per head; 16 x 30720 / 16 + 1 pages are
+        3.69 GiB; weights twice (the engine's construction) and the
+        pool fit a 15.75 GiB chip, which every expert at 1 + 4 layers
+        would not. What the check cannot size it refuses by name."""
+        import dataclasses
+
+        from tpu_hpc.models import latent_moe
+
+        cfg = dataclasses.replace(
+            latent_moe.JOYAI_LLM_FLASH, n_layers=7, max_seq_len=30720,
+            held_experts=tuple(range(64)),
+        )
+        counts = fit.param_counts(cfg)
+        shapes = jax.tree.leaves(
+            latent_moe.param_shapes(cfg),
+            is_leaf=lambda s: isinstance(s, tuple),
+        )
+        pages = 16 * 30720 // 16 + 1
+        pool = fit.kv_paged_bytes(cfg, pages, 16)
+        one = 3 * 2048 * 768
+        if what == "weights":
+            assert counts["total"] == sum(
+                int(np.prod(s)) for s in shapes
+            ) == 2_601_432_576
+            assert round(2 * counts["total"] / 2**30, 2) == 4.85
+        elif what == "by_kind":
+            kinds = latent_moe.count_params(cfg)
+            assert kinds["dense_layer"] == 70_391_808       # 70.4M
+            assert kinds["expert_layer"] == 333_584_640     # 333.6M
+            assert counts["total"] == kinds["dense_layer"] \
+                + 6 * kinds["expert_layer"] + 2 * 129280 * 2048 + 2048
+        elif what == "active":
+            outside = 333_584_640 - 64 * one
+            assert counts["active"] == 70_391_808 \
+                + 6 * (outside + 8 * one) + 129280 * 2048 + 2048
+            assert counts["total"] / counts["active"] > 3
+        elif what == "pool":
+            assert pages == 30721 and round(pool / 2**30, 2) == 3.69
+        elif what == "token":
+            assert pool == pages * 16 * 8064
+            assert 8064 == 7 * (512 + 64) * 2
+            assert fit.kv_paged_bytes(
+                cfg, pages, 16, cache_dtype="float32"
+            ) == 2 * pool
+        elif what == "fits":
+            assert (2 * 2 * counts["total"] + pool) / 2**30 < 15.75
+        elif what == "all_256":
+            whole = fit.param_counts(dataclasses.replace(
+                cfg, n_layers=5, held_experts=None
+            ))
+            assert round(whole["total"] / 1e9, 2) == 5.56
+            assert 2 * 2 * whole["total"] / 2**30 > 15.75
+        elif what == "int8":
+            with pytest.raises(NotImplementedError, match="joyai-llm-flash"):
+                fit.kv_paged_bytes(cfg, pages, 16, kv_quant="int8")
+        else:
+            with pytest.raises(NotImplementedError, match="joyai-llm-flash"):
+                fit.kv_cache_bytes(cfg, 16)
 
     @pytest.fixture(scope="class")
     def with_paged(self, full_7b):

@@ -245,7 +245,7 @@ def test_no_assignment_is_dropped_when_every_token_picks_the_same_experts():
     )
     assert {k: int(v) for k, v in counts.items()} == {
         "assignments": 18, "experts_touched": 2,
-        "max_tokens_per_expert": 9, "dropped": 0,
+        "max_tokens_per_expert": 9, "dropped": 0, "assignments_held": 18,
     }
 
 

@@ -40,7 +40,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import llama2, sparse_moe
+from tpu_hpc.models import latent_moe, llama2, sparse_moe
 from tpu_hpc.parallel import hybrid, tp
 from tpu_hpc.parallel.plans import derived_pspecs, shardings_for
 
@@ -90,7 +90,13 @@ def kv_cache_bytes(
     analysis previously ignored -- at 70B GQA with 4k context and 64
     slots this is ~80 GiB, not a rounding error. Divide by the mesh
     extents sharding the cache (slots over data, kv_heads over model)
-    for the per-chip share; analyze() does that with its own mesh."""
+    for the per-chip share; analyze() does that with its own mesh.
+    A latent configuration (``models/latent_moe.py``) has no such
+    cache: only the paged pool keeps its rows (:func:`kv_paged_bytes`)."""
+    latent_moe.refuse(
+        cfg, "the slab KV cache's size (checks/fit.py)",
+        "the slab engine keeps per-head keys and values",
+    )
     s = max_seq_len if max_seq_len is not None else cfg.max_seq_len
     itemsize = jnp.dtype(cache_dtype).itemsize
     return (
@@ -105,9 +111,15 @@ def param_counts(cfg: llama2.LlamaConfig) -> Dict[str, int]:
     sparse-expert one (``models/sparse_moe.py``) memory is sized by the
     total (every held expert) and a decode step's products by the
     active (``experts_per_token`` experts a layer), sixteen times
-    apart at Keye-VL-2.0-30B-A3B's 8 of 128."""
+    apart at Keye-VL-2.0-30B-A3B's 8 of 128. A latent configuration
+    (``models/latent_moe.py``) counts by kind of layer: its leading
+    dense layers, then expert layers with the experts HELD here."""
+    counts = None
     if sparse_moe.is_sparse_moe(cfg):
         counts = sparse_moe.count_params(cfg)
+    elif latent_moe.is_latent_moe(cfg):
+        counts = latent_moe.count_params(cfg)
+    if counts is not None:
         return {"total": counts["total"], "active": counts["active"]}
     n = llama2.count_params(cfg)
     return {"total": n, "active": n}
@@ -139,7 +151,18 @@ def kv_paged_bytes(
     A sparse-expert configuration (``models/sparse_moe.py``) keeps a
     third array under the same pages: the indexer's one key a token a
     layer, ``indexer_head_dim`` numbers (128 B in bf16 at 64), which
-    the engine's ``cache_bytes`` counts too."""
+    the engine's ``cache_bytes`` counts too. A latent configuration
+    (``models/latent_moe.py``) keeps ONE row a token a layer, the
+    latent and the rotary key (``latent_dim`` numbers: 1152 B in bf16
+    at 512 + 64), nothing per head, and has no int8 page."""
+    if latent_moe.is_latent_moe(cfg):
+        if kv_quant != "none":
+            latent_moe.refuse(
+                cfg, "an int8 page pool",
+                "the int8 page write quantises per-head K and V pages",
+            )
+        return num_blocks * block_size * cfg.n_layers * cfg.latent_dim \
+            * jnp.dtype(cache_dtype).itemsize
     if sparse_moe.is_sparse_moe(cfg):
         if kv_quant != "none":
             sparse_moe.refuse(

@@ -44,12 +44,13 @@ EDGE_KEYS = ("tok_embeddings", "norm", "output")
 
 
 def layers_per_stage(cfg: LlamaConfig, n_stages: int) -> int:
-    from tpu_hpc.models import sparse_moe
+    from tpu_hpc.models import latent_moe, sparse_moe
 
-    sparse_moe.refuse(
-        cfg, "the pipeline split (models/llama_pp.py)",
-        "its stages are llama2's dense blocks",
-    )
+    for model in (sparse_moe, latent_moe):
+        model.refuse(
+            cfg, "the pipeline split (models/llama_pp.py)",
+            "its stages are llama2's dense blocks",
+        )
     if n_stages < 1 or cfg.n_layers % n_stages:
         raise ValueError(
             f"pipeline needs n_layers {cfg.n_layers} divisible by "

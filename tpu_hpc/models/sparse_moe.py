@@ -283,9 +283,11 @@ def expert_ffn(h, gates, experts, lp, cfg: SparseMoEConfig, weight=None):
 
     ``weight [tokens]`` (0/1) marks the tokens that count (a decode
     step's inactive slots compute garbage nobody reads). Counts, all
-    int32: assignments made, distinct experts chosen, the most tokens
-    one expert got, assignments to held experts that the product did
-    not cover (0 by construction; the counter is the contract)."""
+    int32: assignments made, those of them that landed on held
+    experts (the rest are other chips'), distinct HELD experts chosen,
+    the most tokens one expert got, assignments to held experts that
+    the product did not cover (0 by construction; the counter is the
+    contract)."""
     n_tok = h.shape[0]
     slots = _held_slots(cfg)[experts]                      # [t, k]
     spread = jax.nn.one_hot(slots, cfg.n_held, dtype=jnp.float32)
@@ -303,11 +305,17 @@ def expert_ffn(h, gates, experts, lp, cfg: SparseMoEConfig, weight=None):
     per_expert = jnp.einsum("t,tke->e", w, chosen)
     to_held = jnp.sum(w[:, None] * (slots < cfg.n_held))
     covered = jnp.sum(w[:, None] * (held_gates > 0))
+    def of_held(chosen):
+        if cfg.held_experts is None:
+            return chosen
+        return chosen & (_held_slots(cfg) < cfg.n_held)
+
     counts = {
         "assignments": jnp.sum(per_expert),
-        "experts_touched": jnp.sum(per_expert > 0),
+        "experts_touched": jnp.sum(of_held(per_expert > 0)),
         "max_tokens_per_expert": jnp.max(per_expert),
         "dropped": to_held - covered,
+        "assignments_held": to_held,
     }
     return out, counts
 

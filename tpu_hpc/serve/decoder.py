@@ -36,7 +36,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from tpu_hpc.models import llama2, sparse_moe
+from tpu_hpc.models import latent_moe, llama2, sparse_moe
 
 
 def _dense(x: jax.Array, kernel: jax.Array, dtype) -> jax.Array:
@@ -61,24 +61,26 @@ def _embed(params: Dict, tokens: jax.Array, cfg: llama2.LlamaConfig):
     """Token embedding lookup in the compute dtype. Identical values to
     both training paths (iota_embed's forward IS a plain gather)."""
     table = params["tok_embeddings"]["embedding"].astype(cfg.dtype)
-    return jnp.take(table, tokens, axis=0)
+    x = jnp.take(table, tokens, axis=0)
+    # The residual stream starts here: in the compute dtype, unless the
+    # configuration keeps what it adds up wider (``residual_dtype``).
+    stream = getattr(cfg, "residual_dtype", None)
+    return x if stream is None else x.astype(stream)
 
 
 def _attn_out_proj(h, lp, cfg):
+    """``h [b, s, heads, value dim]`` through ``wo``."""
     b, s = h.shape[0], h.shape[1]
     return _dense(
-        h.reshape(b, s, cfg.n_heads * cfg.head_dim),
-        lp["attention"]["wo"]["kernel"], cfg.dtype,
+        h.reshape(b, s, -1), lp["attention"]["wo"]["kernel"], cfg.dtype,
     )
 
 
-def _mlp(x, lp, cfg):
-    gate = _dense(x, lp["feed_forward"]["w1"]["kernel"], cfg.dtype)
-    up = _dense(x, lp["feed_forward"]["w3"]["kernel"], cfg.dtype)
-    return _dense(
-        jax.nn.silu(gate) * up, lp["feed_forward"]["w2"]["kernel"],
-        cfg.dtype,
-    )
+def _mlp(x, ff, cfg):
+    """The SwiGLU of one ``{w1, w3, w2}`` group of weights."""
+    gate = _dense(x, ff["w1"]["kernel"], cfg.dtype)
+    up = _dense(x, ff["w3"]["kernel"], cfg.dtype)
+    return _dense(jax.nn.silu(gate) * up, ff["w2"]["kernel"], cfg.dtype)
 
 
 def _qkv(x, lp, cfg):
@@ -98,29 +100,55 @@ def _qkv(x, lp, cfg):
 
 def _rope_tables(cfg, n, positions=None):
     """``llama2.rope_cos_sin`` at the configuration's rotary base
-    (10000 unless it names one): ``[n, head_dim / 2]`` for positions
-    ``0..n-1``, or a row for each of ``positions``."""
+    (10000 unless it names one) over the numbers of a head it rotates
+    (``rope_dim``; the whole head unless it names fewer): ``[n,
+    rope_dim / 2]`` for positions ``0..n-1``, or a row for each of
+    ``positions``."""
     return llama2.rope_cos_sin(
-        n, cfg.head_dim, getattr(cfg, "rope_theta", 10000.0),
-        positions=positions,
+        n, getattr(cfg, "rope_dim", cfg.head_dim),
+        getattr(cfg, "rope_theta", 10000.0), positions=positions,
     )
 
 
-def _grouped_attention(q, k, v, mask, cfg):
+def _grouped_attention(q, k, v, mask, cfg, scale=None):
     """The model's einsum attention with an explicit mask: scores in
     the compute dtype, fp32 softmax, GQA via the grouped query view
-    (llama2.Attention's no-repeat-KV contraction)."""
+    (llama2.Attention's no-repeat-KV contraction). ``scale`` is
+    ``head_dim ** -0.5`` unless the caller names another (a latent
+    configuration's expanded read, whose keys are wider than its
+    values: the result has the values' width)."""
     b, s_q = q.shape[0], q.shape[1]
     n_kv = cfg.kv_heads
     groups = cfg.n_heads // n_kv
-    qg = q.reshape(b, s_q, n_kv, groups, cfg.head_dim)
-    scale = cfg.head_dim ** -0.5
+    qg = q.reshape(b, s_q, n_kv, groups, q.shape[-1])
+    if scale is None:
+        scale = cfg.head_dim ** -0.5
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
     scores = scores.astype(jnp.float32)
     scores = jnp.where(mask, scores, -jnp.inf)
     probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
     out = jnp.einsum("bhgqk,bkhd->bqhgd", probs, v)
-    return out.reshape(b, s_q, cfg.n_heads, cfg.head_dim)
+    return out.reshape(b, s_q, cfg.n_heads, v.shape[-1])
+
+
+def _latent_attention(q, q_rope, latents, k_rope, mask, cfg, scale):
+    """A latent configuration's ABSORBED read
+    (``models/latent_moe.py``): queries already carried into the
+    latent space ``q [b, s, heads, rank]`` with their rotary part
+    ``q_rope [b, s, heads, rope]``, against the cached rows themselves,
+    ``latents [b, n, rank]`` and their rotary keys ``k_rope [b, n,
+    rope]``, one row a token under every head: ``score = (q . c +
+    q_rope . kR) * scale``, fp32 softmax under ``mask [b, 1, 1, s,
+    n]``, and the attended latent ``sum_n p c`` a head, ``[b, s,
+    heads, rank]``. The rows are key AND value; no per-head key or
+    value is built."""
+    scores = (
+        jnp.einsum("bqhr,bkr->bhqk", q, latents)
+        + jnp.einsum("bqhd,bkd->bhqk", q_rope, k_rope)
+    ) * scale
+    scores = jnp.where(mask[:, 0], scores.astype(jnp.float32), -jnp.inf)
+    probs = jax.nn.softmax(scores, axis=-1).astype(cfg.dtype)
+    return jnp.einsum("bhqk,bkr->bqhr", probs, latents)
 
 
 def _grouped_attention_paged(q, k_pages, v_pages, mask, cfg):
@@ -198,44 +226,86 @@ def _grouped_attention_flat(q, k_pages, v_pages, own, mask, cfg):
 
 
 def _logits_head(x, params, cfg):
+    """Final norm and vocabulary product. The logits come out in the
+    compute dtype unless the configuration names a ``residual_dtype``
+    (``models/latent_moe.py``: float32 out of the same product of
+    compute-dtype operands, so that the arg-max over 129280 logits is
+    not decided by their own rounding)."""
     x = _rmsnorm(x, params["norm"]["scale"], cfg.norm_eps)
-    return _dense(x, params["output"]["kernel"], cfg.dtype)
+    kernel = params["output"]["kernel"]
+    if getattr(cfg, "residual_dtype", None) is None:
+        return _dense(x, kernel, cfg.dtype)
+    return jax.lax.dot_general(
+        x.astype(cfg.dtype), kernel.astype(cfg.dtype),
+        (((x.ndim - 1,), (0,)), ((), ())),
+        preferred_element_type=cfg.residual_dtype,
+    )
 
 
 def _ffn_stage(x, lp, cfg, weight=None):
-    """The configuration's feed-forward, residual included ->
-    ``(x, counts)``: the dense SwiGLU under ``mlp`` (``counts`` None),
-    or the router and the held experts under ``router`` / ``experts``
+    """The layer's feed-forward, residual included -> ``(x, counts)``,
+    by the weights the layer holds: the dense SwiGLU under ``mlp``
+    (``counts`` None), or the configuration's router and its held
+    experts under ``router`` / ``experts``
     (``sparse_moe.expert_ffn``'s counts; ``weight`` marks the tokens
-    that count)."""
+    that count) and, where the layer has one, the shared expert every
+    token passes, under ``mlp``."""
     scope = jax.named_scope
-    if not sparse_moe.is_sparse_moe(cfg):
+    if "moe" not in lp:
         with scope("mlp"):
             h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-            x = x + _mlp(h, lp, cfg)
+            x = x + _mlp(h, lp["feed_forward"], cfg)
         return x, None
+    route = latent_moe.route if latent_moe.is_latent_moe(cfg) \
+        else sparse_moe.route
     b, s, d = x.shape
     with scope("router"):
         h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
         h = h.reshape(b * s, d)
-        gates, experts = sparse_moe.route(h, lp, cfg)
+        gates, experts = route(h, lp, cfg)
+    shared = lp["moe"].get("shared")
     with scope("experts"):
         y, counts = sparse_moe.expert_ffn(
             h, gates, experts, lp, cfg, weight=weight
         )
-        x = x + y.reshape(b, s, d).astype(x.dtype)
+        if shared is None:
+            x = x + y.reshape(b, s, d).astype(x.dtype)
+    if shared is not None:
+        with scope("mlp"):
+            # Routed and shared parts meet in float32 and reach the
+            # residual stream in ONE rounding of it.
+            y = y.astype(jnp.float32) + _mlp(h, shared, cfg)
+            x = x + y.reshape(b, s, d).astype(x.dtype)
     return x, counts
+
+
+def _project(h, lp, cfg, cos, sin):
+    """The layer's projections of the normed input, rotated by the
+    program's ``cos`` / ``sin`` tables: what :func:`decoder_layers`
+    hands the attention state as ``q, k, v``. Per-head queries, keys
+    and values (:func:`_qkv`), or a latent configuration's queries,
+    latent row and rotary key (``latent_moe.project``)."""
+    if latent_moe.is_latent_moe(cfg):
+        return latent_moe.project(h, lp, cfg, cos, sin)
+    q, k, v = _qkv(h, lp, cfg)
+    # [s, D/2] tables rotate every row alike, [b, s, D/2] each to its
+    # own position (apply_rope broadcasts either shape).
+    q = llama2.apply_rope(q, cos, sin)
+    k = llama2.apply_rope(k, cos, sin)
+    return q, k, v
 
 
 def decoder_layers(params, cfg, x, cos, sin, attend, weight=None):
     """Every layer of the decoder over ``x [b, s, dim]`` -> ``(x,
-    counts)``. A layer is: ``qkv`` (the norm, :func:`_qkv`, the rotation
-    by the program's ``cos`` / ``sin`` tables); ``attend(layer, h, lp,
-    q, k, v)``, the program's attention state, which writes this
-    step's K/V where the program keeps them and returns the attended
-    rows ``[b, s, n_heads, head_dim]`` (``h`` is the normed input, for
-    a stage of its own such as an indexer's projections); ``attn_out``
-    (the output projection and its residual); :func:`_ffn_stage`.
+    counts)``. A layer is: ``qkv`` (the norm and :func:`_project`: the
+    configuration's projections, rotated by the program's ``cos`` /
+    ``sin`` tables); ``attend(layer, h, lp, q, k, v)``, the program's
+    attention state, which writes this step's K/V (a latent
+    configuration's row) where the program keeps them and returns the
+    attended rows ``[b, s, n_heads, value dim]`` (``h`` is the normed
+    input, for a stage of its own such as an indexer's projections);
+    ``attn_out`` (the output projection and its residual);
+    :func:`_ffn_stage`, by the kind of layer.
     ``counts`` are the expert layers' of a sparse-expert configuration
     over the tokens ``weight`` marks, summed over layers (``max*``: the
     largest), and empty for a dense one."""
@@ -245,11 +315,7 @@ def decoder_layers(params, cfg, x, cos, sin, attend, weight=None):
         lp = params[f"layers_{i}"]
         with scope("qkv"):
             h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
-            q, k, v = _qkv(h, lp, cfg)
-            # [s, D/2] tables rotate every row alike, [b, s, D/2] each
-            # to its own position (apply_rope broadcasts either shape).
-            q = llama2.apply_rope(q, cos, sin)
-            k = llama2.apply_rope(k, cos, sin)
+            q, k, v = _project(h, lp, cfg, cos, sin)
         attn = attend(i, h, lp, q, k, v)
         with scope("attn_out"):
             x = x + _attn_out_proj(attn, lp, cfg)

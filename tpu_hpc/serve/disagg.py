@@ -48,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import llama2, sparse_moe
+from tpu_hpc.models import latent_moe, llama2, sparse_moe
 from tpu_hpc.obs import get_registry, span
 from tpu_hpc.serve.engine import Engine, ServeConfig
 
@@ -135,6 +135,11 @@ class DisaggEngine:
         sparse_moe.refuse(
             cfg, "disaggregated serving (serve/disagg.py)",
             "the cross-tier hop ships keys and values only",
+        )
+        latent_moe.refuse(
+            cfg, "disaggregated serving (serve/disagg.py)",
+            "the cross-tier hop ships per-head keys and values, not "
+            "latent rows",
         )
         self.cfg = cfg
         self.serve_cfg = serve_cfg

@@ -69,7 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import llama2, sparse_moe
+from tpu_hpc.models import latent_moe, llama2, sparse_moe
 from tpu_hpc.kernels.paged_attention import (
     INT8_SCALE_FLOOR,
     dequantize_pages_int8,
@@ -86,6 +86,7 @@ from tpu_hpc.serve.decoder import (
     _grouped_attention,
     _grouped_attention_flat,
     _grouped_attention_paged,
+    _latent_attention,
     _logits_head,
     _rope_tables,
     decoder_layers,
@@ -152,6 +153,29 @@ SPARSE_COUNTERS: Tuple[Tuple[str, str, str], ...] = (
      "Cached tokens the indexer scored (active slots x layers, the "
      "whole context each)"),
 )
+
+# The same for a latent-attention configuration
+# (``models/latent_moe.py``): its expert layers' counts, no indexer's.
+LATENT_COUNTERS: Tuple[Tuple[str, str, str], ...] = SPARSE_COUNTERS[:4] + (
+    ("assignments_held", "serve_moe_assignments_held_total",
+     "Assignments that landed on experts held here (the rest are "
+     "other chips' of the expert-parallel deployment, and no drop)"),
+)
+
+# Kept on the host where pages are taken and released, added up once a
+# decode step (``_LivePages``).
+LATENT_PAGES_LIVE = (
+    "serve_latent_pages_live_total",
+    "Distinct latent pages that at least one active slot read, summed "
+    "over decode steps (a page several slots share counts once)",
+)
+
+
+def step_counters(cfg) -> Tuple[Tuple[str, str, str], ...]:
+    """What the configuration's decode step packs behind its tokens."""
+    if sparse_moe.is_sparse_moe(cfg):
+        return SPARSE_COUNTERS
+    return LATENT_COUNTERS if latent_moe.is_latent_moe(cfg) else ()
 
 
 class BlockBudgetError(RuntimeError):
@@ -315,6 +339,17 @@ def derive_paged_config(
         kv_quant=kv_quant or "none",
     )
     return cfg, max_seq
+
+
+def rope_pack(cfg, block_size: int) -> int:
+    """Tokens whose rotary keys share one row of a latent
+    configuration's second pool array (``models/latent_moe.py``): as
+    many as fill the chip's 128 lanes and divide a page (2 of 64
+    numbers at a page of 16: pages of ``[8, 128]``). A row narrower
+    than the lanes makes the TPU runtime lay the pool out pages-minor,
+    and every program then copies the whole pool in and out to reach a
+    page (PERF.md, PR 31)."""
+    return math.gcd(block_size, max(1, 128 // cfg.rope_dim))
 
 
 def paged_kv_cache_pspec(mesh: Mesh, kv_heads: int) -> P:
@@ -857,6 +892,12 @@ def _check_read_path(cfg, kernel: str, kv_quant: str) -> None:
             "selection runs on the gather read path over an "
             "unquantised pool only",
         )
+        latent_moe.refuse(
+            cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
+            "the Pallas kernels contract per-head K and V pages and "
+            "the int8 page write quantises them; a latent page has "
+            "neither",
+        )
 
 
 class PagedAttention:
@@ -916,6 +957,31 @@ class PagedAttention:
       in-kernel under ``shard_map`` over ``mesh`` (required for this
       kernel), all of it under ``kv_read``. Both read paths dequantize,
       so they always see the identical pool state.
+
+    A latent configuration (``models/latent_moe.py``) keeps ONE row a
+    token and nothing per head, in two arrays without a head axis:
+    ``ks [layers, pages, block_size, kv_lora_rank]`` holds the normed
+    latent ``c`` and ``vs [layers, pages, block_size / pack, pack *
+    rope_dim]`` the rotated key ``kR``, :func:`rope_pack` tokens a row
+    (apart, and packed, because a row that does not fill the 128 lanes
+    -- 576 numbers, or 64 -- is laid out pages-minor by the runtime and
+    costs a copy of the whole pool a program: PERF.md, PR 31). The
+    layer loop hands both over as ``k, v`` and ``kv_write`` puts them
+    down under the same page ids and offsets. ``kv_read`` is the same
+    gather of the view's pages; with no head axis inside a page its
+    rows ARE the tokens end to end, so nothing is transposed. A row
+    step then reads them in the ABSORBED form -- the query carried into
+    the latent space under ``qkv``, the rows as the one shared key AND
+    value of every head under ``attention``
+    (:func:`_latent_attention`), the head's output brought out by
+    ``W_UV`` -- so no per-head key or value is ever built. A chunk,
+    with hundreds of query rows to spend them on, expands its one
+    view's rows into every head's key and value first (57 ms a
+    512-row chunk on the v5e where the absorbed form took 135:
+    PERF.md, PR 31).
+    The flat list of live pages is not for it: a page's owner's query
+    is twice the page (32 heads x 576 against 16 rows x 576), and a
+    flat rung ran slower than the rectangle at any occupancy.
     """
 
     def __init__(self, cfg, block_size: int, max_blocks: int,
@@ -928,6 +994,7 @@ class PagedAttention:
         self.own = None                 # set by live_pages()
         self.quant = kv_quant == "int8"
         self.sparse = sparse_moe.is_sparse_moe(cfg)
+        self.latent = latent_moe.is_latent_moe(cfg)
         self.kernel = None
         if kernel == "pallas":
             self.kernel = _on_mesh(
@@ -1009,6 +1076,7 @@ class PagedAttention:
         by."""
         cfg = self.cfg
         self.pb, self.off, self.mask, self.slot = pb, off, mask, slot
+        self.counts = {}
         if self.quant:
             idx = jnp.arange(self.block_size)
             # Rows of the write-target page already live, broadcast
@@ -1037,6 +1105,9 @@ class PagedAttention:
         )
 
     def __call__(self, layer, h, lp, q, k, v):
+        if self.latent:
+            self._write_latent(layer, k, v)
+            return self._read_latent(layer, lp, q)
         self._write(layer, k, v)
         mask = self.mask
         if self.sparse:
@@ -1095,6 +1166,61 @@ class PagedAttention:
                 self.vs = self.vs.at[layer, ids].set(
                     v_pages.astype(self.vs.dtype)
                 )
+
+    def _write_latent(self, layer, latents, k_rope):
+        """``latents [b, s, rank]`` into ``ks``, ``k_rope [b, s, rope]``
+        into ``vs``: a chunk's as whole pages, a row step's one call a
+        candidate row (one writer a page a call), through a page of
+        ``vs`` seen a token a row."""
+        bs = self.block_size
+        with jax.named_scope("kv_write"):
+            if self.chunk:
+                def put(pool, rows):
+                    return pool.at[layer, self.blk_ids].set(
+                        rows[0].reshape(-1, *pool.shape[2:])
+                        .astype(pool.dtype)
+                    )
+
+                self.ks, self.vs = put(self.ks, latents), put(self.vs, k_rope)
+                return
+            for j in range(latents.shape[1]):
+                pb, off = self._target(j)
+                self.ks = write_tokens(self.ks, layer, pb, off, latents[:, j])
+                pages = self.vs[layer, pb]
+                at_row = (jnp.arange(bs) == off[:, None])[:, :, None]
+                self.vs = self.vs.at[layer, pb].set(jnp.where(
+                    at_row, k_rope[:, j, None, :].astype(pages.dtype),
+                    pages.reshape(pb.shape[0], bs, -1),
+                ).reshape(pages.shape))
+
+    def _read_latent(self, layer, lp, q):
+        cfg = self.cfg
+        scope = jax.named_scope
+        scale = cfg.qk_head_dim ** -0.5
+        absorbed = not self.chunk
+        if absorbed:
+            with scope("qkv"):
+                q, q_rope = latent_moe.absorb(q, lp, cfg)
+        with scope("kv_read"):
+            # [b, tokens, width]: a page's rows end to end are its
+            # tokens (the rotary keys unpacked: a relayout of 64
+            # numbers a token, which cost the v5e less than scoring the
+            # packs as they lie, PERF.md PR 31).
+            latents, k_rope = (
+                pool[layer, self.view_ids].astype(cfg.dtype).reshape(
+                    q.shape[0], -1, width
+                ) for pool, width in (
+                    (self.ks, cfg.kv_lora_rank), (self.vs, cfg.rope_dim)
+                )
+            )
+        with scope("attention"):
+            if absorbed:
+                u = _latent_attention(
+                    q, q_rope, latents, k_rope, self.mask, cfg, scale
+                )
+                return latent_moe.unabsorb(u, lp, cfg)
+            k, v = latent_moe.expand(latents, k_rope, lp, cfg)
+            return _grouped_attention(q, k, v, self.mask, cfg, scale=scale)
 
     def _select(self, layer, h, lp):
         """The layer's selection as attention's mask. ``h [b, s,
@@ -1342,6 +1468,9 @@ def make_paged_decode_fn(
     its result is ``tokens ++ counts`` in one int32 vector
     (``SPARSE_COUNTERS``' order), so the counts cost no second fetch
     (and ``prev`` is that vector: its first ``slots`` entries are read).
+    A latent configuration (``models/latent_moe.py``) runs it with the
+    absorbed read over its headless pool and packs its expert layers'
+    counts the same way (``LATENT_COUNTERS``).
     ``probe=True`` (such configurations only) also returns each layer's
     selection ``[layers, slots, columns]``: the benchmark's check
     reads it, no serving path does.
@@ -1356,16 +1485,19 @@ def make_paged_decode_fn(
     program by them).
     """
     cache_cap = max_blocks * block_size
+    counters = step_counters(cfg)
     attention = PagedAttention(
         cfg, block_size, max_blocks, kernel, kv_quant, mesh
     )
     if flat_pages is not None and (
-        attention.sparse or attention.kernel is not None
+        attention.sparse or attention.latent
+        or attention.kernel is not None
     ):
         raise ValueError(
             "flat_pages is the gather read of a dense configuration: an "
-            "indexer ranks the columns of each slot's own view and a "
-            "table-walking kernel reads no view at all"
+            "indexer ranks the columns of each slot's own view, a latent "
+            "page is smaller than its owner's query, and a table-walking "
+            "kernel reads no view at all"
         )
 
     def body(params, ks, vs, ksc, vsc, xs, prev, step, tables):
@@ -1399,12 +1531,12 @@ def make_paged_decode_fn(
         with scope("head"):
             logits = _logits_head(x, params, cfg)
             tok = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            if attention.sparse:
+            if counters:
                 # The step's counts ride behind its tokens: one array,
-                # one fetch (order: SPARSE_COUNTERS).
+                # one fetch (order: step_counters).
                 counts.update(pool.counts)
                 tok = jnp.concatenate([tok, jnp.stack([
-                    counts[key] for key, _, _ in SPARSE_COUNTERS
+                    counts[key] for key, _, _ in counters
                 ]).astype(jnp.int32)])
         if probe:
             # The selection each layer made, for the benchmark's
@@ -1460,6 +1592,47 @@ class _PagedSlot:
     seed: int = 0
     temperature: float = 0.0
     top_p: float = 1.0
+
+
+class _LivePages:
+    """The distinct pages the decoding slots read, kept where pages
+    are taken and released instead of recounted each step: a slot
+    reads the leading ``pos // block_size + 1`` pages of its table, a
+    page several slots share (a prefix) is one page. ``len()`` is the
+    count a step adds to ``serve_latent_pages_live_total``."""
+
+    def __init__(self, block_size: int):
+        self.block_size = block_size
+        self.readers: Dict[int, int] = {}       # page -> slots reading it
+        self.upto: Dict[int, int] = {}          # slot -> pages counted
+
+    def __len__(self) -> int:
+        return len(self.readers)
+
+    def _add(self, page: int, n: int) -> None:
+        left = self.readers.get(page, 0) + n
+        if left:
+            self.readers[page] = left
+        else:
+            del self.readers[page]
+
+    def reach(self, slot: int, blocks: Sequence[int], pos: int) -> None:
+        """``slot`` decodes at ``pos``: count the pages it has grown
+        into since it was last seen (one every ``block_size`` steps)."""
+        have, need = self.upto.get(slot, 0), pos // self.block_size + 1
+        for page in blocks[have:need]:
+            self._add(page, 1)
+        self.upto[slot] = max(have, need)
+
+    def moved(self, slot: int, index: int, old: int, new: int) -> None:
+        """Copy-on-write gave ``slot`` page ``new`` for ``old``."""
+        if index < self.upto.get(slot, 0):
+            self._add(old, -1)
+            self._add(new, 1)
+
+    def leave(self, slot: int, blocks: Sequence[int]) -> None:
+        for page in blocks[:self.upto.pop(slot, 0)]:
+            self._add(page, -1)
 
 
 class PagedEngine(Engine):
@@ -1523,6 +1696,12 @@ class PagedEngine(Engine):
                 "side arrays are always f32)"
             )
         _check_read_path(cfg, paged.kernel, paged.kv_quant)
+        if "model" in mesh.axis_names and mesh.shape["model"] > 1:
+            latent_moe.refuse(
+                cfg, "a serving mesh with a tensor axis",
+                "the latent page has no head axis to shard and the "
+                "latent projections have no tensor-parallel plan",
+            )
         per_seq = serve_cfg.max_seq_len // bs
         # A pool SMALLER than one full-capacity sequence is legal --
         # it simply cannot serve max-length requests, and
@@ -1543,7 +1722,9 @@ class PagedEngine(Engine):
         # it (``decode_rungs``).
         self.view_pages = serve_cfg.slots * per_seq
         self._flat_rungs: Tuple[int, ...] = ()
-        if paged.kernel == "gather" and not sparse_moe.is_sparse_moe(cfg):
+        if paged.kernel == "gather" and not (
+            sparse_moe.is_sparse_moe(cfg) or latent_moe.is_latent_moe(cfg)
+        ):
             self._flat_rungs = tuple(sorted(
                 {int(self.view_pages * r) for r in FLAT_RUNGS} - {0}
             ))
@@ -1581,9 +1762,9 @@ class PagedEngine(Engine):
         # result, on the device; whether the host has yet to take it;
         # and the slots whose NEXT input token is in it and nowhere
         # else (active in that step and not released since).
+        self._step_counters = step_counters(cfg)
         self._toks = self._rep_arr(np.zeros(
-            serve_cfg.slots + len(SPARSE_COUNTERS)
-            * sparse_moe.is_sparse_moe(cfg), np.int32,
+            serve_cfg.slots + len(self._step_counters), np.int32,
         ))
         self._unfetched = False
         self._on_device = np.zeros(serve_cfg.slots, bool)
@@ -1598,9 +1779,15 @@ class PagedEngine(Engine):
             "prefix_hit_blocks": 0, "prefill_chunks": 0,
             "cow_copies": 0, "trie_evictions": 0, "decode_steps": 0,
         }
-        counters = DECODE_COUNTERS
-        if sparse_moe.is_sparse_moe(cfg):
-            counters += tuple(c[1:] for c in SPARSE_COUNTERS)
+        counters = DECODE_COUNTERS \
+            + tuple(c[1:] for c in self._step_counters)
+        # The distinct pages the active slots read (a latent
+        # configuration only: its read's roofline counts a shared page
+        # once).
+        self._live_pages: Optional[_LivePages] = None
+        if latent_moe.is_latent_moe(cfg):
+            self._live_pages = _LivePages(bs)
+            counters += (LATENT_PAGES_LIVE,)
         for name, help_ in counters:
             self.paged_stats[name] = 0
             get_registry().describe(name, help_)
@@ -1630,6 +1817,8 @@ class PagedEngine(Engine):
         )
 
     def _cache_pspec(self) -> P:
+        if latent_moe.is_latent_moe(self.cfg):
+            return P()      # no head axis to shard
         return paged_kv_cache_pspec(self.mesh, self.cfg.kv_heads)
 
     def _init_cache(self) -> None:
@@ -1640,6 +1829,27 @@ class PagedEngine(Engine):
         collective for 4 bytes). ``cache_bytes`` counts both, which is
         what makes the fit-report capacity claim honest."""
         self.xs = None
+        if latent_moe.is_latent_moe(self.cfg):
+            # One row a token and nothing per head: the latent in
+            # ``ks``, its rotary key in ``vs`` (``rope_pack`` a row).
+            dtype = jnp.dtype(self.serve_cfg.cache_dtype or self.cfg.dtype)
+            bs = self.paged.block_size
+            pack = rope_pack(self.cfg, bs)
+            page = (self.cfg.n_layers, self.paged.num_blocks)
+            shapes = (
+                (*page, bs, self.cfg.kv_lora_rank),
+                (*page, bs // pack, pack * self.cfg.rope_dim),
+            )
+            self._cache_sharding = NamedSharding(
+                self.mesh, self._cache_pspec()
+            )
+            self.ks, self.vs = jax.jit(
+                lambda: tuple(jnp.zeros(shape, dtype) for shape in shapes),
+                out_shardings=(self._cache_sharding,) * 2,
+            )()
+            self.k_scales = self.v_scales = None
+            self.cache_bytes = sum(map(math.prod, shapes)) * dtype.itemsize
+            return
         if getattr(self.paged, "kv_quant", "none") != "int8":
             super()._init_cache()
             self.k_scales = self.v_scales = None
@@ -1692,9 +1902,10 @@ class PagedEngine(Engine):
     _STATE = ("ks", "vs", "k_scales", "v_scales", "xs")
 
     def _state(self) -> List[Any]:
-        """Keys, values, then an int8 pool's two scale arrays, then a
-        sparse-expert configuration's indexer keys: what every paged
-        program takes after the weights, donates and returns first."""
+        """Keys, values (a latent pool: latents, rotary keys), then an
+        int8 pool's two scale arrays, then a sparse-expert
+        configuration's indexer keys: what every paged program takes
+        after the weights, donates and returns first."""
         return [
             a for a in (getattr(self, n) for n in self._STATE)
             if a is not None
@@ -1724,7 +1935,6 @@ class PagedEngine(Engine):
         # the tier too.
         if key[0] in self._tier_builders:
             return self._tier_builders[key[0]](key)
-        cache = self._cache_abstract()
         params_abs = self._params_abstract()
         scalar = jax.ShapeDtypeStruct((), jnp.int32, sharding=self._rep)
         slots = self.serve_cfg.slots
@@ -1734,9 +1944,9 @@ class PagedEngine(Engine):
         # engine-resident and donated.
         state_shardings = (self._cache_sharding, self._cache_sharding) \
             + (self._rep,) * (len(self._state()) - 2)
-        state = (cache, cache) + tuple(
-            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=self._rep)
-            for a in self._state()[2:]
+        state = tuple(
+            jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding)
+            for a, sharding in zip(self._state(), state_shardings)
         )
         if key[0] == "prefill":
             bucket = key[1]
@@ -1823,9 +2033,11 @@ class PagedEngine(Engine):
         """The flat decode programs this engine holds below the
         rectangle, by the pages each reads (``FLAT_RUNGS`` of ``slots x
         pages a slot``), smallest first. None where the read is not the
-        dense gather's: an indexer ranks each slot's own view, a
-        table-walking kernel reads no view, and a speculative engine's
-        step is the verify program."""
+        dense gather's: an indexer ranks each slot's own view, a latent
+        page is smaller than its owner's query (a flat rung ran slower
+        than the rectangle: PERF.md, PR 31), a table-walking kernel
+        reads no view, and a speculative engine's step is the verify
+        program."""
         return () if self.spec is not None else self._flat_rungs
 
     @property
@@ -2147,6 +2359,8 @@ class PagedEngine(Engine):
                 *self._state(), self._rep_arr(blk), self._rep_arr(new),
             ))
             st.blocks[idx] = new
+            if self._live_pages is not None:
+                self._live_pages.moved(slot, idx, blk, new)
             self._write_table(slot, st.blocks)
             self.paged_stats["cow_copies"] += 1
             get_bus().emit(
@@ -2214,6 +2428,10 @@ class PagedEngine(Engine):
                 for s, (is_on, pos) in enumerate(zip(active, positions)):
                     if is_on and s in self._slot_state:
                         self._cow_write_target(s, int(pos))
+                        if self._live_pages is not None:
+                            self._live_pages.reach(
+                                s, self._slot_state[s].blocks, int(pos)
+                            )
                 # The smallest rung that holds the step's live pages
                 # (exactly what the program will count from the same
                 # positions), else the rectangle.
@@ -2247,6 +2465,10 @@ class PagedEngine(Engine):
                 self._count(
                     "serve_decode_view_pages_total", self.view_pages
                 )
+                if self._live_pages is not None:
+                    self._count(
+                        LATENT_PAGES_LIVE[0], len(self._live_pages)
+                    )
             if not self.decode_lag:
                 return self.flush()
             return None if before is None else self._take(before)
@@ -2297,15 +2519,18 @@ class PagedEngine(Engine):
         get_registry().inc(name, n)
 
     def _take(self, toks) -> np.ndarray:
-        """Fetch one step's result: its tokens, and a sparse-expert
-        step's counts (``SPARSE_COUNTERS``' order) into ``paged_stats``
-        and the registry."""
+        """Fetch one step's result: its tokens, and the counts an
+        expert configuration's step packs behind them
+        (``step_counters``' order) into ``paged_stats`` and the
+        registry."""
         with span("decode.fetch"):
             fetched = np.asarray(toks)
         slots = self.serve_cfg.slots
         stats = self.paged_stats
         stats["decode_steps"] += 1
-        for (_, name, _), value in zip(SPARSE_COUNTERS, fetched[slots:]):
+        for (_, name, _), value in zip(
+            self._step_counters, fetched[slots:]
+        ):
             if name.endswith("_total"):
                 self._count(name, int(value))
             else:
@@ -2324,6 +2549,8 @@ class PagedEngine(Engine):
             # flight: that slot-step ran past the request's end.
             self._on_device[slot] = False
             self._count("serve_decode_discarded_total")
+        if self._live_pages is not None:
+            self._live_pages.leave(slot, st.blocks)
         freed = self.allocator.release(st.blocks)
         self._write_table(slot, [])
         get_bus().emit("kv_block", action="free", n=freed, slot=slot)
@@ -2362,6 +2589,8 @@ class PagedEngine(Engine):
                 "engines)"
             )
         self._slot_state = {}
+        if self._live_pages is not None:
+            self._live_pages = _LivePages(self.paged.block_size)
         self._unfetched = False
         self._on_device[:] = False
         self.allocator = BlockAllocator(

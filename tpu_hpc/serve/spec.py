@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_hpc.models import llama2, sparse_moe
+from tpu_hpc.models import latent_moe, llama2, sparse_moe
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.decoder import (
     _embed,
@@ -541,6 +541,13 @@ class SpecRunner:
                 model_cfg, "speculative decoding (serve/spec.py)",
                 "its draft, verify and mirrored-pool programs carry "
                 "keys and values only, and a page now has three arrays",
+            )
+            latent_moe.refuse(
+                model_cfg, "speculative decoding (serve/spec.py)",
+                "its draft, verify and mirrored-pool programs write and "
+                "read per-head keys and values, not a latent row (and "
+                "the model's multi-token-prediction module is no "
+                "proposer here)",
             )
         if cfg.k > max(engine.serve_cfg.prefill_buckets):
             raise ValueError(

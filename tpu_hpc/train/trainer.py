@@ -484,12 +484,17 @@ class Trainer:
         mesh (bench.py: the mode picks the mesh family) pass it here
         so the trainer runs exactly that decision instead of
         re-planning. Ignored unless cfg.comm_mode == "auto"."""
-        from tpu_hpc.models import sparse_moe
+        from tpu_hpc.models import latent_moe, sparse_moe
 
         sparse_moe.refuse_weights(
             params, "the Trainer",
             "no training forward, loss or sharding plan goes through "
             "the expert layer or the indexer",
+        )
+        latent_moe.refuse_weights(
+            params, "the Trainer",
+            "no training forward, loss or sharding plan goes through "
+            "latent attention or the expert layer",
         )
         self.cfg = cfg
         self.mesh = mesh
