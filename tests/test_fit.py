@@ -43,6 +43,26 @@ def test_7b_fits_v4_hbm(full_7b):
     assert full_7b.total_bytes < 0.5 * 32 * GIB
 
 
+@pytest.mark.parametrize("keeping", [0, 1, 32])
+def test_activation_model_counts_keeping_blocks(full_7b, keeping):
+    """``keeping`` blocks hold their six matmul outputs beside the
+    block input: (4096 + 2 x 4096 + 4096 + 2 x 11008) bf16 numbers a
+    token, split 8 ways by the model axis; the other terms do not
+    move, and 0 is the model the reports were written from."""
+    args = (full_7b.cfg, 4, 8, 8, 4096)
+    base = fit.activation_model(*args)
+    assert base == full_7b.act_bytes
+    got = fit.activation_model(*args, keeping=keeping)
+    block = 2 * 4096 * (4 * 4096 + 2 * 11008) * 2 // 8
+    assert fit.kept_block_bytes(full_7b.cfg, 2 * 4096, 8) == block
+    assert got.pop("kept_matmul_outputs", 0) == keeping * block
+    assert got == base
+    # Halving the microbatch halves them, like every other term.
+    assert fit.activation_model(
+        *args, grad_accum=2, keeping=32
+    )["kept_matmul_outputs"] == 16 * block
+
+
 def test_7b_every_large_param_is_sharded():
     """No big tensor may stay replicated under the hybrid plan."""
     cfg = llama2.LlamaConfig(max_seq_len=4096, remat=True)
@@ -561,6 +581,78 @@ def test_latent_programs_address_the_pool_in_place(v5e_2x2, program):
     )
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2 * view_tokens * row + 2 * expanded + pool_bytes // 2, temp
+
+
+def test_keeping_blocks_hold_what_the_model_reckons(v5e_2x2):
+    """The real train step (``make_step_fn``: forward, backward, AdamW)
+    compiled for the chip twice: with no budget open (every block
+    recomputes) and under a budget reckoned from the v5e's own limit
+    and this model's state, which holds every block's matmul outputs.
+    The compiler's temporaries grow by what ``kept_block_bytes``
+    reckons, within 25 %, and twelve matmuls a keeping pair fewer are
+    recomputed. A peak over the whole step, not a sum, so the
+    agreement is for THIS shape (vocabulary large enough that the
+    recomputing step peaks at the head, where every kept product is
+    live): with 2 layers of the same widths the growth reads 1.4 x the
+    reckoning, with 3 and a quarter of the vocabulary 0.58 x (PR 32).
+    The CPU compiler cannot show any of it: it drops the barriers that
+    make recomputation real. ~45 s."""
+    import jax.numpy as jnp
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from tpu_hpc.config import TrainingConfig
+    from tpu_hpc.models import remat
+    from tpu_hpc.train import trainer
+
+    mesh = Mesh(np.array(v5e_2x2.devices[:1]), ("data",))
+    rep = NamedSharding(mesh, P())
+    cfg = llama2.LlamaConfig(
+        dim=1024, n_layers=4, n_heads=8, n_kv_heads=2, vocab_size=8192,
+        multiple_of=256, max_seq_len=1024, remat=True,
+    )
+    batch, seq = 2, cfg.max_seq_len
+    flash = tp.make_tp_flash_attn_fn(
+        mesh, "data", None, impl="pallas", block_q=512, block_k=512
+    )
+    optimizer = trainer.make_optimizer(
+        TrainingConfig(epochs=1, steps_per_epoch=1, global_batch_size=batch)
+    )
+    step = trainer.make_step_fn(
+        llama2.make_forward(cfg, attn_fn=flash), optimizer, 0
+    )
+    params = jax.eval_shape(
+        lambda: llama2.init_llama(jax.random.key(0), cfg)
+    )
+    state = trainer.TrainState(
+        step=jax.ShapeDtypeStruct((), jnp.int32), params=params,
+        opt_state=jax.eval_shape(optimizer.init, params), model_state={},
+    )
+    nbytes = lambda tree: sum(  # noqa: E731
+        int(np.prod(a.shape)) * a.dtype.itemsize
+        for a in jax.tree.leaves(tree)
+    )
+    budget = remat.RematBudget(
+        limit_bytes=int(15.75 * GIB), resident_bytes=nbytes(state),
+        grad_bytes=nbytes(params),
+    )
+    placed = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=rep),
+        tree,
+    )
+    tokens = jax.ShapeDtypeStruct((batch, seq), jnp.int32)
+    temps, matmuls = [], []
+    for open_budget in (None, budget):
+        with remat.lowering_under(open_budget):
+            compiled = jax.jit(
+                lambda s, b: step(s, b), donate_argnums=(0,)
+            ).lower(placed(state), placed((tokens, tokens))).compile()
+        temps.append(compiled.memory_analysis().temp_size_in_bytes)
+        matmuls.append(compiled.as_text().count(" convolution("))
+    assert budget.blocks_kept == cfg.n_layers
+    block = fit.kept_block_bytes(cfg, batch * seq)
+    assert budget.kept_bytes == cfg.n_layers * block == 128 * 2 ** 20
+    assert temps[1] - temps[0] == pytest.approx(budget.kept_bytes, rel=0.25)
+    assert matmuls[0] - matmuls[1] == 6 * cfg.n_layers
 
 
 class TestCPLayout:

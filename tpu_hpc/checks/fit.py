@@ -269,27 +269,37 @@ class FitResult:
         return d
 
 
-def activation_model(
-    cfg: llama2.LlamaConfig, dp: int, tp_size: int,
-    global_batch: int, seq_len: int, grad_accum: int = 1,
+def kept_block_bytes(
+    cfg: llama2.LlamaConfig, tokens: int, tp_size: int = 1
+) -> int:
+    """Bytes ONE keeping block holds on a chip between its forward and
+    its backward pass, beyond the block input every block saves: the
+    six matmul outputs ``models/llama2.py`` tags (models/remat.py) --
+    the rotated query and key and the value, the residual stream after
+    the output projection, the feed-forward's gate and up -- in the
+    compute dtype, for the ``tokens`` a chip holds of one microbatch.
+    Tensor parallelism shards q/k/v by heads and gate/up by the hidden
+    width, and the sequence-parallel constraint shards the residual
+    stream, so all six are split ``tp_size`` ways.
+
+    The Trainer's decision (how many blocks keep) and this module's
+    fit report read the same number from here."""
+    per_token = (
+        (cfg.n_heads + 2 * cfg.kv_heads) * cfg.head_dim
+        + cfg.dim + 2 * cfg.ffn_hidden
+    )
+    return tokens * per_token * jnp.dtype(cfg.dtype).itemsize // tp_size
+
+
+def activation_bytes(
+    cfg: llama2.LlamaConfig, tokens: int, tp_size: int = 1,
+    keeping: int = 0,
 ) -> Dict[str, int]:
-    """Per-chip activation bytes under the bench configuration:
-    remat-per-block (only block inputs saved), Megatron-SP (residual
-    stream sequence-sharded over the model axis between blocks), flash
-    attention (O(S) saved state, no S x S scores), bf16 compute.
-
-    ``grad_accum > 1``: each microbatch's activations live only for its
-    own forward/backward inside the accumulation scan, so every term
-    scales by 1/grad_accum (the gradient-sum carry is accounted
-    separately in analyze()).
-
-    An analytic model, not a measurement: XLA's actual peak adds fusion
-    temporaries, but the dominant terms (checkpointed residuals, one
-    block's recompute live-set, the logits/CE head) are all here.
-    """
-    # Per-chip, per-microbatch rows (DP shards the batch dim).
-    bl = global_batch // dp // grad_accum
-    s_sp = seq_len // tp_size        # SP-sharded sequence slice
+    """:func:`activation_model` in terms of the ``tokens`` a chip holds
+    of one microbatch (rows x sequence, after data parallelism and
+    gradient accumulation): the form the model itself can evaluate
+    while it is traced (``llama2._blocks_keeping``)."""
+    t_sp = tokens // tp_size         # SP-sharded residual stream
     d, hd = cfg.dim, cfg.head_dim
     h_loc = cfg.n_heads // tp_size   # TP shards heads
     kv_loc = max(cfg.kv_heads // tp_size, 1)
@@ -298,28 +308,65 @@ def activation_model(
 
     # Saved between fwd and bwd: one residual checkpoint per block
     # (sequence-sharded thanks to SP) + embedding output.
-    checkpoints = (cfg.n_layers + 1) * bl * s_sp * d * bf16
+    checkpoints = (cfg.n_layers + 1) * t_sp * d * bf16
     # Live while recomputing/backpropping ONE block (full seq per chip
     # -- the SP all-gather happens at the block boundary): input + QKV +
     # flash out/LSE + two SwiGLU hiddens, roughly doubled for the
     # matching gradient buffers.
-    qkv = bl * seq_len * (h_loc + 2 * kv_loc) * hd * bf16
-    attn_out = bl * seq_len * h_loc * hd * bf16
-    lse = bl * h_loc * seq_len * f32
-    mlp = 2 * bl * seq_len * ffn_loc * bf16
-    block_live = 2 * (bl * seq_len * d * bf16 + qkv + attn_out + lse + mlp)
+    qkv = tokens * (h_loc + 2 * kv_loc) * hd * bf16
+    attn_out = tokens * h_loc * hd * bf16
+    lse = tokens * h_loc * f32
+    mlp = 2 * tokens * ffn_loc * bf16
+    block_live = 2 * (tokens * d * bf16 + qkv + attn_out + lse + mlp)
     # LM head: logits are vocab-sharded (output Colwise) and stay in
     # bf16 -- the loss upcasts inside its fused reductions, so no
     # [B, S, V] fp32 buffer exists (models/llama2.py Llama.__call__).
     # bf16 logits + bf16 logit-grad + one fp32 reduction pass that XLA
     # may materialise while fusing logsumexp.
     vocab_loc = cfg.vocab_size // tp_size
-    head = bl * seq_len * vocab_loc * (2 * bf16 + f32)
-    return {
+    head = tokens * vocab_loc * (2 * bf16 + f32)
+    out = {
         "residual_checkpoints": checkpoints,
         "block_recompute_live": block_live,
         "lm_head_and_loss": head,
     }
+    if keeping:
+        out["kept_matmul_outputs"] = keeping * kept_block_bytes(
+            cfg, tokens, tp_size
+        )
+    return out
+
+
+def activation_model(
+    cfg: llama2.LlamaConfig, dp: int, tp_size: int,
+    global_batch: int, seq_len: int, grad_accum: int = 1,
+    keeping: int = 0,
+) -> Dict[str, int]:
+    """Per-chip activation bytes under the bench configuration:
+    remat-per-block, Megatron-SP (residual stream sequence-sharded over
+    the model axis between blocks), flash attention (O(S) saved state,
+    no S x S scores), bf16 compute.
+
+    ``keeping``: how many blocks keep their six matmul outputs for the
+    backward pass (:func:`kept_block_bytes` each); the others save
+    their input alone and recompute the rest. 0, the default, is full
+    recomputation. A Trainer on a device that reports a memory limit
+    chooses the count from this same model (train/trainer.py,
+    models/remat.py) and reports it as ``train_remat_blocks_kept``.
+
+    ``grad_accum > 1``: each microbatch's activations live only for its
+    own forward/backward inside the accumulation scan, so every term
+    scales by 1/grad_accum (the gradient-sum carry is accounted
+    separately in analyze()).
+
+    An analytic model, not a measurement: XLA's actual peak adds fusion
+    temporaries, but the dominant terms (checkpointed residuals, one
+    block's recompute live-set, the logits/CE head, the kept products)
+    are all here.
+    """
+    # Per-chip, per-microbatch rows (DP shards the batch dim).
+    bl = global_batch // dp // grad_accum
+    return activation_bytes(cfg, bl * seq_len, tp_size, keeping)
 
 
 def activation_model_cp(

@@ -21,8 +21,11 @@ TPU-first design (not a translation):
   * an optional ``constrain`` hook threads activation sharding
     constraints (Megatron-SP sequence sharding) through the block
     structure without the model knowing about meshes.
-  * optional ``remat`` (jax.checkpoint) per block -- the HBM/FLOPs
-    trade for long sequences.
+  * optional ``remat`` (jax.checkpoint) per block: every block saves
+    its input and recomputes the rest in the backward pass, except the
+    leading blocks whose six matmul outputs a Trainer finds room to
+    keep (models/remat.py: the count comes from the bytes the chip has
+    left, through the trace, never from a field here).
 """
 from __future__ import annotations
 
@@ -33,6 +36,9 @@ from typing import Any, Callable, Dict, Mapping, Optional, Tuple
 import flax.linen as nn
 import jax
 import jax.numpy as jnp
+from jax.ad_checkpoint import checkpoint_name
+
+from tpu_hpc.models import remat
 
 Constrain = Callable[[jax.Array], jax.Array]
 # (q [B,S,Hq,D], k [B,S,Hkv,D], v) -> [B,S,Hq,D]; plugs ring/Ulysses
@@ -115,6 +121,10 @@ class LlamaConfig:
     depth_init: bool = True
     dtype: Any = jnp.bfloat16       # compute dtype (the reference's
     param_dtype: Any = jnp.float32  # use_amp/amp_dtype pair, utils/config.py:40-44)
+    # False: every activation is kept for the backward pass. True:
+    # recompute what does not fit -- blocks keep their matmul outputs
+    # while the Trainer lowering the step finds room (models/remat.py);
+    # traced anywhere else, every block recomputes.
     remat: bool = False
     # Matmul-backward embedding lookup: forward is a plain gather
     # (cheap on TPU), but the gradient is computed as one_hot^T @ g on
@@ -323,6 +333,13 @@ class RMSNorm(nn.Module):
         return (normed * scale).astype(x.dtype)
 
 
+def _kept(x: jax.Array, name: str, keeps: bool) -> jax.Array:
+    """Tag one of the products a keeping block saves (remat.py). Only a
+    keeping block tags: every other trace holds no ``name`` equation
+    and lowers to the text it always did."""
+    return checkpoint_name(x, name) if keeps else x
+
+
 def _dense(
     features: int, std: float, cfg: "LlamaConfig", name: str
 ) -> nn.Dense:
@@ -349,6 +366,7 @@ class Attention(nn.Module):
     cfg: LlamaConfig
     out_std: float
     attn_fn: AttnFn = None
+    keeps: bool = False  # see TransformerBlock.keeps
 
     @nn.compact
     def __call__(
@@ -367,9 +385,18 @@ class Attention(nn.Module):
             v = _dense(n_kv * hd, std, cfg, "wv")(x)
 
             cos, sin = rope_cos_sin(s, hd, positions=positions)
-            q = apply_rope(q.reshape(b, s, cfg.n_heads, hd), cos, sin)
-            k = apply_rope(k.reshape(b, s, n_kv, hd), cos, sin)
-            v = v.reshape(b, s, n_kv, hd)
+            # Kept AFTER the rotation: a keeping block then recomputes
+            # neither the product nor the rotary arithmetic, whose own
+            # backward needs only the tables.
+            q = _kept(
+                apply_rope(q.reshape(b, s, cfg.n_heads, hd), cos, sin),
+                "proj_q", self.keeps,
+            )
+            k = _kept(
+                apply_rope(k.reshape(b, s, n_kv, hd), cos, sin),
+                "proj_k", self.keeps,
+            )
+            v = _kept(v.reshape(b, s, n_kv, hd), "proj_v", self.keeps)
 
         with jax.named_scope("attention"):
             if self.attn_fn is not None:
@@ -396,13 +423,18 @@ class FeedForward(nn.Module):
 
     cfg: LlamaConfig
     out_std: float
+    keeps: bool = False  # see TransformerBlock.keeps
 
     @nn.compact
     def __call__(self, x: jax.Array) -> jax.Array:
         cfg = self.cfg
         hidden = cfg.ffn_hidden
-        gate = _dense(hidden, 0.02, cfg, "w1")(x)
-        up = _dense(hidden, 0.02, cfg, "w3")(x)
+        gate = _kept(
+            _dense(hidden, 0.02, cfg, "w1")(x), "ffn_gate", self.keeps
+        )
+        up = _kept(
+            _dense(hidden, 0.02, cfg, "w3")(x), "ffn_up", self.keeps
+        )
         return _dense(cfg.dim, self.out_std, cfg, "w2")(
             nn.silu(gate) * up
         )
@@ -420,6 +452,9 @@ class TransformerBlock(nn.Module):
     layer_id: int
     constrain: Constrain = _identity
     attn_fn: AttnFn = None
+    # Set by ``Llama`` on the blocks it runs under the keeping policy
+    # (models/remat.py): the six matmul outputs are tagged for it.
+    keeps: bool = False
 
     @nn.compact
     def __call__(
@@ -436,13 +471,22 @@ class TransformerBlock(nn.Module):
             normed = RMSNorm(
                 cfg.norm_eps, cfg.param_dtype, name="attention_norm"
             )(x)
-        h = x + self.constrain(
-            Attention(cfg, out_std, self.attn_fn, name="attention")(
-                normed, positions
-            )
+        # The residual sum, not the projection's own output: the same
+        # size, one add less to recompute, and behind the constraint,
+        # so under sequence parallelism what is kept is a chip's share.
+        h = _kept(
+            x + self.constrain(
+                Attention(
+                    cfg, out_std, self.attn_fn, self.keeps,
+                    name="attention",
+                )(normed, positions)
+            ),
+            "attn_residual", self.keeps,
         )
         with jax.named_scope("mlp"):
-            ffn = FeedForward(cfg, out_std, name="feed_forward")(
+            ffn = FeedForward(
+                cfg, out_std, self.keeps, name="feed_forward"
+            )(
                 RMSNorm(cfg.norm_eps, cfg.param_dtype, name="ffn_norm")(h)
             )
         return h + self.constrain(ffn)
@@ -481,12 +525,19 @@ class Llama(nn.Module):
             else:
                 x = emb(tokens)
         x = self.constrain(x)
-        block = TransformerBlock
+        block = keeping_block = TransformerBlock
+        keeping = 0
         if cfg.remat:
             block = nn.remat(TransformerBlock)
+            keeping = _blocks_keeping(cfg, tokens.size)
+            keeping_block = nn.remat(
+                TransformerBlock, policy=remat.keep_products()
+            )
         for i in range(cfg.n_layers):
-            x = block(
-                cfg, i, self.constrain, self.attn_fn, name=f"layers_{i}"
+            keeps = i < keeping
+            x = (keeping_block if keeps else block)(
+                cfg, i, self.constrain, self.attn_fn, keeps,
+                name=f"layers_{i}",
             )(x, positions)
         with jax.named_scope("head"):
             x = RMSNorm(cfg.norm_eps, cfg.param_dtype, name="norm")(x)
@@ -505,6 +556,27 @@ class Llama(nn.Module):
         # matmul output is already rounded to cfg.dtype before any
         # cast.
         return logits
+
+
+def _blocks_keeping(cfg: LlamaConfig, n_tokens: int) -> int:
+    """How many leading blocks keep their matmul outputs: what the
+    budget of the Trainer lowering this trace holds, 0 with no budget
+    open. ``n_tokens`` is the (micro)batch as this trace sees it."""
+    budget = remat.open_budget()
+    if budget is None:
+        return 0
+    # checks/fit.py imports this module; its activation model is the
+    # ONE reckoning the planner's report and this decision share.
+    from tpu_hpc.checks import fit
+
+    tokens = n_tokens // budget.batch_shards
+    return budget.decide(
+        cfg.n_layers,
+        fit.kept_block_bytes(cfg, tokens, budget.model_shards),
+        sum(fit.activation_bytes(
+            cfg, tokens, budget.model_shards
+        ).values()),
+    )
 
 
 def _dense_only(cfg: LlamaConfig, who: str) -> None:

@@ -282,6 +282,15 @@ EVENTS: Dict[str, EventSpec] = {
             "reason", "resolved_from",
         ),
     ),
+    # -- recomputation by memory budget (train/trainer.py,
+    #    models/remat.py): one record per step or chunk program built on
+    #    a device that reports a memory limit -- how many blocks keep
+    #    their matmul outputs, their bytes a chip, and the limit and
+    #    the room the count was reckoned from --
+    "remat_plan": EventSpec((
+        "blocks_kept", "kept_bytes", "n_blocks", "block_bytes",
+        "bytes_limit", "budget_bytes",
+    )),
     # -- elastic resume (ckpt.restore_latest cross-topology path) --
     "elastic_restore": EventSpec(
         ("from_step", "src_mesh", "tgt_mesh"),

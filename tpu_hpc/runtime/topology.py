@@ -13,6 +13,15 @@ from typing import Any, Dict, List
 import jax
 
 
+def memory_stats(device) -> Dict[str, int]:
+    """``device.memory_stats()``, or ``{}`` from a backend that keeps
+    none (the CPU) or cannot be asked."""
+    try:
+        return device.memory_stats() or {}
+    except Exception:
+        return {}
+
+
 def device_summary() -> List[Dict[str, Any]]:
     """One record per addressable device: TPU analogue of the per-GPU
     property gather in check_environment.py:118-179."""
@@ -33,13 +42,10 @@ def device_summary() -> List[Dict[str, Any]]:
         slice_idx = getattr(d, "slice_index", None)
         if slice_idx is not None:
             rec["slice_index"] = slice_idx
-        try:
-            stats = d.memory_stats()
-            if stats:
-                rec["bytes_limit"] = stats.get("bytes_limit")
-                rec["bytes_in_use"] = stats.get("bytes_in_use")
-        except Exception:
-            pass
+        stats = memory_stats(d)
+        if stats:
+            rec["bytes_limit"] = stats.get("bytes_limit")
+            rec["bytes_in_use"] = stats.get("bytes_in_use")
         out.append(rec)
     return out
 

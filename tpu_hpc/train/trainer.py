@@ -15,6 +15,7 @@ from __future__ import annotations
 
 import contextlib
 import dataclasses
+import functools
 import math
 import os
 import time
@@ -29,6 +30,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from tpu_hpc import obs
 from tpu_hpc.config import TrainingConfig
 from tpu_hpc.logging_ import get_logger
+from tpu_hpc.models import remat
 from tpu_hpc.parallel.fsdp import validate_grad_sync_mode
 from tpu_hpc.parallel.plans import derived_pspecs, shardings_for
 from tpu_hpc.resilience import guard as guard_lib
@@ -44,6 +46,7 @@ from tpu_hpc.resilience.signals import (
     ENV_ELASTIC_MANAGED,
     PreemptionGuard,
 )
+from tpu_hpc.runtime import topology
 from tpu_hpc.train.metrics import GoodputMeter, ThroughputMeter
 
 
@@ -75,16 +78,29 @@ def _json_finite(x) -> Optional[float]:
     return x if math.isfinite(x) else None
 
 
+def _spec_extent(mesh: Mesh, spec: P) -> int:
+    """Product of the mesh-axis sizes a spec names on ANY dim: how many
+    ways an array under it is split."""
+    out = 1
+    for entry in spec:
+        for n in (entry if isinstance(entry, tuple) else (entry,)):
+            if n is not None:
+                out *= mesh.shape[n]
+    return out
+
+
 def _leading_spec_extent(mesh: Mesh, spec: P) -> int:
     """Product of mesh-axis sizes sharding a spec's leading dim."""
-    if len(spec) == 0 or spec[0] is None:
-        return 1
-    entry = spec[0]
-    names = entry if isinstance(entry, tuple) else (entry,)
-    out = 1
-    for n in names:
-        out *= mesh.shape[n]
-    return out
+    return _spec_extent(mesh, spec[:1])
+
+
+def _bytes_per_device(tree: Any) -> int:
+    """Bytes one device holds of a tree of placed arrays."""
+    return sum(
+        math.prod(leaf.sharding.shard_shape(leaf.shape))
+        * leaf.dtype.itemsize
+        for leaf in jax.tree.leaves(tree)
+    )
 
 
 def make_microbatch_constrain(
@@ -771,12 +787,12 @@ class Trainer:
             opt_state=opt_shardings,
             model_state=ms_shardings,
         )
-        self._train_step = jax.jit(
-            self._step_impl,
-            donate_argnums=(0,),
-            out_shardings=(self._state_shardings, None),
-        )
+        self._step_fns: Dict[Any, Callable] = {}
         self._epoch_fns: Dict[Any, Callable] = {}
+        # How far recomputation by budget engaged in the newest program
+        # (models/remat.py); None until one is built on a device that
+        # reports a memory limit.
+        self.remat_plan: Optional[Dict[str, int]] = None
         self._eval_fns: Dict[Any, Callable] = {}
         self.meter = ThroughputMeter(n_devices=mesh.size)
         self._resumed = False
@@ -828,6 +844,12 @@ class Trainer:
         reg.describe("train_step", "Current global optimizer step")
         reg.describe("train_step_s",
                      "Per-step wall time within the last chunk (s)")
+        reg.describe("train_remat_blocks_kept",
+                     "Blocks of the newest step program that keep their "
+                     "matmul outputs for the backward pass (the rest "
+                     "recompute)")
+        reg.describe("train_remat_kept_bytes",
+                     "Bytes a chip holds of those kept outputs")
         # Anomaly-triggered capture (obs/trace.py): a stall-watermark
         # trip or a guard poisoned verdict auto-arms ONE bounded
         # jax.profiler trace + flight dump, keyed by the triggering
@@ -951,18 +973,122 @@ class Trainer:
 
             lower_args = (self.state,)
 
-        fn = jax.jit(
-            epoch_fn,
-            donate_argnums=(0,),
-            out_shardings=(self._state_shardings, None),
-        )
         # AOT-compile now, outside the caller's timing window: epoch-0
         # throughput previously included XLA compilation (VERDICT r1
         # metering note), forcing benches to discard the whole first
         # epoch. The compiled executable is what gets cached.
-        fn = fn.lower(*lower_args).compile()
+        fn = self._compile_keeping(epoch_fn, lower_args)
         self._epoch_fns[key] = (fn, dataset)
         return fn
+
+    def _remat_budget(self) -> Optional[remat.RematBudget]:
+        """What a chip of this mesh has left for activations, for the
+        model to spend on blocks that keep their matmul outputs
+        (models/remat.py). None where no device reports a limit."""
+        # Only what every process of a multi-host run reads alike may
+        # decide the count (they must all lower the same program): the
+        # limit of the hardware and the state by its shapes, never what
+        # happens to be allocated on this host right now.
+        limit = min(
+            (
+                topology.memory_stats(d).get("bytes_limit") or 0
+                for d in self.mesh.local_devices
+            ),
+            default=0,
+        )
+        if not limit:
+            return None
+        # The gradient tree mirrors the parameters; under accumulation
+        # the scan carries their sum beside each microbatch's own.
+        grads = _bytes_per_device(self.state.params) * (
+            2 if self.cfg.grad_accum_steps > 1 else 1
+        )
+        # A manual gradient sync runs the forward inside a whole-mesh
+        # shard_map over replicated parameters: the model is handed its
+        # own shard of the batch, and nothing is split by a model axis.
+        manual = self.comm_mode_resolved != "flat"
+        return remat.RematBudget(
+            limit_bytes=limit,
+            resident_bytes=_bytes_per_device(self.state),
+            grad_bytes=grads,
+            batch_shards=1 if manual else max(
+                _spec_extent(self.mesh, s.spec)
+                for s in jax.tree.leaves(self.batch_sharding)
+            ),
+            model_shards=1 if manual else self.mesh.shape.get("model", 1),
+        )
+
+    def _compile_keeping(self, fn: Callable, lower_args: Tuple) -> Callable:
+        """Lower ``fn`` (a step or chunk over the donated state) with
+        the remat budget held open, so that a model under ``remat``
+        keeps what fits, and compile it. A compile refused for memory
+        halves the blocks that keep, down to none: the program the
+        budget never touched, which is all a device without a limit
+        ever gets."""
+        budget = self._remat_budget()
+        while True:
+            jitted = jax.jit(
+                fn,
+                donate_argnums=(0,),
+                out_shardings=(self._state_shardings, None),
+            )
+            with remat.lowering_under(budget):
+                lowered = jitted.lower(*lower_args)
+            try:
+                compiled = lowered.compile()
+                break
+            except jax.errors.JaxRuntimeError as e:
+                if (
+                    budget is None or not budget.blocks_kept
+                    or "RESOURCE_EXHAUSTED" not in str(e)
+                ):
+                    raise
+                budget.cap = budget.blocks_kept // 2
+                self.logger.warning(
+                    "remat | the step with %d of %d blocks keeping their "
+                    "matmul outputs was refused for memory; retrying "
+                    "with at most %d | %s",
+                    budget.blocks_kept, budget.n_blocks, budget.cap,
+                    str(e).splitlines()[0],
+                )
+                # jit keeps a trace by the function's identity: a fresh
+                # one (same name, same program text) is traced again,
+                # under the lowered cap.
+                fn = functools.wraps(fn)(functools.partial(fn))
+        self._report_remat(budget)
+        return compiled
+
+    def _report_remat(self, budget: Optional[remat.RematBudget]) -> None:
+        """How far recomputation by budget engaged in the program just
+        built: two gauges, one log line, one run-log record."""
+        reg = obs.get_registry()
+        reg.set_gauge(
+            "train_remat_blocks_kept", budget.blocks_kept if budget else 0
+        )
+        reg.set_gauge(
+            "train_remat_kept_bytes", budget.kept_bytes if budget else 0
+        )
+        if budget is None:
+            return
+        room = max(budget.room_bytes, 0)
+        self.remat_plan = {
+            "blocks_kept": budget.blocks_kept,
+            "kept_bytes": budget.kept_bytes,
+            "n_blocks": budget.n_blocks,
+            "block_bytes": budget.block_bytes,
+            "bytes_limit": budget.limit_bytes,
+            "budget_bytes": room,
+        }
+        self.logger.info(
+            "remat | %d of %d blocks keep their matmul outputs "
+            "(%.3f GiB a chip; room %.3f GiB of a limit of %.3f GiB)",
+            budget.blocks_kept, budget.n_blocks,
+            budget.kept_bytes / 2 ** 30, room / 2 ** 30,
+            budget.limit_bytes / 2 ** 30,
+        )
+        self._append_metrics(
+            {"event": "remat_plan", "time": time.time(), **self.remat_plan}
+        )
 
     def _offset_arg(self, off: int):
         """The chunk's skip-window offset as a mesh-replicated traced
@@ -976,12 +1102,18 @@ class Trainer:
         batch = jax.tree.map(
             lambda a: jax.device_put(a, self.batch_sharding), batch
         )
+        args = (self.state, batch)
         if self._guard_tracked:
-            self.state, metrics = self._train_step(
-                self.state, batch, self._offset_arg(self._fit_offset)
+            args += (self._offset_arg(self._fit_offset),)
+        key = tuple(
+            (a.shape, a.dtype.name) for a in jax.tree.leaves(batch)
+        )
+        fn = self._step_fns.get(key)
+        if fn is None:
+            fn = self._step_fns[key] = self._compile_keeping(
+                self._step_impl, args
             )
-        else:
-            self.state, metrics = self._train_step(self.state, batch)
+        self.state, metrics = fn(*args)
         return metrics
 
     def _dataset_key(self, dataset, *extra):
