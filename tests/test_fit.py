@@ -583,6 +583,97 @@ def test_latent_programs_address_the_pool_in_place(v5e_2x2, program):
     assert temp < 2 * view_tokens * row + 2 * expanded + pool_bytes // 2, temp
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_hybrid_programs_keep_the_state_in_place(v5e_2x2, program):
+    """``serve-docqa-granite4h-small``'s decode and chunk programs,
+    compiled for the chip at the cell's shape (layers 3-6 of its ten:
+    two state-space layers, the attention layer, one more): the
+    recurrent state's two arrays go in and out in their own layout
+    with no copy of either (with heads and head_dim apart the chunk
+    program's products wanted them in the other order and copied all
+    slots' state in and out, 2.3 GB a chunk at ten layers; three
+    convolution rows are no tile), the decode program materialises no
+    per-layer slice of the state, and the tied table is read as it
+    lies: no transposed copy of it for the head."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_hpc.models import hybrid_ssm_moe
+    from tpu_hpc.serve import paging
+
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    slots, capacity, bs, bucket = 16, 30720, 16, 512
+    cfg = dataclasses.replace(
+        hybrid_ssm_moe.GRANITE_4_0_H_SMALL, n_layers=4,
+        layer_types=hybrid_ssm_moe.GRANITE_4_0_H_SMALL.layer_types[3:],
+        max_seq_len=capacity, held_experts=tuple(range(18)),
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    )
+    assert (cfg.n_ssm_layers, cfg.n_attention_layers) == (3, 1)
+    mb = capacity // bs
+    width = mb + bucket // bs
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: hybrid_ssm_moe.init_hybrid_ssm_moe(
+                jax.random.key(0), cfg
+            )
+        ),
+    )
+    pool_shape = (1, slots * mb + 1, cfg.kv_heads, bs, cfg.head_dim)
+    s_shape, rows_shape = cfg.state_shapes(slots)
+    state = [sds(pool_shape, jnp.bfloat16), sds(pool_shape, jnp.bfloat16),
+             sds(s_shape, jnp.float32), sds(rows_shape, jnp.bfloat16)]
+    i32 = jnp.int32
+    if program == "decode":
+        fn = paging.make_paged_decode_fn(cfg, bs, mb, width)
+        args = (sds((slots + len(paging.LATENT_COUNTERS),), i32),
+                sds((len(paging.STEP_ROWS), slots), i32),
+                sds((slots, width), i32))
+    else:
+        fn = paging.make_chunk_prefill_fn(cfg, bucket, bs, mb, width)
+        args = (sds((1, bucket), i32), sds((), i32), sds((), i32),
+                sds((width,), i32), sds((), i32), sds((), i32))
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+        params, *state, *args
+    ).compile()
+
+    def spelled(dtype, shape):
+        return dtype + "[" + ",".join(map(str, shape)) + "]"
+
+    whole = [spelled("bf16", pool_shape), spelled("f32", s_shape),
+             spelled("bf16", rows_shape)]
+    # (the pool has ONE layer here: its "slice" is the pool, bitcast)
+    sliced = [spelled("f32", s_shape[1:]), spelled("bf16", rows_shape[1:])]
+    table = spelled("bf16", (cfg.vocab_size, cfg.dim))
+    flipped = spelled("bf16", (cfg.dim, cfg.vocab_size))
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    seen = 0
+    for result, opcode in _HLO_INSTRUCTION.findall(entry):
+        for shape in whole:
+            if shape in result:
+                seen += 1
+                assert opcode != "copy", f"whole-state copy: {result}"
+        for shape in sliced:
+            if shape in result and program == "decode":
+                assert opcode in ("parameter", "bitcast"), (
+                    f"{opcode} materialises a per-layer slice: {result}"
+                )
+        assert flipped not in result, f"a transposed table: {result}"
+        if table in result:
+            assert opcode == "parameter", f"{opcode} of the table: {result}"
+    assert seen >= 6
+    # a slot's state a layer is 4 MB; nothing state-sized is temporary
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < 2.5 * 2**30, temp
+
+
 def test_keeping_blocks_hold_what_the_model_reckons(v5e_2x2):
     """The real train step (``make_step_fn``: forward, backward, AdamW)
     compiled for the chip twice: with no budget open (every block

@@ -243,6 +243,85 @@ class TestPrefixTrie:
         alloc.check_invariant()
 
 
+class TestTrieSnapshots:
+    """A node's optional snapshot of a recurrent state
+    (``models/hybrid_ssm_moe.py``), host side: the states here are
+    plain strings."""
+
+    def _setup(self, budget):
+        alloc = BlockAllocator(32)
+        trie = PrefixTrie(block_size=2, snapshot_budget=budget)
+        return alloc, trie
+
+    def _chain(self, alloc, trie, prompt):
+        blocks = alloc.alloc(len(prompt) // 2)
+        trie.insert(prompt, blocks, alloc)
+        alloc.release(blocks)
+        return blocks
+
+    def test_a_trie_without_a_budget_takes_none(self):
+        alloc, trie = self._setup(0)
+        self._chain(alloc, trie, [1, 2, 3, 4])
+        assert not trie.put_snapshot([1, 2, 3, 4], 2, ("s",), 10, cost=4)
+        assert trie.deepest_snapshot([1, 2, 3, 4], 2) == (0, None)
+        assert trie.snapshot_bytes == 0
+
+    def test_deepest_snapshot_on_the_chain_and_no_deeper_than_asked(self):
+        alloc, trie = self._setup(100)
+        prompt = [1, 2, 3, 4, 5, 6, 7, 8]
+        self._chain(alloc, trie, prompt)
+        assert trie.put_snapshot(prompt, 1, ("at2",), 10, cost=2)
+        assert trie.put_snapshot(prompt, 3, ("at6",), 10, cost=4)
+        assert not trie.put_snapshot(prompt, 3, ("again",), 10, cost=4)
+        assert not trie.put_snapshot([9, 9], 1, ("gone",), 10, cost=2)
+        assert not trie.put_snapshot(prompt, 0, ("root",), 10, cost=0)
+        depth, snap = trie.deepest_snapshot(prompt, 4)
+        assert (depth, snap.state) == (3, ("at6",))
+        depth, snap = trie.deepest_snapshot(prompt, 2)
+        assert (depth, snap.state) == (1, ("at2",))
+        # another branch shares the first block only
+        depth, snap = trie.deepest_snapshot([1, 2, 9, 9], 2)
+        assert (depth, snap.state) == (1, ("at2",))
+        assert trie.snapshot_bytes == 20
+
+    def test_over_the_budget_the_cheapest_to_rebuild_goes_first(self):
+        """Room for two: a document's snapshot (cost 6) outlasts the
+        never-reused ones of the questions about it (cost 2 each),
+        which go oldest first; plain LRU would drop the document's for
+        the second question."""
+        alloc, trie = self._setup(20)
+        doc = [1, 2, 3, 4, 5, 6]
+        self._chain(alloc, trie, doc)
+        assert trie.put_snapshot(doc, 3, ("doc",), 10, cost=6)
+        for q in range(4):
+            prompt = doc + [10 + q, 20 + q]
+            self._chain(alloc, trie, prompt)
+            assert trie.put_snapshot(prompt, 4, (f"q{q}",), 10, cost=2)
+            assert trie.snapshot_bytes <= 20
+            assert trie.deepest_snapshot(doc, 3)[1].state == ("doc",)
+        assert trie.snapshot_evictions == 3
+        assert trie.deepest_snapshot(doc + [13, 23], 4)[1].state == ("q3",)
+        assert trie.deepest_snapshot(doc + [12, 22], 4)[0] == 3
+        # ... but not for ever: the floor rises with every drop, and a
+        # document nobody asks about any more goes in its turn.
+        other = [7, 7, 8, 8, 9, 9]
+        self._chain(alloc, trie, other)
+        for q in range(12):
+            prompt = other + [30 + q, 40 + q]
+            self._chain(alloc, trie, prompt)
+            trie.put_snapshot(prompt, 4, (f"o{q}",), 10, cost=2)
+        assert trie.deepest_snapshot(doc, 3) == (0, None)
+
+    def test_a_snapshot_dies_with_its_node(self):
+        alloc, trie = self._setup(100)
+        self._chain(alloc, trie, [1, 2, 3, 4])
+        trie.put_snapshot([1, 2, 3, 4], 2, ("s",), 10, cost=4)
+        assert trie.evict(alloc, 1) == 1          # the leaf, LRU
+        assert trie.snapshot_bytes == 0
+        assert trie.deepest_snapshot([1, 2, 3, 4], 2) == (0, None)
+        alloc.check_invariant()
+
+
 # ---------------------------------------------------------------------
 # Token exactness
 # ---------------------------------------------------------------------

@@ -40,7 +40,7 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import latent_moe, llama2, sparse_moe
+from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
 from tpu_hpc.parallel import hybrid, tp
 from tpu_hpc.parallel.plans import derived_pspecs, shardings_for
 
@@ -97,6 +97,11 @@ def kv_cache_bytes(
         cfg, "the slab KV cache's size (checks/fit.py)",
         "the slab engine keeps per-head keys and values",
     )
+    hybrid_ssm_moe.refuse(
+        cfg, "the slab KV cache's size (checks/fit.py)",
+        "the slab engine keeps keys and values for every layer and no "
+        "recurrent state",
+    )
     s = max_seq_len if max_seq_len is not None else cfg.max_seq_len
     itemsize = jnp.dtype(cache_dtype).itemsize
     return (
@@ -113,12 +118,16 @@ def param_counts(cfg: llama2.LlamaConfig) -> Dict[str, int]:
     active (``experts_per_token`` experts a layer), sixteen times
     apart at Keye-VL-2.0-30B-A3B's 8 of 128. A latent configuration
     (``models/latent_moe.py``) counts by kind of layer: its leading
-    dense layers, then expert layers with the experts HELD here."""
+    dense layers, then expert layers with the experts HELD here; one
+    with state-space layers (``models/hybrid_ssm_moe.py``) by each
+    layer's own mixer."""
     counts = None
     if sparse_moe.is_sparse_moe(cfg):
         counts = sparse_moe.count_params(cfg)
     elif latent_moe.is_latent_moe(cfg):
         counts = latent_moe.count_params(cfg)
+    elif hybrid_ssm_moe.is_hybrid_ssm_moe(cfg):
+        counts = hybrid_ssm_moe.count_params(cfg)
     if counts is not None:
         return {"total": counts["total"], "active": counts["active"]}
     n = llama2.count_params(cfg)
@@ -154,7 +163,20 @@ def kv_paged_bytes(
     the engine's ``cache_bytes`` counts too. A latent configuration
     (``models/latent_moe.py``) keeps ONE row a token a layer, the
     latent and the rotary key (``latent_dim`` numbers: 1152 B in bf16
-    at 512 + 64), nothing per head, and has no int8 page."""
+    at 512 + 64), nothing per head, and has no int8 page. One with
+    state-space layers (``models/hybrid_ssm_moe.py``) keeps pages for
+    its ATTENTION layers only; the recurrent state of the others is a
+    fixed size a slot (``cfg.state_bytes(slots)``), no part of the
+    pool, and it has no int8 page either."""
+    layers = cfg.n_layers
+    if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg):
+        if kv_quant != "none":
+            hybrid_ssm_moe.refuse(
+                cfg, "an int8 page pool",
+                "the int8 pool has not been held to this decoder's "
+                "reference",
+            )
+        layers = cfg.n_attention_layers
     if latent_moe.is_latent_moe(cfg):
         if kv_quant != "none":
             latent_moe.refuse(
@@ -180,7 +202,7 @@ def kv_paged_bytes(
         return page_bytes + scale_bytes
     itemsize = jnp.dtype(cache_dtype).itemsize
     return (
-        num_blocks * block_size * cfg.n_layers * cfg.kv_heads
+        num_blocks * block_size * layers * cfg.kv_heads
         * cfg.head_dim * 2 * itemsize
     )
 

@@ -584,11 +584,12 @@ def _dense_only(cfg: LlamaConfig, who: str) -> None:
     experts (``models/sparse_moe.py``) is refused, never run as a
     dense model of its widths."""
     if getattr(cfg, "n_experts", 0):
-        from tpu_hpc.models import latent_moe, sparse_moe
+        from tpu_hpc.models import hybrid_ssm_moe, latent_moe, sparse_moe
 
         why = "models/llama2.py builds the dense block only"
         sparse_moe.refuse(cfg, who, why)
         latent_moe.refuse(cfg, who, why)
+        hybrid_ssm_moe.refuse(cfg, who, why)
 
 
 def init_llama(

@@ -44,9 +44,9 @@ EDGE_KEYS = ("tok_embeddings", "norm", "output")
 
 
 def layers_per_stage(cfg: LlamaConfig, n_stages: int) -> int:
-    from tpu_hpc.models import latent_moe, sparse_moe
+    from tpu_hpc.models import hybrid_ssm_moe, latent_moe, sparse_moe
 
-    for model in (sparse_moe, latent_moe):
+    for model in (sparse_moe, latent_moe, hybrid_ssm_moe):
         model.refuse(
             cfg, "the pipeline split (models/llama_pp.py)",
             "its stages are llama2's dense blocks",

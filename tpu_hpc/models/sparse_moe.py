@@ -249,8 +249,19 @@ def _dot(x, kernel, dtype):
 def route(h, lp, cfg: SparseMoEConfig):
     """``h [..., dim]`` -> ``(gates [..., k] float32, experts [..., k]
     int32)``: softmax over ALL experts, the exact top-k (ties to the
-    lower id), gates renormalised over the chosen."""
-    logits = _dot(h, lp["moe"]["router"]["kernel"], cfg.dtype)
+    lower id), gates renormalised over the chosen. A configuration
+    that keeps its residual stream float32 (``residual_dtype``:
+    ``models/hybrid_ssm_moe.py``) has the router's product in float32
+    too, as ``latent_moe.route`` does and for its reason."""
+    kernel = lp["moe"]["router"]["kernel"]
+    if getattr(cfg, "residual_dtype", None) is None:
+        logits = _dot(h, kernel, cfg.dtype)
+    else:
+        logits = jax.lax.dot_general(
+            h.astype(jnp.float32), kernel.astype(jnp.float32),
+            (((h.ndim - 1,), (0,)), ((), ())),
+            precision=jax.lax.Precision.HIGHEST,
+        )
     probs = jax.nn.softmax(logits, axis=-1)
     gates, experts = jax.lax.top_k(probs, cfg.experts_per_token)
     if cfg.norm_topk_prob:

@@ -102,6 +102,10 @@ STAGE_SPANS: Dict[str, str] = {
     "prefill.prep": "pad the chunk, executable lookup, transfers",
     "prefill.dispatch": "the chunk executable's call (async enqueue)",
     "prefill.fetch": "the final chunk's first-token fetch",
+    "prefill.snapshot": "hand the prefix trie a copy of a slot's "
+                        "recurrent state; drop snapshots over budget",
+    "admit.restore": "put a new tenant's recurrent state into its "
+                     "slot: a snapshot out of the prefix trie, or zeros",
     "chunk.dispatch": "the scanned chunk's call, or the per-step loop",
     "chunk.fetch": "the one device_get a chunk (the chunk barrier)",
     "chunk.host": "a fit outside dispatch and fetch: from a fetch's "

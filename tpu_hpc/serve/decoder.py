@@ -16,18 +16,23 @@ and that is the **attention state** the program hands the loop: a
 callable ``(layer, h, lp, q, k, v) -> attended rows`` that lives next
 to the cache it knows (engine.py: slab rows; paging.py:
 ``PagedAttention``, the page pool with its int8 scales, Pallas kernels
-and indexer keys). A configuration's new stage is an edit to the loop
-(a stage every cache sees the same way, as the feed-forward in
-:func:`_ffn_stage`) or to one attention state (a stage that reads or
-writes the cache), never to a program.
+and indexer keys). A layer whose mixer is a state-space one
+(``models/hybrid_ssm_moe.py``; told by the weights it holds, as the
+feed-forward is) goes through the program's **recurrent state**
+instead, ``(layer, lp, xbc, dt) -> y`` (paging.py:
+``RecurrentState``, a state a slot beside the pool). A configuration's
+new stage is an edit to the loop (a stage every cache sees the same
+way, as the feed-forward in :func:`_ffn_stage`) or to one such state
+(a stage that reads or writes what is cached), never to a program.
 
 The loop names its stages for the trace
 (docs/guide/observability.md, "Stage names"): ``qkv`` and ``attn_out``
 here, ``mlp`` or ``router`` / ``experts`` in :func:`_ffn_stage`,
 ``kv_write``, ``indexer``, ``kv_read`` and ``attention`` in the
-attention state, ``embed`` and ``head`` in the program. An operation's
-stage is the LAST of these names on its path, so no stage wraps
-another.
+attention state, ``ssm_in`` and ``ssm_out`` here and ``ssm_conv`` and
+``ssm_scan`` in the recurrent state for a state-space layer, ``embed``
+and ``head`` in the program. An operation's stage is the LAST of these
+names on its path, so no stage wraps another.
 """
 from __future__ import annotations
 
@@ -36,7 +41,7 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from tpu_hpc.models import latent_moe, llama2, sparse_moe
+from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
 
 
 def _dense(x: jax.Array, kernel: jax.Array, dtype) -> jax.Array:
@@ -63,9 +68,26 @@ def _embed(params: Dict, tokens: jax.Array, cfg: llama2.LlamaConfig):
     table = params["tok_embeddings"]["embedding"].astype(cfg.dtype)
     x = jnp.take(table, tokens, axis=0)
     # The residual stream starts here: in the compute dtype, unless the
-    # configuration keeps what it adds up wider (``residual_dtype``).
+    # configuration keeps what it adds up wider (``residual_dtype``),
+    # and at the configuration's multiple of the row, if it names one.
     stream = getattr(cfg, "residual_dtype", None)
-    return x if stream is None else x.astype(stream)
+    x = x if stream is None else x.astype(stream)
+    scale = getattr(cfg, "embedding_multiplier", 1.0)
+    return x if scale == 1.0 else x * scale
+
+
+def _residual(x, y, cfg):
+    """``x + y``, ``y`` at the configuration's ``residual_multiplier``
+    where it names one."""
+    scale = getattr(cfg, "residual_multiplier", 1.0)
+    return x + y if scale == 1.0 else x + scale * y.astype(x.dtype)
+
+
+def _score_scale(cfg):
+    """What attention multiplies its scores by: ``head_dim ** -0.5``,
+    or the configuration's ``attention_multiplier``."""
+    scale = getattr(cfg, "attention_multiplier", None)
+    return cfg.head_dim ** -0.5 if scale is None else scale
 
 
 def _attn_out_proj(h, lp, cfg):
@@ -113,16 +135,16 @@ def _rope_tables(cfg, n, positions=None):
 def _grouped_attention(q, k, v, mask, cfg, scale=None):
     """The model's einsum attention with an explicit mask: scores in
     the compute dtype, fp32 softmax, GQA via the grouped query view
-    (llama2.Attention's no-repeat-KV contraction). ``scale`` is
-    ``head_dim ** -0.5`` unless the caller names another (a latent
-    configuration's expanded read, whose keys are wider than its
-    values: the result has the values' width)."""
+    (llama2.Attention's no-repeat-KV contraction). ``scale`` is the
+    configuration's (:func:`_score_scale`) unless the caller names
+    another (a latent configuration's expanded read, whose keys are
+    wider than its values: the result has the values' width)."""
     b, s_q = q.shape[0], q.shape[1]
     n_kv = cfg.kv_heads
     groups = cfg.n_heads // n_kv
     qg = q.reshape(b, s_q, n_kv, groups, q.shape[-1])
     if scale is None:
-        scale = cfg.head_dim ** -0.5
+        scale = _score_scale(cfg)
     scores = jnp.einsum("bqhgd,bkhd->bhgqk", qg, k) * scale
     scores = scores.astype(jnp.float32)
     scores = jnp.where(mask, scores, -jnp.inf)
@@ -166,7 +188,7 @@ def _grouped_attention_paged(q, k_pages, v_pages, mask, cfg):
     groups = cfg.n_heads // n_kv
     n_pages, block_size = k_pages.shape[1], k_pages.shape[3]
     qg = q.reshape(b, s_q, n_kv, groups, cfg.head_dim)
-    scale = cfg.head_dim ** -0.5
+    scale = _score_scale(cfg)
     scores = jnp.einsum("bqhgd,bphkd->bhgqpk", qg, k_pages) * scale
     scores = scores.reshape(b, n_kv, groups, s_q, n_pages * block_size)
     scores = scores.astype(jnp.float32)
@@ -197,7 +219,7 @@ def _grouped_attention_flat(q, k_pages, v_pages, own, mask, cfg):
     groups = cfg.n_heads // n_kv
     n_pages = k_pages.shape[0]
     exact = jax.lax.Precision.HIGHEST
-    scale = cfg.head_dim ** -0.5
+    scale = _score_scale(cfg)
     own_f = own.astype(jnp.float32)
     q_of = jnp.einsum(
         "ps,sf->pf", own.astype(q.dtype), q.reshape(slots, -1)
@@ -230,16 +252,24 @@ def _logits_head(x, params, cfg):
     compute dtype unless the configuration names a ``residual_dtype``
     (``models/latent_moe.py``: float32 out of the same product of
     compute-dtype operands, so that the arg-max over 129280 logits is
-    not decided by their own rounding)."""
+    not decided by their own rounding). A configuration that ties its
+    two ends (``tie_word_embeddings``) has no ``output``: the product
+    contracts the embedding table's second axis, the one array read as
+    it lies, and its logits are divided by ``logits_scaling``."""
     x = _rmsnorm(x, params["norm"]["scale"], cfg.norm_eps)
-    kernel = params["output"]["kernel"]
-    if getattr(cfg, "residual_dtype", None) is None:
-        return _dense(x, kernel, cfg.dtype)
-    return jax.lax.dot_general(
+    if getattr(cfg, "tie_word_embeddings", False):
+        kernel, axis = params["tok_embeddings"]["embedding"], 1
+    else:
+        kernel, axis = params["output"]["kernel"], 0
+        if getattr(cfg, "residual_dtype", None) is None:
+            return _dense(x, kernel, cfg.dtype)
+    logits = jax.lax.dot_general(
         x.astype(cfg.dtype), kernel.astype(cfg.dtype),
-        (((x.ndim - 1,), (0,)), ((), ())),
-        preferred_element_type=cfg.residual_dtype,
+        (((x.ndim - 1,), (axis,)), ((), ())),
+        preferred_element_type=getattr(cfg, "residual_dtype", None),
     )
+    scale = getattr(cfg, "logits_scaling", 1.0)
+    return logits if scale == 1.0 else logits / scale
 
 
 def _ffn_stage(x, lp, cfg, weight=None):
@@ -254,7 +284,7 @@ def _ffn_stage(x, lp, cfg, weight=None):
     if "moe" not in lp:
         with scope("mlp"):
             h = _rmsnorm(x, lp["ffn_norm"]["scale"], cfg.norm_eps)
-            x = x + _mlp(h, lp["feed_forward"], cfg)
+            x = _residual(x, _mlp(h, lp["feed_forward"], cfg), cfg)
         return x, None
     route = latent_moe.route if latent_moe.is_latent_moe(cfg) \
         else sparse_moe.route
@@ -269,13 +299,13 @@ def _ffn_stage(x, lp, cfg, weight=None):
             h, gates, experts, lp, cfg, weight=weight
         )
         if shared is None:
-            x = x + y.reshape(b, s, d).astype(x.dtype)
+            x = _residual(x, y.reshape(b, s, d).astype(x.dtype), cfg)
     if shared is not None:
         with scope("mlp"):
             # Routed and shared parts meet in float32 and reach the
             # residual stream in ONE rounding of it.
             y = y.astype(jnp.float32) + _mlp(h, shared, cfg)
-            x = x + y.reshape(b, s, d).astype(x.dtype)
+            x = _residual(x, y.reshape(b, s, d).astype(x.dtype), cfg)
     return x, counts
 
 
@@ -283,11 +313,15 @@ def _project(h, lp, cfg, cos, sin):
     """The layer's projections of the normed input, rotated by the
     program's ``cos`` / ``sin`` tables: what :func:`decoder_layers`
     hands the attention state as ``q, k, v``. Per-head queries, keys
-    and values (:func:`_qkv`), or a latent configuration's queries,
-    latent row and rotary key (``latent_moe.project``)."""
+    and values (:func:`_qkv`; rotated by nothing where the
+    configuration's ``position_embedding`` is "nope"), or a latent
+    configuration's queries, latent row and rotary key
+    (``latent_moe.project``)."""
     if latent_moe.is_latent_moe(cfg):
         return latent_moe.project(h, lp, cfg, cos, sin)
     q, k, v = _qkv(h, lp, cfg)
+    if getattr(cfg, "position_embedding", "rope") == "nope":
+        return q, k, v
     # [s, D/2] tables rotate every row alike, [b, s, D/2] each to its
     # own position (apply_rope broadcasts either shape).
     q = llama2.apply_rope(q, cos, sin)
@@ -295,9 +329,18 @@ def _project(h, lp, cfg, cos, sin):
     return q, k, v
 
 
-def decoder_layers(params, cfg, x, cos, sin, attend, weight=None):
+def decoder_layers(params, cfg, x, cos, sin, attend, weight=None,
+                   recur=None):
     """Every layer of the decoder over ``x [b, s, dim]`` -> ``(x,
-    counts)``. A layer is: ``qkv`` (the norm and :func:`_project`: the
+    counts)``. A layer's mixer is attention, or, where the layer holds
+    a state-space mixer's weights (``ssm``:
+    ``models/hybrid_ssm_moe.py``): ``ssm_in`` (the norm and the input
+    projection); ``recur(layer, lp, xbc, dt)``, the program's recurrent
+    state, which runs the convolution and the recurrence over the
+    state it keeps for the step's sequences, leaves both advanced and
+    returns ``y [b, s, heads, head_dim]``; ``ssm_out`` (the gated norm,
+    the output projection and its residual). An attention layer is:
+    ``qkv`` (the norm and :func:`_project`: the
     configuration's projections, rotated by the program's ``cos`` /
     ``sin`` tables); ``attend(layer, h, lp, q, k, v)``, the program's
     attention state, which writes this step's K/V (a latent
@@ -313,12 +356,22 @@ def decoder_layers(params, cfg, x, cos, sin, attend, weight=None):
     counts = {}
     for i in range(cfg.n_layers):
         lp = params[f"layers_{i}"]
-        with scope("qkv"):
-            h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
-            q, k, v = _project(h, lp, cfg, cos, sin)
-        attn = attend(i, h, lp, q, k, v)
-        with scope("attn_out"):
-            x = x + _attn_out_proj(attn, lp, cfg)
+        if "ssm" in lp:
+            with scope("ssm_in"):
+                h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
+                z, xbc, dt = hybrid_ssm_moe.in_proj(h, lp, cfg)
+            y = recur(i, lp, xbc, dt)
+            with scope("ssm_out"):
+                x = _residual(
+                    x, hybrid_ssm_moe.out_proj(y, z, lp, cfg), cfg
+                )
+        else:
+            with scope("qkv"):
+                h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
+                q, k, v = _project(h, lp, cfg, cos, sin)
+            attn = attend(i, h, lp, q, k, v)
+            with scope("attn_out"):
+                x = _residual(x, _attn_out_proj(attn, lp, cfg), cfg)
         x, moe = _ffn_stage(x, lp, cfg, weight=weight)
         for name, value in (moe or {}).items():
             with scope("experts"):

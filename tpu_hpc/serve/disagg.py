@@ -48,7 +48,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import latent_moe, llama2, sparse_moe
+from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
 from tpu_hpc.obs import get_registry, span
 from tpu_hpc.serve.engine import Engine, ServeConfig
 
@@ -140,6 +140,11 @@ class DisaggEngine:
             cfg, "disaggregated serving (serve/disagg.py)",
             "the cross-tier hop ships per-head keys and values, not "
             "latent rows",
+        )
+        hybrid_ssm_moe.refuse(
+            cfg, "disaggregated serving (serve/disagg.py)",
+            "the cross-tier hop ships pages, not the recurrent state "
+            "a prefilled sequence also leaves",
         )
         self.cfg = cfg
         self.serve_cfg = serve_cfg

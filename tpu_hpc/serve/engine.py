@@ -37,7 +37,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import latent_moe, llama2, sparse_moe
+from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
 from tpu_hpc.obs import get_registry, span
 from tpu_hpc.serve.decoder import (
     _embed,
@@ -232,6 +232,11 @@ class Engine:
                 cfg, "the slab Engine",
                 "its cache holds per-head keys and values, and its "
                 "programs have no absorbed latent read",
+            )
+            hybrid_ssm_moe.refuse(
+                cfg, "the slab Engine",
+                "its cache holds keys and values only, no recurrent "
+                "state a slot",
             )
         if cfg.n_heads % cfg.kv_heads:
             raise ValueError(

@@ -69,7 +69,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import latent_moe, llama2, sparse_moe
+from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
 from tpu_hpc.kernels.paged_attention import (
     INT8_SCALE_FLOOR,
     dequantize_pages_int8,
@@ -171,11 +171,49 @@ LATENT_PAGES_LIVE = (
 )
 
 
+# What an engine with a recurrent state beside its pages counts
+# (``models/hybrid_ssm_moe.py``), on the host where it admits, snapshots
+# and dispatches; and its two gauges.
+SSM_COUNTERS: Tuple[Tuple[str, str], ...] = (
+    ("serve_ssm_slot_steps_total",
+     "Slots whose recurrent state a decode step advanced (the active "
+     "ones), summed over decode steps"),
+    ("serve_ssm_snapshots_total",
+     "Copies of a slot's recurrent state the prefix trie took (a "
+     "finished prompt's last full block, or the end of a page match "
+     "no snapshot covered)"),
+    ("serve_ssm_restores_total",
+     "Admissions whose recurrent state was restored from a snapshot "
+     "in the prefix trie"),
+    ("serve_ssm_restored_tokens_total",
+     "Prompt tokens whose recurrent state came from a snapshot "
+     "(the snapshot's depth, an admission)"),
+    ("serve_ssm_snapshot_evictions_total",
+     "Snapshots dropped to stay under the snapshot byte budget"),
+)
+# The same count as ``LATENT_PAGES_LIVE`` over the pages of such an
+# engine's attention layers: what the floor of its decode step reads.
+KV_PAGES_LIVE = (
+    "serve_kv_pages_live_total",
+    "Distinct KV pages that at least one active slot read, summed over "
+    "decode steps (a page several slots share counts once)",
+)
+SSM_GAUGES: Tuple[Tuple[str, str], ...] = (
+    ("serve_ssm_state_bytes",
+     "Bytes of recurrent state the engine holds for its slots"),
+    ("serve_ssm_snapshot_bytes",
+     "Bytes of recurrent-state snapshots the prefix trie holds"),
+)
+
+
 def step_counters(cfg) -> Tuple[Tuple[str, str, str], ...]:
     """What the configuration's decode step packs behind its tokens."""
     if sparse_moe.is_sparse_moe(cfg):
         return SPARSE_COUNTERS
-    return LATENT_COUNTERS if latent_moe.is_latent_moe(cfg) else ()
+    if latent_moe.is_latent_moe(cfg) \
+            or hybrid_ssm_moe.is_hybrid_ssm_moe(cfg):
+        return LATENT_COUNTERS
+    return ()
 
 
 class BlockBudgetError(RuntimeError):
@@ -219,7 +257,12 @@ class PagedConfig:
     gather-then-dense oracle, for float32, bfloat16 and int8 alike
     (PERF.md, PR 21 sweep). HBM stores a page short of a tile
     unpadded -- XLA narrows the tiling -- so no dtype needs a larger
-    minimum; which size is FASTEST is not measured."""
+    minimum; which size is FASTEST is not measured.
+
+    ``ssm_snapshot_bytes``: what the prefix trie may hold in snapshots
+    of a recurrent state (a configuration with state-space layers
+    only: ``models/hybrid_ssm_moe.py``); ``None`` is as many snapshots
+    as the engine has slots."""
 
     block_size: int = 16
     num_blocks: int = 64
@@ -228,6 +271,7 @@ class PagedConfig:
     host_blocks: int = 0
     kernel: str = "gather"
     kv_quant: str = "none"
+    ssm_snapshot_bytes: Optional[int] = None
 
     def __post_init__(self):
         if self.block_size < 1:
@@ -649,6 +693,24 @@ class _TrieNode:
     # block's K/V while it is spilled out of HBM; None = device-
     # resident (block is the live page id; spilled nodes park -1).
     host: Optional[int] = None
+    # The recurrent state of a sequence that has read the chain down
+    # to this block, where the trie holds one (a configuration with
+    # state-space layers only).
+    snapshot: Optional["_Snapshot"] = None
+
+
+@dataclasses.dataclass
+class _Snapshot:
+    """A copy of one slot's recurrent state at a block boundary, the
+    trie's: ``state`` are the device arrays, ``cost`` the prompt tokens
+    whoever left it had prefilled since the state IT started from (what
+    a later request saves by finding it), ``credit`` its standing in
+    the eviction order."""
+
+    state: Tuple[Any, ...]
+    nbytes: int
+    cost: int
+    credit: float = 0.0
 
 
 class PrefixTrie:
@@ -662,13 +724,32 @@ class PrefixTrie:
     its chain, so leaves must go first), and releasing the trie's
     reference frees the page only when no live request still holds
     it -- which is exactly why a prefix hit stays token-exact after
-    the original owner was evicted."""
+    the original owner was evicted.
 
-    def __init__(self, block_size: int):
+    A configuration with state-space layers keeps a recurrent state a
+    sequence beside its pages, and a shared page is only worth having
+    where the state AT THAT POSITION is there too: a node may hold a
+    **snapshot** of it (:meth:`put_snapshot`), and an admission uses a
+    page match only down to :meth:`deepest_snapshot`. Snapshots live
+    under ``snapshot_budget`` bytes and die with their node. Over the
+    budget the one worth least goes: each carries ``credit = floor +
+    cost``, its cost the tokens it saves, refreshed when it is restored
+    from, and ``floor`` rises to the credit of whatever was last
+    dropped (GreedyDual: plain LRU where every cost is the same). A
+    document's snapshot at 28672 tokens so outlasts hundreds of
+    never-reused 300-token ones of the questions asked about it, where
+    LRU would drop it for the first that finds the budget full."""
+
+    def __init__(self, block_size: int, snapshot_budget: int = 0):
         self.block_size = block_size
         self._root: Dict[Tuple[int, ...], _TrieNode] = {}
         self._clock = 0
         self.nodes = 0
+        self.snapshot_budget = snapshot_budget
+        self.snapshot_bytes = 0
+        self.snapshot_evictions = 0
+        self._snapshots: Dict[int, _TrieNode] = {}   # id(node) -> node
+        self._floor = 0.0
 
     def _tick(self) -> int:
         self._clock += 1
@@ -700,6 +781,74 @@ class PrefixTrie:
             blocks.append(node.block)
             level = node.children
         return blocks
+
+    def _node_at(
+        self, prompt: Sequence[int], n_blocks: int
+    ) -> Optional[_TrieNode]:
+        level, node = self._root, None
+        for key in self._full_blocks(prompt)[:n_blocks]:
+            node = level.get(key)
+            if node is None:
+                return None
+            level = node.children
+        return node
+
+    def deepest_snapshot(
+        self, prompt: Sequence[int], n_blocks: int
+    ) -> Tuple[int, Optional[_Snapshot]]:
+        """``(depth in blocks, snapshot)`` of the deepest node among
+        the leading ``n_blocks`` of ``prompt``'s chain that holds one;
+        ``(0, None)`` where none does. The caller restores from it, so
+        its standing is refreshed."""
+        found: Tuple[int, Optional[_Snapshot]] = (0, None)
+        level = self._root
+        for depth, key in enumerate(
+            self._full_blocks(prompt)[:n_blocks], 1
+        ):
+            node = level.get(key)
+            if node is None:
+                break
+            if node.snapshot is not None:
+                found = (depth, node.snapshot)
+            level = node.children
+        if found[1] is not None:
+            found[1].credit = self._floor + found[1].cost
+        return found
+
+    def put_snapshot(
+        self, prompt: Sequence[int], n_blocks: int, state: Tuple[Any, ...],
+        nbytes: int, cost: int,
+    ) -> bool:
+        """Leave ``state``, a sequence's recurrent state after the
+        leading ``n_blocks`` full blocks of ``prompt``, at that node.
+        An existing snapshot wins (it is the same state); a chain the
+        trie no longer holds, or a budget too small for one, takes
+        none. Then drops snapshots, least credit first, until the
+        budget holds. Returns whether the node took it."""
+        node = self._node_at(prompt, n_blocks) if n_blocks else None
+        if node is None or node.snapshot is not None \
+                or nbytes > self.snapshot_budget:
+            return False
+        node.snapshot = _Snapshot(
+            state, nbytes, cost, credit=self._floor + cost
+        )
+        self._snapshots[id(node)] = node
+        self.snapshot_bytes += nbytes
+        while self.snapshot_bytes > self.snapshot_budget:
+            victim = min(
+                self._snapshots.values(),
+                key=lambda n: (n.snapshot.credit, n.last_used),
+            )
+            self._floor = victim.snapshot.credit
+            self._drop_snapshot(victim)
+            self.snapshot_evictions += 1
+        return node.snapshot is not None
+
+    def _drop_snapshot(self, node: _TrieNode) -> None:
+        if node.snapshot is not None:
+            self.snapshot_bytes -= node.snapshot.nbytes
+            del self._snapshots[id(node)]
+            node.snapshot = None
 
     def spilled_chain(
         self, prompt: Sequence[int]
@@ -833,6 +982,7 @@ class PrefixTrie:
                 for _, level, key, node in leaves:
                     del level[key]
                     self.nodes -= 1
+                    self._drop_snapshot(node)
                     freed += allocator.release([node.block])
                     if freed >= n_needed:
                         break
@@ -858,21 +1008,28 @@ class PrefixTrie:
 # ---------------------------------------------------------------------
 
 
-def _with_state(body, name: str, quant: bool, sparse: bool):
-    """A program body ``(params, ks, vs, ksc, vsc, xs, *args) -> (ks,
-    vs, ksc, vsc, xs, *results)`` under the signature the pool has:
-    ``(params, ks, vs, *args)``, with ``ksc, vsc`` after ``vs`` for an
-    int8 pool and ``xs`` after those for a sparse-expert
-    configuration, in the arguments and in the results alike. ``name``
-    is the program's (the jitted module's, which the trace reports)."""
+def _with_state(body, name: str, quant: bool, sparse: bool,
+                recurrent: bool = False):
+    """A program body ``(params, ks, vs, ksc, vsc, xs, rec, *args) ->
+    (ks, vs, ksc, vsc, xs, *results)`` under the signature the engine's
+    state has: ``(params, ks, vs, *args)``, with ``ksc, vsc`` after
+    ``vs`` for an int8 pool, ``xs`` after those for a sparse-expert
+    configuration, and the recurrent state's two arrays (``rec``; the
+    body returns them ahead of its results) after those for one with
+    state-space layers, in the arguments and in the results alike.
+    ``name`` is the program's (the jitted module's, which the trace
+    reports)."""
 
     def program(params, ks, vs, *rest):
         n = 2 * quant + sparse
         extra, args = rest[:n], rest[n:]
         ksc, vsc = extra[:2] if quant else (None, None)
         xs = extra[-1] if sparse else None
+        rec = None
+        if recurrent:
+            rec, args = args[:2], args[2:]
         ks, vs, ksc, vsc, xs, *out = body(
-            params, ks, vs, ksc, vsc, xs, *args
+            params, ks, vs, ksc, vsc, xs, rec, *args
         )
         return (
             *(a for a in (ks, vs, ksc, vsc, xs) if a is not None), *out
@@ -897,6 +1054,12 @@ def _check_read_path(cfg, kernel: str, kv_quant: str) -> None:
             "the Pallas kernels contract per-head K and V pages and "
             "the int8 page write quantises them; a latent page has "
             "neither",
+        )
+        hybrid_ssm_moe.refuse(
+            cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
+            "the Pallas kernels scale scores by head_dim ** -0.5, not "
+            "by the configuration's multiplier, and neither they nor "
+            "the int8 pool have been held to this decoder's reference",
         )
 
 
@@ -995,6 +1158,8 @@ class PagedAttention:
         self.quant = kv_quant == "int8"
         self.sparse = sparse_moe.is_sparse_moe(cfg)
         self.latent = latent_moe.is_latent_moe(cfg)
+        # A stack with state-space layers: pages for the others only.
+        self.hybrid = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
         self.kernel = None
         if kernel == "pallas":
             self.kernel = _on_mesh(
@@ -1105,6 +1270,9 @@ class PagedAttention:
         )
 
     def __call__(self, layer, h, lp, q, k, v):
+        if self.hybrid:
+            # The pool has a row for each ATTENTION layer.
+            layer = self.cfg.state_layer(layer)
         if self.latent:
             self._write_latent(layer, k, v)
             return self._read_latent(layer, lp, q)
@@ -1317,6 +1485,129 @@ class PagedAttention:
             )
 
 
+class RecurrentState:
+    """The recurrent state of every program of a configuration with
+    state-space layers (``models/hybrid_ssm_moe.py``), beside the page
+    pool: ``ss [ssm layers, slots, heads * head_dim, state]``, the
+    recurrence's ``S``, and ``sc [ssm layers, slots, (taps - 1) *
+    conv_dim]``, the pre-convolution rows the next token's convolution
+    comes behind, both in ``ssm_state_dtype``
+    (``HybridSSMMoEConfig.state_shapes`` says why both lie flat). A
+    fixed size a SLOT, not a row a
+    token: no table names it, nothing of it is shared between slots,
+    and what the prefix trie keeps of it is a copy at one position
+    (``PrefixTrie.put_snapshot``).
+
+    ``serve/decoder.py``'s layer loop calls it once a state-space
+    layer, with the layer's projected rows, where it calls
+    :class:`PagedAttention` for an attention layer; it is built, copied
+    over a call's arrays (:meth:`on`) and handed back (:meth:`state`)
+    the same way. A layer is, by stage name:
+
+    * ``ssm_conv`` -- the causal convolution behind the slot's kept
+      rows, which are replaced by the last ``taps - 1`` REAL rows;
+    * ``ssm_scan`` -- the step size and decay, the state's read, the
+      recurrence, ``y = S C + D x`` and the state's write.
+
+    A chunk (:meth:`run`: one slot's run of rows) uses the chunked form
+    and masks its bucket's padded rows out (``dt`` 0: they decay
+    nothing and leave nothing), so the state it leaves is the state
+    after ``true_len`` rows; it also keeps each layer's state after the
+    leading ``snap_len`` rows (:meth:`snapshot`), which the engine
+    hands the trie where it wants one at that position and drops
+    otherwise: the state at a block boundary INSIDE a chunk, for the
+    price of one more small product and with no chunk cut short for
+    it. A row step (:meth:`rows`: every slot, one token) uses the
+    one-step form and leaves a slot that is not ``active`` as it was,
+    bit for bit: a free slot's, and one in the middle of its prompt,
+    whose chunks ride between decode steps."""
+
+    def __init__(self, cfg, chunk: bool = False):
+        self.cfg, self.chunk = cfg, chunk
+
+    def on(self, ss, sc):
+        state = copy.copy(self)
+        state.ss, state.sc = ss, sc
+        state.snaps = []
+        return state
+
+    def state(self):
+        return self.ss, self.sc
+
+    def run(self, slot, true_len, snap_len):
+        """What a chunk advances: ``slot``'s state, by the leading
+        ``true_len`` of its rows."""
+        self.slot, self.true_len, self.snap_len = slot, true_len, snap_len
+
+    def rows(self, active):
+        """What a row step advances: the ``active`` slots' states."""
+        self.active = active > 0
+
+    def snapshot(self):
+        """Every layer's state after the chunk's leading ``snap_len``
+        rows, laid out as a slot's: ``([ssm layers, heads * head_dim,
+        state], [ssm layers, (taps - 1) * conv_dim])``."""
+        s, conv = zip(*self.snaps)
+        return jnp.stack(s), jnp.stack(conv)
+
+    def __call__(self, layer, lp, xbc, dt):
+        cfg, scope = self.cfg, jax.named_scope
+        j = cfg.state_layer(layer)
+        d_skip = lp["ssm"]["D"].astype(jnp.float32)[:, None]
+        heads = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
+        taps = (cfg.ssm_conv - 1, cfg.conv_dim)
+
+        def flat_s(a):      # [..., heads, head_dim, state], as kept
+            return a.reshape(*a.shape[:-3], -1, a.shape[-1]).astype(
+                self.ss.dtype
+            )
+
+        def flat_rows(a):   # [..., taps - 1, conv_dim], as kept
+            return a.reshape(*a.shape[:-2], -1).astype(self.sc.dtype)
+
+        if self.chunk:
+            n = xbc.shape[1]
+            with scope("ssm_conv"):
+                conv, kept, snap_rows = hybrid_ssm_moe.conv_chunk(
+                    xbc[0], self.sc[j, self.slot].reshape(taps), lp,
+                    self.true_len, self.snap_len,
+                )
+                self.sc = self.sc.at[j, self.slot].set(flat_rows(kept))
+            with scope("ssm_scan"):
+                x, b, c = hybrid_ssm_moe.split_xbc(conv, cfg)
+                step, a = hybrid_ssm_moe.discretise(dt[0], lp)
+                step = jnp.where(
+                    (jnp.arange(n) < self.true_len)[:, None], step, 0.0
+                )
+                y, after, snap = hybrid_ssm_moe.scan_chunk(
+                    x, step, a, b, c, self.ss[j, self.slot].reshape(heads),
+                    cfg.ssm_chunk, self.snap_len,
+                )
+                self.ss = self.ss.at[j, self.slot].set(flat_s(after))
+                self.snaps.append(
+                    (flat_s(snap), flat_rows(snap_rows))
+                )
+                return (y + d_skip * x)[None]
+        slots = xbc.shape[0]
+        with scope("ssm_conv"):
+            conv, kept = hybrid_ssm_moe.conv_step(
+                xbc[:, 0], self.sc[j].reshape(slots, *taps), lp
+            )
+            self.sc = self.sc.at[j].set(jnp.where(
+                self.active[:, None], flat_rows(kept), self.sc[j]
+            ))
+        with scope("ssm_scan"):
+            x, b, c = hybrid_ssm_moe.split_xbc(conv, cfg)
+            step, a = hybrid_ssm_moe.discretise(dt[:, 0], lp)
+            y, after = hybrid_ssm_moe.scan_step(
+                x, step, a, b, c, self.ss[j].reshape(slots, *heads)
+            )
+            self.ss = self.ss.at[j].set(jnp.where(
+                self.active[:, None, None], flat_s(after), self.ss[j]
+            ))
+            return (y + d_skip * x)[:, None]
+
+
 def make_chunk_logits_fn(
     cfg: llama2.LlamaConfig,
     bucket: int,
@@ -1347,6 +1638,12 @@ def make_chunk_logits_fn(
     The pool's arrays follow ``vs`` in the arguments and the results
     alike (:func:`_with_state`): ``ksc, vsc`` for ``kv_quant="int8"``,
     ``xs`` for a sparse-expert configuration (``models/sparse_moe.py``).
+    A configuration with state-space layers
+    (``models/hybrid_ssm_moe.py``) has its recurrent state's two arrays
+    there too, takes ``slot, snap_len`` after ``table`` (whose state
+    the chunk advances; the row count at which it also keeps a copy)
+    and returns that copy's two arrays after the logits
+    (:class:`RecurrentState`).
 
     ``table_width > max_blocks``: the trailing entries are scratch
     padding, so a bucket-padded write near the capacity edge can
@@ -1358,11 +1655,17 @@ def make_chunk_logits_fn(
     attention = PagedAttention(
         cfg, block_size, max_blocks, kernel, kv_quant, mesh, chunk=True
     )
+    recurrent = RecurrentState(cfg, chunk=True) \
+        if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg) else None
 
-    def body(params, ks, vs, ksc, vsc, xs, tokens, start, true_len,
-             table):
+    def body(params, ks, vs, ksc, vsc, xs, rec, tokens, start, true_len,
+             table, *where):
         scope = jax.named_scope
         pool = attention.on(ks, vs, ksc, vsc, xs)
+        recur = None
+        if recurrent is not None:
+            recur = recurrent.on(*rec)
+            recur.run(where[0], true_len, where[1])
         with scope("embed"):
             x = _embed(params, tokens, cfg)
         qpos = start + jnp.arange(bucket)
@@ -1374,17 +1677,20 @@ def make_chunk_logits_fn(
         )
         pool.view(table, start)
         pool.pages(blk_ids, qpos, mask)
-        x, _ = decoder_layers(params, cfg, x, cos, sin, pool)
+        x, _ = decoder_layers(params, cfg, x, cos, sin, pool, recur=recur)
         with scope("head"):
             last = jax.lax.dynamic_slice(
                 x, (0, true_len - 1, 0), (1, 1, cfg.dim)
             )
             logits = _logits_head(last, params, cfg)
-        return *pool.state(), logits[0, 0]
+        if recur is None:
+            return *pool.state(), logits[0, 0]
+        return *pool.state(), *recur.state(), logits[0, 0], \
+            *recur.snapshot()
 
     return _with_state(
         body, "chunk_logits_q" if attention.quant else "chunk_logits",
-        attention.quant, attention.sparse,
+        attention.quant, attention.sparse, recurrent is not None,
     )
 
 
@@ -1405,11 +1711,15 @@ def make_chunk_prefill_fn(
         kernel=kernel, kv_quant=kv_quant, mesh=mesh,
     )
 
+    # What follows the logits: a recurrent state's snapshot.
+    after = 2 * hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
+
     def chunk_prefill(params, *args):
-        *state, logits = inner(params, *args)
+        out = inner(params, *args)
+        at = len(out) - 1 - after
         with jax.named_scope("head"):
-            tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (*state, tok)
+            tok = jnp.argmax(out[at], axis=-1).astype(jnp.int32)
+        return (*out[:at], tok, *out[at + 1:])
 
     if kv_quant == "int8":
         chunk_prefill.__name__ = "chunk_prefill_q"
@@ -1471,6 +1781,11 @@ def make_paged_decode_fn(
     A latent configuration (``models/latent_moe.py``) runs it with the
     absorbed read over its headless pool and packs its expert layers'
     counts the same way (``LATENT_COUNTERS``).
+    A configuration with state-space layers
+    (``models/hybrid_ssm_moe.py``) runs it with its recurrent state's
+    two arrays behind the pool's (:class:`RecurrentState`): an active
+    slot's state advances by its token, any other slot's stays as it
+    was; its expert layers' counts ride as a latent configuration's.
     ``probe=True`` (such configurations only) also returns each layer's
     selection ``[layers, slots, columns]``: the benchmark's check
     reads it, no serving path does.
@@ -1489,18 +1804,21 @@ def make_paged_decode_fn(
     attention = PagedAttention(
         cfg, block_size, max_blocks, kernel, kv_quant, mesh
     )
+    recurrent = RecurrentState(cfg) \
+        if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg) else None
     if flat_pages is not None and (
         attention.sparse or attention.latent
-        or attention.kernel is not None
+        or attention.kernel is not None or recurrent is not None
     ):
         raise ValueError(
             "flat_pages is the gather read of a dense configuration: an "
             "indexer ranks the columns of each slot's own view, a latent "
-            "page is smaller than its owner's query, and a table-walking "
-            "kernel reads no view at all"
+            "page is smaller than its owner's query, a table-walking "
+            "kernel reads no view at all, and a stack with state-space "
+            "layers has not been measured on it"
         )
 
-    def body(params, ks, vs, ksc, vsc, xs, prev, step, tables):
+    def body(params, ks, vs, ksc, vsc, xs, rec, prev, step, tables):
         scope = jax.named_scope
         pool = attention.on(ks, vs, ksc, vsc, xs)
         host_tokens, pos, active, fresh = step
@@ -1525,8 +1843,12 @@ def make_paged_decode_fn(
         else:
             pool.live_pages(tables, pos, active, flat_pages)
         pool.rows(pb, off, mask, slot=rows)
+        recur = None
+        if recurrent is not None:
+            recur = recurrent.on(*rec)
+            recur.rows(active)
         x, counts = decoder_layers(
-            params, cfg, x, cos, sin, pool, weight=active
+            params, cfg, x, cos, sin, pool, weight=active, recur=recur
         )
         with scope("head"):
             logits = _logits_head(x, params, cfg)
@@ -1542,11 +1864,13 @@ def make_paged_decode_fn(
             # The selection each layer made, for the benchmark's
             # check against the reference: [layers, slots, columns].
             return *pool.state(), tok, jnp.stack(pool.picked)
+        if recur is not None:
+            return *pool.state(), *recur.state(), tok
         return *pool.state(), tok
 
     return _with_state(
         body, "decode_q" if attention.quant else "decode",
-        attention.quant, attention.sparse,
+        attention.quant, attention.sparse, recurrent is not None,
     )
 
 
@@ -1556,7 +1880,9 @@ def make_copy_block_fn():
     copy-on-write. Keys and values, an int8 pool's scale entries (a
     copied page that kept the source's bytes but not its scale would
     dequantize to garbage) and a sparse-expert configuration's indexer
-    keys all index pages on axis 1, so one rule moves them all."""
+    keys all index pages on axis 1, so one rule moves them all. (A
+    slot's recurrent state is nobody else's: nothing of it is ever
+    copied on a write.)"""
 
     def copy_block(*args):
         *state, src, dst = args
@@ -1569,6 +1895,22 @@ def make_copy_block_fn():
         )
 
     return copy_block
+
+
+def make_restore_state_fn():
+    """``(ss, sc, snap_s, snap_conv, slot)``: put one sequence's
+    recurrent state (a snapshot out of the prefix trie, or zeros) into
+    ``slot``'s rows of the engine's (:class:`RecurrentState`) -- the
+    device half of an admission."""
+
+    def ssm_restore(ss, sc, snap_s, snap_conv, slot):
+        return tuple(
+            jax.lax.dynamic_update_slice_in_dim(
+                a, snap[:, None].astype(a.dtype), slot, axis=1
+            ) for a, snap in ((ss, snap_s), (sc, snap_conv))
+        )
+
+    return ssm_restore
 
 
 # ---------------------------------------------------------------------
@@ -1592,6 +1934,12 @@ class _PagedSlot:
     seed: int = 0
     temperature: float = 0.0
     top_p: float = 1.0
+    # A configuration with a recurrent state: the prompt tokens whose
+    # state came out of a snapshot, and where (in tokens; 0: nowhere)
+    # the request's page match ran past that snapshot, so that its
+    # prefill leaves one there.
+    restored: int = 0
+    branch: int = 0
 
 
 class _LivePages:
@@ -1647,7 +1995,11 @@ class PagedEngine(Engine):
       reservation for prompt + max_new (no mid-flight OOM: a request
       that admits always finishes), chunk plan; raises
       :class:`BlockBudgetError` when the pool is transiently full
-      (after trying to reclaim trie-only pages);
+      (after trying to reclaim trie-only pages). A configuration with
+      a recurrent state (:class:`RecurrentState`) shares pages only as
+      deep as the trie also holds that state, restores the slot's from
+      the snapshot there (else zeroes it), and its prefill leaves
+      snapshots behind for the next;
     * :meth:`prefill_step` -- run the next chunk; returns the first
       greedy token once the prompt is fully prefilled (and registers
       the prompt's full pages in the trie);
@@ -1702,6 +2054,12 @@ class PagedEngine(Engine):
                 "the latent page has no head axis to shard and the "
                 "latent projections have no tensor-parallel plan",
             )
+            hybrid_ssm_moe.refuse(
+                cfg, "a serving mesh with a tensor axis",
+                "the recurrent state's heads, the state-space mixer's "
+                "projections and the held experts have no "
+                "tensor-parallel plan",
+            )
         per_seq = serve_cfg.max_seq_len // bs
         # A pool SMALLER than one full-capacity sequence is legal --
         # it simply cannot serve max-length requests, and
@@ -1722,8 +2080,10 @@ class PagedEngine(Engine):
         # it (``decode_rungs``).
         self.view_pages = serve_cfg.slots * per_seq
         self._flat_rungs: Tuple[int, ...] = ()
+        self._recurrent = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
         if paged.kernel == "gather" and not (
             sparse_moe.is_sparse_moe(cfg) or latent_moe.is_latent_moe(cfg)
+            or self._recurrent
         ):
             self._flat_rungs = tuple(sorted(
                 {int(self.view_pages * r) for r in FLAT_RUNGS} - {0}
@@ -1740,7 +2100,7 @@ class PagedEngine(Engine):
             paged.num_blocks, host_blocks=paged.host_blocks
         )
         self.trie: Optional[PrefixTrie] = (
-            PrefixTrie(bs) if paged.prefix_cache else None
+            self._new_trie() if paged.prefix_cache else None
         )
         # Host-DRAM page tier (serve/tier.py): parked pages spill to
         # host buffers under pool pressure and prefetch back on a
@@ -1782,12 +2142,22 @@ class PagedEngine(Engine):
         counters = DECODE_COUNTERS \
             + tuple(c[1:] for c in self._step_counters)
         # The distinct pages the active slots read (a latent
-        # configuration only: its read's roofline counts a shared page
-        # once).
+        # configuration, and one with a recurrent state: their
+        # rooflines count a shared page once).
         self._live_pages: Optional[_LivePages] = None
-        if latent_moe.is_latent_moe(cfg):
+        if latent_moe.is_latent_moe(cfg) or self._recurrent:
             self._live_pages = _LivePages(bs)
-            counters += (LATENT_PAGES_LIVE,)
+            self._live_pages_total = (
+                KV_PAGES_LIVE if self._recurrent else LATENT_PAGES_LIVE
+            )
+            counters += (self._live_pages_total,)
+        if self._recurrent:
+            counters += SSM_COUNTERS
+            for name, help_ in SSM_GAUGES:
+                get_registry().describe(name, help_)
+            get_registry().set_gauge(
+                "serve_ssm_state_bytes", self.ssm_state_bytes
+            )
         for name, help_ in counters:
             self.paged_stats[name] = 0
             get_registry().describe(name, help_)
@@ -1810,11 +2180,23 @@ class PagedEngine(Engine):
 
     # -- cache layout overrides ----------------------------------------
     def _cache_shape(self) -> Tuple[int, ...]:
+        # A layer that attends keeps pages; a state-space layer keeps
+        # a state a slot (``_init_cache``).
+        layers = self.cfg.n_attention_layers if self._recurrent \
+            else self.cfg.n_layers
         return (
-            self.cfg.n_layers, self.paged.num_blocks,
+            layers, self.paged.num_blocks,
             self.cfg.kv_heads, self.paged.block_size,
             self.cfg.head_dim,
         )
+
+    def _new_trie(self) -> PrefixTrie:
+        budget = 0
+        if self._recurrent:
+            budget = self.paged.ssm_snapshot_bytes
+            if budget is None:
+                budget = self.serve_cfg.slots * self._snapshot_nbytes
+        return PrefixTrie(self.paged.block_size, snapshot_budget=budget)
 
     def _cache_pspec(self) -> P:
         if latent_moe.is_latent_moe(self.cfg):
@@ -1828,7 +2210,28 @@ class PagedEngine(Engine):
         page -- sharding them would turn every page write into a
         collective for 4 bytes). ``cache_bytes`` counts both, which is
         what makes the fit-report capacity claim honest."""
-        self.xs = None
+        self.xs = self.ssm_s = self.ssm_conv = None
+        if self._recurrent:
+            # The recurrent state beside the pool: ``S`` and the
+            # convolution rows of every state-space layer, a slot; and
+            # the state of a sequence that has read nothing, which an
+            # admission with no snapshot to restore from puts there.
+            cfg = self.cfg
+            dtype = jnp.dtype(cfg.ssm_state_dtype)
+            shapes = cfg.state_shapes(self.serve_cfg.slots)
+            one = tuple(
+                shape[:1] + shape[2:] for shape in cfg.state_shapes(1)
+            )
+            made = jax.jit(
+                lambda: tuple(
+                    jnp.zeros(shape, dtype) for shape in shapes + one
+                ),
+                out_shardings=(self._rep,) * 4,
+            )()
+            self.ssm_s, self.ssm_conv = made[:2]
+            self._no_state = made[2:]
+            self.ssm_state_bytes = cfg.state_bytes(self.serve_cfg.slots)
+            self._snapshot_nbytes = cfg.state_bytes()
         if latent_moe.is_latent_moe(self.cfg):
             # One row a token and nothing per head: the latent in
             # ``ks``, its rotary key in ``vs`` (``rope_pack`` a row).
@@ -1899,16 +2302,18 @@ class PagedEngine(Engine):
         )
 
     # -- the pool's arrays, in the programs' argument order ------------
-    _STATE = ("ks", "vs", "k_scales", "v_scales", "xs")
+    _PAGED = ("ks", "vs", "k_scales", "v_scales", "xs")
+    _STATE = _PAGED + ("ssm_s", "ssm_conv")
 
-    def _state(self) -> List[Any]:
+    def _state(self, names: Tuple[str, ...] = _STATE) -> List[Any]:
         """Keys, values (a latent pool: latents, rotary keys), then an
         int8 pool's two scale arrays, then a sparse-expert
-        configuration's indexer keys: what every paged program takes
-        after the weights, donates and returns first."""
+        configuration's indexer keys, then the recurrent state's two
+        arrays of one with state-space layers: what every paged program
+        takes after the weights, donates and returns first. ``_PAGED``
+        names the arrays that are indexed by page."""
         return [
-            a for a in (getattr(self, n) for n in self._STATE)
-            if a is not None
+            a for a in (getattr(self, n) for n in names) if a is not None
         ]
 
     def _set_state(self, out) -> Any:
@@ -1964,6 +2369,8 @@ class PagedEngine(Engine):
             )
             args = (params_abs,) + state + (tokens, scalar, scalar,
                                             table)
+            if self._recurrent:
+                args += (scalar, scalar)        # slot, snap_len
         elif key[0] in ("decode", "decode_probe"):
             # ("decode",) is the rectangle, ("decode", pages) a flat
             # rung: one name in the trace, so the per-scope readers
@@ -1985,19 +2392,32 @@ class PagedEngine(Engine):
                 (slots, self.table_width), jnp.int32, sharding=self._rep
             )
             args = (params_abs,) + state + (prev, step, tables)
+        elif key[0] == "ssm_restore":
+            snap = tuple(
+                jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=self._rep)
+                for a in self._no_state
+            )
+            return jax.jit(
+                make_restore_state_fn(), donate_argnums=(0, 1),
+                out_shardings=(self._rep,) * 2,
+            ).lower(*state[-2:], *snap, scalar).compile()
         else:  # ("copy_block",)
             fn = make_copy_block_fn()
+            n_paged = len(self._state(self._PAGED))
             jitted = jax.jit(
                 fn,
-                donate_argnums=tuple(range(len(state))),
-                out_shardings=state_shardings,
+                donate_argnums=tuple(range(n_paged)),
+                out_shardings=state_shardings[:n_paged],
             )
-            return jitted.lower(*state, scalar, scalar).compile()
+            return jitted.lower(*state[:n_paged], scalar, scalar).compile()
         jitted = jax.jit(
             fn,
             donate_argnums=tuple(range(1, 1 + len(state))),
+            # After the state: the token(s); a probe's selection, or a
+            # chunk's snapshot of the recurrent state.
             out_shardings=state_shardings + (self._rep,) * (
-                2 if key[0] == "decode_probe" else 1
+                2 if key[0] == "decode_probe"
+                else 3 if key[0] == "prefill" and self._recurrent else 1
             ),
         )
         return jitted.lower(*args).compile()
@@ -2024,6 +2444,8 @@ class PagedEngine(Engine):
             self._get_exec(("decode", pages))
         self._get_exec(("decode",))
         self._get_exec(("copy_block",))
+        if self._recurrent:
+            self._get_exec(("ssm_restore",))
         if self.host_tier is not None:
             self.host_tier.warmup()
         return self.compile_count
@@ -2075,6 +2497,12 @@ class PagedEngine(Engine):
             f"serve_kv_blocks_used{self.gauge_suffix}",
             self.allocator.used_blocks,
         )
+        if self._recurrent and self.trie is not None:
+            # (snapshots die with evicted nodes too: read here, where
+            # pages are taken and released)
+            reg.set_gauge(
+                "serve_ssm_snapshot_bytes", self.trie.snapshot_bytes
+            )
 
     @property
     def block_occupancy(self) -> float:
@@ -2134,8 +2562,11 @@ class PagedEngine(Engine):
             self.serve_cfg.bucket_for(prompt_len)
 
     def _chunk_plan(
-        self, start: int, prompt_len: int
+        self, start: int, prompt_len: int, split: int = 0
     ) -> List[Tuple[int, int, int]]:
+        """``(start, run, bucket)`` chunks over ``[start,
+        prompt_len)``; one of them ends at ``split`` where that lies
+        inside (a block boundary: chunks still start on pages)."""
         plan = []
         pos = start
         stride = self.paged.prefill_chunk or None
@@ -2143,6 +2574,8 @@ class PagedEngine(Engine):
             run = prompt_len - pos
             if stride is not None:
                 run = min(stride, run)
+            if pos < split:
+                run = min(run, split - pos)
             plan.append((pos, run, self.serve_cfg.bucket_for(run)))
             pos += run
         return plan
@@ -2181,6 +2614,19 @@ class PagedEngine(Engine):
             # logits, which a fully-cached prompt would never compute.
             while shared and len(shared) * self.paged.block_size >= plen:
                 shared.pop()
+        snapshot, branch = None, 0
+        if self._recurrent and shared:
+            # Pages are worth sharing only as deep as the trie also
+            # holds the recurrent state at that very position: cut the
+            # match back to the deepest snapshot on its chain, and have
+            # the prefill leave one where the match ended, so that the
+            # next request on this branch shares all of it.
+            depth, snapshot = self.trie.deepest_snapshot(
+                prompt, len(shared)
+            )
+            if depth < len(shared):
+                branch = len(shared) * self.paged.block_size
+            shared = shared[:depth]
         self.allocator.retain(shared)
         fresh_needed = need - len(shared)
         short = fresh_needed - self.allocator.free_blocks
@@ -2200,7 +2646,7 @@ class PagedEngine(Engine):
             self.allocator.release(shared)
             raise
         start = len(shared) * self.paged.block_size
-        plan = self._chunk_plan(start, plen) if run_prefill else []
+        plan = self._chunk_plan(start, plen, branch) if run_prefill else []
         seed, temperature, top_p = sampling or (0, 0.0, 1.0)
         state = _PagedSlot(
             prompt=list(int(t) for t in prompt),
@@ -2210,9 +2656,12 @@ class PagedEngine(Engine):
             plan=plan,
             seed=int(seed), temperature=float(temperature),
             top_p=float(top_p),
+            restored=start if snapshot is not None else 0, branch=branch,
         )
         self._slot_state[slot] = state
         self._write_table(slot, state.blocks)
+        if self._recurrent:
+            self._restore_state(slot, snapshot, state.restored)
         if self.spec is not None:
             self.spec.on_admit(slot, prompt, max_new)
         bus = get_bus()
@@ -2246,6 +2695,40 @@ class PagedEngine(Engine):
             "chunks": len(plan),
             "planned_prefill_tokens": sum(b for _, _, b in plan),
         }
+
+    def _restore_state(
+        self, slot: int, snapshot: Optional[_Snapshot], tokens: int
+    ) -> None:
+        """``slot``'s recurrent state for a new tenant: the snapshot
+        its shared pages end at, else that of a sequence that has read
+        nothing. Dispatched behind whatever step is in flight, so a
+        step computed past the last tenant's end (``decode``) advanced
+        a state nobody reads again."""
+        with span("admit.restore"):
+            self.ssm_s, self.ssm_conv = self._get_exec(("ssm_restore",))(
+                self.ssm_s, self.ssm_conv,
+                *(self._no_state if snapshot is None else snapshot.state),
+                self._rep_arr(slot),
+            )
+        if snapshot is not None:
+            self._count("serve_ssm_restores_total")
+            self._count("serve_ssm_restored_tokens_total", tokens)
+
+    def _leave_snapshot(self, st: _PagedSlot, tokens: int, state) -> None:
+        """Hand the trie ``state``, the recurrent state of ``st``'s
+        prompt after its leading ``tokens`` (a block boundary)."""
+        with span("prefill.snapshot"):
+            dropped = self.trie.snapshot_evictions
+            if self.trie.put_snapshot(
+                st.prompt, tokens // self.paged.block_size, state,
+                self._snapshot_nbytes, cost=tokens - st.restored,
+            ):
+                self._count("serve_ssm_snapshots_total")
+            self._count(
+                "serve_ssm_snapshot_evictions_total",
+                self.trie.snapshot_evictions - dropped,
+            )
+            self._set_block_gauges()
 
     def prefetch_prompt(self, prompt: Sequence[int]) -> int:
         """Refill host-spilled prefix pages for ``prompt`` back into
@@ -2298,6 +2781,17 @@ class PagedEngine(Engine):
         if st.next_chunk >= len(st.plan):
             raise ValueError(f"slot {slot} has no prefill pending")
         start, run, bucket = st.plan[st.next_chunk]
+        bs = self.paged.block_size
+        n_full = len(st.prompt) // bs
+        # Where this chunk also keeps a copy of the recurrent state
+        # (rows into it): at the prompt's last full block, which lies
+        # in the last chunk, and at the end of a chunk that ends where
+        # the page match did (``admit``).
+        snap_len = 0
+        if st.next_chunk == len(st.plan) - 1:
+            snap_len = n_full * bs - start
+        elif start + run == st.branch:
+            snap_len = run
         with span("prefill", hist="serve_prefill_s", n=bucket):
             with span("prefill.prep"):
                 padded = np.zeros((1, bucket), np.int32)
@@ -2307,6 +2801,8 @@ class PagedEngine(Engine):
                     self._rep_arr(run),
                     self._rep_arr(self._tables[slot]),
                 ]
+                if self._recurrent:
+                    args += [self._rep_arr(slot), self._rep_arr(snap_len)]
                 if self.spec is not None:
                     # The sampled prefill variant: same layer loop,
                     # seeded temperature/top-p first-token head (only
@@ -2322,7 +2818,10 @@ class PagedEngine(Engine):
                 else:
                     exec_ = self._get_exec(("prefill", bucket))
             with span("prefill.dispatch"):
-                tok = self._set_state(exec_(*args))
+                out = exec_(*args)
+                tok = self._set_state(out)
+            if st.branch == start + run and self.trie is not None:
+                self._leave_snapshot(st, st.branch, tuple(out[-2:]))
             st.next_chunk += 1
             st.forwarded += bucket
             self.prefill_forwarded_total += bucket
@@ -2331,12 +2830,12 @@ class PagedEngine(Engine):
                 return None
             with span("prefill.fetch"):
                 first = int(tok)
-        if self.trie is not None:
-            n_full = len(st.prompt) // self.paged.block_size
-            if n_full:
-                self.trie.insert(
-                    st.prompt, st.blocks[:n_full], self.allocator
-                )
+        if self.trie is not None and n_full:
+            self.trie.insert(
+                st.prompt, st.blocks[:n_full], self.allocator
+            )
+            if self._recurrent:
+                self._leave_snapshot(st, n_full * bs, tuple(out[-2:]))
         if self.spec is not None:
             self.spec.on_prefill_done(slot)
         return first
@@ -2356,7 +2855,8 @@ class PagedEngine(Engine):
         new, copied = self.allocator.cow(blk)
         if copied:
             self._set_state(self._get_exec(("copy_block",))(
-                *self._state(), self._rep_arr(blk), self._rep_arr(new),
+                *self._state(self._PAGED), self._rep_arr(blk),
+                self._rep_arr(new),
             ))
             st.blocks[idx] = new
             if self._live_pages is not None:
@@ -2467,7 +2967,12 @@ class PagedEngine(Engine):
                 )
                 if self._live_pages is not None:
                     self._count(
-                        LATENT_PAGES_LIVE[0], len(self._live_pages)
+                        self._live_pages_total[0], len(self._live_pages)
+                    )
+                if self._recurrent:
+                    self._count(
+                        "serve_ssm_slot_steps_total",
+                        int(self._on_device.sum()),
                     )
             if not self.decode_lag:
                 return self.flush()
@@ -2597,7 +3102,7 @@ class PagedEngine(Engine):
             self.paged.num_blocks, host_blocks=self.paged.host_blocks
         )
         if self.trie is not None:
-            self.trie = PrefixTrie(self.paged.block_size)
+            self.trie = self._new_trie()
         if self.host_tier is not None:
             # Host pages also encode old-weight K/V: flush them too.
             self.host_tier.reset()
@@ -2648,6 +3153,13 @@ class PagedEngine(Engine):
             "prefill_chunks": s["prefill_chunks"],
             "cow_copies": s["cow_copies"],
             "trie_evictions": s["trie_evictions"],
+            **({
+                "ssm_state_bytes": self.ssm_state_bytes,
+                "ssm_snapshot_bytes": self.trie.snapshot_bytes
+                if self.trie is not None else 0,
+                **{name[len("serve_"):]: s[name]
+                   for name, _ in SSM_COUNTERS},
+            } if self._recurrent else {}),
             **(
                 self.host_tier.summary()
                 if self.host_tier is not None else {}

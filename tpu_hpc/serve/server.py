@@ -27,8 +27,9 @@ import os
 import sys
 from typing import Optional, Sequence
 
+from tpu_hpc.checks.fit import param_counts
 from tpu_hpc.checks.roofline import peak_flops_for_device
-from tpu_hpc.models import llama2
+from tpu_hpc.models import hybrid_ssm_moe, llama2
 
 
 def tiny_config(vocab_size: int = 512) -> llama2.LlamaConfig:
@@ -46,13 +47,30 @@ def build_serving_mesh(n_devices: int, cfg: llama2.LlamaConfig):
     """Serving mesh: TP capped at 4 over ``model`` (head divisibility
     validated), remaining chips over ``data`` for batch slots -- the
     same auto split bench.py's training headline uses
-    (tp.auto_mesh_axes is the single policy both call)."""
+    (tp.auto_mesh_axes is the single policy both call). A decoder with
+    state-space layers (``models/hybrid_ssm_moe.py``) has no
+    tensor-parallel plan: every chip over ``data``."""
     from tpu_hpc.parallel import tp
     from tpu_hpc.runtime import MeshSpec, build_mesh
 
+    if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg):
+        return build_mesh(MeshSpec(axes={"data": n_devices}))
     return build_mesh(MeshSpec(axes=tp.auto_mesh_axes(
         n_devices, cfg.n_heads, cfg.kv_heads, cap=4
     )))
+
+
+def seeded_weights(cfg: llama2.LlamaConfig, seed: int):
+    """Dev-mode weights from ``seed``, by the configuration's own
+    init."""
+    import jax
+
+    key = jax.random.key(seed)
+    if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg):
+        return jax.jit(
+            lambda k: hybrid_ssm_moe.init_hybrid_ssm_moe(k, cfg)
+        )(key)
+    return llama2.init_llama(key, cfg)
 
 
 def build_spec(
@@ -151,7 +169,7 @@ def run_replay(
         if checkpoint_dir:
             params = load_serving_params(checkpoint_dir, cfg, mesh)
         else:
-            params = llama2.init_llama(jax.random.key(seed), cfg)
+            params = seeded_weights(cfg, seed)
     if disagg:
         engine = DisaggEngine(
             params, cfg, serve_cfg, prefill_mesh, decode_mesh,
@@ -202,7 +220,7 @@ def run_replay(
     peak = peak_flops_for_device(jax.devices()[0])
     summary = meter.summary(
         n_devices=jax.device_count(),
-        n_params=llama2.count_params(cfg),
+        n_params=param_counts(cfg)["active"],
         peak_flops_per_device=peak,
     )
     summary.update(
@@ -300,7 +318,7 @@ def run_loadgen(
         if checkpoint_dir:
             params = load_serving_params(checkpoint_dir, cfg, mesh)
         else:
-            params = llama2.init_llama(jax.random.key(seed), cfg)
+            params = seeded_weights(cfg, seed)
     if paged is not None:
         engine = PagedEngine(params, cfg, serve_cfg, mesh, paged)
     else:
@@ -356,7 +374,7 @@ def run_loadgen(
     # its SLO-breach trigger -- counting here would miss it.)
     return harness.summarize(
         n_devices=jax.device_count(),
-        n_params=llama2.count_params(cfg),
+        n_params=param_counts(cfg)["active"],
         peak_flops_per_device=peak,
         extra=extra,
     )
@@ -510,8 +528,11 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
     )
     ap.add_argument(
         "--model", type=str, default="tiny",
-        choices=("tiny", *sorted(llama2.PRESETS)),
-        help="model architecture (tiny = the 8-device-sim config)",
+        choices=(
+            "tiny", *sorted(llama2.PRESETS), *sorted(hybrid_ssm_moe.PRESETS)
+        ),
+        help="model architecture (tiny = the 8-device-sim config; "
+        "granite-4.0-h-small and its sim-sized hybrid-tiny need --paged)",
     )
     ap.add_argument("--vocab", type=int, default=512,
                     help="vocab size for --model tiny")
@@ -915,6 +936,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
 
     if args.model == "tiny":
         cfg = tiny_config(args.vocab)
+    elif args.model in hybrid_ssm_moe.PRESETS:
+        cfg = hybrid_ssm_moe.PRESETS[args.model]
     else:
         cfg = llama2.PRESETS[args.model]
     buckets = tuple(int(b) for b in args.buckets.split(","))

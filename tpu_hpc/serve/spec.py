@@ -61,7 +61,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_hpc.models import latent_moe, llama2, sparse_moe
+from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.decoder import (
     _embed,
@@ -548,6 +548,11 @@ class SpecRunner:
                 "read per-head keys and values, not a latent row (and "
                 "the model's multi-token-prediction module is no "
                 "proposer here)",
+            )
+            hybrid_ssm_moe.refuse(
+                model_cfg, "speculative decoding (serve/spec.py)",
+                "a rejected draft would have to roll the recurrent "
+                "state back, and its programs keep none",
             )
         if cfg.k > max(engine.serve_cfg.prefill_buckets):
             raise ValueError(

@@ -42,7 +42,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from tpu_hpc.models import latent_moe, sparse_moe
+from tpu_hpc.models import hybrid_ssm_moe, latent_moe, sparse_moe
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.paging import SCRATCH_BLOCK, BlockBudgetError
 
@@ -73,6 +73,11 @@ class HostTier:
             engine.cfg, "the host KV tier (serve/tier.py)",
             "it spills and refills a page's keys and values, and a "
             "latent page is one array of rows with no head axis",
+        )
+        hybrid_ssm_moe.refuse(
+            engine.cfg, "the host KV tier (serve/tier.py)",
+            "it spills and refills pages, and a page refilled without "
+            "the recurrent state at its position serves nothing",
         )
         self.engine = engine
         c = engine.cfg

@@ -500,8 +500,13 @@ class Trainer:
         mesh (bench.py: the mode picks the mesh family) pass it here
         so the trainer runs exactly that decision instead of
         re-planning. Ignored unless cfg.comm_mode == "auto"."""
-        from tpu_hpc.models import latent_moe, sparse_moe
+        from tpu_hpc.models import hybrid_ssm_moe, latent_moe, sparse_moe
 
+        hybrid_ssm_moe.refuse_weights(
+            params, "the Trainer",
+            "no training forward, loss or sharding plan goes through "
+            "the state-space mixer or the expert layer",
+        )
         sparse_moe.refuse_weights(
             params, "the Trainer",
             "no training forward, loss or sharding plan goes through "
