@@ -169,6 +169,7 @@ def _per_request(grown):
     return {k: v for k, v in grown.items() if k not in (
         "decode_steps", OVERLAPPED, *VIEW_PAGES,
         "serve_moe_experts_touched_total",
+        "serve_moe_experts_read_total",
         "serve_moe_max_tokens_per_expert",
     )}
 

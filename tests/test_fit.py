@@ -303,6 +303,20 @@ def test_every_pallas_kernel_lowers_for_v5e(v5e_2x2):
             sds((mb + 4,), i32), sds((), i32), scales,
         ) == 1
 
+    # The grouped expert product at Keye's decode shape: 12 rows, a
+    # list of 96 of 128 experts of 2048 x 768 (two whole experts in
+    # fast memory: more than Mosaic's default budget, which the call
+    # raises for itself).
+    from tpu_hpc.kernels import grouped_experts
+
+    stack = sds((128, 2048, 768), jnp.bfloat16)
+    assert mosaic_calls(
+        grouped_experts.grouped_expert_ffn,
+        sds((12, 2048), jnp.bfloat16), sds((12, 128), jnp.float32),
+        sds((96,), i32), sds((), i32), stack, stack,
+        sds((128, 768, 2048), jnp.bfloat16),
+    ) == 1
+
     # The paged decode program, 7B width x 2 layers, on the serving
     # mesh the engine would build on a four-chip host.
     cfg = dataclasses.replace(llama2.PRESETS["7b"], n_layers=2)
@@ -480,7 +494,9 @@ def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_latent_programs_address_the_pool_in_place(v5e_2x2, program):
+def test_latent_programs_address_the_pool_in_place(
+    v5e_2x2, monkeypatch, program
+):
     """``serve-docqa-joyai-flash``'s decode and chunk programs,
     compiled for the chip at the cell's shape (2 of its 7 layers):
     every pool goes in and out in its own layout with no copy of it,
@@ -488,7 +504,12 @@ def test_latent_programs_address_the_pool_in_place(v5e_2x2, program):
     no copy of a gathered view (with no head axis inside a page the
     view's rows ARE its tokens: a page-major contraction made the
     compiler transpose all 566 MB of it a layer) and builds no per-head
-    key or value of the cached tokens."""
+    key or value of the cached tokens. The decode step's ONE expert
+    layer is the grouped kernel, compiled by Mosaic as the chip would
+    (the program asks the backend whether to interpret: here the test
+    answers for the described chip), over the expert stacks as they
+    lie; the chunk keeps the whole-stack product."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     import dataclasses
 
     import jax.numpy as jnp
@@ -548,6 +569,13 @@ def test_latent_programs_address_the_pool_in_place(v5e_2x2, program):
 
     text = compiled.as_text()
     entry = text[text.index("\nENTRY "):]
+    assert text.count("tpu_custom_call") == (program == "decode")
+    for stack in ((64, 2048, 768), (64, 768, 2048)):
+        for result, opcode in _HLO_INSTRUCTION.findall(entry):
+            if spelled(stack) in result:
+                assert opcode == "parameter", (
+                    f"{opcode} copies an expert stack: {result}"
+                )
     pool_results = 0
     for result, opcode in _HLO_INSTRUCTION.findall(entry):
         for shape in pool_shapes:

@@ -546,6 +546,59 @@ def test_the_shares_of_four_chips_add_up_to_the_uncut_layer(params):
     _close(np.asarray(total), np.asarray(want), 1e-5)
 
 
+@pytest.mark.parametrize("case", [
+    "two_rows", "two_rows_one_inactive", "three_rows", "ten_rows",
+])
+def test_the_expert_product_reads_by_the_shape_of_the_step(
+    monkeypatch, params, case
+):
+    """Three rows x three experts a token cover the eight experts (9 >=
+    8): the product reads its whole share, as Granite's decode step
+    does (160 >= 72). Two rows (6 < 8) visit the held experts they
+    touched, and give the same rows and the same counts."""
+    lp = params["layers_1"]
+    rows = {"two_rows": 2, "two_rows_one_inactive": 2, "three_rows": 3,
+            "ten_rows": 10}[case]
+    h = jnp.asarray(
+        np.random.default_rng(4).standard_normal((rows, 64)), jnp.float32
+    )
+    weight = jnp.asarray([0, 1]) if case == "two_rows_one_inactive" \
+        else None
+    gates, experts = sparse_moe.route(h, lp, TINY)
+    got, counts = sparse_moe.expert_ffn(h, gates, experts, lp, TINY, weight)
+    with monkeypatch.context() as m:
+        m.setattr(sparse_moe, "grouped_by_shape", lambda *_: False)
+        dense, dense_counts = sparse_moe.expert_ffn(
+            h, gates, experts, lp, TINY, weight
+        )
+    jaxpr = str(jax.make_jaxpr(
+        lambda h, g, e: sparse_moe.expert_ffn(h, g, e, lp, TINY, weight)
+    )(h, gates, experts))
+    assert ("pallas_call" in jaxpr) == (rows == 2)
+    counted = np.ones(rows, bool) if weight is None \
+        else np.asarray(weight, bool)
+    _close(np.asarray(got)[counted], np.asarray(dense)[counted], 1e-5)
+    assert {k: int(v) for k, v in counts.items()} \
+        == {k: int(v) for k, v in dense_counts.items()}
+    assert int(counts["dropped"]) == 0
+
+
+def test_a_step_that_covers_its_share_reads_every_held_expert(params, mesh):
+    """Three slots x three >= eight experts: the decode program keeps
+    the whole-stack product, and the host counts every held expert of
+    every layer read a step (``moe_experts_read_pct.serve`` 100)."""
+    eng = _engine(params, mesh, prefix_cache=False)
+    rng = np.random.default_rng(29)
+    _serve(eng, {"a": rng.integers(0, TINY.vocab_size, 9).tolist()},
+           max_new=4)
+    stats = eng.paged_stats
+    assert stats["decode_steps"] == 3
+    assert stats["serve_moe_experts_read_total"] \
+        == 3 * len(HELD) * TINY.n_layers
+    assert 0 < stats["serve_moe_experts_touched_total"] \
+        <= stats["serve_moe_experts_read_total"]
+
+
 def test_the_router_is_the_softmax_over_the_chosen_logits(params):
     lp = params["layers_0"]
     h = jnp.asarray(

@@ -392,6 +392,48 @@ def test_the_program_and_the_reference_agree_on_one_share():
     assert int(counts["dropped"]) == 0
 
 
+@pytest.mark.parametrize("case", [
+    "share_even", "share_inactive_slot", "whole_stack", "just_over",
+])
+def test_a_share_visits_the_touched_experts_it_holds(monkeypatch, case):
+    """Through the sigmoid router: three rows x four < sixteen experts,
+    so the product visits the HELD experts the counted rows touched (a
+    share sees its share of the assignments: the same test of the
+    shape), and gives what the whole-stack form gives with identical
+    counts; four rows x four read the whole stack."""
+    lp, x = _layer_inputs()
+    held = None if case == "whole_stack" else (1, 2, 6, 11, 15)
+    tokens = 4 if case == "just_over" else 3
+    cfg = dataclasses.replace(TINY, held_experts=held)
+    lp = lp if held is None else _share(lp, held)
+    h = _norm(x[0, :tokens], lp)
+    gates, experts = latent_moe.route(h, lp, cfg)
+    weight = jnp.asarray([1, 0, 1]) if case == "share_inactive_slot" \
+        else None
+    got, counts = sparse_moe.expert_ffn(h, gates, experts, lp, cfg, weight)
+    with monkeypatch.context() as m:
+        m.setattr(sparse_moe, "grouped_by_shape", lambda *_: False)
+        dense, dense_counts = sparse_moe.expert_ffn(
+            h, gates, experts, lp, cfg, weight
+        )
+    jaxpr = str(jax.make_jaxpr(
+        lambda h, g, e: sparse_moe.expert_ffn(h, g, e, lp, cfg, weight)
+    )(h, gates, experts))
+    assert ("pallas_call" in jaxpr) == (case != "just_over")
+    counted = np.ones(tokens, bool) if weight is None \
+        else np.asarray(weight, bool)
+    np.testing.assert_allclose(
+        np.asarray(got)[counted], np.asarray(dense)[counted],
+        atol=2e-6, rtol=1e-5,
+    )
+    assert {k: int(v) for k, v in counts.items()} \
+        == {k: int(v) for k, v in dense_counts.items()}
+    on_held = set(range(TINY.n_experts) if held is None else held)
+    chosen = set(np.asarray(experts)[counted].ravel().tolist())
+    assert int(counts["experts_touched"]) == len(chosen & on_held)
+    assert int(counts["dropped"]) == 0
+
+
 # -- counters -----------------------------------------------------------------
 def test_counts_come_back_with_the_tokens(params, mesh):
     """``assignments_held`` + the absent ones = ``assignments``;
@@ -410,6 +452,10 @@ def test_counts_come_back_with_the_tokens(params, mesh):
     assert 0 < stats["serve_moe_experts_touched_total"] \
         <= min(held, steps * expert_layers * len(HELD))
     assert stats["serve_moe_max_tokens_per_expert"] == 1
+    # three slots x four < sixteen experts: the products read the held
+    # experts the steps touched, and no other
+    assert stats["serve_moe_experts_read_total"] \
+        == stats["serve_moe_experts_touched_total"]
     assert stats["serve_latent_pages_live_total"] == sum(
         (30 + j) // BLOCK + 1 for j in range(steps)
     )

@@ -249,6 +249,156 @@ def test_no_assignment_is_dropped_when_every_token_picks_the_same_experts():
     }
 
 
+# -- one sum, two traversals ----------------------------------------------
+def _both_forms(monkeypatch, h, gates, experts, lp, cfg, weight=None):
+    """``expert_ffn`` as the shape has it, and with the shape rule
+    saying "dense" on the same inputs."""
+    got = sparse_moe.expert_ffn(h, gates, experts, lp, cfg, weight)
+    with monkeypatch.context() as m:
+        m.setattr(sparse_moe, "grouped_by_shape", lambda *_: False)
+        dense = sparse_moe.expert_ffn(h, gates, experts, lp, cfg, weight)
+    return got, dense
+
+
+def _form_of(fn, *args):
+    """"grouped" or "dense", from the lowered text: the interpreted
+    kernel walks its visit list in a loop, the dense form is three
+    contractions and no loop."""
+    text = jax.jit(fn).lower(*args).as_text()
+    assert ("pallas_call" in str(jax.make_jaxpr(fn)(*args))) \
+        == ("stablehlo.while" in text)
+    return "grouped" if "stablehlo.while" in text else "dense"
+
+
+GROUPED_CASES = {
+    # tokens, held, every token on experts 0 and 1, weight
+    "even": (2, None, False, None),
+    "one_expert_pair": (3, None, True, None),
+    "inactive_slot": (3, None, False, (1, 0, 1)),
+    "no_active_slot": (3, None, False, (0, 0, 0)),
+    "share_with_absent": (3, (1, 2, 6), False, None),
+    "share_and_inactive": (3, (0, 3, 5, 7), False, (0, 1, 1)),
+    "one_token": (1, None, False, None),
+    "just_under": (3, None, False, None),
+    "just_over": (4, None, False, None),
+}
+
+
+@pytest.mark.parametrize("seed", [0, 1])
+@pytest.mark.parametrize("case", sorted(GROUPED_CASES))
+def test_the_grouped_form_is_the_dense_sum_without_its_zero_terms(
+    monkeypatch, case, seed
+):
+    """Where the rows' assignments are fewer than the experts
+    (``tokens * 2 < 8``) the product visits the touched experts alone
+    (the Pallas kernel, interpreted): the rows that count equal the
+    dense form's, the counts are identical, nothing is dropped, and an
+    inactive slot's experts are not on the list."""
+    tokens, held, same, weight = GROUPED_CASES[case]
+    lp = sparse_moe.init_sparse_moe(jax.random.key(5 + seed), TINY)["layers_0"]
+    if same:
+        lp = {**lp, "moe": dict(lp["moe"], router={
+            "kernel": jnp.zeros_like(lp["moe"]["router"]["kernel"])
+        })}
+    cfg = TINY if held is None \
+        else dataclasses.replace(TINY, held_experts=held)
+    lp = lp if held is None else _share(lp, held)
+    h = jax.random.normal(jax.random.key(60 + seed), (tokens, TINY.dim))
+    gates, experts = sparse_moe.route(h, lp, cfg)
+    w = None if weight is None else jnp.asarray(weight)
+    (got, counts), (dense, dense_counts) = _both_forms(
+        monkeypatch, h, gates, experts, lp, cfg, w
+    )
+    form = _form_of(
+        lambda h, g, e: sparse_moe.expert_ffn(h, g, e, lp, cfg, w)[0],
+        h, gates, experts,
+    )
+    assert form == ("dense" if case == "just_over" else "grouped")
+    assert sparse_moe.grouped_by_shape(tokens, cfg) == (form == "grouped")
+    counted = np.ones(tokens, bool) if weight is None \
+        else np.asarray(weight, bool)
+    np.testing.assert_allclose(
+        np.asarray(got)[counted], np.asarray(dense)[counted],
+        atol=1e-6, rtol=1e-5,
+    )
+    if counted.any() and held is None:
+        assert float(jnp.abs(dense[counted]).max()) > 0
+    assert {k: int(v) for k, v in counts.items()} \
+        == {k: int(v) for k, v in dense_counts.items()}
+    assert int(counts["dropped"]) == 0
+    on_held = set(range(TINY.n_experts) if held is None else held)
+    chosen = set(np.asarray(experts)[counted].ravel().tolist())
+    assert int(counts["experts_touched"]) == len(chosen & on_held)
+
+
+@pytest.mark.parametrize("case", [
+    "none", "one", "scattered", "all", "last_only",
+])
+def test_the_visit_list_is_the_touched_rows_then_the_last_repeated(case):
+    touched = {
+        "none": [0] * 8, "one": [0, 0, 1, 0, 0, 0, 0, 0],
+        "scattered": [1, 0, 1, 1, 0, 0, 1, 0], "all": [1] * 8,
+        "last_only": [0] * 7 + [1],
+    }[case]
+    rows = [i for i, t in enumerate(touched) if t]
+    n_visit = 6 if case != "all" else 8
+    visit, n = sparse_moe.visit_list(jnp.asarray(touched, bool), n_visit)
+    assert int(n) == len(rows)
+    want = rows + [rows[-1] if rows else 0] * (n_visit - len(rows))
+    assert np.asarray(visit).tolist() == want and visit.dtype == jnp.int32
+
+
+def _cell_configs():
+    from tpu_hpc.models import hybrid_ssm_moe, latent_moe
+
+    return {
+        # the cell's configuration as it is held, its decode slots
+        "keye": (sparse_moe.KEYE_VL2_30B_A3B, 12),
+        "joyai": (dataclasses.replace(
+            latent_moe.JOYAI_LLM_FLASH, held_experts=tuple(range(64))
+        ), 16),
+        "granite": (dataclasses.replace(
+            hybrid_ssm_moe.GRANITE_4_0_H_SMALL,
+            held_experts=tuple(range(18)),
+        ), 16),
+    }
+
+
+@pytest.mark.parametrize("rows", ["decode", 128, 256, 512])
+@pytest.mark.parametrize("cell", ["keye", "joyai", "granite"])
+def test_the_form_at_the_cells_real_shapes(cell, rows):
+    """By shape, at the three expert cells' published sizes (abstract
+    values only: nothing is allocated): Keye's and JoyAI's decode
+    steps visit the touched experts (96 < 128, 128 < 256), Granite's
+    reads its eighteen whole (160 >= 72), and every chunk bucket of
+    all three is the dense form."""
+    cfg, slots = _cell_configs()[cell]
+    cfg = dataclasses.replace(
+        cfg, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16
+    )
+    tokens = slots if rows == "decode" else rows
+    k, d, f = cfg.experts_per_token, cfg.dim, cfg.expert_hidden
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype)
+
+    lp = {"moe": {
+        "w1": sds((cfg.n_held, d, f), cfg.dtype),
+        "w3": sds((cfg.n_held, d, f), cfg.dtype),
+        "w2": sds((cfg.n_held, f, d), cfg.dtype),
+    }}
+    jaxpr = str(jax.make_jaxpr(
+        lambda h, g, e, lp: sparse_moe.expert_ffn(h, g, e, lp, cfg)
+    )(sds((tokens, d), cfg.dtype), sds((tokens, k), jnp.float32),
+      sds((tokens, k), jnp.int32), lp))
+    grouped = rows == "decode" and cell != "granite"
+    assert ("pallas_call" in jaxpr) == grouped
+    assert sparse_moe.grouped_by_shape(tokens, cfg) == grouped
+    if grouped:
+        # the list is as long as the step can touch, and no longer
+        assert f"grid=({min(cfg.n_held, tokens * k)},)" in jaxpr
+
+
 # -- the selection --------------------------------------------------------
 @pytest.mark.parametrize("case", ["random", "ties", "few_valid", "zeros"])
 def test_select_topk_is_the_exact_top_k_with_ties_to_the_lower_column(case):
@@ -347,6 +497,9 @@ def test_counts_come_back_with_the_tokens(params, mesh):
     assert stats["serve_moe_dropped_total"] == 0
     assert 1 <= stats["serve_moe_max_tokens_per_expert"] <= 1
     assert stats["serve_moe_experts_touched_total"] == steps * 2 * 2
+    # three slots x two < eight experts: the steps' products visited
+    # the experts they touched, and read no other
+    assert stats["serve_moe_experts_read_total"] == steps * 2 * 2
     assert stats["serve_sparse_selected_tokens_total"] == steps * 2 * TOPK
     assert stats["serve_sparse_candidate_tokens_total"] == 2 * sum(
         30 + j + 1 for j in range(steps)

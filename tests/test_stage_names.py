@@ -579,13 +579,19 @@ def _table_of_record():
         return f.read()
 
 
-@pytest.mark.parametrize("name", [n for _, n, _ in paging.SPARSE_COUNTERS])
+@pytest.mark.parametrize(
+    "name", [n for _, n, _ in paging.SPARSE_COUNTERS]
+    + [paging.MOE_EXPERTS_READ[0]],
+)
 def test_sparse_counter_is_described_and_in_the_table_of_record(name):
-    """Each count a sparse-expert decode step returns with its tokens
-    has HELP text in the registry (set when such an engine is built:
+    """Each count a sparse-expert decode step returns with its tokens,
+    and the one the host keeps from them (``MOE_EXPERTS_READ``), has
+    HELP text in the registry (set when such an engine is built:
     tests/test_sparse_moe.py drives them) and a row in the guide."""
     assert f"`{name}`" in _table_of_record()
-    help_ = {n: h for _, n, h in paging.SPARSE_COUNTERS}[name]
+    help_ = dict(
+        [paging.MOE_EXPERTS_READ] + [c[1:] for c in paging.SPARSE_COUNTERS]
+    )[name]
     assert help_ and name.startswith(("serve_moe_", "serve_sparse_"))
 
 

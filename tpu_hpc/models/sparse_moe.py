@@ -33,12 +33,15 @@ checkpoint layout of ``llama2`` where the two share a matrix.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
+from tpu_hpc.kernels import grouped_experts
 from tpu_hpc.models import llama2
 
 
@@ -280,42 +283,149 @@ def _held_slots(cfg: SparseMoEConfig):
     return jnp.asarray(slot, jnp.int32)
 
 
-def expert_ffn(h, gates, experts, lp, cfg: SparseMoEConfig, weight=None):
+def grouped_by_shape(n_tokens: int, cfg: SparseMoEConfig) -> bool:
+    """Whether :func:`expert_ffn` over ``n_tokens`` rows visits the
+    touched experts only: where the rows' assignments are fewer than
+    the experts routed among (``n_tokens * experts_per_token <
+    n_experts``), so that most experts get no token WHATEVER the
+    routing (a share of the experts sees that share of the
+    assignments: the same test). A decode step of a dozen slots passes
+    it, a prefill chunk does not. Measured on the v5e the grouped
+    traversal costs what it touches (12.3 us an expert of 9.44 MB) and
+    meets the dense one only where every expert is touched, so the
+    rule is conservative: it keeps the dense form where a list saves
+    few bytes or none (docs/guide/sparse_moe.md has the numbers). And
+    two whole experts have to fit the chip's fast memory, which is how
+    the kernel streams them."""
+    return n_tokens * cfg.experts_per_token < cfg.n_experts \
+        and grouped_experts.fits(
+            cfg.dim, cfg.expert_hidden, jnp.dtype(cfg.dtype).itemsize
+        )
+
+
+def visit_list(touched, n_visit: int):
+    """``touched [n_held]`` bool -> ``(visit [n_visit] int32,
+    n_touched [] int32)``: the touched rows in ascending order, then
+    the last of them repeated (row 0 where none is touched).
+    ``n_visit`` must be at least the most rows that can be touched."""
+    rows = jnp.arange(touched.shape[0], dtype=jnp.int32)
+    steps = jnp.arange(n_visit, dtype=jnp.int32)
+    n_touched = jnp.sum(touched, dtype=jnp.int32)
+    rank = jnp.cumsum(touched, dtype=jnp.int32) - 1
+    at = touched[None, :] & (rank[None, :] == steps[:, None])
+    visit = jnp.sum(jnp.where(at, rows[None, :], 0), axis=1)
+    last = jnp.max(jnp.where(touched, rows, 0))
+    return jnp.where(steps < n_touched, visit, last), n_touched
+
+
+def _on_mesh(kernel, mesh):
+    """The kernel where the program runs: interpreted unless that is a
+    TPU (the serving ``mesh``'s devices; the default backend's where
+    the caller is no serving program), and on a mesh of several chips
+    under ``shard_map`` with every operand whole on every chip (the
+    expert stacks are replicated, ``serving_pspecs``; XLA cannot
+    partition a Mosaic call by itself)."""
+    platform = jax.default_backend() if mesh is None \
+        else mesh.devices.flat[0].platform
+    call = functools.partial(kernel, interpret=platform != "tpu")
+    if mesh is None or mesh.size == 1 or platform != "tpu":
+        return call
+    return jax.shard_map(
+        call, mesh=mesh, in_specs=P(), out_specs=P(), check_vma=False
+    )
+
+
+def _dense_experts(h, held_gates, moe, cfg: SparseMoEConfig):
+    """ONE feed-forward of width ``n_held * expert_hidden`` whose hidden
+    activations are scaled by the gates: every held expert's weights
+    read once, whatever the routing."""
+    x = h.astype(cfg.dtype)
+    gate = jnp.einsum("td,edf->tef", x, moe["w1"].astype(cfg.dtype))
+    up = jnp.einsum("td,edf->tef", x, moe["w3"].astype(cfg.dtype))
+    hidden = jax.nn.silu(gate) * up * held_gates.astype(cfg.dtype)[..., None]
+    return jnp.einsum("tef,efd->td", hidden, moe["w2"].astype(cfg.dtype))
+
+
+def _grouped_experts(h, held_gates, spread, w, moe, cfg, mesh):
+    """The held experts that at least one counted row (``w``) chose,
+    each read once by ``kernels/grouped_experts.py`` -> ``(out
+    [tokens, dim], visited [n_held] bool)``: the float32 sum rounded
+    once, and the experts the list really named (what ``dropped`` is
+    reckoned against)."""
+    n_tok = h.shape[0]
+    touched = jnp.einsum("t,tke->e", w.astype(jnp.float32), spread) > 0
+    visit, n_touched = visit_list(
+        touched, min(cfg.n_held, n_tok * cfg.experts_per_token)
+    )
+    out = _on_mesh(grouped_experts.grouped_expert_ffn, mesh)(
+        h.astype(cfg.dtype), held_gates, visit, n_touched,
+        moe["w1"].astype(cfg.dtype), moe["w3"].astype(cfg.dtype),
+        moe["w2"].astype(cfg.dtype),
+    )
+    made = jnp.arange(visit.shape[0]) < n_touched
+    visited = jnp.any(
+        made[:, None] & (visit[:, None] == jnp.arange(cfg.n_held)), axis=0
+    )
+    return out.astype(cfg.dtype), visited
+
+
+def expert_ffn(h, gates, experts, lp, cfg: SparseMoEConfig, weight=None,
+               mesh=None):
     """The held experts' part of ``sum_e gate_e * W2_e(silu(W1_e h) *
     W3_e h)`` for tokens ``h [tokens, dim]``, and the step's counts.
 
     Every assignment to a held expert is computed, at any imbalance:
     the gates are spread over the held experts' axis (zero where a
-    token did not choose one) and the layer is ONE feed-forward of
-    width ``n_held * expert_hidden`` whose hidden activations are
-    scaled by them. Each expert's weights are read once whatever the
-    routing; a token costs every held expert's products (PERF.md, PR
-    27, has what the chip said of this form against a grouped one).
+    token did not choose one) and every row meets every expert the
+    product reads. ONE sum, two traversals, chosen by the static shape
+    (:func:`grouped_by_shape`; no option selects one):
+
+    * **dense**: one feed-forward over the whole held stack
+      (:func:`_dense_experts`). Each held expert's weights are read
+      once whatever the routing: right where the rows' assignments
+      cover the experts (a prefill chunk; a decode step of sixteen
+      slots x ten over eighteen held experts);
+    * **grouped**: the experts with at least one counted token, in
+      ascending order (:func:`visit_list`), each read once by
+      ``kernels/grouped_experts.py`` and multiplied against every row
+      (a row that did not choose it has gate zero). It leaves out
+      terms that are exactly zero and nothing else: the same operands,
+      the sum over experts in float32, rounded once where the dense
+      form rounds. A decode step of twelve slots x eight over 128
+      experts touches 69 of them.
 
     ``weight [tokens]`` (0/1) marks the tokens that count (a decode
-    step's inactive slots compute garbage nobody reads). Counts, all
-    int32: assignments made, those of them that landed on held
-    experts (the rest are other chips'), distinct HELD experts chosen,
-    the most tokens one expert got, assignments to held experts that
-    the product did not cover (0 by construction; the counter is the
-    contract)."""
+    step's inactive slots compute garbage nobody reads, and add no
+    expert to the grouped form's list). Counts, all int32: assignments
+    made, those of them that landed on held experts (the rest are
+    other chips'), distinct HELD experts chosen (what the grouped form
+    reads), the most tokens one expert got, assignments of counted
+    tokens to held experts that the product did not cover (0 by
+    construction, in the grouped form against the list it visited; the
+    counter is the contract)."""
     n_tok = h.shape[0]
     slots = _held_slots(cfg)[experts]                      # [t, k]
     spread = jax.nn.one_hot(slots, cfg.n_held, dtype=jnp.float32)
     held_gates = jnp.einsum("tk,tke->te", gates, spread)   # [t, held]
     moe = lp["moe"]
-    x = h.astype(cfg.dtype)
-    gate = jnp.einsum("td,edf->tef", x, moe["w1"].astype(cfg.dtype))
-    up = jnp.einsum("td,edf->tef", x, moe["w3"].astype(cfg.dtype))
-    hidden = jax.nn.silu(gate) * up * held_gates.astype(cfg.dtype)[..., None]
-    out = jnp.einsum("tef,efd->td", hidden, moe["w2"].astype(cfg.dtype))
+    grouped = grouped_by_shape(n_tok, cfg)
+    if not grouped:
+        # Ahead of the counts, as it always was: trace order is the
+        # lowered text's order, and the dense programs' text is pinned.
+        out = _dense_experts(h, held_gates, moe, cfg)
 
     w = jnp.ones((n_tok,), jnp.int32) if weight is None \
         else weight.astype(jnp.int32)
     chosen = jax.nn.one_hot(experts, cfg.n_experts, dtype=jnp.int32)
     per_expert = jnp.einsum("t,tke->e", w, chosen)
     to_held = jnp.sum(w[:, None] * (slots < cfg.n_held))
-    covered = jnp.sum(w[:, None] * (held_gates > 0))
+    if grouped:
+        out, visited = _grouped_experts(
+            h, held_gates, spread, w, moe, cfg, mesh
+        )
+        covered = jnp.sum(w[:, None] * ((held_gates > 0) & visited))
+    else:
+        covered = jnp.sum(w[:, None] * (held_gates > 0))
     def of_held(chosen):
         if cfg.held_experts is None:
             return chosen

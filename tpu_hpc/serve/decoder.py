@@ -272,14 +272,15 @@ def _logits_head(x, params, cfg):
     return logits if scale == 1.0 else logits / scale
 
 
-def _ffn_stage(x, lp, cfg, weight=None):
+def _ffn_stage(x, lp, cfg, weight=None, mesh=None):
     """The layer's feed-forward, residual included -> ``(x, counts)``,
     by the weights the layer holds: the dense SwiGLU under ``mlp``
     (``counts`` None), or the configuration's router and its held
     experts under ``router`` / ``experts``
     (``sparse_moe.expert_ffn``'s counts; ``weight`` marks the tokens
-    that count) and, where the layer has one, the shared expert every
-    token passes, under ``mlp``."""
+    that count; ``mesh``, the program's, is where its kernel runs) and,
+    where the layer has one, the shared expert every token passes,
+    under ``mlp``."""
     scope = jax.named_scope
     if "moe" not in lp:
         with scope("mlp"):
@@ -296,7 +297,7 @@ def _ffn_stage(x, lp, cfg, weight=None):
     shared = lp["moe"].get("shared")
     with scope("experts"):
         y, counts = sparse_moe.expert_ffn(
-            h, gates, experts, lp, cfg, weight=weight
+            h, gates, experts, lp, cfg, weight=weight, mesh=mesh
         )
         if shared is None:
             x = _residual(x, y.reshape(b, s, d).astype(x.dtype), cfg)
@@ -330,7 +331,7 @@ def _project(h, lp, cfg, cos, sin):
 
 
 def decoder_layers(params, cfg, x, cos, sin, attend, weight=None,
-                   recur=None):
+                   recur=None, mesh=None):
     """Every layer of the decoder over ``x [b, s, dim]`` -> ``(x,
     counts)``. A layer's mixer is attention, or, where the layer holds
     a state-space mixer's weights (``ssm``:
@@ -351,7 +352,8 @@ def decoder_layers(params, cfg, x, cos, sin, attend, weight=None,
     :func:`_ffn_stage`, by the kind of layer.
     ``counts`` are the expert layers' of a sparse-expert configuration
     over the tokens ``weight`` marks, summed over layers (``max*``: the
-    largest), and empty for a dense one."""
+    largest), and empty for a dense one. ``mesh`` is the serving mesh
+    of a program whose expert layers may run a kernel on it."""
     scope = jax.named_scope
     counts = {}
     for i in range(cfg.n_layers):
@@ -372,7 +374,7 @@ def decoder_layers(params, cfg, x, cos, sin, attend, weight=None,
             attn = attend(i, h, lp, q, k, v)
             with scope("attn_out"):
                 x = _residual(x, _attn_out_proj(attn, lp, cfg), cfg)
-        x, moe = _ffn_stage(x, lp, cfg, weight=weight)
+        x, moe = _ffn_stage(x, lp, cfg, weight=weight, mesh=mesh)
         for name, value in (moe or {}).items():
             with scope("experts"):
                 counts[name] = jnp.maximum(

@@ -162,6 +162,18 @@ LATENT_COUNTERS: Tuple[Tuple[str, str, str], ...] = SPARSE_COUNTERS[:4] + (
      "other chips' of the expert-parallel deployment, and no drop)"),
 )
 
+# Kept on the host from what the step's fetch already holds: which of
+# the two it adds is the decode program's static shape
+# (``sparse_moe.grouped_by_shape``), so the packed vector stays as wide
+# as it was.
+MOE_EXPERTS_READ = (
+    "serve_moe_experts_read_total",
+    "Experts whose weights the decode steps' expert products read, "
+    "summed over layers and steps: the experts touched where the "
+    "step's shape has the product visit those alone, every held "
+    "expert of every expert layer where it reads the whole stack",
+)
+
 # Kept on the host where pages are taken and released, added up once a
 # decode step (``_LivePages``).
 LATENT_PAGES_LIVE = (
@@ -1677,7 +1689,9 @@ def make_chunk_logits_fn(
         )
         pool.view(table, start)
         pool.pages(blk_ids, qpos, mask)
-        x, _ = decoder_layers(params, cfg, x, cos, sin, pool, recur=recur)
+        x, _ = decoder_layers(
+            params, cfg, x, cos, sin, pool, recur=recur, mesh=mesh
+        )
         with scope("head"):
             last = jax.lax.dynamic_slice(
                 x, (0, true_len - 1, 0), (1, 1, cfg.dim)
@@ -1848,7 +1862,8 @@ def make_paged_decode_fn(
             recur = recurrent.on(*rec)
             recur.rows(active)
         x, counts = decoder_layers(
-            params, cfg, x, cos, sin, pool, weight=active, recur=recur
+            params, cfg, x, cos, sin, pool, weight=active, recur=recur,
+            mesh=mesh,
         )
         with scope("head"):
             logits = _logits_head(x, params, cfg)
@@ -2141,6 +2156,16 @@ class PagedEngine(Engine):
         }
         counters = DECODE_COUNTERS \
             + tuple(c[1:] for c in self._step_counters)
+        # What a decode step's expert products read where they read
+        # every held expert (None: the experts the step touched).
+        self._experts_read: Optional[int] = None
+        if self._step_counters:
+            counters += (MOE_EXPERTS_READ,)
+            if not sparse_moe.grouped_by_shape(serve_cfg.slots, cfg):
+                self._experts_read = cfg.n_held * sum(
+                    "moe" in params[f"layers_{i}"]
+                    for i in range(cfg.n_layers)
+                )
         # The distinct pages the active slots read (a latent
         # configuration, and one with a recurrent state: their
         # rooflines count a shared page once).
@@ -3027,13 +3052,14 @@ class PagedEngine(Engine):
         """Fetch one step's result: its tokens, and the counts an
         expert configuration's step packs behind them
         (``step_counters``' order) into ``paged_stats`` and the
-        registry."""
+        registry, with the experts that step's products read
+        (``MOE_EXPERTS_READ``)."""
         with span("decode.fetch"):
             fetched = np.asarray(toks)
         slots = self.serve_cfg.slots
         stats = self.paged_stats
         stats["decode_steps"] += 1
-        for (_, name, _), value in zip(
+        for (key, name, _), value in zip(
             self._step_counters, fetched[slots:]
         ):
             if name.endswith("_total"):
@@ -3041,6 +3067,12 @@ class PagedEngine(Engine):
             else:
                 stats[name] = max(stats[name], int(value))
                 get_registry().set_gauge(name, stats[name])
+            if key == "experts_touched":
+                self._count(
+                    MOE_EXPERTS_READ[0],
+                    int(value) if self._experts_read is None
+                    else self._experts_read,
+                )
         return fetched[:slots]
 
     def release(self, slot: int) -> None:
