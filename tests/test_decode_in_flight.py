@@ -60,6 +60,7 @@ CONFIGS = {
 }
 COUNTERS = [name for name, _ in paging.DECODE_COUNTERS]
 OVERLAPPED, DISCARDED, *VIEW_PAGES = COUNTERS
+LADDER = [name for name, _ in paging.LADDER_COUNTERS]
 
 
 @pytest.fixture(scope="module")
@@ -167,11 +168,19 @@ def _trace(eos=None):
 def _per_request(grown):
     """The counts that do not depend on which requests share a step."""
     return {k: v for k, v in grown.items() if k not in (
-        "decode_steps", OVERLAPPED, *VIEW_PAGES,
+        "decode_steps", OVERLAPPED, *VIEW_PAGES, *LADDER,
         "serve_moe_experts_touched_total",
         "serve_moe_experts_read_total",
         "serve_moe_max_tokens_per_expert",
     )}
+
+
+def _but_gaps(stats):
+    return {k: v for k, v in stats.items() if "_gap_" not in k}
+
+
+def _gap_tokens(stats):
+    return sum(v for k, v in stats.items() if "_gap_tokens_" in k)
 
 
 # -- (a) token-exact against the synchronous tick ------------------------
@@ -186,7 +195,10 @@ def test_every_count_agrees_where_the_steps_are_the_same(engine):
     assert sync_grown.pop(OVERLAPPED) == 0
     assert lag_grown.pop(OVERLAPPED) == lag_grown["decode_steps"] - 1
     assert lag_grown == sync_grown
-    assert lag.stats == sync.stats
+    # The filing of token gaps (PR 35) is the lag's own too: which
+    # emission a chunk is ahead of, and the wall between emissions.
+    assert _but_gaps(lag.stats) == _but_gaps(sync.stats)
+    assert _gap_tokens(lag.stats) == _gap_tokens(sync.stats) > 0
 
 
 def test_lagged_tick_is_token_exact_against_the_synchronous_one(engine):

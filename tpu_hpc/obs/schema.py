@@ -159,9 +159,14 @@ EVENTS: Dict[str, EventSpec] = {
         "step", "preempted", "attempt", "resumed_from_step", "goodput",
     ), optional=("rolled_back",)),
     # -- the telemetry spine itself (obs/) --
+    # ``chunks`` .. ``gap_class``: what a scheduler ``tick`` ran and
+    # the class it filed its emission under (serve/scheduler.py).
     "span": EventSpec(
         ("name", "dur_s"),
-        optional=("parent", "depth", "n", "tier", "slot"),
+        optional=(
+            "parent", "depth", "n", "tier", "slot",
+            "chunks", "firsts", "admitted", "emitted", "gap_class",
+        ),
     ),
     "metrics": EventSpec(("metrics",)),
     "stall": EventSpec(("step", "step_s", "watermark_s", "ratio")),

@@ -127,7 +127,9 @@ class span:
     duration -- the flight recorder wants exactly the event that
     preceded the crash). ``annotate=False`` skips the profiler
     annotation for spans on paths where jax may not be initialized
-    yet.
+    yet. ``with span(...) as s`` hands back the span, so that what is
+    known only at the block's end can go into ``s.fields`` and reach
+    the record.
     """
 
     __slots__ = (
@@ -149,11 +151,12 @@ class span:
         self.step, self.hist, self.fields = step, hist, fields
         self._ann = _annotation(name) if annotate else None
 
-    def __enter__(self) -> None:
+    def __enter__(self) -> "span":
         _current_stack().append(self.name)
         if self._ann is not None:
             self._ann.__enter__()
         self._t0 = time.perf_counter()
+        return self
 
     def __exit__(self, *exc) -> bool:
         dur = time.perf_counter() - self._t0

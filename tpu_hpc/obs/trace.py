@@ -640,7 +640,40 @@ def _analyze_requests(requests: Dict[str, RequestTrace],
                 if decode_spans else None
             ),
         }
+    classes = _gap_classes(records)
+    if classes is not None:
+        out["itl_classes"] = classes
     return out
+
+
+def _gap_classes(records: Sequence[dict]) -> Optional[dict]:
+    """What the scheduler's ``tick`` records say the token gaps closed
+    behind (serve/scheduler.py: an emission is filed under the prefill
+    chunk programs ahead of it, ``gap_class`` 0 .. 3): tokens a class,
+    and the class the 95th percentile of the gaps falls in, classes in
+    order. None where no tick record carries a class (ticks land in
+    the flight ring, so: without ``--flight-dir`` or an event file)."""
+    tokens: Dict[int, int] = {}
+    for r in records:
+        if (
+            r.get("event") == "span" and r.get("name") == "tick"
+            and "gap_class" in r
+        ):
+            cls = int(r["gap_class"])
+            tokens[cls] = tokens.get(cls, 0) + int(r.get("emitted", 0))
+    total = sum(tokens.values())
+    if not total:
+        return None
+    cum, p95 = 0, max(tokens)
+    for cls in sorted(tokens):
+        cum += tokens[cls]
+        if 100 * cum >= 95 * total:
+            p95 = cls
+            break
+    return {
+        "tokens": {f"c{cls}": n for cls, n in sorted(tokens.items())},
+        "p95_class": f"c{p95}",
+    }
 
 
 def _analyze_steps(steps: Dict[str, StepTrace]) -> Optional[dict]:
@@ -833,6 +866,17 @@ def format_analysis(rep: dict) -> str:
                     if att.get("dominant") else ""
                 ),
             ]
+        classes = req.get("itl_classes")
+        if classes:
+            total = sum(classes["tokens"].values())
+            lines.append(
+                "Token gaps by prefill chunks ahead: "
+                + ", ".join(
+                    f"{c} {n / total:.1%}"
+                    for c, n in classes["tokens"].items()
+                )
+                + f" -- p95 falls in **{classes['p95_class']}**"
+            )
     steps = rep.get("steps")
     if steps:
         lines += [

@@ -119,6 +119,18 @@ DECODE_COUNTERS: Tuple[Tuple[str, str], ...] = (
      "every slot's capacity the steps read"),
 )
 
+# What an engine WITH a ladder (``decode_rungs`` non-empty) counts
+# beside them, in the same line of ``decode``; an engine without one
+# holds neither name.
+LADDER_COUNTERS: Tuple[Tuple[str, str], ...] = (
+    ("serve_decode_ladder_steps_total",
+     "Decode steps dispatched by an engine that holds flat rungs "
+     "below the rectangle"),
+    ("serve_decode_rectangle_steps_total",
+     "Of those, the steps whose live pages no rung held and that ran "
+     "the rectangle (every slot's whole capacity)"),
+)
+
 # The flat decode rungs, as shares of slots x pages a slot: a step
 # whose live pages fit one reads that many pages and no more; above the
 # last the rectangle reads every slot's whole capacity (at half of it
@@ -2156,6 +2168,8 @@ class PagedEngine(Engine):
         }
         counters = DECODE_COUNTERS \
             + tuple(c[1:] for c in self._step_counters)
+        if self._flat_rungs:
+            counters += LADDER_COUNTERS
         # What a decode step's expert products read where they read
         # every held expert (None: the experts the step touched).
         self._experts_read: Optional[int] = None
@@ -2990,6 +3004,12 @@ class PagedEngine(Engine):
                 self._count(
                     "serve_decode_view_pages_total", self.view_pages
                 )
+                if self.decode_rungs:
+                    self._count("serve_decode_ladder_steps_total")
+                    self._count(
+                        "serve_decode_rectangle_steps_total",
+                        int(pages is None),
+                    )
                 if self._live_pages is not None:
                     self._count(
                         self._live_pages_total[0], len(self._live_pages)

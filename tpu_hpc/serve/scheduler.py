@@ -24,6 +24,28 @@ the slot ran one step more and that token is dropped
 the request's last token is on the host. With a lag of 0 the same
 routine emits the step just dispatched.
 
+Every token gap is filed under what the device ran ahead of it. An
+emission (``_emit``: one decode step's tokens handed to their requests)
+is counted in class ``c0`` .. ``c3`` by the prefill chunk programs the
+device ran between the step before and this one, or that the host
+waited for before it could emit (``GAP_CLASSES``; three or more are
+``c3``). The device runs what it is handed in order, so: a chunk whose
+token is NOT fetched delays the decode step dispatched after it, rides
+in that step's flight record and is filed when THAT step's tokens are
+emitted (the next tick under a lag of 1, the same tick under 0); a
+chunk whose first token IS fetched holds the host until it, and every
+chunk queued before it, has run, so all of those are filed with the
+next emission, the same tick's. Chunks with no step dispatched behind
+them (a tick that only prefills) carry over. ``stats`` holds, a class,
+``serve_gap_emissions_<c>_total``, ``serve_gap_tokens_<c>_total`` and
+``serve_gap_seconds_<c>_total`` (wall since the emission before, by
+the meter's clock), and ``serve_gap_chunks_total``,
+``serve_gap_first_fetch_tokens_total`` (``GAP_COUNTERS``); the registry
+mirrors them, and the ``tick`` span's record says what the tick ran
+and the class it filed. A batcher's FIRST emission has no gap behind
+it: its tokens are filed (each closes its request's first gap), its
+wall and its chunks are not.
+
 Slot invariants (pinned by tests/test_serve.py):
   * a slot's position counter equals prompt_len + decode steps
     dispatched so far, resets on (re-)admission, and is what feeds
@@ -81,6 +103,42 @@ from tpu_hpc.obs.trace import (
     request_trace_id,
 )
 from tpu_hpc.serve.engine import Engine
+
+# The classes an emission is filed under: prefill chunk programs ahead
+# of it, the last holding "or more" (module docstring).
+GAP_CLASSES = ("c0", "c1", "c2", "c3")
+_GAP_WHAT = (
+    ("emissions", "Emissions (one decode step's tokens handed to "
+     "their requests) that followed another, with {} ahead"),
+    ("tokens", "Tokens kept in emissions with {} ahead: each closes "
+     "one token gap of one request"),
+    ("seconds", "Wall between an emission with {} ahead and the "
+     "emission before it, summed (seconds)"),
+)
+_GAP_AHEAD = (
+    "no prefill chunk", "one prefill chunk", "two prefill chunks",
+    "three or more prefill chunks",
+)
+# By class: the registry names of its (emissions, tokens, seconds).
+_GAP_KEYS = tuple(
+    tuple(f"serve_gap_{what}_{c}_total" for what, _ in _GAP_WHAT)
+    for c in GAP_CLASSES
+)
+# (registry name, HELP) of everything the filing counts;
+# ``ContinuousBatcher.stats`` holds them under the same names.
+GAP_COUNTERS = tuple(
+    (name, help_.format(ahead))
+    for names, ahead in zip(_GAP_KEYS, _GAP_AHEAD)
+    for name, (_, help_) in zip(names, _GAP_WHAT)
+) + (
+    ("serve_gap_chunks_total",
+     "Prefill chunk programs filed with an emission that followed "
+     "another (every class): what the classes' seconds paid for"),
+    ("serve_gap_first_fetch_tokens_total",
+     "Tokens kept in emissions that waited for at least one "
+     "synchronous first-token fetch (a chunk that completed a "
+     "prompt)"),
+)
 
 
 def paged_drain_bound(engine, requests) -> int:
@@ -236,9 +294,10 @@ class ContinuousBatcher:
         self._spec = getattr(engine, "spec", None) is not None
         # 1: ``engine.decode`` returns the tokens of the step BEFORE
         # the one it dispatches (module docstring); the step awaiting
-        # its tokens is ``_flight``: [(slot index, rid)].
+        # its tokens is ``_flight``: ([(slot index, rid)], the chunk
+        # programs queued ahead of it).
         self._lag = int(getattr(engine, "decode_lag", 0))
-        self._flight: Optional[List[tuple]] = None
+        self._flight: Optional[tuple] = None
         self.slots = [_Slot() for _ in range(engine.serve_cfg.slots)]
         self.pending: List[Request] = []
         self.results: Dict[str, List[int]] = {}
@@ -247,6 +306,20 @@ class ContinuousBatcher:
         }
         if self._paged:
             self.stats["block_stalls"] = 0
+        # The filing of token gaps (module docstring). Chunk programs
+        # dispatched since the last decode step and not waited for
+        # (``_ahead``: they ride with the next step), those the host
+        # has waited for since the last emission and whether a
+        # first-token fetch was among them (``_waited``, ``_fetched``),
+        # the clock at the last emission, and what the running tick's
+        # span will say.
+        for name, help_ in GAP_COUNTERS:
+            self.stats[name] = 0.0 if "_seconds_" in name else 0
+            get_registry().describe(name, help_)
+        self._ahead = self._waited = 0
+        self._fetched = False
+        self._gap_t: Optional[float] = None
+        self._ticked: Dict[str, int] = self._tick_fields()
         # Per-tenant acceptance evidence ("per request class" in the
         # obs registry): the batcher is the one layer that knows both
         # the tenant and the per-slot verify outcome.
@@ -498,7 +571,9 @@ class ContinuousBatcher:
             "prefill_chunk", self._clock() - t0, sink=self._sink(),
             trace_id=tid, slot=idx,
         )
+        self._note_chunk(fetched=True)
         self.stats["admitted"] += 1
+        self._ticked["admitted"] += 1
         slot.rid = req.rid
         slot.pos = len(req.prompt)
         slot.last_token = first
@@ -602,6 +677,7 @@ class ContinuousBatcher:
         slot.pos = 0
         slot.remaining = req.max_new_tokens
         self.stats["admitted"] += 1
+        self._ticked["admitted"] += 1
         self._set_occupancy()
         if self.meter is not None:
             self.meter.admitted(
@@ -626,6 +702,7 @@ class ContinuousBatcher:
                 "prefill_chunk", self._clock() - t0,
                 sink=self._sink(), trace_id=tid, slot=idx,
             )
+            self._note_chunk(fetched=first is not None)
             if first is None:
                 continue
             req = self._requests[slot.rid]
@@ -646,8 +723,58 @@ class ContinuousBatcher:
         ``tick`` span brackets the whole of it, whichever way it
         ends; its children (docs/guide/observability.md, "Stage
         names") say where inside a tick the host was."""
-        with span("tick"):
+        with span("tick") as tick:
+            # The record is made on the way out: it carries what the
+            # tick ran (``_tick_fields``) however it ends.
+            tick.fields = self._ticked = self._tick_fields()
             self._tick()
+
+    @staticmethod
+    def _tick_fields() -> Dict[str, int]:
+        """What a ``tick`` span's record says beside its time: prefill
+        chunk programs dispatched and the first-token fetches among
+        them, requests admitted, tokens kept in its emission and,
+        where it emitted, ``gap_class`` (the index into
+        ``GAP_CLASSES`` it was filed under)."""
+        return {"chunks": 0, "firsts": 0, "admitted": 0, "emitted": 0}
+
+    def _note_chunk(self, fetched: bool) -> None:
+        """One prefill chunk program went to the device. Unfetched, it
+        is ahead of the next decode step dispatched; fetched, the host
+        has waited for it and for every chunk queued before it."""
+        self._ticked["chunks"] += 1
+        if not fetched:
+            self._ahead += 1
+            return
+        self._ticked["firsts"] += 1
+        self._fetched = True
+        self._waited += self._ahead + 1
+        self._ahead = 0
+
+    def _file_gap(self, ahead: int, kept: int) -> None:
+        """File one emission of ``kept`` tokens, ``ahead`` chunk
+        programs queued before its step, under its class (module
+        docstring)."""
+        chunks = ahead + self._waited
+        cls = min(chunks, len(GAP_CLASSES) - 1)
+        emissions, tokens, seconds = _GAP_KEYS[cls]
+        now = self._clock()
+        counts = [(tokens, kept)]
+        if self._fetched:
+            counts.append(("serve_gap_first_fetch_tokens_total", kept))
+        if self._gap_t is not None:
+            counts += [
+                (emissions, 1), (seconds, now - self._gap_t),
+                ("serve_gap_chunks_total", chunks),
+            ]
+        stats, reg = self.stats, get_registry()
+        for name, n in counts:
+            stats[name] += n
+            reg.inc(name, n)
+        self._gap_t = now
+        self._waited, self._fetched = 0, False
+        self._ticked["emitted"] += kept
+        self._ticked["gap_class"] = cls
 
     def _tick(self) -> None:
         with span("tick.admission"):
@@ -687,25 +814,28 @@ class ContinuousBatcher:
         for idx, _ in step:
             self.slots[idx].pos += 1
             self.slots[idx].remaining -= 1
+        flight, self._ahead = (step, self._ahead), 0
         if self._lag:
-            step, self._flight = self._flight, step
-        if step is not None:
-            self._emit(step, out)
+            flight, self._flight = self._flight, flight
+        if flight is not None:
+            self._emit(*flight, out)
 
     def _flush(self) -> None:
         """Take the step in flight off the engine and emit it: before
         a tick with nothing to dispatch, and before ``done``."""
         if self._flight is not None:
-            step, self._flight = self._flight, None
-            self._emit(step, self.engine.flush())
+            flight, self._flight = self._flight, None
+            self._emit(*flight, self.engine.flush())
 
-    def _emit(self, step, out) -> None:
+    def _emit(self, step, ahead, out) -> None:
         """Hand the tokens ``out`` of the decode step ``step``
         ([(slot index, rid)]: the one just dispatched, or with a lag
         the one before) to their requests; end those that are whole
-        or hit their end of sequence."""
+        or hit their end of sequence. ``ahead``: the chunk programs
+        that were queued before the step, for the filing."""
         with span("tick.emit"):
             out = np.asarray(out)
+            kept = 0
             for idx, rid in step:
                 slot = self.slots[idx]
                 if slot.rid != rid:
@@ -714,6 +844,7 @@ class ContinuousBatcher:
                     continue
                 req = self._requests[rid]
                 tok = int(out[idx])
+                kept += 1
                 self.results[rid].append(tok)
                 if self.meter is not None:
                     self.meter.token(rid)
@@ -722,6 +853,7 @@ class ContinuousBatcher:
                     len(self.results[rid]) == req.max_new_tokens
                 ):
                     self._evict(idx, slot)
+            self._file_gap(ahead, kept)
 
     def _spec_tick(self) -> None:
         """One speculative decode tick (serve/spec.py): every decoding
@@ -768,6 +900,8 @@ class ContinuousBatcher:
         )
         self.stats["decode_steps"] += 1
         reg = get_registry()
+        ahead, self._ahead = self._ahead, 0
+        kept = 0
         for idx, slot in enumerate(slots):
             if not slot.decoding:
                 continue
@@ -788,6 +922,7 @@ class ContinuousBatcher:
             index = self._ngram_idx.get(slot.rid)
             for tok in out[idx, :int(n_acc[idx]) + 1]:
                 tok = int(tok)
+                kept += 1
                 self.results[slot.rid].append(tok)
                 if index is not None:
                     index.append(tok)
@@ -802,6 +937,7 @@ class ContinuousBatcher:
                     # stopped -- the tail beyond it is discarded.
                     self._evict(idx, slot)
                     break
+        self._file_gap(ahead, kept)
 
     def _evict(self, idx: int, slot: _Slot) -> None:
         if self.meter is not None:
