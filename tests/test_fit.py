@@ -499,16 +499,18 @@ def test_latent_programs_address_the_pool_in_place(
 ):
     """``serve-docqa-joyai-flash``'s decode and chunk programs,
     compiled for the chip at the cell's shape (2 of its 7 layers):
-    every pool goes in and out in its own layout with no copy of it,
-    no per-layer slice is materialised, and the decode program makes
-    no copy of a gathered view (with no head axis inside a page the
-    view's rows ARE its tokens: a page-major contraction made the
-    compiler transpose all 566 MB of it a layer) and builds no per-head
-    key or value of the cached tokens. The decode step's ONE expert
-    layer is the grouped kernel, compiled by Mosaic as the chip would
-    (the program asks the backend whether to interpret: here the test
-    answers for the described chip), over the expert stacks as they
-    lie; the chunk keeps the whole-stack product."""
+    every pool goes in and out in its own layout with no copy of it
+    and no per-layer slice is materialised. The decode program reads
+    its pages through the kernel that walks the tables
+    (``latent_paged_decode``, a Mosaic call a layer, over the pools as
+    they lie): it holds NO tensor of a gathered view's size (16 x 30720
+    x 512 latents, in any grouping of its pages and rows), no float32
+    score over the 30,720 columns of a slot, and no per-head key or
+    value of the cached tokens. Its ONE expert layer is the grouped
+    kernel; both kernels are compiled by Mosaic as the chip would (the
+    program asks the backend whether to interpret: here the test
+    answers for the described chip). The chunk keeps its gathered view
+    and the whole-stack product."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     import dataclasses
 
@@ -537,14 +539,14 @@ def test_latent_programs_address_the_pool_in_place(
             lambda: latent_moe.init_latent_moe(jax.random.key(0), cfg)
         ),
     )
-    # The latents a token a row; the rotary keys two tokens a row of
-    # 128 lanes (a 64-wide row is laid out pages-minor by the runtime,
+    # Two tokens a row of either array: the rotary keys fill the 128
+    # lanes so (a 64-wide row is laid out pages-minor by the runtime,
     # and the program then copies the whole pool in and out: this test
-    # failed on exactly that).
+    # failed on exactly that), and the latents lie as the keys do.
     pack = paging.rope_pack(cfg, bs)
     assert pack * cfg.rope_dim == 128
     pool_shapes = [
-        (cfg.n_layers, num_blocks, bs, cfg.kv_lora_rank),
+        (cfg.n_layers, num_blocks, bs // pack, pack * cfg.kv_lora_rank),
         (cfg.n_layers, num_blocks, bs // pack, pack * cfg.rope_dim),
     ]
     pools = [sds(shape, jnp.bfloat16) for shape in pool_shapes]
@@ -569,7 +571,17 @@ def test_latent_programs_address_the_pool_in_place(
 
     text = compiled.as_text()
     entry = text[text.index("\nENTRY "):]
-    assert text.count("tpu_custom_call") == (program == "decode")
+    # A decode program: the walk a layer, and the grouped experts of
+    # its one expert layer (layer 0 is dense).
+    kernels = (
+        {"latent_paged_decode": cfg.n_layers, "grouped_experts": 1}
+        if program == "decode" else {}
+    )
+    assert text.count("tpu_custom_call") == sum(kernels.values())
+    for name, calls in kernels.items():
+        assert len(re.findall(
+            rf"^\s*%{name}[.\d]* = [^\n]*tpu_custom_call", text, re.M
+        )) == calls, name
     for stack in ((64, 2048, 768), (64, 768, 2048)):
         for result, opcode in _HLO_INSTRUCTION.findall(entry):
             if spelled(stack) in result:
@@ -586,15 +598,20 @@ def test_latent_programs_address_the_pool_in_place(
             if spelled(shape) in result:
                 pool_results += 1
                 assert opcode != "copy", f"whole-pool copy: {result}"
-        if program == "decode" and opcode == "copy":
-            # (the rotary keys' view IS unpacked, 64 numbers a token:
-            # cheaper on the chip than scoring the packs as they lie)
+        if program == "decode":
+            # no gathered view of the latents, whatever groups its
+            # pages, rows and tokens, and no float32 score a column
             w = cfg.kv_lora_rank
-            for view in ((slots, mb, bs, w), (slots, capacity, w),
-                         (slots * mb, bs, w)):
+            for view in ((slots, mb, bs // pack, pack * w),
+                         (slots, mb, bs, w), (slots, capacity, w),
+                         (slots * mb, bs, w),
+                         (slots * mb, bs // pack, pack * w)):
                 assert spelled(view) not in result, (
-                    f"copy of a gathered latent view: {result}"
+                    f"{opcode} holds a gathered latent view: {result}"
                 )
+            assert not re.search(
+                rf"f32\[[\d,]*\b{capacity}\b[\d,]*\]", result
+            ), f"{opcode} holds a score a column: {result}"
         if program == "decode":
             # no key or value a head of the cached tokens
             for w in (cfg.qk_head_dim, cfg.qk_nope_head_dim):
@@ -608,6 +625,10 @@ def test_latent_programs_address_the_pool_in_place(
         cfg.qk_head_dim + cfg.v_head_dim
     )
     temp = compiled.memory_analysis().temp_size_in_bytes
+    if program == "decode":
+        # nothing the size of one slot's view, let alone sixteen
+        assert temp < capacity * row, temp
+        return
     assert temp < 2 * view_tokens * row + 2 * expanded + pool_bytes // 2, temp
 
 

@@ -46,9 +46,9 @@ ARCH = dict(
 SERVE = ServeConfig(slots=3, max_seq_len=96, prefill_buckets=(8, 16))
 BLOCK = 4
 ROW_BYTES = (32 + 8) * 4            # c and kR in float32
-# A page of each pool array: latents a token a row, rotary keys
+# A page of each pool array, latents and rotary keys:
 # ``paging.rope_pack`` (here all 4) tokens a row.
-PAGES = ((BLOCK, 32), (1, BLOCK * 8))
+PAGES = ((1, BLOCK * 32), (1, BLOCK * 8))
 
 
 @pytest.fixture(scope="module")
@@ -260,13 +260,15 @@ def test_the_engine_holds_no_flat_rung(params, mesh, engine):
     page (16 rows x 576) is half its owner's query (32 heads x 576),
     which the flat form copies a page, and on the v5e a flat rung ran
     slower than the rectangle at every occupancy (PERF.md, PR 31). The
-    engine holds the rectangle alone, every step reads every slot's
-    view, and the factory refuses the flat form by reason."""
+    engine holds ONE decode program, whose kernel walks the tables
+    (PR 36): a step reads the live pages of its active slots, fewer
+    than every slot's whole view, and the factory refuses the flat
+    form by reason."""
     assert engine.decode_rungs == ()
     assert set(k for k in engine._execs if k[0] == "decode") == {("decode",)}
     stats = engine.paged_stats
-    assert stats["serve_decode_view_pages_read_total"] \
-        == stats["serve_decode_view_pages_total"] > 0
+    assert 0 < stats["serve_decode_view_pages_read_total"] \
+        < stats["serve_decode_view_pages_total"]
     with pytest.raises(ValueError, match="latent page"):
         paging.make_paged_decode_fn(TINY, BLOCK, 12, 16, flat_pages=18)
 
@@ -564,12 +566,16 @@ def test_what_is_added_up_stays_float32():
 
 # -- the programs as they lower -----------------------------------------------
 # sha256[:16] of ``lowered.as_text()`` of the latent programs at this
-# file's tiny size, as the PR that brought them left them (PR 31):
-# tests/test_sparse_moe.py holds the dense and sparse-expert programs'
-# the same way. A PR that MEANS to change one re-pins it and says so.
+# file's tiny size: tests/test_sparse_moe.py holds the dense and
+# sparse-expert programs' the same way. A PR that MEANS to change one
+# re-pins it and says so. Both re-pinned at PR 36 (from PR 31's
+# ce3317758663fc68 / 5177233dc2839a56): the decode step reads through
+# the kernel that walks the page tables (interpreted here, so the
+# kernel's own text is part of the program's), and the latents lie two
+# tokens a row, which the chunk's write and gather see too.
 PROGRAM_DIGESTS = {
-    "decode": "ce3317758663fc68",
-    "prefill": "5177233dc2839a56",
+    "decode": "da731408af9ce5bc",
+    "prefill": "549bdc3c1a8c73e8",
 }
 
 

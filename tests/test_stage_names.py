@@ -598,3 +598,65 @@ def test_sparse_counter_is_described_and_in_the_table_of_record(name):
 @pytest.mark.parametrize("scope", SPARSE_SCOPES)
 def test_sparse_scope_is_in_the_table_of_record(scope):
     assert f"| `{scope}` |" in _table_of_record()
+
+
+# -- a latent configuration's decode step (PR 36) -----------------------
+@pytest.fixture(scope="module")
+def latent_decode(devices):
+    """The decode program of a tiny latent configuration
+    (``models/latent_moe.py``): ``(function, abstract arguments)``."""
+    from tpu_hpc.models import latent_moe
+
+    cfg = latent_moe.LatentMoEConfig(
+        name="tiny-latent", dim=64, n_layers=2, n_heads=4, vocab_size=128,
+        max_seq_len=48, q_lora_rank=48, kv_lora_rank=32,
+        qk_nope_head_dim=16, qk_rope_head_dim=8, v_head_dim=16,
+        dense_hidden=96, first_dense_layers=1, n_experts=16,
+        experts_per_token=4, expert_hidden=24,
+        held_experts=(0, 1, 2, 3, 8, 9),
+        dtype=jnp.float32, param_dtype=jnp.float32,
+    )
+    i32 = jnp.int32
+    weights = jax.eval_shape(
+        lambda: latent_moe.init_latent_moe(jax.random.key(0), cfg)
+    )
+    pack = paging.rope_pack(cfg, BLOCK)
+    pools = [
+        jax.ShapeDtypeStruct(
+            (2, 48, BLOCK // pack, pack * width), jnp.float32
+        ) for width in (cfg.kv_lora_rank, cfg.rope_dim)
+    ]
+    return (
+        paging.make_paged_decode_fn(cfg, BLOCK, PER_SEQ, WIDTH),
+        (weights, *pools,
+         jax.ShapeDtypeStruct(
+             (SERVE.slots + len(paging.LATENT_COUNTERS),), i32
+         ),
+         jax.ShapeDtypeStruct((len(paging.STEP_ROWS), SERVE.slots), i32),
+         jax.ShapeDtypeStruct((SERVE.slots, WIDTH), i32)),
+    )
+
+
+def test_the_latent_walk_is_named_and_filed_under_kv_read(latent_decode):
+    """The kernel that walks the tables carries its name into the trace
+    (``latent_paged_decode``, one call a layer) and runs under
+    ``kv_read`` (the rule for a table-walking kernel); what is left of
+    the read under ``attention`` is the value's way out of the latent
+    space (``W_UV``). ``latent_attention_roofline`` divides by the time
+    under the two: both keep operations."""
+    fn, args = latent_decode
+    closed = jax.make_jaxpr(fn)(*args)
+    assert "name=latent_paged_decode" in str(closed)
+    ops = _ops_by_scope(fn, args)
+    assert ops["kv_read"] >= 2 and ops["attention"] >= 2
+    # The kernel is traced once and called a layer (``_walk``).
+    walks = [
+        str(e.source_info.name_stack).split("/")
+        for e in closed.jaxpr.eqns
+        if e.primitive.name == "jit" and e.params["name"] == "_walk"
+    ]
+    assert len(walks) == 2
+    for path in walks:
+        # the LAST stage name on the path is the operation's stage
+        assert next(p for p in reversed(path) if p in SERVE_SCOPES) \
+            == "kv_read"

@@ -26,6 +26,20 @@ Two kernels, sharing the flash online-softmax core of
     kv-page grid, global causal mask built from the chunk ``start``
     carried as data (no per-bucket mask tensors).
 
+**Why these two have only lost on the chip** (PERF.md, PR 27 / PR 30:
+187 ms a decode tick at ``serve-decode-deepseek7b``'s shape where the
+gather path takes 23). Both take ONE page a grid step: the page is a
+``BlockSpec`` block, so every page of every (slot, KV head) pays a grid
+step's fixed cost (~0.35 us) to bring in ``block_size x head_dim``
+numbers (4 KiB at a page of 16) and multiply them against a handful of
+query rows, and neither was ever tiled for the chip. A kernel that
+wants to win there brings MANY pages of a slot into fast memory a step
+with copies of its own and runs the online softmax over the block:
+``kernels/latent_paged_attention.py`` does that for a latent
+configuration's headless page (PR 36); the per-head form of it (jax's
+``paged_attention``: ``pages_per_compute_block``) is what ROADMAP A3.2
+still asks for.
+
 Both kernels optionally dequantize int8 pages in-register: per-page
 scales live in a small side array allocated with the pool
 (``quantize_pages_int8`` below is the single write-side definition),

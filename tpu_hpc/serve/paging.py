@@ -70,6 +70,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
+from tpu_hpc.kernels.latent_paged_attention import latent_paged_decode
 from tpu_hpc.kernels.paged_attention import (
     INT8_SCALE_FLOOR,
     dequantize_pages_int8,
@@ -86,7 +87,6 @@ from tpu_hpc.serve.decoder import (
     _grouped_attention,
     _grouped_attention_flat,
     _grouped_attention_paged,
-    _latent_attention,
     _logits_head,
     _rope_tables,
     decoder_layers,
@@ -111,8 +111,10 @@ DECODE_COUNTERS: Tuple[Tuple[str, str], ...] = (
      "Slot-steps computed past an end the host saw one step late "
      "(end of sequence) and dropped"),
     ("serve_decode_view_pages_read_total",
-     "KV pages the dispatched decode programs gathered a layer: the "
-     "flat rung's size, or slots x pages a slot on the rectangle"),
+     "KV pages the dispatched decode programs read a layer: the flat "
+     "rung's size, slots x pages a slot on the rectangle, or (a latent "
+     "configuration, whose kernel walks the tables) the live pages of "
+     "the active slots, a shared page once a slot"),
     ("serve_decode_view_pages_total",
      "KV pages the rectangle would have gathered a layer (slots x "
      "pages a slot, a decode step); read over this is how much of "
@@ -410,14 +412,32 @@ def derive_paged_config(
 
 
 def rope_pack(cfg, block_size: int) -> int:
-    """Tokens whose rotary keys share one row of a latent
-    configuration's second pool array (``models/latent_moe.py``): as
-    many as fill the chip's 128 lanes and divide a page (2 of 64
-    numbers at a page of 16: pages of ``[8, 128]``). A row narrower
-    than the lanes makes the TPU runtime lay the pool out pages-minor,
-    and every program then copies the whole pool in and out to reach a
-    page (PERF.md, PR 31)."""
+    """Tokens that share one row of a latent configuration's pool
+    arrays (``models/latent_moe.py``): as many rotary keys as fill the
+    chip's 128 lanes and divide a page (2 of 64 numbers at a page of
+    16: pages of ``[8, 128]`` rotary keys and ``[8, 1024]`` latents).
+    A row narrower than the lanes makes the TPU runtime lay the pool
+    out pages-minor, and every program then copies the whole pool in
+    and out to reach a page (PERF.md, PR 31); the latents keep the
+    rotary keys' packing so that the decode kernel scores both as they
+    lie (``kernels/latent_paged_attention.py``)."""
     return math.gcd(block_size, max(1, 128 // cfg.rope_dim))
+
+
+def _write_packed(pool, layer, page_ids, offsets, rows, pack: int):
+    """``write_tokens`` for a latent pool ``[layers, pages, block_size
+    / pack, pack * width]``, ``pack`` tokens a row: token ``offsets[s]``
+    of page ``page_ids[s]`` is lanes ``(off % pack) * width ..`` of row
+    ``off // pack``. The same page-granular read-modify-write, and the
+    same rule: one writer a page a call."""
+    pages = pool[layer, page_ids]
+    width = pages.shape[-1] // pack
+    row = jnp.arange(pages.shape[1])[None, :, None]
+    lane = jnp.arange(pages.shape[2])[None, None, :]
+    at = (row == offsets[:, None, None] // pack) \
+        & (lane // width == offsets[:, None, None] % pack)
+    new = jnp.tile(rows.astype(pool.dtype), (1, pack))[:, None, :]
+    return pool.at[layer, page_ids].set(jnp.where(at, new, pages))
 
 
 def paged_kv_cache_pspec(mesh: Mesh, kv_heads: int) -> P:
@@ -1075,9 +1095,11 @@ def _check_read_path(cfg, kernel: str, kv_quant: str) -> None:
         )
         latent_moe.refuse(
             cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
-            "the Pallas kernels contract per-head K and V pages and "
-            "the int8 page write quantises them; a latent page has "
-            "neither",
+            "kernels/paged_attention.py contracts per-head K and V "
+            "pages and the int8 page write quantises them; a latent "
+            "page has neither, and its decode step already walks the "
+            "tables in a kernel of its own "
+            "(kernels/latent_paged_attention.py) under 'gather'",
         )
         hybrid_ssm_moe.refuse(
             cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
@@ -1146,26 +1168,39 @@ class PagedAttention:
       so they always see the identical pool state.
 
     A latent configuration (``models/latent_moe.py``) keeps ONE row a
-    token and nothing per head, in two arrays without a head axis:
-    ``ks [layers, pages, block_size, kv_lora_rank]`` holds the normed
-    latent ``c`` and ``vs [layers, pages, block_size / pack, pack *
-    rope_dim]`` the rotated key ``kR``, :func:`rope_pack` tokens a row
-    (apart, and packed, because a row that does not fill the 128 lanes
-    -- 576 numbers, or 64 -- is laid out pages-minor by the runtime and
-    costs a copy of the whole pool a program: PERF.md, PR 31). The
-    layer loop hands both over as ``k, v`` and ``kv_write`` puts them
-    down under the same page ids and offsets. ``kv_read`` is the same
-    gather of the view's pages; with no head axis inside a page its
-    rows ARE the tokens end to end, so nothing is transposed. A row
-    step then reads them in the ABSORBED form -- the query carried into
-    the latent space under ``qkv``, the rows as the one shared key AND
-    value of every head under ``attention``
-    (:func:`_latent_attention`), the head's output brought out by
-    ``W_UV`` -- so no per-head key or value is ever built. A chunk,
-    with hundreds of query rows to spend them on, expands its one
-    view's rows into every head's key and value first (57 ms a
-    512-row chunk on the v5e where the absorbed form took 135:
-    PERF.md, PR 31).
+    token and nothing per head, in two arrays without a head axis,
+    :func:`rope_pack` tokens side by side in a row of either: ``ks
+    [layers, pages, block_size / pack, pack * kv_lora_rank]`` holds the
+    normed latent ``c`` and ``vs [layers, pages, block_size / pack,
+    pack * rope_dim]`` the rotated key ``kR`` (packed because a row
+    that does not fill the 128 lanes -- 576 numbers, or 64 -- is laid
+    out pages-minor by the runtime and costs a copy of the whole pool
+    a program: PERF.md, PR 31; both alike so that a kernel reads the
+    ``j``-th token of every row of both as lane-aligned slices). A
+    page's rows end to end are still its tokens end to end. The layer
+    loop hands both over as ``k, v`` and ``kv_write`` puts them down
+    under the same page ids and offsets (:func:`_write_packed`). What
+    reads them is decided by the program's kind and by nothing a user
+    sets:
+
+    * a ROW step reads in the ABSORBED form -- the query carried into
+      the latent space under ``qkv``, the rows as the one shared key
+      AND value of every head -- through
+      ``kernels/latent_paged_attention.py``, which takes the tables,
+      ``pos`` and ``active`` and walks each slot's live pages itself:
+      every page once, no gathered view, no score tensor in HBM, pages
+      past ``pos`` and inactive slots unread. All of the walk is under
+      ``kv_read`` (the rule for a table-walking kernel); the head's
+      output is brought out by ``W_UV`` under ``attention``. Compiled by
+      Mosaic on a TPU, interpreted elsewhere
+      (``sparse_moe._on_mesh``'s rule). The same products over a
+      gathered view (``decoder._latent_attention``) are the oracle the
+      tests hold it to; no serving program reaches them.
+    * a CHUNK, with hundreds of query rows to spend them on, gathers
+      its ONE view and expands its rows into every head's key and value
+      first (57 ms a 512-row chunk on the v5e where the absorbed form
+      took 135: PERF.md, PR 31).
+
     The flat list of live pages is not for it: a page's owner's query
     is twice the page (32 heads x 576 against 16 rows x 576), and a
     flat rung ran slower than the rectangle at any occupancy.
@@ -1182,6 +1217,12 @@ class PagedAttention:
         self.quant = kv_quant == "int8"
         self.sparse = sparse_moe.is_sparse_moe(cfg)
         self.latent = latent_moe.is_latent_moe(cfg)
+        if self.latent:
+            self.pack = rope_pack(cfg, block_size)
+            # A row step's read (a chunk gathers its one view).
+            self.walk = sparse_moe._on_mesh(functools.partial(
+                latent_paged_decode, scale=cfg.qk_head_dim ** -0.5
+            ), mesh)
         # A stack with state-space layers: pages for the others only.
         self.hybrid = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
         self.kernel = None
@@ -1361,10 +1402,10 @@ class PagedAttention:
 
     def _write_latent(self, layer, latents, k_rope):
         """``latents [b, s, rank]`` into ``ks``, ``k_rope [b, s, rope]``
-        into ``vs``: a chunk's as whole pages, a row step's one call a
-        candidate row (one writer a page a call), through a page of
-        ``vs`` seen a token a row."""
-        bs = self.block_size
+        into ``vs``: a chunk's as whole pages (its tokens end to end
+        ARE a page's rows end to end), a row step's one call a
+        candidate row (one writer a page a call) into its place in a
+        row of :func:`rope_pack` tokens."""
         with jax.named_scope("kv_write"):
             if self.chunk:
                 def put(pool, rows):
@@ -1377,27 +1418,43 @@ class PagedAttention:
                 return
             for j in range(latents.shape[1]):
                 pb, off = self._target(j)
-                self.ks = write_tokens(self.ks, layer, pb, off, latents[:, j])
-                pages = self.vs[layer, pb]
-                at_row = (jnp.arange(bs) == off[:, None])[:, :, None]
-                self.vs = self.vs.at[layer, pb].set(jnp.where(
-                    at_row, k_rope[:, j, None, :].astype(pages.dtype),
-                    pages.reshape(pb.shape[0], bs, -1),
-                ).reshape(pages.shape))
+                self.ks = _write_packed(
+                    self.ks, layer, pb, off, latents[:, j], self.pack
+                )
+                self.vs = _write_packed(
+                    self.vs, layer, pb, off, k_rope[:, j], self.pack
+                )
 
     def _read_latent(self, layer, lp, q):
         cfg = self.cfg
         scope = jax.named_scope
-        scale = cfg.qk_head_dim ** -0.5
-        absorbed = not self.chunk
-        if absorbed:
+        if not self.chunk:
+            # A row step: the absorbed read, by the kernel that walks
+            # the tables. No view is gathered and no score leaves fast
+            # memory; all of the walk under ``kv_read`` (the rule for
+            # a table-walking kernel), ``W_UV`` under ``attention``.
+            pack, rope = self.pack, cfg.rope_dim
             with scope("qkv"):
                 q, q_rope = latent_moe.absorb(q, lp, cfg)
+                # The rotary query where each of a row's tokens keeps
+                # its key: lanes j * rope .. of pack * rope, else 0.
+                q_rope = jnp.stack([
+                    jnp.pad(q_rope[:, 0], (
+                        (0, 0), (0, 0), (j * rope, (pack - 1 - j) * rope)
+                    )) for j in range(pack)
+                ], axis=1)
+            with scope("kv_read"):
+                pos, active = self.where
+                u = self.walk(
+                    q[:, 0], q_rope, self.ks, self.vs,
+                    jnp.asarray(layer, jnp.int32), self.view_ids, pos,
+                    active,
+                )
+            with scope("attention"):
+                return latent_moe.unabsorb(u[:, None], lp, cfg)
         with scope("kv_read"):
-            # [b, tokens, width]: a page's rows end to end are its
-            # tokens (the rotary keys unpacked: a relayout of 64
-            # numbers a token, which cost the v5e less than scoring the
-            # packs as they lie, PERF.md PR 31).
+            # [1, tokens, width]: a page's rows end to end are its
+            # tokens, so unpacking them is a reshape.
             latents, k_rope = (
                 pool[layer, self.view_ids].astype(cfg.dtype).reshape(
                     q.shape[0], -1, width
@@ -1406,13 +1463,10 @@ class PagedAttention:
                 )
             )
         with scope("attention"):
-            if absorbed:
-                u = _latent_attention(
-                    q, q_rope, latents, k_rope, self.mask, cfg, scale
-                )
-                return latent_moe.unabsorb(u, lp, cfg)
             k, v = latent_moe.expand(latents, k_rope, lp, cfg)
-            return _grouped_attention(q, k, v, self.mask, cfg, scale=scale)
+            return _grouped_attention(
+                q, k, v, self.mask, cfg, scale=cfg.qk_head_dim ** -0.5
+            )
 
     def _select(self, layer, h, lp):
         """The layer's selection as attention's mask. ``h [b, s,
@@ -1805,8 +1859,9 @@ def make_paged_decode_fn(
     (``SPARSE_COUNTERS``' order), so the counts cost no second fetch
     (and ``prev`` is that vector: its first ``slots`` entries are read).
     A latent configuration (``models/latent_moe.py``) runs it with the
-    absorbed read over its headless pool and packs its expert layers'
-    counts the same way (``LATENT_COUNTERS``).
+    absorbed read over its headless pool (a kernel that walks the
+    tables: no view, and no mask but ``pos``) and packs its expert
+    layers' counts the same way (``LATENT_COUNTERS``).
     A configuration with state-space layers
     (``models/hybrid_ssm_moe.py``) runs it with its recurrent state's
     two arrays behind the pool's (:class:`RecurrentState`): an active
@@ -2184,7 +2239,10 @@ class PagedEngine(Engine):
         # configuration, and one with a recurrent state: their
         # rooflines count a shared page once).
         self._live_pages: Optional[_LivePages] = None
-        if latent_moe.is_latent_moe(cfg) or self._recurrent:
+        # A latent configuration's decode program walks the tables in
+        # its kernel and gathers no view.
+        self._walks = latent_moe.is_latent_moe(cfg)
+        if self._walks or self._recurrent:
             self._live_pages = _LivePages(bs)
             self._live_pages_total = (
                 KV_PAGES_LIVE if self._recurrent else LATENT_PAGES_LIVE
@@ -2273,13 +2331,14 @@ class PagedEngine(Engine):
             self._snapshot_nbytes = cfg.state_bytes()
         if latent_moe.is_latent_moe(self.cfg):
             # One row a token and nothing per head: the latent in
-            # ``ks``, its rotary key in ``vs`` (``rope_pack`` a row).
+            # ``ks``, its rotary key in ``vs``, ``rope_pack`` tokens
+            # side by side in a row of either.
             dtype = jnp.dtype(self.serve_cfg.cache_dtype or self.cfg.dtype)
             bs = self.paged.block_size
             pack = rope_pack(self.cfg, bs)
             page = (self.cfg.n_layers, self.paged.num_blocks)
             shapes = (
-                (*page, bs, self.cfg.kv_lora_rank),
+                (*page, bs // pack, pack * self.cfg.kv_lora_rank),
                 (*page, bs // pack, pack * self.cfg.rope_dim),
             )
             self._cache_sharding = NamedSharding(
@@ -2981,6 +3040,14 @@ class PagedEngine(Engine):
                 pages = next(
                     (p for p in self.decode_rungs if p >= live), None
                 )
+                # What the program reads a layer: a flat rung's pages,
+                # a latent configuration's walk (exactly the live
+                # pages, a shared one once a slot), else every slot's
+                # whole capacity.
+                if pages is not None:
+                    read = pages
+                else:
+                    read = live if self._walks else self.view_pages
                 exec_ = self._get_exec(
                     ("decode",) if pages is None else ("decode", pages)
                 )
@@ -2997,10 +3064,7 @@ class PagedEngine(Engine):
                 self._on_device = np.array(active, bool)
                 if before is not None:
                     self._count("serve_decode_overlapped_total")
-                self._count(
-                    "serve_decode_view_pages_read_total",
-                    pages or self.view_pages,
-                )
+                self._count("serve_decode_view_pages_read_total", read)
                 self._count(
                     "serve_decode_view_pages_total", self.view_pages
                 )
