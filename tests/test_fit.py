@@ -723,6 +723,88 @@ def test_hybrid_programs_keep_the_state_in_place(v5e_2x2, program):
     assert temp < 2.5 * 2**30, temp
 
 
+def test_trained_expert_layer_and_narrow_flash_lower_for_v5e(
+    v5e_2x2, monkeypatch
+):
+    """``train-lfm2-24b-a2b-1chip``'s two new shapes, compiled for the
+    chip forward AND backward: one expert layer at the cell's 32768
+    tokens (router, the sort by expert, the row buffer of 131072 rows,
+    the ragged products) and the flash kernels at 64-wide heads. The
+    ragged products are Mosaic calls (three forward, six backward: a
+    tile-skipping grouped kernel, not a dense product over the buffer),
+    and no scatter moves a row of the model's width: the dispatch's and
+    the combine's transposes are gathers (the one scatter left is the
+    router's, 4 gates a token into 64 scores); the products' row tile
+    is the ``RAGGED_ROW_TILE`` that ``rows_computed`` is reckoned in.
+    ~15 s."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import dataclasses
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_hpc.kernels.attention import blockwise_attention
+    from tpu_hpc.models import conv_moe, sparse_moe
+
+    one = SingleDeviceSharding(v5e_2x2.devices[0])
+    cfg = dataclasses.replace(
+        conv_moe.LFM2_24B_A2B, n_layers=1, first_dense_layers=0,
+        held_experts=tuple(range(8)),
+    )
+    placed = lambda tree: jax.tree.map(  # noqa: E731
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=one), tree
+    )
+    lp = placed(jax.eval_shape(
+        lambda: conv_moe.init_conv_moe(jax.random.key(0), cfg)
+    )["layers_0"])
+    bias = jax.ShapeDtypeStruct((64,), jnp.float32, sharding=one)
+    u = jax.ShapeDtypeStruct((4, 8192, 2048), jnp.float32, sharding=one)
+
+    def layer(lp, u, b):
+        out, counts, _ = conv_moe.expert_layer(u, lp, b, cfg)
+        return jnp.sum(out), counts
+
+    text = jax.jit(
+        jax.value_and_grad(layer, argnums=(0, 1), has_aux=True)
+    ).lower(lp, u, bias).compile().as_text()
+    products = [
+        line for line in text.splitlines()
+        if "custom-call(" in line and "ragged-dot" in line.split("=")[0]
+        and "metadata" not in line.split("=")[0]
+    ]
+    assert len(products) == 9, len(products)
+    # ``rows_computed`` is reckoned, not returned by the kernel: the
+    # tile it is reckoned in has to be the compiled call's own, whose
+    # metadata lists at most rows / tile + groups - 1 visits.
+    visits = sparse_moe.ragged_rows(4 * 8192, cfg) \
+        // sparse_moe.RAGGED_ROW_TILE + cfg.n_held - 1
+    listed = [
+        line for line in text.splitlines()
+        if "custom-call(" in line
+        and "ragged-dot-metadata" in line.split("=")[0]
+    ]
+    assert listed and all(f"s32[{visits}]" in line.split("custom-call(")[0]
+                          for line in listed), visits
+    assert not [
+        line for line in text.splitlines()
+        if " scatter(" in line and ",2048]" in line.split(" scatter(")[0]
+    ]
+
+    q = jax.ShapeDtypeStruct((1, 8192, 32, 64), jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((1, 8192, 8, 64), jnp.bfloat16, sharding=one)
+
+    def attend(q, k, v):
+        out, _ = blockwise_attention(
+            q, k, v, causal=True, impl="pallas", block_q=512, block_k=1024
+        )
+        return jnp.sum(out.astype(jnp.float32))
+
+    text = jax.jit(jax.grad(attend, argnums=(0, 1, 2))).lower(
+        q, kv, kv
+    ).compile().as_text()
+    assert text.count("tpu_custom_call") >= 3
+
+
 def test_keeping_blocks_hold_what_the_model_reckons(v5e_2x2):
     """The real train step (``make_step_fn``: forward, backward, AdamW)
     compiled for the chip twice: with no budget open (every block

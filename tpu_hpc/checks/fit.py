@@ -40,7 +40,9 @@ import numpy as np
 import optax
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
+from tpu_hpc.models import (
+    conv_moe, hybrid_ssm_moe, latent_moe, llama2, sparse_moe,
+)
 from tpu_hpc.parallel import hybrid, tp
 from tpu_hpc.parallel.plans import derived_pspecs, shardings_for
 
@@ -120,9 +122,14 @@ def param_counts(cfg: llama2.LlamaConfig) -> Dict[str, int]:
     (``models/latent_moe.py``) counts by kind of layer: its leading
     dense layers, then expert layers with the experts HELD here; one
     with state-space layers (``models/hybrid_ssm_moe.py``) by each
-    layer's own mixer."""
+    layer's own mixer. One that is TRAINED (``models/conv_moe.py``)
+    counts the same way: the step's expert products follow the
+    assignments computed, not the experts held, and ``active`` counts
+    a token's ``experts_per_token`` wherever they are held."""
     counts = None
-    if sparse_moe.is_sparse_moe(cfg):
+    if conv_moe.is_conv_moe(cfg):
+        counts = conv_moe.count_params(cfg)
+    elif sparse_moe.is_sparse_moe(cfg):
         counts = sparse_moe.count_params(cfg)
     elif latent_moe.is_latent_moe(cfg):
         counts = latent_moe.count_params(cfg)
@@ -357,6 +364,63 @@ def activation_bytes(
             cfg, tokens, tp_size
         )
     return out
+
+
+def conv_moe_kept_block_bytes(
+    cfg: "conv_moe.ConvMoEConfig", layer: int, tokens: int
+) -> int:
+    """:func:`kept_block_bytes` for block ``layer`` of a
+    ``models/conv_moe.py`` stack (``remat.CONV_MOE_PRODUCTS``): the
+    mixer's projections in the compute dtype, the float32 stream after
+    the mixer, a dense layer's gate and up. An expert layer keeps
+    nothing of its row buffer."""
+    item = jnp.dtype(cfg.dtype).itemsize
+    if cfg.is_attention_layer(layer):
+        per_token = (cfg.n_heads + 2 * cfg.kv_heads) * cfg.head_dim * item
+    else:
+        per_token = 3 * cfg.dim * item
+    per_token += 4 * cfg.dim
+    if cfg.is_dense_layer(layer):
+        per_token += 2 * cfg.dense_hidden * item
+    return tokens * per_token
+
+
+def conv_moe_activation_bytes(
+    cfg: "conv_moe.ConvMoEConfig", tokens: int
+) -> Dict[str, int]:
+    """:func:`activation_bytes` for a ``models/conv_moe.py`` stack on
+    one chip when every block recomputes: the float32 stream saved at
+    each block's input, the most any one block holds while it is
+    recomputed and differentiated, and the head. An expert layer's
+    buffers are sized for the worst routing
+    (``sparse_moe.ragged_rows``), whatever the step routes."""
+    d, item, f32 = cfg.dim, jnp.dtype(cfg.dtype).itemsize, 4
+    live = 0
+    for i in range(cfg.n_layers):
+        if cfg.is_attention_layer(i):
+            mixer = tokens * (
+                2 * (cfg.n_heads + cfg.kv_heads) * cfg.head_dim * item
+                + cfg.n_heads * f32
+            )
+        else:
+            mixer = tokens * 5 * d * item
+        if cfg.is_dense_layer(i):
+            ffn = 3 * tokens * cfg.dense_hidden * item
+        else:
+            rows = sparse_moe.ragged_rows(tokens, cfg)
+            k = cfg.experts_per_token
+            ffn = rows * (2 * d + 3 * cfg.expert_hidden) * item \
+                + tokens * k * d * (item + f32)
+        # The block's input and two stream-sized results, float32.
+        # (Calibrated on the v5e compiler at the benchmark's cut, 5
+        # layers x 32768 tokens: it reads 5.6 GiB of temporaries beside
+        # the gradient tree where this reckons 6.5.)
+        live = max(live, 3 * tokens * d * f32 + mixer + ffn)
+    return {
+        "residual_checkpoints": (cfg.n_layers + 1) * tokens * d * f32,
+        "block_recompute_live": live,
+        "lm_head_and_loss": tokens * cfg.vocab_size * (2 * item + f32),
+    }
 
 
 def activation_model(

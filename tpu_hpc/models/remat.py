@@ -34,9 +34,21 @@ KEPT_PRODUCTS = (
 )
 
 
-def keep_products():
+# What a keeping block of ``models/conv_moe.py`` saves: the short
+# convolution's input projection (``B``, ``C`` and ``X`` in one), the
+# attention layer's three as above, the stream after the mixer, and a
+# dense layer's gate and up. Nothing of an expert layer: its products
+# live in a row buffer sized for the worst routing a step can produce,
+# eight times the rows that carry an assignment at an even load.
+CONV_MOE_PRODUCTS = (
+    "conv_in", "proj_q", "proj_k", "proj_v", "attn_residual", "ffn_gate",
+    "ffn_up",
+)
+
+
+def keep_products(names=KEPT_PRODUCTS):
     """The ``jax.checkpoint`` policy of a keeping block."""
-    return jax.checkpoint_policies.save_only_these_names(*KEPT_PRODUCTS)
+    return jax.checkpoint_policies.save_only_these_names(*names)
 
 
 # The share of a device's memory limit the budget leaves unspoken for:
@@ -85,14 +97,27 @@ class RematBudget:
         self, n_blocks: int, block_bytes: int, recompute_bytes: int
     ) -> int:
         """Called by the model: ``recompute_bytes`` is what its
-        activations take a chip when every block recomputes."""
-        self.n_blocks = n_blocks
-        self.block_bytes = block_bytes
+        activations take a chip when every block recomputes. Blocks
+        keep, in order, while the room holds one more."""
+        return self.decide_each([block_bytes] * n_blocks, recompute_bytes)
+
+    def decide_each(self, block_bytes, recompute_bytes: int) -> int:
+        """:meth:`decide` for a stack whose blocks differ
+        (``block_bytes``: one number a block, in order): leading blocks
+        keep while the room holds the next one's products.
+        ``block_bytes`` then reads the mean of those that keep."""
+        self.n_blocks = len(block_bytes)
         self.room_bytes = self.free_bytes - recompute_bytes
-        # Blocks keep, in order, while the room holds one more.
-        kept = min(n_blocks, max(self.room_bytes, 0) // max(block_bytes, 1))
-        self.blocks_kept = kept if self.cap is None else min(kept, self.cap)
-        return self.blocks_kept
+        kept = spent = 0
+        for cost in block_bytes:
+            if spent + cost > self.room_bytes or kept == self.cap:
+                break
+            kept, spent = kept + 1, spent + cost
+        self.blocks_kept = kept
+        self.block_bytes = (
+            spent // kept if kept else max(block_bytes, default=0)
+        )
+        return kept
 
 
 _OPEN: contextvars.ContextVar[Optional[RematBudget]] = (

@@ -441,6 +441,168 @@ def expert_ffn(h, gates, experts, lp, cfg: SparseMoEConfig, weight=None,
     return out, counts
 
 
+# ---------------------------------------------------------------------
+# The third traversal: many rows, differentiable (training)
+# ---------------------------------------------------------------------
+
+# Rows a tile of the TPU's ragged product holds (read off its compiled
+# metadata on the v5e: ``jax.lax.ragged_dot`` over 131072 rows lowers
+# to a Mosaic call over 256 row tiles, a tile visited once for each
+# group it overlaps and the tiles past the last group's rows not at
+# all). ``rows_computed`` is RECKONED in these from the groups' sizes,
+# not returned by the kernel; tests/test_fit.py compiles the products
+# for the v5e and holds this number to the visits their metadata lists.
+RAGGED_ROW_TILE = 512
+
+
+def ragged_rows(n_tokens: int, cfg) -> int:
+    """The most rows :func:`ragged_expert_ffn` can be asked to compute
+    for ``n_tokens`` tokens: every one of them choosing as many held
+    experts as it may. The row buffer's static size."""
+    return n_tokens * min(cfg.experts_per_token, cfg.n_held)
+
+
+@jax.custom_vjp
+def _dispatch(x, row_token, at, held):
+    """``rows[r] = x[row_token[r]]``. Its transpose is written as the
+    gather it is (``dx[t] = sum_j drows[at[t, j]]`` over the held
+    assignments of token ``t``): the gather's own transpose would be a
+    scatter-add of every row, which a TPU walks index by index."""
+    return x[row_token]
+
+
+def _dispatch_fwd(x, row_token, at, held):
+    return x[row_token], (at, held)
+
+
+def _dispatch_bwd(res, d_rows):
+    at, held = res
+    picked = jnp.where(held[..., None], d_rows[at], 0)
+    return jnp.sum(picked.astype(jnp.float32), axis=1) \
+        .astype(d_rows.dtype), None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _combine(rows, gates, at, held, row_token, row_gate_at, n_rows):
+    """``out[t] = sum_j gates[t, j] * rows[at[t, j]]`` over the held
+    assignments of token ``t``, in float32. ``row_token [R]`` and
+    ``row_gate_at [R]`` (a row's token and its place in the flattened
+    gates) and ``n_rows`` (the rows that carry an assignment) are for
+    the transpose, a gather too: ``drows[r] = gate of r * dout[token of
+    r]``, and ``dgates[t, j] = dout[t] . rows[at[t, j]]``."""
+    picked = jnp.where(held[..., None], rows[at], 0).astype(jnp.float32)
+    return jnp.einsum("tk,tkd->td", gates, picked)
+
+
+def _combine_fwd(rows, gates, at, held, row_token, row_gate_at, n_rows):
+    out = _combine(rows, gates, at, held, row_token, row_gate_at, n_rows)
+    return out, (rows, gates, at, held, row_token, row_gate_at, n_rows)
+
+
+def _combine_bwd(res, d_out):
+    rows, gates, at, held, row_token, row_gate_at, n_rows = res
+    picked = jnp.where(held[..., None], rows[at], 0).astype(jnp.float32)
+    d_gates = jnp.einsum("td,tkd->tk", d_out, picked)
+    live = jnp.arange(rows.shape[0]) < n_rows
+    row_gate = jnp.where(live, gates.reshape(-1)[row_gate_at], 0.0)
+    d_rows = (row_gate[:, None] * d_out[row_token]).astype(rows.dtype)
+    return d_rows, d_gates, None, None, None, None, None
+
+
+_combine.defvjp(_combine_fwd, _combine_bwd)
+
+
+def ragged_expert_ffn(h, gates, experts, moe, cfg):
+    """The held experts' part of ``sum_e gate_e * W2_e(silu(W1_e h) *
+    W3_e h)`` for MANY rows ``h [tokens, dim]``, float32, and the
+    step's counts: :func:`expert_ffn`'s sum by a third traversal, whose
+    work follows the assignments MADE and which ``jax.grad`` goes
+    through.
+
+    The assignments to held experts are sorted by expert (stable: a
+    group's rows stay in token order), their tokens' rows gathered into
+    a buffer of :func:`ragged_rows` rows (static: the worst case the
+    step can produce), and three ragged grouped products
+    (``jax.lax.ragged_dot``: group ``e`` is the run of rows expert
+    ``e`` got, of any length) make ``W2_e(silu(W1_e x) * W3_e x)`` for
+    each row; a token adds up its rows by gate. The product visits the
+    row tiles that hold a group's rows and no tile past the last row
+    that exists, so EVERY assignment to a held expert is computed at
+    any imbalance (no capacity, nothing dropped: ``dropped`` counts the
+    held assignments whose row lies outside its expert's group, 0 by
+    construction) and an absent expert adds nothing. Operands in ``cfg.dtype``, float32
+    accumulation, each product rounded once.
+
+    Counts, int32: ``assignments`` made, ``assignments_held`` of them
+    on held experts (= rows that carry an assignment), ``rows_computed``
+    (rows of the tiles the products visit: :data:`RAGGED_ROW_TILE` a
+    tile, a tile once for each group it overlaps),
+    ``max_rows_per_expert`` (the longest group) and ``dropped``."""
+    n_tok, k = experts.shape
+    n_held, n_rows_max = cfg.n_held, ragged_rows(n_tok, cfg)
+    with jax.named_scope("dispatch"):
+        slot = _held_slots(cfg)[experts]                    # [t, k]
+        held = slot < n_held
+        flat = slot.reshape(-1)
+        # Sorted by expert, absent ones (slot n_held) last; an
+        # assignment's place among the rows is the inverse permutation
+        # (a second sort: the scatter that would write it walks its
+        # indices one by one).
+        order = jnp.argsort(flat, stable=True).astype(jnp.int32)
+        at = jnp.argsort(order).astype(jnp.int32).reshape(n_tok, k)
+        group_sizes = jnp.sum(
+            flat[:, None] == jnp.arange(n_held, dtype=jnp.int32), axis=0,
+            dtype=jnp.int32,
+        )
+        ends = jnp.cumsum(group_sizes)
+        starts = ends - group_sizes
+        n_rows = ends[-1]
+        # Where a token can choose more experts than are held, the
+        # rows past the bound are absent experts' alone.
+        order = order[:n_rows_max]
+        row_token = order // k
+
+    def product(x, w):
+        return jax.lax.ragged_dot(
+            x, w.astype(cfg.dtype), group_sizes,
+            preferred_element_type=jnp.float32,
+        ).astype(cfg.dtype)
+
+    with jax.named_scope("dispatch"):
+        at = jnp.minimum(at, n_rows_max - 1)
+        rows = _dispatch(h.astype(cfg.dtype), row_token, at, held)
+    with jax.named_scope("experts"):
+        hidden = jax.nn.silu(product(rows, moe["w1"])) \
+            * product(rows, moe["w3"])
+        rows = product(hidden, moe["w2"])
+    with jax.named_scope("combine"):
+        out = _combine(
+            rows, gates.astype(jnp.float32), at, held, row_token, order,
+            n_rows,
+        )
+    tiles = jnp.where(
+        group_sizes > 0,
+        (ends + RAGGED_ROW_TILE - 1) // RAGGED_ROW_TILE
+        - starts // RAGGED_ROW_TILE,
+        0,
+    )
+    # An assignment is covered where its row lies inside its expert's
+    # group, which is all the products multiply by that expert.
+    own = jnp.minimum(slot, n_held - 1)
+    covered = held & (at >= starts[own]) & (at < ends[own])
+    counts = {
+        "assignments": jnp.int32(n_tok * k),
+        "assignments_held": n_rows,
+        "rows_computed": jnp.sum(tiles) * RAGGED_ROW_TILE,
+        "max_rows_per_expert": jnp.max(group_sizes),
+        "dropped": jnp.sum(held & ~covered, dtype=jnp.int32),
+    }
+    return out, counts
+
+
 def rope_part(x, cos, sin, n):
     """Rotate the leading ``n`` numbers of the last dim (adjacent
     pairs, as ``llama2.apply_rope``), pass the rest."""

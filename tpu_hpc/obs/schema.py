@@ -152,7 +152,9 @@ EVENTS: Dict[str, EventSpec] = {
     "epoch": EventSpec(
         ("epoch", "step", "loss", "items_per_s",
          "items_per_s_per_device", "s_per_step"),
-        optional=("grad_norm",),
+        # ``counted``: what the forward counted over the chunk's steps
+        # (an expert layer's ``train_moe_*``), by name.
+        optional=("grad_norm", "counted"),
     ),
     "eval": EventSpec(("step", "n_steps", "loss"), open=True),
     "run_end": EventSpec((

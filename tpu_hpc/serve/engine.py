@@ -37,7 +37,9 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
+from tpu_hpc.models import (
+    conv_moe, hybrid_ssm_moe, latent_moe, llama2, sparse_moe,
+)
 from tpu_hpc.obs import get_registry, span
 from tpu_hpc.serve.decoder import (
     _embed,
@@ -222,6 +224,15 @@ class Engine:
     ):
         from tpu_hpc.serve.weights import place_params, serving_pspecs
 
+        conv_moe.refuse(
+            cfg, "the serving engine (slab or paged)",
+            "a short-convolution layer leaves a state of rows alone "
+            "(the last two inputs of its taps): the slab cache holds "
+            "keys and values, and paging.RecurrentState holds three "
+            "rows AND a state-space matrix a layer and has no "
+            "constructor for rows alone, nor the prefix trie a "
+            "snapshot of them",
+        )
         if not getattr(self, "is_paged", False):
             sparse_moe.refuse(
                 cfg, "the slab Engine",

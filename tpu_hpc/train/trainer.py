@@ -103,6 +103,47 @@ def _bytes_per_device(tree: Any) -> int:
     )
 
 
+def is_counter(name: str) -> bool:
+    """A step metric that COUNTS (``*_total``) is summed over a chunk's
+    steps and a step's microbatches; a high-water mark
+    (:func:`is_high_water`) takes the largest; any other reports the
+    chunk's last step and the microbatches' mean."""
+    return name.endswith("_total")
+
+
+def is_high_water(name: str) -> bool:
+    return "_max_" in name
+
+
+def fold_metrics(stacked: Dict, mean: bool = False) -> Dict:
+    """Stacked step metrics (leading axis: a chunk's steps, or a step's
+    microbatches under ``mean``) -> one value each, by
+    :func:`is_counter`'s rule."""
+    def fold(name):
+        if is_counter(name):
+            return lambda a: jnp.sum(a, axis=0)
+        if is_high_water(name):
+            return lambda a: jnp.max(a, axis=0)
+        return (lambda a: jnp.mean(a, axis=0)) if mean else (lambda a: a[-1])
+
+    return {
+        name: jax.tree.map(fold(name), value)
+        for name, value in stacked.items()
+    }
+
+
+def merge_metrics(chunk: Dict, step: Dict) -> Dict:
+    """:func:`fold_metrics` a step at a time, for the host-fed loop."""
+    def merge(name, new):
+        if name in chunk and is_counter(name):
+            return chunk[name] + new
+        if name in chunk and is_high_water(name):
+            return jnp.maximum(chunk[name], new)
+        return new
+
+    return {name: merge(name, new) for name, new in step.items()}
+
+
 def make_microbatch_constrain(
     mesh: Mesh, batch_sharding: Any
 ) -> Callable[[Any], Any]:
@@ -362,7 +403,7 @@ def make_step_fn(
             )
             loss = lsum / grad_accum
             grads = jax.tree.map(lambda g: g / grad_accum, gsum)
-            aux = jax.tree.map(lambda a: jnp.mean(a, axis=0), aux_stack)
+            aux = fold_metrics(aux_stack, mean=True)
 
         if numeric_fault is not None:
             # Chaos injection keyed on the DATA index: after a guard
@@ -500,7 +541,9 @@ class Trainer:
         mesh (bench.py: the mode picks the mesh family) pass it here
         so the trainer runs exactly that decision instead of
         re-planning. Ignored unless cfg.comm_mode == "auto"."""
-        from tpu_hpc.models import hybrid_ssm_moe, latent_moe, sparse_moe
+        from tpu_hpc.models import (
+            conv_moe, hybrid_ssm_moe, latent_moe, sparse_moe,
+        )
 
         hybrid_ssm_moe.refuse_weights(
             params, "the Trainer",
@@ -517,6 +560,9 @@ class Trainer:
             "no training forward, loss or sharding plan goes through "
             "latent attention or the expert layer",
         )
+        # An expert stack that none of the three served-only trees
+        # owns trains only through its own configuration's forward.
+        conv_moe.check_forward(params, forward, "the Trainer")
         self.cfg = cfg
         self.mesh = mesh
         self.forward = forward
@@ -855,6 +901,11 @@ class Trainer:
                      "recompute)")
         reg.describe("train_remat_kept_bytes",
                      "Bytes a chip holds of those kept outputs")
+        # What the forward itself counts each step (an expert layer's
+        # ``train_moe_*``: models/conv_moe.py), described by the
+        # forward that returns it.
+        for name, text in getattr(forward, "counters", {}).items():
+            reg.describe(name, text)
         # Anomaly-triggered capture (obs/trace.py): a stall-watermark
         # trip or a guard poisoned verdict auto-arms ONE bounded
         # jax.profiler trace + flight dump, keyed by the triggering
@@ -1587,7 +1638,7 @@ class Trainer:
                             )
                         else:
                             self.state, stacked = epoch_fn(self.state)
-                        last_metrics = jax.tree.map(lambda a: a[-1], stacked)
+                        last_metrics = fold_metrics(stacked)
                         if self.guard_policy is not None:
                             # The guard's per-step evidence: the stacked
                             # health vectors for the WHOLE chunk (a few
@@ -1606,7 +1657,10 @@ class Trainer:
                                 done + i + off, cfg.global_batch_size
                             )
                             data_s += time.perf_counter() - t_data
-                            last_metrics = self.train_step(batch)
+                            last_metrics = merge_metrics(
+                                last_metrics if i else {},
+                                self.train_step(batch),
+                            )
                             if self.guard_policy is not None:
                                 per_step_health.append({
                                     k: last_metrics[k]
@@ -1680,6 +1734,18 @@ class Trainer:
             reg.inc("train_items_total", chunk * cfg.global_batch_size)
             reg.set_gauge("train_step", done)
             reg.observe("train_step_s", s_per_step)
+            # What the forward counts (an expert layer's assignments
+            # and rows: models/conv_moe.py) came in the chunk's one
+            # fetch, already summed over its steps.
+            counted = {
+                k: int(v) for k, v in last_metrics.items()
+                if is_counter(k) or is_high_water(k)
+            }
+            for name, value in counted.items():
+                if is_counter(name):
+                    reg.inc(name, value)
+                else:
+                    reg.set_gauge(name, max(reg.gauge(name) or 0, value))
             if self._watchdog is not None:
                 self._watchdog.tick()
             if self.heartbeat is not None:
@@ -1725,6 +1791,8 @@ class Trainer:
                     rec["grad_norm"] = _json_finite(
                         last_metrics["grad_norm"]
                     )
+                if counted:
+                    rec["counted"] = counted
                 self._append_metrics(rec)
                 reg.set_gauge("train_loss", loss)
                 reg.set_gauge(
