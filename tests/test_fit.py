@@ -632,6 +632,103 @@ def test_latent_programs_address_the_pool_in_place(
     assert temp < 2 * view_tokens * row + 2 * expanded + pool_bytes // 2, temp
 
 
+def test_the_selected_decode_program_walks_the_pool_in_place(
+    v5e_2x2, monkeypatch
+):
+    """``serve-docqa-keye30b``'s decode program, compiled for the chip
+    at the cell's shape (2 of its 4 layers): it reads K and V through
+    the kernel that walks the tables (``sparse_paged_decode``, a Mosaic
+    call a layer over the pools as they lie) and holds NO gathered
+    view of either (12 x 1920 pages of 4 x 16 x 128), no score a query
+    head and column, no copy of the K or V pool and no per-layer slice
+    of one; its temporaries stay under ONE of the two views the
+    gathered form wrote a layer (what is left is the indexer's: its
+    keys' pool and view, and its scores). Both kernels are compiled by
+    Mosaic as the chip would (the program asks the backend whether to
+    interpret: here the test answers for the described chip)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import dataclasses
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_hpc.models import sparse_moe
+    from tpu_hpc.serve import paging
+
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    slots, capacity, bs, bucket = 12, 30720, 16, 512
+    cfg = dataclasses.replace(
+        sparse_moe.KEYE_VL2_30B_A3B, n_layers=2, max_seq_len=capacity,
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    )
+    mb = capacity // bs
+    width = mb + bucket // bs
+    num_blocks = 8 * 1792 + slots * 140 + 1
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: sparse_moe.init_sparse_moe(jax.random.key(0), cfg)
+        ),
+    )
+    page = (cfg.kv_heads, bs, cfg.head_dim)
+    pool_shapes = [
+        (cfg.n_layers, num_blocks, *page), (cfg.n_layers, num_blocks, *page),
+        (cfg.n_layers, num_blocks, bs, cfg.indexer_head_dim),
+    ]
+    i32 = jnp.int32
+    compiled = jax.jit(
+        paging.make_paged_decode_fn(cfg, bs, mb, width),
+        donate_argnums=(1, 2, 3),
+    ).lower(
+        params, *(sds(shape, jnp.bfloat16) for shape in pool_shapes),
+        sds((slots + len(paging.SPARSE_COUNTERS),), i32),
+        sds((len(paging.STEP_ROWS), slots), i32), sds((slots, width), i32),
+    ).compile()
+
+    def spelled(shape):
+        return "bf16[" + ",".join(map(str, shape)) + "]"
+
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    kernels = {
+        "sparse_paged_decode": cfg.n_layers, "grouped_experts": cfg.n_layers
+    }
+    assert text.count("tpu_custom_call") == sum(kernels.values())
+    for name, calls in kernels.items():
+        assert len(re.findall(
+            rf"^\s*%{name}[.\d]* = [^\n]*tpu_custom_call", text, re.M
+        )) == calls, name
+    pool_results = 0
+    for result, opcode in _HLO_INSTRUCTION.findall(entry):
+        # K and V. (The indexer's keys, 64 to a row, are laid out
+        # pages-minor and copied in and out by this program as by the
+        # parent's: ROADMAP A10, the indexer's own.)
+        if spelled(pool_shapes[0][1:]) in result:
+            assert opcode == "parameter", (
+                f"{opcode} materialises a per-layer slice: {result}"
+            )
+        if spelled(pool_shapes[0]) in result:
+            pool_results += 1
+            assert opcode != "copy", f"whole-pool copy: {result}"
+        for view in ((slots, mb, *page), (slots * mb, *page),
+                     (slots, cfg.kv_heads, capacity, cfg.head_dim)):
+            assert spelled(view) not in result, (
+                f"{opcode} holds a gathered view: {result}"
+            )
+        # no score a query head and column (the indexer's are a column)
+        assert not re.search(
+            rf"\[{slots},({cfg.kv_heads},\d+|{cfg.n_heads}),[\d,]*"
+            rf"\b{capacity}\b", result
+        ), f"{opcode} holds a score a head and column: {result}"
+    assert pool_results >= 4
+    view_bytes = 2 * slots * mb * cfg.kv_heads * bs * cfg.head_dim
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < view_bytes, temp
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_hybrid_programs_keep_the_state_in_place(v5e_2x2, program):
     """``serve-docqa-granite4h-small``'s decode and chunk programs,

@@ -521,7 +521,13 @@ def test_counts_come_back_with_the_tokens(params, mesh):
 # parent commit of the PR that gave every program one layer loop
 # (PR 29, on 2a3e19e's code). ``flat<pages>`` are the decode programs
 # of the flat rungs an engine of this shape holds (4 slots x 12 pages:
-# 18 and 24), as the PR that brought them left them (PR 30).
+# 18 and 24), as the PR that brought them left them (PR 30). PR 38
+# re-pinned ``sparse-decode`` and ``sparse-decode_probe`` (from
+# 901f33d0d5597192 / 9317ff59722907af) and no other: a sparse-selection
+# row step reads K and V through the kernel that walks the page tables
+# (``kernels/sparse_paged_attention.py``: interpreted here, so the
+# kernel's own text is part of the program's); ``sparse-prefill`` still
+# gathers its one view.
 PROGRAM_DIGESTS = {
     "gqa-decode-gather-none": "81408bbaba39a8c9",
     "gqa-prefill-gather-none": "8a8972db9fc5d25e",
@@ -546,8 +552,8 @@ PROGRAM_DIGESTS = {
     "gqa-spec_draft": "adf0b8caaeed1392",
     "gqa-spec_verify-onehot": "263c7331d62839f2",
     "gqa-spec_verify-probs": "3b8616518ea90903",
-    "sparse-decode": "901f33d0d5597192",
-    "sparse-decode_probe": "9317ff59722907af",
+    "sparse-decode": "b7a3b99c22315c58",
+    "sparse-decode_probe": "1f367c6a19665171",
     "sparse-prefill": "98bb54d41635cafb",
     "gqa-flat18-gather-none": "ba224ec5956db4d4",
     "gqa-flat24-gather-none": "b21373b7aa6c8bf3",

@@ -29,15 +29,12 @@ compute block, brought in by the kernel's own copies.)
 **What the chip asked for** (TPU v5 lite at 16 slots x ~29k tokens;
 docs/guide/latent_moe.md has the table). A layer's walk is ~59k copies
 of 16 KB and 2 KB, and what bounds it is how fast they are ISSUED, not
-the bandwidth. So the copies of a whole block are straight-line code
-(a branch or a loop round between them costs more than the copy), a
-whole block is waited for with one wait an array, only a slot's first
-and last block go through a loop, and the loop over blocks runs two a
-round so that each block's buffer is known where the kernel is
-compiled: then the next block's copies are issued beside this block's
-products. The kernel is traced and lowered ONCE for all the layers of
-a program (:func:`_walk` is jitted and takes its layer as an operand):
-a decode program's build is part of what a server's start pays.
+the bandwidth: ``kernels/page_walk.py`` (the walk itself, which a
+sparse-selection configuration's read shares) says what that made of
+the copies, the waits and the loop over blocks. The kernel is traced
+and lowered ONCE for all the layers of a program (:func:`_walk` is
+jitted and takes its layer as an operand): a decode program's build is
+part of what a server's start pays.
 
 **Tokens as they lie.** Both pool arrays hold ``pack`` tokens a row
 (``paging.rope_pack``: two, at a rotary key of 64 numbers and the
@@ -65,6 +62,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from tpu_hpc.kernels.page_walk import Paged, page_walk
+
 # Pages a compute block: what one buffer holds and one round of the
 # online softmax scores. Swept on the v5e at serve-docqa-joyai-flash's
 # shape (docs/guide/latent_moe.md has the table).
@@ -82,99 +81,20 @@ def _kernel(
     layer = layer_ref[0]
     pos = pos_ref[s]
     n_live = jnp.where(active_ref[s] > 0, pos // block_size + 1, 0)
-    n_blocks = pl.cdiv(n_live, pages)
 
-    @pl.when(s == 0)
-    def _clean():
-        # What a buffer holds where no page landed is multiplied by a
-        # probability of zero: it has to be a number.
-        c_buf[...] = jnp.zeros_like(c_buf)
-        r_buf[...] = jnp.zeros_like(r_buf)
-
-    def copies(block, buf, i):
-        page = tables_ref[s * width + block * pages + i]
-        at = pl.ds(pl.multiple_of(i * rows, rows), rows)
-        return (
-            pltpu.make_async_copy(
-                ks_ref.at[layer, page], c_buf.at[buf, at], sems.at[0, buf]
-            ),
-            pltpu.make_async_copy(
-                vs_ref.at[layer, page], r_buf.at[buf, at], sems.at[1, buf]
-            ),
-        )
-
-    def in_a_loop(block, buf, what):
-        """``what`` the copies of as many of ``block``'s pages as are
-        live, a page a round of a loop."""
-        def page(i, _):
-            for copy in copies(block, buf, i):
-                what(copy)
-
-        jax.lax.fori_loop(
-            0, jnp.minimum(n_live - block * pages, pages), page, None
-        )
-
-    def each_page(block, buf, what, whole=None):
-        """``what`` every live page's two copies of ``block``. Those
-        of a WHOLE block are straight-line code, no branch and no loop
-        between them (or ``whole``, if given, in their place): that is
-        what lets the chip issue them beside the products of the block
-        before (a branch a copy cost the v5e 0.4 ms a layer, a loop
-        round of eight copies as much). A slot's LAST block, the one
-        block that may hold fewer than ``pages`` live pages, takes the
-        loop."""
-        full = (block + 1) * pages <= n_live
-
-        @pl.when(full)
-        def _whole():
-            if whole is not None:
-                return whole()
-            for i in range(pages):
-                for copy in copies(block, buf, i):
-                    what(copy)
-
-        @pl.when(jnp.logical_not(full))
-        def _last():
-            in_a_loop(block, buf, what)
-
-    def start(copy):
-        copy.start()
-
-    def finish(copy):
-        copy.wait()
-
-    def wait(block, buf):
-        def whole():
-            # A DMA semaphore counts bytes: one wait an array for a
-            # whole buffer's worth is the wait for its ``pages`` copies.
-            for array, sem in ((c_buf, 0), (r_buf, 1)):
-                pltpu.make_async_copy(
-                    array.at[1 - buf], array.at[buf], sems.at[sem, buf]
-                ).wait()
-
-        each_page(block, buf, finish, whole)
-
-    # A slot's first block: once a slot, so the loop will do (and the
-    # kernel is a third shorter to trace and lower).
-    in_a_loop(0, 0, start)
+    run = page_walk(s, n_live, tables_ref, width, pages, rows, (
+        Paged(lambda page: ks_ref.at[layer, page], c_buf),
+        Paged(lambda page: vs_ref.at[layer, page], r_buf),
+    ), sems)
 
     q = q_ref[...]                             # [heads, rank]
     heads = q.shape[0]
     nt = (((1,), (1,)), ((), ()))              # a @ b.T
 
     def score(block, buf, carry):
-        """One round of the online softmax over ``block``, which lands
-        in buffer ``buf`` (a Python int: with the buffer known where
-        the program is compiled, the next block's copies overlap this
-        block's products; indexed by ``block % 2`` they did not, 1.45
-        against 1.16 ms a layer on the v5e)."""
+        """One round of the online softmax over ``block``, landed in
+        buffer ``buf``."""
         top, total, acc = carry
-
-        @pl.when(block + 1 < n_blocks)
-        def _next():
-            each_page(block + 1, 1 - buf, start)
-
-        wait(block, buf)
         # [pages * rows, pack * rank] and [.., pack * rope]
         c, r = c_buf[buf].astype(q.dtype), r_buf[buf].astype(q.dtype)
         # Row i of the block is tokens first + pack * i + j.
@@ -208,15 +128,7 @@ def _kernel(
             )
         return new_top, total, acc
 
-    def two_blocks(i, carry):
-        carry = score(2 * i, 0, carry)
-        return jax.lax.cond(
-            2 * i + 1 < n_blocks,
-            lambda carry: score(2 * i + 1, 1, carry),
-            lambda carry: carry, carry,
-        )
-
-    _, total, acc = jax.lax.fori_loop(0, pl.cdiv(n_blocks, 2), two_blocks, (
+    _, total, acc = run(score, lambda: (
         jnp.full((heads, 1), -jnp.inf, jnp.float32),
         jnp.zeros((heads, 1), jnp.float32),
         jnp.zeros((heads, rank), jnp.float32),

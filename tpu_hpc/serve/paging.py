@@ -81,6 +81,7 @@ from tpu_hpc.kernels.paged_attention import (
     tokens_to_pages,
     write_tokens,
 )
+from tpu_hpc.kernels.sparse_paged_attention import sparse_paged_decode
 from tpu_hpc.obs import get_bus, get_registry, span
 from tpu_hpc.serve.decoder import (
     _embed,
@@ -89,6 +90,7 @@ from tpu_hpc.serve.decoder import (
     _grouped_attention_paged,
     _logits_head,
     _rope_tables,
+    _score_scale,
     decoder_layers,
 )
 from tpu_hpc.serve.engine import Engine, ServeConfig
@@ -113,8 +115,9 @@ DECODE_COUNTERS: Tuple[Tuple[str, str], ...] = (
     ("serve_decode_view_pages_read_total",
      "KV pages the dispatched decode programs read a layer: the flat "
      "rung's size, slots x pages a slot on the rectangle, or (a latent "
-     "configuration, whose kernel walks the tables) the live pages of "
-     "the active slots, a shared page once a slot"),
+     "or sparse-selection configuration, whose kernel walks the "
+     "tables) the live pages of the active slots, a shared page once "
+     "a slot"),
     ("serve_decode_view_pages_total",
      "KV pages the rectangle would have gathered a layer (slots x "
      "pages a slot, a decode step); read over this is how much of "
@@ -1084,14 +1087,18 @@ def _with_state(body, name: str, quant: bool, sparse: bool,
 
 
 def _check_read_path(cfg, kernel: str, kv_quant: str) -> None:
-    """A sparse-expert configuration reads through ``gather`` from a
-    pool in the compute dtype: the Pallas kernels walk the whole table
-    (no selection) and the int8 page write has no indexer key."""
+    """A sparse-expert configuration reads under ``gather`` from a
+    pool in the compute dtype: ``kernels/paged_attention.py`` walks the
+    whole table (no selection) and the int8 page write has no indexer
+    key."""
     if kernel != "gather" or kv_quant != "none":
         sparse_moe.refuse(
             cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
-            "selection runs on the gather read path over an "
-            "unquantised pool only",
+            "kernels/paged_attention.py applies no selection and the "
+            "int8 page write quantises no indexer key; the selected "
+            "decode step already walks the tables in a kernel of its "
+            "own (kernels/sparse_paged_attention.py) under 'gather', "
+            "over an unquantised pool",
         )
         latent_moe.refuse(
             cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
@@ -1144,13 +1151,24 @@ class PagedAttention:
       ``xs`` under the page ids and rows their K/V went to, score every
       column of the view against each query and keep the exact top
       ``indexer_topk`` of the columns the program's mask allows. The
-      read and attention stages run as they are under THAT mask (the
-      gathered pages under the selection: on the v5e this read 2.3 ms
-      a step faster than a token-granular gather of the selected rows,
-      PERF.md PR 27). A row step also keeps each layer's selection
-      (``picked``, for the benchmark's probe) and counts what the
-      indexer scored and what attention read (``counts``, by
-      ``SPARSE_COUNTERS``' keys).
+      read runs under THAT mask, over whole pages: a CHUNK's gathered
+      view as it is, a ROW step's through
+      ``kernels/sparse_paged_attention.py``, which takes the tables,
+      ``pos``, ``active`` and the selection and walks each slot's live
+      pages itself: every K and V page once, the mask applied to the
+      block that has landed, no gathered view, no score tensor in HBM,
+      pages past ``pos`` and inactive slots unread; all of the walk
+      under ``kv_read``, what is left under ``attention`` the rounding
+      of its float32 result. Chosen by the program's kind and by
+      nothing a user sets; compiled by Mosaic on a TPU, interpreted
+      elsewhere (``sparse_moe._on_mesh``'s rule). (Whole pages under a
+      mask, not the selected rows: rows of 256 B move at 16 GB/s, and a
+      token-granular gather of them read 2.3 ms a step SLOWER than the
+      gathered rectangle, PERF.md PR 27; the walk reads 1.0 ms a layer
+      where the rectangle read 4.8, PR 38.) A row step also keeps each
+      layer's selection (``picked``, for the benchmark's probe) and
+      counts what the indexer scored and what attention read
+      (``counts``, by ``SPARSE_COUNTERS``' keys).
     * ``kv_read`` + ``attention`` -- ``kernel="gather"``: ONE
       data-indexed gather of the view's pages by layer and table
       (dequantized from an int8 pool) and the model's dense attention
@@ -1222,6 +1240,12 @@ class PagedAttention:
             # A row step's read (a chunk gathers its one view).
             self.walk = sparse_moe._on_mesh(functools.partial(
                 latent_paged_decode, scale=cfg.qk_head_dim ** -0.5
+            ), mesh)
+        if self.sparse and not chunk:
+            # A row step's read under the selection (a chunk attends
+            # over its one gathered view).
+            self.walk = sparse_moe._on_mesh(functools.partial(
+                sparse_paged_decode, scale=_score_scale(cfg)
             ), mesh)
         # A stack with state-space layers: pages for the others only.
         self.hybrid = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
@@ -1345,6 +1369,8 @@ class PagedAttention:
         mask = self.mask
         if self.sparse:
             mask = self._select(layer, h, lp)
+            if not self.chunk:
+                return self._read_selected(layer, q, mask)
         return self._read(layer, q, mask)
 
     def _target(self, j):
@@ -1507,6 +1533,29 @@ class PagedAttention:
                 jnp.where(self.counted[:, None, None], chosen, False)
             )
         return mask
+
+    def _read_selected(self, layer, q, mask):
+        """A row step's read under the layer's selection, by the
+        kernel that walks the tables: every live page of a slot once,
+        no gathered view, no score in HBM. All of the walk under
+        ``kv_read`` (the rule for a table-walking kernel); what is
+        left under ``attention`` is the rounding of its float32
+        result."""
+        cfg = self.cfg
+        slots = q.shape[0]
+        with jax.named_scope("kv_read"):
+            pos, active = self.where
+            out = self.walk(
+                q[:, 0].astype(cfg.dtype).reshape(
+                    slots, cfg.kv_heads, -1, cfg.head_dim
+                ),
+                self.ks, self.vs, jnp.asarray(layer, jnp.int32),
+                self.view_ids, pos, active, mask[:, 0, 0, 0],
+            )
+        with jax.named_scope("attention"):
+            return out.astype(cfg.dtype).reshape(
+                slots, 1, cfg.n_heads, cfg.head_dim
+            )
 
     def _read(self, layer, q, mask):
         cfg, quant = self.cfg, self.quant
@@ -2235,14 +2284,16 @@ class PagedEngine(Engine):
                     "moe" in params[f"layers_{i}"]
                     for i in range(cfg.n_layers)
                 )
+        # A latent or a sparse-selection configuration's decode
+        # program walks the tables in its kernel and gathers no view.
+        self._walks = (
+            latent_moe.is_latent_moe(cfg) or sparse_moe.is_sparse_moe(cfg)
+        )
         # The distinct pages the active slots read (a latent
         # configuration, and one with a recurrent state: their
         # rooflines count a shared page once).
         self._live_pages: Optional[_LivePages] = None
-        # A latent configuration's decode program walks the tables in
-        # its kernel and gathers no view.
-        self._walks = latent_moe.is_latent_moe(cfg)
-        if self._walks or self._recurrent:
+        if latent_moe.is_latent_moe(cfg) or self._recurrent:
             self._live_pages = _LivePages(bs)
             self._live_pages_total = (
                 KV_PAGES_LIVE if self._recurrent else LATENT_PAGES_LIVE
@@ -3041,7 +3092,7 @@ class PagedEngine(Engine):
                     (p for p in self.decode_rungs if p >= live), None
                 )
                 # What the program reads a layer: a flat rung's pages,
-                # a latent configuration's walk (exactly the live
+                # a table-walking kernel's walk (exactly the live
                 # pages, a shared one once a slot), else every slot's
                 # whole capacity.
                 if pages is not None:
