@@ -820,6 +820,87 @@ def test_hybrid_programs_keep_the_state_in_place(v5e_2x2, program):
     assert temp < 2.5 * 2**30, temp
 
 
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_parallel_programs_keep_the_pool_and_the_state_in_place(
+    v5e_2x2, program
+):
+    """``serve-docqa-falcon-h1-34b``'s decode and chunk programs,
+    compiled for the chip at the cell's shape (two of its four parallel
+    layers: every layer keeps pages AND a state): the pool's two arrays
+    and the state's two go in and out in their own layout with no copy
+    of any, and neither program materialises a per-layer slice of the
+    pool or (decode) of the state; temporaries stay under one layer's
+    two gathered views (1 GB: 16 slots x 1920 pages of 4 x 16 x 128)."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_hpc.models import hybrid_ssm_moe
+    from tpu_hpc.serve import paging
+
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    slots, capacity, bs, bucket = 16, 30720, 16, 512
+    cfg = dataclasses.replace(
+        hybrid_ssm_moe.FALCON_H1_34B, n_layers=2, vocab_size=65280,
+        max_seq_len=capacity, dtype=jnp.bfloat16, param_dtype=jnp.bfloat16,
+    )
+    mb = capacity // bs
+    width = mb + bucket // bs
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype), jax.eval_shape(
+            lambda: hybrid_ssm_moe.init_hybrid_ssm_moe(
+                jax.random.key(0), cfg
+            )
+        ),
+    )
+    pool_shape = (2, slots * mb + 1, cfg.kv_heads, bs, cfg.head_dim)
+    s_shape, rows_shape = cfg.state_shapes(slots)
+    state = [sds(pool_shape, jnp.bfloat16), sds(pool_shape, jnp.bfloat16),
+             sds(s_shape, jnp.float32), sds(rows_shape, jnp.float32)]
+    i32 = jnp.int32
+    if program == "decode":
+        fn = paging.make_paged_decode_fn(cfg, bs, mb, width)
+        args = (sds((slots,), i32), sds((len(paging.STEP_ROWS), slots), i32),
+                sds((slots, width), i32))
+    else:
+        fn = paging.make_chunk_prefill_fn(cfg, bucket, bs, mb, width)
+        args = (sds((1, bucket), i32), sds((), i32), sds((), i32),
+                sds((width,), i32), sds((), i32), sds((), i32))
+    compiled = jax.jit(fn, donate_argnums=(1, 2, 3, 4)).lower(
+        params, *state, *args
+    ).compile()
+
+    def spelled(dtype, shape):
+        return dtype + "[" + ",".join(map(str, shape)) + "]"
+
+    whole = [spelled("bf16", pool_shape), spelled("f32", s_shape),
+             spelled("f32", rows_shape)]
+    sliced = [spelled("bf16", pool_shape[1:])]
+    if program == "decode":
+        sliced += [spelled("f32", s_shape[1:]), spelled("f32", rows_shape[1:])]
+    text = compiled.as_text()
+    entry = text[text.index("\nENTRY "):]
+    seen = 0
+    for result, opcode in _HLO_INSTRUCTION.findall(entry):
+        for shape in whole:
+            if shape in result:
+                seen += 1
+                assert opcode != "copy", f"whole-array copy: {result}"
+        for shape in sliced:
+            if shape in result:
+                assert opcode in ("parameter", "bitcast"), (
+                    f"{opcode} materialises a per-layer slice: {result}"
+                )
+    assert seen >= 8
+    views = 2 * slots * mb * cfg.kv_heads * bs * cfg.head_dim * 2
+    assert compiled.memory_analysis().temp_size_in_bytes < views
+
+
 def test_trained_expert_layer_and_narrow_flash_lower_for_v5e(
     v5e_2x2, monkeypatch
 ):

@@ -711,14 +711,18 @@ def test_every_other_path_refuses_it_by_name(params, mesh, who):
 def test_the_flat_read_and_bad_sizes_are_refused():
     with pytest.raises(ValueError, match="state-space"):
         paging.make_paged_decode_fn(TINY, BLOCK, 12, 16, flat_pages=18)
+    # Several groups and an untied head are a parallel decoder's
+    # (tests/test_parallel_hybrid.py); groups that do not divide the
+    # heads, a width that is neither given nor ssm_expand x dim, and a
+    # rotation of another convention are nobody's.
     with pytest.raises(ValueError, match="ssm_groups"):
-        dataclasses.replace(TINY, ssm_groups=2)
+        dataclasses.replace(TINY, ssm_groups=3)
     with pytest.raises(ValueError, match="layer_types"):
         dataclasses.replace(TINY, n_layers=5)
     with pytest.raises(ValueError, match="nope"):
         dataclasses.replace(TINY, position_embedding="rope")
-    with pytest.raises(ValueError, match="tie_word_embeddings"):
-        dataclasses.replace(TINY, tie_word_embeddings=False)
+    with pytest.raises(ValueError, match="ssm_expand"):
+        dataclasses.replace(TINY, ssm_expand=3)
 
 
 # -- the programs as they lower -------------------------------------------

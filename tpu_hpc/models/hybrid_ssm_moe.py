@@ -39,6 +39,30 @@ field here:
   scaled by ``residual_multiplier``, and ``logits = rmsnorm(x) E^T /
   logits_scaling``: ONE table for both ends (``tie_word_embeddings``).
 
+The same module serves ``tiiuae/Falcon-H1-34B-Instruct`` (preset
+:data:`FALCON_H1_34B`; ``model_type`` ``falcon_h1``), whose every layer
+is of a third kind, ``"parallel"``: attention and a Mamba-2 mixer side
+by side on ONE normed input, their outputs added in one residual, so
+that every layer keeps both K/V pages and a recurrent state. What it
+brings, each a field that is 1 (or off) for granite and then not
+applied:
+
+* attention rotated at ``rope_theta`` in the rotate-half convention
+  (``position_embedding`` "rope_half"), heads of ``attention_head_dim``
+  numbers where that is not ``dim / n_heads``, the keys times
+  ``key_multiplier`` and the output times ``attention_out_multiplier``;
+* a mixer of ``ssm_groups`` groups (head ``j`` reads group ``j //
+  (heads / groups)``'s ``B`` and ``C``; the gated norm is taken over
+  each group's ``d_inner / groups`` numbers), a width ``ssm_d_ssm``
+  given by the source rather than ``ssm_expand * dim``, its input times
+  ``ssm_in_multiplier`` and each segment of ``[z | x | B | C | dt]``
+  times its own multiplier, its output times ``ssm_out_multiplier``;
+* a dense SwiGLU feed-forward of ``mlp_hidden`` (``n_experts`` 0),
+  ``down(silu(mlp_gate_multiplier * gate) * up) * mlp_down_multiplier``;
+* an untied head (``output``) whose logits are divided by
+  ``logits_scaling`` (Falcon-H1's ``lm_head_multiplier`` is its
+  inverse).
+
 Only the paged server runs it (``serve/paging.py``: a recurrent state a
 slot beside the page pool, snapshots of it in the prefix trie). The slab
 engine, the speculative runner (a rejected draft would have to roll a
@@ -62,6 +86,7 @@ from tpu_hpc.models.sparse_moe import _is_shape
 
 _PERIOD = ("mamba",) * 5 + ("attention",) + ("mamba",) * 4
 _EXACT = jax.lax.Precision.HIGHEST
+_KINDS = ("mamba", "attention", "parallel")
 
 
 @dataclasses.dataclass(frozen=True)
@@ -77,11 +102,20 @@ class HybridSSMMoEConfig(llama2.LlamaConfig):
     vocab_size: int = 100352
     norm_eps: float = 1e-5
     max_seq_len: int = 131072
-    # A layer's mixer, "mamba" or "attention": the leading
-    # ``n_layers`` entries are the layers run.
+    # A layer's mixer, "mamba", "attention" or "parallel" (both on one
+    # normed input, added): the leading ``n_layers`` entries are the
+    # layers run.
     layer_types: Tuple[str, ...] = _PERIOD * 4
+    # "nope" (nothing rotated) or "rope_half" (rotate-half at
+    # ``rope_theta``).
     position_embedding: str = "nope"
-    attention_multiplier: float = 0.0078125
+    rope_theta: float = 10000.0
+    # Numbers a head; None: ``dim / n_heads``.
+    attention_head_dim: Optional[int] = None
+    # What the scores are multiplied by; None: ``head_dim ** -0.5``.
+    attention_multiplier: Optional[float] = 0.0078125
+    key_multiplier: float = 1.0
+    attention_out_multiplier: float = 1.0
     embedding_multiplier: float = 12.0
     residual_multiplier: float = 0.22
     logits_scaling: float = 16.0
@@ -92,17 +126,32 @@ class HybridSSMMoEConfig(llama2.LlamaConfig):
     ssm_groups: int = 1
     ssm_conv: int = 4
     ssm_expand: int = 2
+    # The mixer's width where the source gives it (``mamba_d_ssm``);
+    # None: ``ssm_expand * dim``.
+    ssm_d_ssm: Optional[int] = None
     ssm_chunk: int = 256
+    ssm_in_multiplier: float = 1.0
+    # Each segment of the input projection ``[z | x | B | C | dt]``.
+    ssm_z_multiplier: float = 1.0
+    ssm_x_multiplier: float = 1.0
+    ssm_b_multiplier: float = 1.0
+    ssm_c_multiplier: float = 1.0
+    ssm_dt_multiplier: float = 1.0
+    ssm_out_multiplier: float = 1.0
     # What a sequence keeps (``S`` and the convolution's rows): what is
     # added up over a whole context stays float32 whatever the
     # products' dtype.
     ssm_state_dtype: Any = jnp.float32
+    # 0: the feed-forward is a dense SwiGLU of ``mlp_hidden``.
     n_experts: int = 72
     experts_per_token: int = 10
     expert_hidden: int = 768
     shared_hidden: int = 1536
     norm_topk_prob: bool = True
     held_experts: Optional[Tuple[int, ...]] = None
+    mlp_hidden: int = 0
+    mlp_gate_multiplier: float = 1.0
+    mlp_down_multiplier: float = 1.0
 
     def __post_init__(self):
         object.__setattr__(self, "layer_types", tuple(self.layer_types))
@@ -117,37 +166,50 @@ class HybridSSMMoEConfig(llama2.LlamaConfig):
                     f"held_experts {held} must be distinct ids below "
                     f"{self.n_experts}"
                 )
-        if not 0 < self.experts_per_token <= self.n_experts:
+        if self.n_experts and not 0 < self.experts_per_token <= self.n_experts:
             raise ValueError("experts_per_token out of range")
+        if not self.n_experts and self.mlp_hidden <= 0:
+            raise ValueError(
+                "n_experts 0 needs mlp_hidden: the width of the dense "
+                "feed-forward in place of the experts"
+            )
         kinds = self.layer_types[:self.n_layers]
-        if len(kinds) < self.n_layers or set(kinds) - {"mamba", "attention"}:
+        if len(kinds) < self.n_layers or set(kinds) - set(_KINDS):
             raise ValueError(
-                f"layer_types must name 'mamba' or 'attention' for each "
-                f"of the {self.n_layers} layers, got {self.layer_types}"
+                f"layer_types must name one of {_KINDS} for each of the "
+                f"{self.n_layers} layers, got {self.layer_types}"
             )
-        if self.position_embedding != "nope":
+        if self.position_embedding not in ("nope", "rope_half"):
             raise ValueError(
-                "position_embedding must be 'nope': the attention layers "
-                "of this decoder rotate nothing"
+                "position_embedding must be 'nope' (nothing rotated) or "
+                "'rope_half' (the rotate-half convention at rope_theta), "
+                f"got {self.position_embedding!r}"
             )
-        if not self.tie_word_embeddings:
+        if self.ssm_groups < 1 or self.ssm_heads % self.ssm_groups:
             raise ValueError(
-                "tie_word_embeddings must be true: this decoder's head "
-                "reads its embedding table and holds no other matrix"
+                f"ssm_groups {self.ssm_groups} must divide ssm_heads "
+                f"{self.ssm_heads}: each head reads one group's B and C"
             )
-        if self.ssm_groups != 1:
+        width = self.ssm_d_ssm or self.ssm_expand * self.dim
+        if self.d_inner != width:
             raise ValueError(
-                "ssm_groups must be 1: every head shares one B and one C"
-            )
-        if self.ssm_heads * self.ssm_head_dim != self.ssm_expand * self.dim:
-            raise ValueError(
-                f"ssm_heads x ssm_head_dim {self.d_inner} must be "
-                f"ssm_expand x dim {self.ssm_expand * self.dim}"
+                f"ssm_heads x ssm_head_dim {self.d_inner} must be the "
+                f"mixer's width {width} (ssm_d_ssm, else ssm_expand x dim)"
             )
 
     @property
+    def head_dim(self) -> int:
+        return self.attention_head_dim or self.dim // self.n_heads
+
+    @property
+    def lm_head_multiplier(self) -> float:
+        """What the logits are multiplied by (Falcon-H1's name for the
+        inverse of ``logits_scaling``)."""
+        return 1.0 / self.logits_scaling
+
+    @property
     def ffn_hidden(self) -> int:
-        return self.expert_hidden
+        return self.expert_hidden if self.n_experts else self.mlp_hidden
 
     @property
     def n_held(self) -> int:
@@ -173,7 +235,12 @@ class HybridSSMMoEConfig(llama2.LlamaConfig):
         return self.d_inner + 2 * self.ssm_groups * self.ssm_state
 
     def is_ssm_layer(self, layer: int) -> bool:
-        return self.layer_types[layer] == "mamba"
+        """Does ``layer`` hold a state-space mixer (and a state)?"""
+        return self.layer_types[layer] in ("mamba", "parallel")
+
+    def has_attention(self, layer: int) -> bool:
+        """Does ``layer`` attend (and keep pages)?"""
+        return self.layer_types[layer] in ("attention", "parallel")
 
     @property
     def n_ssm_layers(self) -> int:
@@ -181,14 +248,16 @@ class HybridSSMMoEConfig(llama2.LlamaConfig):
 
     @property
     def n_attention_layers(self) -> int:
-        return self.n_layers - self.n_ssm_layers
+        return sum(self.has_attention(i) for i in range(self.n_layers))
 
-    def state_layer(self, layer: int) -> int:
-        """Where ``layer`` keeps what it caches: its row of the page
-        pool among the attention layers, or of the recurrent state
-        among the state-space ones."""
-        kind = self.layer_types[layer]
-        return sum(k == kind for k in self.layer_types[:layer])
+    def state_layer(self, layer: int, kind: str) -> int:
+        """Where ``layer`` keeps what ``kind`` caches: its row of the
+        page pool among the layers that attend (``"attention"``), or of
+        the recurrent state among those with a state-space mixer
+        (``"mamba"``). A parallel layer has a row in both."""
+        held = self.has_attention if kind == "attention" \
+            else self.is_ssm_layer
+        return sum(held(i) for i in range(layer))
 
     def state_shapes(self, slots: int) -> Tuple[Tuple[int, ...], ...]:
         """The recurrent state of ``slots`` sequences as an engine
@@ -213,6 +282,26 @@ class HybridSSMMoEConfig(llama2.LlamaConfig):
 
 GRANITE_4_0_H_SMALL = HybridSSMMoEConfig(name="granite-4.0-h-small")
 
+# tiiuae/Falcon-H1-34B-Instruct's config.json: 72 parallel layers.
+FALCON_H1_34B = HybridSSMMoEConfig(
+    name="falcon-h1-34b", dim=5120, n_layers=72, n_heads=20, n_kv_heads=4,
+    vocab_size=261120, max_seq_len=262144, layer_types=("parallel",) * 72,
+    position_embedding="rope_half", rope_theta=1e11, attention_head_dim=128,
+    attention_multiplier=None, key_multiplier=0.011048543456039804,
+    attention_out_multiplier=0.0375,
+    embedding_multiplier=5.656854249492381, residual_multiplier=1.0,
+    logits_scaling=128.0, tie_word_embeddings=False, ssm_heads=32,
+    ssm_head_dim=128, ssm_state=256, ssm_groups=2, ssm_d_ssm=4096,
+    ssm_chunk=128, ssm_in_multiplier=0.25,
+    ssm_z_multiplier=0.3535533905932738, ssm_x_multiplier=0.25,
+    ssm_b_multiplier=0.1767766952966369, ssm_c_multiplier=0.5,
+    ssm_dt_multiplier=0.3535533905932738,
+    ssm_out_multiplier=0.08838834764831845, n_experts=0,
+    experts_per_token=0, expert_hidden=0, shared_hidden=0,
+    mlp_hidden=21504, mlp_gate_multiplier=0.1767766952966369,
+    mlp_down_multiplier=0.011160714285714284,
+)
+
 # ``python -m tpu_hpc.serve --model <name>``: the published sizes, and a
 # size for the simulated mesh with every kind of layer present.
 PRESETS: Dict[str, HybridSSMMoEConfig] = {
@@ -225,6 +314,21 @@ PRESETS: Dict[str, HybridSSMMoEConfig] = {
         ssm_state=16, ssm_chunk=8, n_experts=8, experts_per_token=3,
         expert_hidden=32, shared_hidden=48,
     ),
+    "falcon-h1-34b": FALCON_H1_34B,
+    # Every multiplier off 1, heads of 24 numbers where dim / n_heads
+    # is 16, a mixer 96 wide where ssm_expand x dim is 128, two groups.
+    "falcon-h1-tiny": dataclasses.replace(
+        FALCON_H1_34B, name="falcon-h1-tiny", dim=64, n_layers=3,
+        layer_types=("parallel",) * 3, n_heads=4, n_kv_heads=2,
+        attention_head_dim=24, vocab_size=512, max_seq_len=512,
+        rope_theta=500.0, key_multiplier=0.3, attention_out_multiplier=0.7,
+        embedding_multiplier=3.0, logits_scaling=4.0, ssm_heads=8,
+        ssm_head_dim=12, ssm_state=16, ssm_groups=2, ssm_d_ssm=96,
+        ssm_chunk=8, ssm_in_multiplier=0.5, ssm_z_multiplier=0.8,
+        ssm_x_multiplier=1.25, ssm_b_multiplier=0.6, ssm_c_multiplier=1.5,
+        ssm_dt_multiplier=0.9, ssm_out_multiplier=0.6, mlp_hidden=96,
+        mlp_gate_multiplier=0.7, mlp_down_multiplier=1.3,
+    ),
 }
 
 
@@ -232,16 +336,28 @@ def is_hybrid_ssm_moe(cfg: Any) -> bool:
     return isinstance(cfg, HybridSSMMoEConfig)
 
 
+def has_experts(cfg: Any) -> bool:
+    """A configuration of this module whose feed-forward is the expert
+    layer (every one but a dense ``n_experts`` 0)."""
+    return is_hybrid_ssm_moe(cfg) and cfg.n_experts > 0
+
+
 def refuse(cfg: Any, who: str, why: str) -> None:
     """One clear error, by name, from every path that has not learned
     this decoder: never a silent run of an attention layer on a
     state-space layer's weights."""
     if is_hybrid_ssm_moe(cfg):
+        if "parallel" in cfg.layer_types[:cfg.n_layers]:
+            what = ("layers that run attention and a state-space mixer "
+                    "side by side, keeping K/V pages AND a recurrent "
+                    "state a sequence, a dense feed-forward")
+        else:
+            what = ("state-space layers with a recurrent state a "
+                    "sequence, attention without positions, an expert "
+                    "feed-forward with a shared expert")
         raise NotImplementedError(
             f"{who} does not run {cfg.name!r} ({type(cfg).__name__}: "
-            f"state-space layers with a recurrent state a sequence, "
-            f"attention without positions, an expert feed-forward with "
-            f"a shared expert): {why}. Serve it through "
+            f"{what}): {why}. Serve it through "
             "serve.paging.PagedEngine (kernel='gather', unquantised "
             "pages, no tensor axis)."
         )
@@ -250,12 +366,17 @@ def refuse(cfg: Any, who: str, why: str) -> None:
 def refuse_weights(params: Any, who: str, why: str) -> None:
     """:func:`refuse` for a path that sees weights and no
     configuration (the trainer): the tree of :func:`param_shapes` is
-    told by a layer's ``ssm`` group."""
-    if isinstance(params, dict) and any(
-        isinstance(layer, dict) and "ssm" in layer
-        for layer in params.values()
-    ):
-        refuse(GRANITE_4_0_H_SMALL, who, why)
+    told by a layer's ``ssm`` group, a parallel layer's by its
+    ``attention`` beside it."""
+    layers = [
+        layer for layer in params.values()
+        if isinstance(layer, dict) and "ssm" in layer
+    ] if isinstance(params, dict) else []
+    if layers:
+        refuse(
+            FALCON_H1_34B if any("attention" in l for l in layers)
+            else GRANITE_4_0_H_SMALL, who, why,
+        )
 
 
 # ---------------------------------------------------------------------
@@ -273,10 +394,13 @@ def _ffn_shapes(d: int, hidden: int) -> Dict:
 
 def param_shapes(cfg: HybridSSMMoEConfig) -> Dict:
     """The weights' tree as shapes, ``[in, out]`` like ``llama2``.
-    ``in_proj``'s columns are ``[z | x | B | C | dt]``; the
-    convolution's kernel is ``[tap, channel]``, tap ``ssm_conv - 1``
-    the current row's; routed experts are stacked on a leading axis of
-    the experts HELD. There is no ``output``: the head reads
+    ``in_proj``'s columns are ``[z | x | B | C | dt]`` (``B`` and ``C``
+    group after group); the convolution's kernel is ``[tap, channel]``,
+    tap ``ssm_conv - 1`` the current row's; routed experts are stacked
+    on a leading axis of the experts HELD, and a dense feed-forward
+    (``n_experts`` 0) is ``feed_forward``. A parallel layer holds both
+    ``attention`` and ``ssm`` beside its one ``attention_norm``. A tied
+    configuration has no ``output``: the head reads
     ``tok_embeddings``."""
     d, hd, e = cfg.dim, cfg.head_dim, cfg.n_held
     attention = {
@@ -306,18 +430,25 @@ def param_shapes(cfg: HybridSSMMoEConfig) -> Dict:
         "w2": (e, cfg.expert_hidden, d),
         "shared": _ffn_shapes(d, cfg.shared_hidden),
     }
+    ffn = {"moe": moe} if cfg.n_experts \
+        else {"feed_forward": _ffn_shapes(d, cfg.mlp_hidden)}
     tree = {}
     for i in range(cfg.n_layers):
-        mixer = {"ssm": ssm} if cfg.is_ssm_layer(i) \
-            else {"attention": attention}
+        mixer = {}
+        if cfg.has_attention(i):
+            mixer["attention"] = attention
+        if cfg.is_ssm_layer(i):
+            mixer["ssm"] = ssm
         tree[f"layers_{i}"] = {
             "attention_norm": {"scale": (d,)},
             **mixer,
             "ffn_norm": {"scale": (d,)},
-            "moe": moe,
+            **ffn,
         }
     tree["tok_embeddings"] = {"embedding": (cfg.vocab_size, d)}
     tree["norm"] = {"scale": (d,)}
+    if not cfg.tie_word_embeddings:
+        tree["output"] = {"kernel": (d, cfg.vocab_size)}
     return tree
 
 
@@ -328,14 +459,16 @@ def _size(tree) -> int:
 def count_params(cfg: HybridSSMMoEConfig) -> Dict[str, int]:
     """``total`` held here, ``active`` a token passes through (its
     ``experts_per_token`` routed experts of each layer, no embedding
-    row but its own; the tied table counts once, as the head), and the
-    parts both are made of."""
+    row but its own; the table counts once, as the head: a tied one IS
+    the head, an untied head is as large), and the parts both are made
+    of."""
     shapes = param_shapes(cfg)
     one_expert = 3 * cfg.dim * cfg.expert_hidden
-    routed = cfg.n_held * one_expert
+    routed = cfg.n_held * one_expert if cfg.n_experts else 0
     stack = [shapes[f"layers_{i}"] for i in range(cfg.n_layers)]
     layers = sum(map(_size, stack))
     table = cfg.vocab_size * cfg.dim
+    head = 0 if cfg.tie_word_embeddings else table
 
     def mixer(kind):
         return next((_size(lp[kind]) for lp in stack if kind in lp), 0)
@@ -344,10 +477,11 @@ def count_params(cfg: HybridSSMMoEConfig) -> Dict[str, int]:
         "ssm_per_layer": mixer("ssm"),
         "attention_per_layer": mixer("attention"),
         "experts_per_layer": routed,
-        "embed_and_head": table,
-        "total": layers + table + cfg.dim,
+        "feed_forward_per_layer": mixer("feed_forward"),
+        "embed_and_head": table + head,
+        "total": layers + table + head + cfg.dim,
         "active": layers - cfg.n_layers * (
-            routed - cfg.experts_per_token * one_expert
+            routed - cfg.experts_per_token * one_expert if routed else 0
         ) + table + cfg.dim,
     }
 
@@ -380,7 +514,27 @@ def init_hybrid_ssm_moe(rng: jax.Array, cfg: HybridSSMMoEConfig) -> Dict:
       give scores a spread of 0.15 and uniform attention over a 29k
       context, whose output is the mean of 29k values, nothing; this
       gives the spread ``head_dim ** -0.5`` gives the other decoders
-      (1.6), so that what attention reads reaches the logits."""
+      (1.6), so that what attention reads reaches the logits.
+
+    A configuration with an untied head and multipliers throughout
+    (Falcon-H1) is conditioned by one rule instead: every matrix is
+    drawn as ``llama2.init_llama`` draws its counterpart (the table
+    Normal(1), the head and the other matrices Normal(0.02), the output
+    projections scaled by depth) and DIVIDED by the multiplier the
+    equations put on its product: the table by
+    ``embedding_multiplier``, the head by ``lm_head_multiplier``,
+    ``wk`` by ``key_multiplier``, ``wo`` by
+    ``attention_out_multiplier``, each column of ``in_proj`` by its
+    segment's multiplier times ``ssm_in_multiplier``, ``out_proj`` by
+    ``ssm_out_multiplier``, ``w1`` and ``w2`` of the feed-forward by
+    the gate's and the down projection's. A trained model's weights are
+    as large as its multipliers are small; drawn so, every product is
+    the size a plain pre-norm decoder's is, the scores spread as
+    ``head_dim ** -0.5`` spreads Normal(0.02) projections (1.6 at 4096
+    wide, 2.0 at 5120), and each half of a parallel layer moves the
+    stream by a share of its own (PERF.md, section 4). With every
+    multiplier 1 and a tied table the draw is the one above."""
+    untied = not cfg.tie_word_embeddings
     shapes = param_shapes(cfg)
     leaves, treedef = jax.tree.flatten_with_path(shapes, is_leaf=_is_shape)
     keys = jax.random.split(rng, len(leaves))
@@ -408,17 +562,39 @@ def init_hybrid_ssm_moe(rng: jax.Array, cfg: HybridSSMMoEConfig) -> Dict:
         else:
             std = 0.02
             if leaf == "embedding":
-                std = 0.001
+                std = 1.0 / cfg.embedding_multiplier if untied else 0.001
+            elif "output" in names:
+                std = 0.02 / cfg.lm_head_multiplier
             elif "wq" in names or "wk" in names:
-                std = 0.02 * cfg.head_dim ** 0.25
+                if cfg.attention_multiplier is not None:
+                    std = 0.02 * cfg.head_dim ** 0.25
             elif cfg.depth_init and (
                 {"wo", "w2", "out_proj"} & set(names)
             ):
                 layer = int(names[0].split("_")[1])
                 std = 0.02 / (2 * (layer + 1)) ** 0.5
+            std = std / _multiplier_on(names, cfg)
             value = std * jax.random.normal(key, shape, jnp.float32)
         out.append(value.astype(dtype))
     return jax.tree.unflatten(treedef, out)
+
+
+def _multiplier_on(names, cfg):
+    """What the equations multiply the product of the matrix at the
+    tree path ``names`` by (the table's and the head's are their own
+    branch of :func:`init_hybrid_ssm_moe`): 1 for a matrix they leave
+    alone, a row over ``in_proj``'s columns where a segment has one."""
+    if "in_proj" in names:
+        return in_scale(cfg, 1.0)
+    if "feed_forward" in names:
+        return {"w1": cfg.mlp_gate_multiplier,
+                "w2": cfg.mlp_down_multiplier}.get(names[-2], 1.0)
+    for leaf, m in (("wk", cfg.key_multiplier),
+                    ("wo", cfg.attention_out_multiplier),
+                    ("out_proj", cfg.ssm_out_multiplier)):
+        if leaf in names:
+            return m
+    return 1.0
 
 
 # ---------------------------------------------------------------------
@@ -442,36 +618,85 @@ def _dense(x, leaf, dtype):
     )
 
 
+def in_scale(cfg: HybridSSMMoEConfig, none=None):
+    """What :func:`in_proj` multiplies its float32 product's columns
+    ``[z | x | B | C | dt]`` by: ``ssm_in_multiplier`` times each
+    segment's own multiplier, as one float32 row, or ``none`` where
+    every one is 1. The equations put ``ssm_in_multiplier`` on the
+    normed input; on the product it gives the same numbers wherever it
+    is a power of two (Falcon-H1's is 0.25)."""
+    bc = cfg.ssm_groups * cfg.ssm_state
+    segments = (
+        (cfg.d_inner, cfg.ssm_z_multiplier),
+        (cfg.d_inner, cfg.ssm_x_multiplier),
+        (bc, cfg.ssm_b_multiplier),
+        (bc, cfg.ssm_c_multiplier),
+        (cfg.ssm_heads, cfg.ssm_dt_multiplier),
+    )
+    if cfg.ssm_in_multiplier == 1.0 and all(m == 1.0 for _, m in segments):
+        return none
+    return jnp.concatenate([
+        jnp.full((width,), cfg.ssm_in_multiplier * m, jnp.float32)
+        for width, m in segments
+    ])
+
+
 def in_proj(h, lp, cfg: HybridSSMMoEConfig):
     """The normed input ``h [b, s, dim]`` -> the gate ``z [b, s,
     d_inner]``, the convolution's input ``xBC [b, s, conv_dim]`` (what
     a sequence's convolution rows are rows of) and the raw step ``dt
-    [b, s, heads]``, float32 (:func:`_dense`)."""
+    [b, s, heads]``, float32 (:func:`_dense`), each segment at its
+    multiplier (:func:`in_scale`)."""
     zxd = _dense(h, lp["ssm"]["in_proj"], cfg.dtype)
+    scale = in_scale(cfg)
+    if scale is not None:
+        zxd = zxd * scale
     d, c = cfg.d_inner, cfg.conv_dim
     return zxd[..., :d], zxd[..., d:d + c], zxd[..., d + c:]
 
 
 def out_proj(y, z, lp, cfg: HybridSSMMoEConfig):
     """``y [b, s, heads, head_dim]`` float32 and the gate ``z`` ->
-    ``rmsnorm(y * silu(z)) W_out``: the gate inside the norm, one group
-    over all of ``d_inner``, in float32."""
+    ``rmsnorm(y * silu(z)) W_out`` times ``ssm_out_multiplier``: the
+    gate inside the norm, the norm over each group's ``d_inner /
+    groups`` numbers (one group: all of ``d_inner``), in float32."""
     ssm = lp["ssm"]
     g = y.reshape(*y.shape[:2], cfg.d_inner) \
         * jax.nn.silu(z.astype(jnp.float32))
+    groups = cfg.ssm_groups
+    if groups > 1:
+        g = g.reshape(*g.shape[:-1], groups, cfg.d_inner // groups)
     g = g * jax.lax.rsqrt(
         jnp.mean(g * g, axis=-1, keepdims=True) + cfg.norm_eps
-    ) * ssm["norm"]["scale"].astype(jnp.float32)
-    return _dense(g, ssm["out_proj"], cfg.dtype)
+    )
+    if groups > 1:
+        g = g.reshape(*g.shape[:-2], cfg.d_inner)
+    out = _dense(
+        g * ssm["norm"]["scale"].astype(jnp.float32), ssm["out_proj"],
+        cfg.dtype,
+    )
+    m = cfg.ssm_out_multiplier
+    return out if m == 1.0 else out * m
 
 
 def split_xbc(xbc, cfg: HybridSSMMoEConfig):
     """The convolved ``xBC [..., conv_dim]`` -> ``x [..., heads,
-    head_dim]``, ``B [..., state]``, ``C [..., state]``."""
-    d, n = cfg.d_inner, cfg.ssm_state
+    head_dim]``, ``B [..., state]``, ``C [..., state]``; with several
+    groups ``B`` and ``C`` are ``[..., groups, state]``."""
+    d, n, groups = cfg.d_inner, cfg.ssm_state, cfg.ssm_groups
     x = xbc[..., :d].reshape(*xbc.shape[:-1], cfg.ssm_heads,
                              cfg.ssm_head_dim)
-    return x, xbc[..., d:d + n], xbc[..., d + n:]
+    b, c = xbc[..., d:d + groups * n], xbc[..., d + groups * n:]
+    if groups > 1:
+        b, c = (v.reshape(*v.shape[:-1], groups, n) for v in (b, c))
+    return x, b, c
+
+
+def _per_head(v, heads):
+    """A group's ``B`` or ``C`` ``[..., groups, state]`` -> each head's
+    ``[..., heads, state]``: head ``j`` reads group ``j // (heads /
+    groups)``."""
+    return jnp.repeat(v, heads // v.shape[-2], axis=-2)
 
 
 def discretise(dt, lp):
@@ -528,26 +753,34 @@ def conv_step(xbc, rows, lp):
 def scan_step(x, dt, a, b, c, state):
     """One step of the recurrence for ``b`` sequences: ``x [b, heads,
     head_dim]``, ``dt [b, heads]``, ``a [heads]``, ``b``, ``c [b,
-    state]`` float32 and ``state [b, heads, head_dim, state]`` ->
-    ``(S C, S)`` with ``S = exp(dt a) state + dt x (outer) b``.
-    Elementwise in float32: no product is rounded."""
+    state]`` (``[b, groups, state]`` with several groups) float32 and
+    ``state [b, heads, head_dim, state]`` -> ``(S C, S)`` with ``S =
+    exp(dt a) state + dt x (outer) b``. Elementwise in float32: no
+    product is rounded."""
     state = state.astype(jnp.float32)
+
+    def heads(v):           # B or C against [b, heads, head_dim, state]
+        if v.ndim == 2:
+            return v[:, None, None, :]
+        return _per_head(v, x.shape[1])[:, :, None, :]
+
     new = state * jnp.exp(dt * a)[..., None, None] \
-        + (dt[..., None] * x)[..., None] * b[:, None, None, :]
-    return jnp.sum(new * c[:, None, None, :], axis=-1), new
+        + (dt[..., None] * x)[..., None] * heads(b)
+    return jnp.sum(new * heads(c), axis=-1), new
 
 
 def _state_after(xd, la, b, state, n):
     """The state after the leading ``n`` rows of a run: ``xd [L, heads,
     head_dim]`` (``dt x``), ``la [L, heads]`` (``dt a``, the log of a
-    row's decay, <= 0), ``b [L, state]``. Every exponent is <= 0, so
-    the run needs no blocks: a row that has decayed to nothing
-    underflows to 0."""
+    row's decay, <= 0), ``b [L, state]`` (each head's: ``[L, heads,
+    state]``). Every exponent is <= 0, so the run needs no blocks: a
+    row that has decayed to nothing underflows to 0."""
     keep = jnp.arange(xd.shape[0]) < n
     cum = jnp.cumsum(jnp.where(keep[:, None], la, 0.0), axis=0)
     to_end = jnp.where(keep[:, None], jnp.exp(cum[-1] - cum), 0.0)
+    bn = "shn" if b.ndim == 3 else "sn"
     return state * jnp.exp(cum[-1])[:, None, None] + jnp.einsum(
-        "sh,shp,sn->hpn", to_end, xd, b, precision=_EXACT
+        f"sh,shp,{bn}->hpn", to_end, xd, b, precision=_EXACT
     )
 
 
@@ -555,9 +788,10 @@ def scan_chunk(x, dt, a, b, c, state, block, snap_len):
     """The recurrence over one sequence's run of rows, in the chunked
     form: ``x [L, heads, head_dim]``, ``dt [L, heads]`` (0 on a padded
     row: it decays nothing and leaves nothing), ``a [heads]``, ``b``,
-    ``c [L, state]`` float32, ``state [heads, head_dim, state]`` the
-    run comes behind -> ``(y [L, heads, head_dim], the state after the
-    run, the state after its leading snap_len rows)``.
+    ``c [L, state]`` (``[L, groups, state]`` with several groups)
+    float32, ``state [heads, head_dim, state]`` the run comes behind ->
+    ``(y [L, heads, head_dim], the state after the run, the state after
+    its leading snap_len rows)``.
 
     Inside a block of ``block`` rows a row reads the rows before it
     through one masked product, ``y_t = sum_{s <= t} exp(sum_{s < u <=
@@ -571,6 +805,12 @@ def scan_chunk(x, dt, a, b, c, state, block, snap_len):
     if n % q:
         raise ValueError(f"a run of {n} rows is no multiple of {q}")
     state = state.astype(jnp.float32)
+    # Several groups: each head's B and C, and every product below a
+    # head's own ("h" before the state's axis).
+    grouped = b.ndim == 3
+    if grouped:
+        b, c = (_per_head(v, x.shape[1]) for v in (b, c))
+    bn, h = ("hn", "h") if grouped else ("n", "")
     la, xd = dt * a, dt[..., None] * x
     causal = jnp.tril(jnp.ones((q, q), bool))
     ys = []
@@ -582,12 +822,13 @@ def scan_chunk(x, dt, a, b, c, state, block, snap_len):
             causal, cum[:, :, None] - cum[:, None, :], -jnp.inf
         ))                                                   # [h, t, s]
         inside = jnp.einsum(
-            "ts,hts,shp->thp",
-            jnp.einsum("tn,sn->ts", c[rows], b[rows], precision=_EXACT),
+            f"{h}ts,hts,shp->thp",
+            jnp.einsum(f"t{bn},s{bn}->{h}ts", c[rows], b[rows],
+                       precision=_EXACT),
             decay, xd[rows], precision=_EXACT,
         )
         before = jnp.einsum(
-            "tn,hpn->thp", c[rows], start, precision=_EXACT
+            f"t{bn},hpn->thp", c[rows], start, precision=_EXACT
         ) * jnp.exp(cum).T[..., None]
         ys.append(inside + before)
     return (

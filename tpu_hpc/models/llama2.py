@@ -581,15 +581,16 @@ def _blocks_keeping(cfg: LlamaConfig, n_tokens: int) -> int:
 
 def _dense_only(cfg: LlamaConfig, who: str) -> None:
     """``Llama`` is the dense decoder: a configuration that names
-    experts (``models/sparse_moe.py``) is refused, never run as a
-    dense model of its widths."""
-    if getattr(cfg, "n_experts", 0):
-        from tpu_hpc.models import hybrid_ssm_moe, latent_moe, sparse_moe
+    experts (``models/sparse_moe.py``) or layers of another kind
+    (``models/hybrid_ssm_moe.py``) is refused, never run as a dense
+    model of its widths."""
+    from tpu_hpc.models import hybrid_ssm_moe, latent_moe, sparse_moe
 
-        why = "models/llama2.py builds the dense block only"
+    why = "models/llama2.py builds the dense block only"
+    if getattr(cfg, "n_experts", 0):
         sparse_moe.refuse(cfg, who, why)
         latent_moe.refuse(cfg, who, why)
-        hybrid_ssm_moe.refuse(cfg, who, why)
+    hybrid_ssm_moe.refuse(cfg, who, why)
 
 
 def init_llama(

@@ -20,7 +20,9 @@ and indexer keys). A layer whose mixer is a state-space one
 (``models/hybrid_ssm_moe.py``; told by the weights it holds, as the
 feed-forward is) goes through the program's **recurrent state**
 instead, ``(layer, lp, xbc, dt) -> y`` (paging.py:
-``RecurrentState``, a state a slot beside the pool). A configuration's
+``RecurrentState``, a state a slot beside the pool); a layer that holds
+both runs both on one normed input and adds them
+(:func:`_parallel_layer`). A configuration's
 new stage is an edit to the loop (a stage every cache sees the same
 way, as the feed-forward in :func:`_ffn_stage`) or to one such state
 (a stage that reads or writes what is cached), never to a program.
@@ -41,7 +43,9 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
-from tpu_hpc.models import hybrid_ssm_moe, latent_moe, llama2, sparse_moe
+from tpu_hpc.models import (
+    conv_moe, hybrid_ssm_moe, latent_moe, llama2, sparse_moe,
+)
 
 
 def _dense(x: jax.Array, kernel: jax.Array, dtype) -> jax.Array:
@@ -99,10 +103,18 @@ def _attn_out_proj(h, lp, cfg):
 
 
 def _mlp(x, ff, cfg):
-    """The SwiGLU of one ``{w1, w3, w2}`` group of weights."""
+    """The SwiGLU of one ``{w1, w3, w2}`` group of weights: ``w2(silu(
+    m_gate * w1 x) * w3 x) * m_down``, at the configuration's
+    ``mlp_gate_multiplier`` / ``mlp_down_multiplier`` where it names
+    them (``models/hybrid_ssm_moe.py``; not applied when 1)."""
     gate = _dense(x, ff["w1"]["kernel"], cfg.dtype)
+    m_gate = getattr(cfg, "mlp_gate_multiplier", 1.0)
+    if m_gate != 1.0:
+        gate = gate * m_gate
     up = _dense(x, ff["w3"]["kernel"], cfg.dtype)
-    return _dense(jax.nn.silu(gate) * up, ff["w2"]["kernel"], cfg.dtype)
+    out = _dense(jax.nn.silu(gate) * up, ff["w2"]["kernel"], cfg.dtype)
+    m_down = getattr(cfg, "mlp_down_multiplier", 1.0)
+    return out if m_down == 1.0 else out * m_down
 
 
 def _qkv(x, lp, cfg):
@@ -110,6 +122,9 @@ def _qkv(x, lp, cfg):
     hd, n_kv = cfg.head_dim, cfg.kv_heads
     q = _dense(x, lp["attention"]["wq"]["kernel"], cfg.dtype)
     k = _dense(x, lp["attention"]["wk"]["kernel"], cfg.dtype)
+    m_key = getattr(cfg, "key_multiplier", 1.0)
+    if m_key != 1.0:
+        k = k * m_key
     v = _dense(x, lp["attention"]["wv"]["kernel"], cfg.dtype)
     q = q.reshape(b, s, cfg.n_heads, hd)
     k = k.reshape(b, s, n_kv, hd)
@@ -321,20 +336,53 @@ def _project(h, lp, cfg, cos, sin):
     if latent_moe.is_latent_moe(cfg):
         return latent_moe.project(h, lp, cfg, cos, sin)
     q, k, v = _qkv(h, lp, cfg)
-    if getattr(cfg, "position_embedding", "rope") == "nope":
+    position = getattr(cfg, "position_embedding", "rope")
+    if position == "nope":
         return q, k, v
     # [s, D/2] tables rotate every row alike, [b, s, D/2] each to its
-    # own position (apply_rope broadcasts either shape).
-    q = llama2.apply_rope(q, cos, sin)
-    k = llama2.apply_rope(k, cos, sin)
+    # own position (both rotations broadcast either shape): adjacent
+    # pairs, or number j with j + D/2 where the configuration says
+    # "rope_half".
+    rotate = conv_moe.rope_half if position == "rope_half" \
+        else llama2.apply_rope
+    q = rotate(q, cos, sin)
+    k = rotate(k, cos, sin)
     return q, k, v
+
+
+def _parallel_layer(x, i, lp, cfg, cos, sin, attend, recur):
+    """A layer that holds both an attention's and a state-space
+    mixer's weights (``"parallel"``: Falcon-H1): ONE norm, under
+    ``qkv``; the attention half under its five stages, its output times
+    ``attention_out_multiplier`` under ``attn_out``; the mixer half
+    under its four, its output times ``ssm_out_multiplier``
+    (``hybrid_ssm_moe.out_proj``) under ``ssm_out``; the sum and its
+    one residual under ``attn_out``. The layer's pages and its state
+    advance in the same step."""
+    scope = jax.named_scope
+    with scope("qkv"):
+        h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
+        q, k, v = _project(h, lp, cfg, cos, sin)
+    attn = attend(i, h, lp, q, k, v)
+    with scope("attn_out"):
+        a = _attn_out_proj(attn, lp, cfg)
+        if cfg.attention_out_multiplier != 1.0:
+            a = a * cfg.attention_out_multiplier
+    with scope("ssm_in"):
+        z, xbc, dt = hybrid_ssm_moe.in_proj(h, lp, cfg)
+    y = recur(i, lp, xbc, dt)
+    with scope("ssm_out"):
+        m = hybrid_ssm_moe.out_proj(y, z, lp, cfg)
+    with scope("attn_out"):
+        return _residual(x, a.astype(m.dtype) + m, cfg)
 
 
 def decoder_layers(params, cfg, x, cos, sin, attend, weight=None,
                    recur=None, mesh=None):
     """Every layer of the decoder over ``x [b, s, dim]`` -> ``(x,
-    counts)``. A layer's mixer is attention, or, where the layer holds
-    a state-space mixer's weights (``ssm``:
+    counts)``. A layer's mixer is attention, both side by side where
+    it holds both kinds of weights (:func:`_parallel_layer`), or, where
+    the layer holds a state-space mixer's weights alone (``ssm``:
     ``models/hybrid_ssm_moe.py``): ``ssm_in`` (the norm and the input
     projection); ``recur(layer, lp, xbc, dt)``, the program's recurrent
     state, which runs the convolution and the recurrence over the
@@ -358,7 +406,9 @@ def decoder_layers(params, cfg, x, cos, sin, attend, weight=None,
     counts = {}
     for i in range(cfg.n_layers):
         lp = params[f"layers_{i}"]
-        if "ssm" in lp:
+        if "ssm" in lp and "attention" in lp:
+            x = _parallel_layer(x, i, lp, cfg, cos, sin, attend, recur)
+        elif "ssm" in lp:
             with scope("ssm_in"):
                 h = _rmsnorm(x, lp["attention_norm"]["scale"], cfg.norm_eps)
                 z, xbc, dt = hybrid_ssm_moe.in_proj(h, lp, cfg)

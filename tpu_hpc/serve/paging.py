@@ -239,8 +239,7 @@ def step_counters(cfg) -> Tuple[Tuple[str, str, str], ...]:
     """What the configuration's decode step packs behind its tokens."""
     if sparse_moe.is_sparse_moe(cfg):
         return SPARSE_COUNTERS
-    if latent_moe.is_latent_moe(cfg) \
-            or hybrid_ssm_moe.is_hybrid_ssm_moe(cfg):
+    if latent_moe.is_latent_moe(cfg) or hybrid_ssm_moe.has_experts(cfg):
         return LATENT_COUNTERS
     return ()
 
@@ -1360,8 +1359,8 @@ class PagedAttention:
 
     def __call__(self, layer, h, lp, q, k, v):
         if self.hybrid:
-            # The pool has a row for each ATTENTION layer.
-            layer = self.cfg.state_layer(layer)
+            # The pool has a row for each layer that attends.
+            layer = self.cfg.state_layer(layer, "attention")
         if self.latent:
             self._write_latent(layer, k, v)
             return self._read_latent(layer, lp, q)
@@ -1679,7 +1678,7 @@ class RecurrentState:
 
     def __call__(self, layer, lp, xbc, dt):
         cfg, scope = self.cfg, jax.named_scope
-        j = cfg.state_layer(layer)
+        j = cfg.state_layer(layer, "mamba")
         d_skip = lp["ssm"]["D"].astype(jnp.float32)[:, None]
         heads = (cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state)
         taps = (cfg.ssm_conv - 1, cfg.conv_dim)

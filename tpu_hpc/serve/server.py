@@ -532,7 +532,8 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             "tiny", *sorted(llama2.PRESETS), *sorted(hybrid_ssm_moe.PRESETS)
         ),
         help="model architecture (tiny = the 8-device-sim config; "
-        "granite-4.0-h-small and its sim-sized hybrid-tiny need --paged)",
+        "granite-4.0-h-small, falcon-h1-34b and their sim-sized "
+        "hybrid-tiny, falcon-h1-tiny need --paged)",
     )
     ap.add_argument("--vocab", type=int, default=512,
                     help="vocab size for --model tiny")
