@@ -729,8 +729,34 @@ def test_the_selected_decode_program_walks_the_pool_in_place(
     assert temp < view_bytes, temp
 
 
+def _walks_the_pool(text, entry, cfg, slots, mb, bs, calls, temp):
+    """What a state-keeping configuration's decode program, compiled for
+    the chip, holds of its K/V read: ``calls`` Mosaic calls of
+    ``sparse_paged_decode`` (one an attention layer) and no other,
+    no gathered view of K or V (``[slots, max_blocks, kv_heads,
+    block_size, head_dim]``, the pages of all slots end to end, or
+    token-major), and temporaries (``temp`` bytes) under a quarter of
+    ONE layer's two gathered views."""
+    assert text.count("tpu_custom_call") == calls
+    assert len(re.findall(
+        r"^\s*%sparse_paged_decode[.\d]* = [^\n]*tpu_custom_call", text,
+        re.M,
+    )) == calls
+    page = (cfg.kv_heads, bs, cfg.head_dim)
+    for result, opcode in _HLO_INSTRUCTION.findall(entry):
+        for view in ((slots, mb, *page), (slots * mb, *page),
+                     (slots, cfg.kv_heads, mb * bs, cfg.head_dim)):
+            assert "[" + ",".join(map(str, view)) + "]" not in result, (
+                f"{opcode} holds a gathered view: {result}"
+            )
+    views = 2 * slots * mb * cfg.kv_heads * bs * cfg.head_dim * 2
+    assert temp < views / 4, temp
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_hybrid_programs_keep_the_state_in_place(v5e_2x2, program):
+def test_hybrid_programs_keep_the_state_in_place(
+    v5e_2x2, program, monkeypatch
+):
     """``serve-docqa-granite4h-small``'s decode and chunk programs,
     compiled for the chip at the cell's shape (layers 3-6 of its ten:
     two state-space layers, the attention layer, one more): the
@@ -740,7 +766,12 @@ def test_hybrid_programs_keep_the_state_in_place(v5e_2x2, program):
     slots' state in and out, 2.3 GB a chunk at ten layers; three
     convolution rows are no tile), the decode program materialises no
     per-layer slice of the state, and the tied table is read as it
-    lies: no transposed copy of it for the head."""
+    lies: no transposed copy of it for the head. The decode program
+    reads K and V through the kernel that walks the tables (one
+    ``sparse_paged_decode`` call for the one attention layer, compiled
+    by Mosaic as the chip would: the test answers for the described
+    chip) and gathers no view; the chunk program calls no kernel."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     import dataclasses
 
     import jax.numpy as jnp
@@ -818,19 +849,30 @@ def test_hybrid_programs_keep_the_state_in_place(v5e_2x2, program):
     # a slot's state a layer is 4 MB; nothing state-sized is temporary
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2.5 * 2**30, temp
+    if program == "decode":
+        _walks_the_pool(text, entry, cfg, slots, mb, bs, 1, temp)
+    else:
+        assert "tpu_custom_call" not in text
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
 def test_parallel_programs_keep_the_pool_and_the_state_in_place(
-    v5e_2x2, program
+    v5e_2x2, program, monkeypatch
 ):
     """``serve-docqa-falcon-h1-34b``'s decode and chunk programs,
     compiled for the chip at the cell's shape (two of its four parallel
     layers: every layer keeps pages AND a state): the pool's two arrays
     and the state's two go in and out in their own layout with no copy
     of any, and neither program materialises a per-layer slice of the
-    pool or (decode) of the state; temporaries stay under one layer's
-    two gathered views (1 GB: 16 slots x 1920 pages of 4 x 16 x 128)."""
+    pool or (decode) of the state. The decode program reads K and V
+    through the kernel that walks the tables (a ``sparse_paged_decode``
+    call a layer, compiled by Mosaic as the chip would: the test
+    answers for the described chip), holds no gathered view (16 slots x
+    1920 pages of 4 x 16 x 128, 1 GB for a layer's two) and keeps its
+    temporaries under a quarter of that; the chunk program, which
+    gathers its one slot's view, keeps them under one layer's two
+    views of every slot."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     import dataclasses
 
     import jax.numpy as jnp
@@ -898,7 +940,12 @@ def test_parallel_programs_keep_the_pool_and_the_state_in_place(
                 )
     assert seen >= 8
     views = 2 * slots * mb * cfg.kv_heads * bs * cfg.head_dim * 2
-    assert compiled.memory_analysis().temp_size_in_bytes < views
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp < views
+    if program == "decode":
+        _walks_the_pool(text, entry, cfg, slots, mb, bs, cfg.n_layers, temp)
+    else:
+        assert "tpu_custom_call" not in text
 
 
 def test_trained_expert_layer_and_narrow_flash_lower_for_v5e(

@@ -730,7 +730,7 @@ def test_the_flat_read_and_bad_sizes_are_refused():
 # this file's tiny size, as the PR that brought them left them (PR 33).
 # A PR that MEANS to change one re-pins it and says so.
 PROGRAM_DIGESTS = {
-    "decode": "6f474bd72d54ae74",
+    "decode": "49e83312f9bf56ec",
     "prefill": "595b050c7836eb2d",
 }
 
@@ -787,6 +787,137 @@ def test_the_decode_program_rotates_nothing():
     for program in PROGRAM_DIGESTS:
         text = _program_text(program)
         assert "cosine" not in text and "sine" not in text
+
+
+# -- the row step's read: a walk of the page tables -----------------------
+def _gathered(self, layer, q, mask=None):
+    """A row step's read as the parent ran it: the view's pages
+    gathered and attended over under the columns ``<= pos``."""
+    return self._read(layer, q, self.mask)
+
+
+def _program_reads(cfg, params, state, max_blocks=12, width=16):
+    """The decode program's jaxpr at a tiny size -> (the calls of the
+    jitted walk, the shapes of every ``[slots, max_blocks, ...]``
+    array it makes)."""
+    slots = state[2].shape[1]
+    i32 = jnp.int32
+    jaxpr = jax.make_jaxpr(
+        paging.make_paged_decode_fn(cfg, BLOCK, max_blocks, width)
+    )(
+        params, *state,
+        jnp.zeros((slots + len(paging.step_counters(cfg)),), i32),
+        jnp.zeros((len(paging.STEP_ROWS), slots), i32),
+        jnp.zeros((slots, width), i32),
+    )
+    views = {
+        tuple(var.aval.shape)
+        for eqn in jaxpr.jaxpr.eqns for var in eqn.outvars
+        if tuple(getattr(var.aval, "shape", ()))[:2] == (slots, max_blocks)
+        and len(var.aval.shape) > 2
+    }
+    return str(jaxpr).count("name=_walk"), views
+
+
+def _holds_the_walk_and_no_view(cfg, params, state, monkeypatch):
+    """The decode program calls the kernel once a layer that attends
+    (its own jitted walk, traced once for all of them) and gathers no
+    view of K or V; the same program with the row step's read patched
+    back to the gathered form holds that view, so the check sees one."""
+    slots = state[2].shape[1]
+    view = (slots, 12, cfg.kv_heads, BLOCK, cfg.head_dim)
+    walks, views = _program_reads(cfg, params, state)
+    assert walks == cfg.n_attention_layers
+    assert view not in views
+    with monkeypatch.context() as patched:
+        patched.setattr(paging.PagedAttention, "_read_walked", _gathered)
+        walks, views = _program_reads(cfg, params, state)
+    assert walks == 0 and view in views
+
+
+def _tokens_of_both_forms(make_engine, serve, prompts, monkeypatch):
+    """What an engine whose row steps walk the tables and one whose row
+    steps gather and attend as the parent's did emit for ``prompts``,
+    served together."""
+    walked = serve(make_engine(), prompts)
+    with monkeypatch.context() as patched:
+        # Every program of this engine is built under the patch.
+        patched.setattr(paging.PagedAttention, "_read_walked", _gathered)
+        gathered = serve(make_engine(), prompts)
+    return walked, gathered
+
+
+def _counts_what_its_walks_read(eng, prompts, steps=6):
+    """The engine's count of the pages its decode steps read against the
+    live pages of the active slots (the last slot stays free), counted
+    by hand; the whole capacity is the total; a step with nobody active
+    reads nothing; nothing compiles after ``warmup``."""
+    (_, _, read_name, total_name) = [n for n, _ in paging.DECODE_COUNTERS]
+    slots = len(prompts) + 1
+    assert eng.decode_rungs == ()
+    warmed = eng.compile_count_total
+    tokens = []
+    for s, prompt in enumerate(prompts):
+        eng.admit(s, prompt, steps + 2)
+        tok = None
+        while tok is None:
+            tok = eng.prefill_step(s)
+        tokens.append(tok)
+    before = dict(eng.paged_stats)
+    positions = [len(p) for p in prompts]
+    read = 0
+    for _ in range(steps):
+        tokens = eng.decode_now(
+            tokens + [0], positions + [0], [True] * len(prompts) + [False]
+        ).tolist()[:len(prompts)]
+        read += sum(p // BLOCK + 1 for p in positions)
+        positions = [p + 1 for p in positions]
+    eng.decode_now([1] * slots, [40] * slots, [False] * slots)
+    stats = eng.paged_stats
+    assert stats[read_name] - before[read_name] == read
+    assert stats[total_name] - before[total_name] == \
+        (steps + 1) * eng.view_pages
+    assert 0 < read < steps * eng.view_pages
+    assert eng.compile_count_total == warmed
+    for s in range(len(prompts)):
+        eng.release(s)
+
+
+def test_the_decode_program_holds_the_walk_and_no_view(params, monkeypatch):
+    _holds_the_walk_and_no_view(
+        TINY, params, _fresh_state(slots=4), monkeypatch
+    )
+
+
+def _shared_prompts(seed):
+    """Two prompts on one prefix of three pages, one of its own."""
+    rng = np.random.default_rng(seed)
+    shared = rng.integers(0, TINY.vocab_size, 3 * BLOCK).tolist()
+    return [
+        shared + rng.integers(0, TINY.vocab_size, 2).tolist(),
+        shared + rng.integers(0, TINY.vocab_size, 7).tolist(),
+        rng.integers(0, TINY.vocab_size, 9).tolist(),
+    ]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_greedy_tokens_agree_with_the_gathered_form(
+    params, mesh, monkeypatch, dtype
+):
+    """Three requests served together, two of them on one prefix: the
+    walk and the gathered form emit the same tokens, with float32
+    products and with bf16 ones (where the walk keeps its scores in
+    float32 and the gathered form rounds them)."""
+    cfg = dataclasses.replace(TINY, dtype=jnp.dtype(dtype))
+    prompts = dict(zip("abc", _shared_prompts(5)))
+    walked, gathered = _tokens_of_both_forms(
+        lambda: _engine(params, mesh, cfg), _serve, prompts, monkeypatch
+    )
+    assert walked == gathered
+
+
+def test_the_engine_counts_what_its_walks_read(engine):
+    _counts_what_its_walks_read(engine, _shared_prompts(7)[:2])
 
 
 def test_a_state_space_layer_carries_its_scopes(params):

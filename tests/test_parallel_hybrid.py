@@ -527,6 +527,52 @@ def test_a_parallel_layer_carries_its_scopes(params):
     assert "cosine" in text and "sine" in text
 
 
+# -- the row step's read: a walk of the page tables -------------------------
+def test_the_decode_program_holds_the_walk_and_no_view(params, monkeypatch):
+    """A walk in every layer (each keeps pages), and no gathered view."""
+    from test_hybrid_ssm_moe import _holds_the_walk_and_no_view
+
+    _holds_the_walk_and_no_view(
+        TINY, params, _fresh_state(slots=4), monkeypatch
+    )
+
+
+def _shared_prompts(seed):
+    """Two prompts on one prefix of three pages, one of its own, none a
+    whole number of chunks."""
+    rng = np.random.default_rng(seed)
+    shared = rng.integers(0, TINY.vocab_size, 3 * BLOCK).tolist()
+    return [
+        shared + rng.integers(0, TINY.vocab_size, 3).tolist(),
+        shared + rng.integers(0, TINY.vocab_size, 9).tolist(),
+        rng.integers(0, TINY.vocab_size, 11).tolist(),
+    ]
+
+
+def test_greedy_tokens_agree_with_the_gathered_form(
+    params, mesh, monkeypatch
+):
+    """Three requests served together, two of them on one prefix: the
+    walk and the gathered form emit the same tokens, and they are the
+    reference's arg-max."""
+    from test_hybrid_ssm_moe import _tokens_of_both_forms
+
+    prompts = dict(zip("abc", _shared_prompts(5)))
+    walked, gathered = _tokens_of_both_forms(
+        lambda: _engine(params, mesh), _serve, prompts, monkeypatch
+    )
+    assert walked == gathered
+    for rid, prompt in prompts.items():
+        got = walked[rid]
+        assert _emitted_from(params, prompt, got).argmax(-1).tolist() == got
+
+
+def test_the_engine_counts_what_its_walks_read(engine):
+    from test_hybrid_ssm_moe import _counts_what_its_walks_read
+
+    _counts_what_its_walks_read(engine, _shared_prompts(7)[:2])
+
+
 def test_the_decode_step_packs_no_expert_count():
     assert paging.step_counters(TINY) == ()
     assert hybrid.has_experts(hybrid.GRANITE_4_0_H_SMALL)

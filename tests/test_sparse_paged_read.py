@@ -13,6 +13,9 @@ the SAME pool under the SAME selection.
     active ones, nobody active, slots that share a prefix's pages, a
     selection that names nothing in the walk's first block, and no mask
     at all (the columns ``<= pos``);
+  * without a mask, as a state-space configuration's row step calls it:
+    4 and 5 query rows a KV head of 128 numbers, 16 KiB and
+    32 KiB pages, both score scales, float32 and bf16 pools;
   * what the walk must not read is poisoned: every page past a slot's
     position, every page of an inactive slot and the scratch page hold
     NaN in the pool the walk reads, and its rows stay finite; the rows
@@ -188,7 +191,7 @@ def test_the_walk_attends_as_the_gathered_view_does(dtype, step):
     # (it reads every page and multiplies the masked ones by zero).
     got = _attended(
         cfg, poisoned, tables, q, positions, active, mask,
-        paging.PagedAttention._read_selected,
+        paging.PagedAttention._read_walked,
     )
     want = _attended(
         cfg, pools, tables, q, positions, active, mask, _gathered
@@ -251,7 +254,7 @@ def test_a_walk_of_several_blocks(monkeypatch, pages):
     monkeypatch.setattr(sparse_paged_attention, "PAGES_PER_BLOCK", pages)
     got = _attended(
         TINY, poisoned, tables, q, positions, active, mask,
-        paging.PagedAttention._read_selected,
+        paging.PagedAttention._read_walked,
     )
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
 
@@ -272,7 +275,7 @@ def test_a_first_block_with_nothing_selected(monkeypatch, pages):
     monkeypatch.setattr(sparse_paged_attention, "PAGES_PER_BLOCK", pages)
     got = _attended(
         TINY, poisoned, tables, q, positions, active, mask,
-        paging.PagedAttention._read_selected,
+        paging.PagedAttention._read_walked,
     )
     assert np.isfinite(got).all()
     np.testing.assert_allclose(got, want, rtol=0, atol=1e-5)
@@ -311,6 +314,109 @@ def test_without_a_mask_the_walk_attends_up_to_the_position(
     on = np.asarray(active, bool)
     assert not got[~on].any()
     np.testing.assert_allclose(got[on], want[on], rtol=0, atol=1e-5)
+
+
+# -- without a selection: a state-space configuration's row step ----------
+# (KV heads, query rows a KV head) of the two cells that walk with no
+# mask: falcon-h1-34b's 4 x 5 and granite-4.0-h-small's 8 x 4. With
+# 128-wide rows of 16 tokens a bf16 page is 16 KiB and 32 KiB.
+UNMASKED = {"falcon": (4, 5), "granite": (8, 4)}
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("scale", [1 / 128, 128 ** -0.5],
+                         ids=["1_over_128", "128_pow_-0.5"])
+@pytest.mark.parametrize("shape", sorted(UNMASKED))
+def test_the_unmasked_walk_attends_as_the_gathered_view_does(
+    monkeypatch, shape, scale, dtype
+):
+    """The kernel as a state-space configuration's row step calls it:
+    no mask, the configuration's scale, 4 or 5 query rows a KV head,
+    128-wide heads, pages of 16 tokens, in blocks of two pages. Slot 0
+    ends on the last row of its walk's first block and slot 2 on the
+    first row of its second, slot 1 is inactive, slot 3 is full; every
+    page the walk must not read is NaN. Against
+    ``decoder._grouped_attention_paged`` over the gathered view of the
+    clean pool under the columns ``<= pos``: to 1e-5 in float32; in
+    bf16 no further from the float32 answer than the gathered form."""
+    kv_heads, groups = UNMASKED[shape]
+    bs, width, slots, per_slot = 16, 128, 4, 6
+    positions, active = [2 * bs - 1, 50, 2 * bs, per_slot * bs - 1], \
+        [1, 0, 1, 1]
+    rng = np.random.default_rng(9)
+    n_pages = slots * per_slot + 1
+    pools = [rng.normal(size=(2, n_pages, kv_heads, bs, width))
+             .astype(np.float32) for _ in range(2)]
+    owned = 1 + rng.permutation(n_pages - 1).reshape(slots, per_slot)
+    tables = np.full((slots, per_slot + 2), paging.SCRATCH_BLOCK, np.int32)
+    read = set()
+    for s, (pos, on) in enumerate(zip(positions, active)):
+        tables[s, :pos // bs + 1] = owned[s, :pos // bs + 1]
+        if on:
+            read.update(owned[s, :pos // bs + 1].tolist())
+    poisoned = [pool.copy() for pool in pools]
+    for pool in poisoned:
+        pool[:, sorted(set(range(n_pages)) - read)] = np.nan
+    q = jnp.asarray(rng.normal(
+        size=(slots, 1, kv_heads * groups, width)
+    ), dtype)
+    view = jnp.asarray(tables[:, :per_slot])
+    valid = (
+        np.arange(per_slot * bs)[None, :] <= np.asarray(positions)[:, None]
+    )[:, None, None, None, :]
+
+    def gathered(pools, dtype):
+        cfg = types.SimpleNamespace(
+            kv_heads=kv_heads, n_heads=kv_heads * groups, head_dim=width,
+            dtype=dtype, attention_multiplier=scale,
+        )
+        k, v = (jnp.asarray(pool, dtype)[1, view] for pool in pools)
+        return np.asarray(decoder._grouped_attention_paged(
+            q.astype(dtype), k, v, valid, cfg
+        ), np.float32)
+
+    monkeypatch.setattr(sparse_paged_attention, "PAGES_PER_BLOCK", 2)
+    got = np.asarray(sparse_paged_attention.sparse_paged_decode(
+        q[:, 0].reshape(slots, kv_heads, groups, width),
+        *(jnp.asarray(pool, dtype) for pool in poisoned), jnp.int32(1),
+        jnp.asarray(tables), jnp.asarray(positions, jnp.int32),
+        jnp.asarray(active, jnp.int32), scale=scale, interpret=True,
+    )).reshape(slots, 1, kv_heads * groups, width)
+    want = gathered(pools, jnp.dtype(dtype))
+    on = np.asarray(active, bool)
+    assert np.isfinite(got).all()
+    assert not got[~on].any()
+    if dtype == "float32":
+        np.testing.assert_allclose(got[on], want[on], rtol=0, atol=1e-5)
+        return
+    exact = gathered(
+        [np.asarray(jnp.asarray(p, jnp.bfloat16), np.float32)
+         for p in pools], jnp.float32,
+    )
+    far = np.abs(want[on] - exact[on]).max()
+    assert np.abs(got[on] - exact[on]).max() <= max(far, 2e-2)
+    np.testing.assert_allclose(got[on], want[on], rtol=0, atol=5e-2)
+
+
+@pytest.mark.parametrize("kv_heads,dtype,pages", [
+    (4, jnp.bfloat16, 64),   # keye's and falcon's 16 KiB pages
+    (8, jnp.bfloat16, 32),   # granite's 32 KiB
+    (8, jnp.float32, 16),    # 64 KiB
+    (1, jnp.float32, 64),    # 8 KiB: no more than PAGES_PER_BLOCK
+])
+def test_a_block_holds_a_buffer_of_pages(monkeypatch, kv_heads, dtype, pages):
+    """The walk's block is chosen by the page's bytes: as many pages as
+    fill ``BUFFER_BYTES`` (1 MiB), at most ``PAGES_PER_BLOCK``."""
+    seen = {}
+    monkeypatch.setattr(
+        sparse_paged_attention, "_walk", lambda *a, **kw: seen.update(kw)
+    )
+    pool = jax.ShapeDtypeStruct((1, 9, kv_heads, 16, 128), dtype)
+    sparse_paged_attention.sparse_paged_decode(
+        None, pool, pool, None, jax.ShapeDtypeStruct((16, 1952), jnp.int32),
+        None, None, scale=1.0,
+    )
+    assert seen["pages"] == pages
 
 
 # -- through the engine ---------------------------------------------------
@@ -376,7 +482,7 @@ def test_greedy_tokens_agree_with_the_gathered_form(
     # row steps' read patched back to the gathered form.
     with monkeypatch.context() as patched:
         patched.setattr(
-            paging.PagedAttention, "_read_selected",
+            paging.PagedAttention, "_read_walked",
             paging.PagedAttention._read,
         )
         streams["gather"], probes["gather"] = run(_engine(params, mesh))

@@ -13,7 +13,8 @@ every column goes through HBM in between.
 Here the slot's row of the block table, its position and whether it is
 active ride in as scalar-prefetch operands (``kernels/page_walk.py``
 has the walk: the grid over slots, ``pos // block_size + 1`` live pages
-in blocks of :data:`PAGES_PER_BLOCK`, one async copy a page and array
+in blocks of :data:`PAGES_PER_BLOCK` pages or :data:`BUFFER_BYTES`,
+whichever is fewer, one async copy a page and array
 into one of two fast-memory buffers, the next block's copies in flight
 while this one is scored). A K or V page is ``pool[layer, page]``,
 ``[kv_heads, block_size, head_dim]``: one contiguous copy. For each KV
@@ -29,8 +30,9 @@ slot's position and every page of an inactive slot are not read.
 The selection is a subset of the columns ``<= pos`` (the indexer ranks
 only those), so the mask is the one limit on what is attended; a block
 none of whose columns is selected adds nothing, whatever it is the
-first. Without a mask (``mask=None``: a caller with no selection) the
-columns ``<= pos`` are attended.
+first. Without a mask (``mask=None``: a caller with no selection, the
+row step of a configuration with state-space layers) the columns ``<=
+pos`` are attended.
 
 Products in the queries' dtype with float32 accumulation, a float32
 softmax over float32 scores (the gathered form rounds its scores to
@@ -40,6 +42,7 @@ form's numbers in another summation order.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -53,6 +56,11 @@ from tpu_hpc.kernels.page_walk import Paged, page_walk
 # online softmax scores. Swept on the v5e at serve-docqa-keye30b's
 # shape (docs/guide/sparse_moe.md has the table).
 PAGES_PER_BLOCK = 64
+# What a buffer holds at most: 64 pages of 16 KiB. Larger pages fill it
+# in fewer (granite-4.0-h-small's 32 KiB: 32), which reads as fast and
+# halves the straight-line copies the kernel is lowered with at every
+# start (docs/guide/sparse_moe.md).
+BUFFER_BYTES = 64 << 14
 
 
 def _kernel(
@@ -146,7 +154,10 @@ def sparse_paged_decode(
     ``t <= pos[s]``; its columns are the view's, ``columns <= width *
     block_size``), or over every ``t <= pos[s]`` without a mask; 0 for
     a slot that is not active, and for one with nothing selected."""
-    pages = min(PAGES_PER_BLOCK, tables.shape[1])
+    page_bytes = math.prod(ks.shape[2:]) * jnp.dtype(ks.dtype).itemsize
+    pages = min(
+        PAGES_PER_BLOCK, max(1, BUFFER_BYTES // page_bytes), tables.shape[1]
+    )
     if mask is not None:
         # A row a block of the walk, whole blocks only (what a table
         # wider than the view names past it is selected by nobody).

@@ -114,10 +114,10 @@ DECODE_COUNTERS: Tuple[Tuple[str, str], ...] = (
      "(end of sequence) and dropped"),
     ("serve_decode_view_pages_read_total",
      "KV pages the dispatched decode programs read a layer: the flat "
-     "rung's size, slots x pages a slot on the rectangle, or (a latent "
-     "or sparse-selection configuration, whose kernel walks the "
-     "tables) the live pages of the active slots, a shared page once "
-     "a slot"),
+     "rung's size, slots x pages a slot on the rectangle, or (a latent, "
+     "sparse-selection or state-space configuration, whose kernel "
+     "walks the tables) the live pages of the active slots, a shared "
+     "page once a slot"),
     ("serve_decode_view_pages_total",
      "KV pages the rectangle would have gathered a layer (slots x "
      "pages a slot, a decode step); read over this is how much of "
@@ -1178,7 +1178,12 @@ class PagedAttention:
       whose caller knows how many pages are live gathers only those,
       as one flat list over all slots (:meth:`live_pages`,
       :func:`_grouped_attention_flat`), in place of every slot's whole
-      capacity. ``kernel="pallas"``: the
+      capacity. A ROW step of a configuration with state-space layers
+      (``models/hybrid_ssm_moe.py``) reads through the selected read's
+      kernel with no selection: the walk over every column ``<= pos``
+      at the configuration's score scale, under ``kv_read``, and the
+      rounding of its result under ``attention``; its chunk gathers its
+      one view as above. ``kernel="pallas"``: the
       table handed to kernels/paged_attention.py, which walks it
       in-kernel under ``shard_map`` over ``mesh`` (required for this
       kernel), all of it under ``kv_read``. Both read paths dequantize,
@@ -1234,20 +1239,21 @@ class PagedAttention:
         self.quant = kv_quant == "int8"
         self.sparse = sparse_moe.is_sparse_moe(cfg)
         self.latent = latent_moe.is_latent_moe(cfg)
+        # A stack with state-space layers: pages for the others only.
+        self.hybrid = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
         if self.latent:
             self.pack = rope_pack(cfg, block_size)
             # A row step's read (a chunk gathers its one view).
             self.walk = sparse_moe._on_mesh(functools.partial(
                 latent_paged_decode, scale=cfg.qk_head_dim ** -0.5
             ), mesh)
-        if self.sparse and not chunk:
-            # A row step's read under the selection (a chunk attends
-            # over its one gathered view).
+        elif self.walks(cfg) and not chunk:
+            # A row step's read of K and V pages, under the selection or
+            # over the columns ``<= pos`` (a chunk attends over its one
+            # gathered view).
             self.walk = sparse_moe._on_mesh(functools.partial(
                 sparse_paged_decode, scale=_score_scale(cfg)
             ), mesh)
-        # A stack with state-space layers: pages for the others only.
-        self.hybrid = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
         self.kernel = None
         if kernel == "pallas":
             self.kernel = _on_mesh(
@@ -1256,6 +1262,18 @@ class PagedAttention:
                 mesh, cfg.kv_heads, 0 if chunk else 1,
                 block_size=block_size, max_blocks=max_blocks,
             )
+
+    @staticmethod
+    def walks(cfg) -> bool:
+        """Whether a row step of ``cfg`` reads by walking its slots'
+        page tables in a kernel, and gathers no view: a latent, a
+        sparse-selection and a state-space configuration's does. The
+        engine counts such a read by the live pages and holds no flat
+        rung for it."""
+        return (
+            latent_moe.is_latent_moe(cfg) or sparse_moe.is_sparse_moe(cfg)
+            or hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
+        )
 
     def on(self, ks, vs, ksc=None, vsc=None, xs=None):
         """This state over one call's arrays (a copy: the factory's
@@ -1369,7 +1387,10 @@ class PagedAttention:
         if self.sparse:
             mask = self._select(layer, h, lp)
             if not self.chunk:
-                return self._read_selected(layer, q, mask)
+                return self._read_walked(layer, q, mask)
+        elif self.hybrid and not self.chunk:
+            # No selection: the walk attends to the columns ``<= pos``.
+            return self._read_walked(layer, q)
         return self._read(layer, q, mask)
 
     def _target(self, j):
@@ -1533,13 +1554,13 @@ class PagedAttention:
             )
         return mask
 
-    def _read_selected(self, layer, q, mask):
-        """A row step's read under the layer's selection, by the
-        kernel that walks the tables: every live page of a slot once,
-        no gathered view, no score in HBM. All of the walk under
-        ``kv_read`` (the rule for a table-walking kernel); what is
-        left under ``attention`` is the rounding of its float32
-        result."""
+    def _read_walked(self, layer, q, mask=None):
+        """A row step's read by the kernel that walks the tables, under
+        the layer's selection (``mask``), or over every column ``<=
+        pos`` without one: every live page of a slot once, no gathered
+        view, no score in HBM. All of the walk under ``kv_read`` (the
+        rule for a table-walking kernel); what is left under
+        ``attention`` is the rounding of its float32 result."""
         cfg = self.cfg
         slots = q.shape[0]
         with jax.named_scope("kv_read"):
@@ -1549,7 +1570,8 @@ class PagedAttention:
                     slots, cfg.kv_heads, -1, cfg.head_dim
                 ),
                 self.ks, self.vs, jnp.asarray(layer, jnp.int32),
-                self.view_ids, pos, active, mask[:, 0, 0, 0],
+                self.view_ids, pos, active,
+                None if mask is None else mask[:, 0, 0, 0],
             )
         with jax.named_scope("attention"):
             return out.astype(cfg.dtype).reshape(
@@ -1914,7 +1936,9 @@ def make_paged_decode_fn(
     (``models/hybrid_ssm_moe.py``) runs it with its recurrent state's
     two arrays behind the pool's (:class:`RecurrentState`): an active
     slot's state advances by its token, any other slot's stays as it
-    was; its expert layers' counts ride as a latent configuration's.
+    was; its attention reads K and V through the selected read's kernel
+    with no mask; its expert layers' counts ride as a latent
+    configuration's.
     ``probe=True`` (such configurations only) also returns each layer's
     selection ``[layers, slots, columns]``: the benchmark's check
     reads it, no serving path does.
@@ -1936,15 +1960,15 @@ def make_paged_decode_fn(
     recurrent = RecurrentState(cfg) \
         if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg) else None
     if flat_pages is not None and (
-        attention.sparse or attention.latent
-        or attention.kernel is not None or recurrent is not None
+        PagedAttention.walks(cfg) or attention.kernel is not None
     ):
         raise ValueError(
             "flat_pages is the gather read of a dense configuration: an "
             "indexer ranks the columns of each slot's own view, a latent "
-            "page is smaller than its owner's query, a table-walking "
-            "kernel reads no view at all, and a stack with state-space "
-            "layers has not been measured on it"
+            "page is smaller than its owner's query, and a table-walking "
+            "kernel (kernel='pallas', and the row step of a latent, a "
+            "sparse-selection or a state-space configuration) reads no "
+            "view at all"
         )
 
     def body(params, ks, vs, ksc, vsc, xs, rec, prev, step, tables):
@@ -2211,10 +2235,10 @@ class PagedEngine(Engine):
         self.view_pages = serve_cfg.slots * per_seq
         self._flat_rungs: Tuple[int, ...] = ()
         self._recurrent = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
-        if paged.kernel == "gather" and not (
-            sparse_moe.is_sparse_moe(cfg) or latent_moe.is_latent_moe(cfg)
-            or self._recurrent
-        ):
+        # Whether the decode program's row step walks the tables in a
+        # kernel, reading the live pages of the active slots and no view.
+        self._walks = PagedAttention.walks(cfg)
+        if paged.kernel == "gather" and not self._walks:
             self._flat_rungs = tuple(sorted(
                 {int(self.view_pages * r) for r in FLAT_RUNGS} - {0}
             ))
@@ -2283,11 +2307,6 @@ class PagedEngine(Engine):
                     "moe" in params[f"layers_{i}"]
                     for i in range(cfg.n_layers)
                 )
-        # A latent or a sparse-selection configuration's decode
-        # program walks the tables in its kernel and gathers no view.
-        self._walks = (
-            latent_moe.is_latent_moe(cfg) or sparse_moe.is_sparse_moe(cfg)
-        )
         # The distinct pages the active slots read (a latent
         # configuration, and one with a recurrent state: their
         # rooflines count a shared page once).
