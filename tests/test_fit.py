@@ -374,7 +374,9 @@ _HLO_INSTRUCTION = re.compile(
     "program", ["decode", "prefill", "flat-3/8", "flat-1/2"],
 )
 @pytest.mark.parametrize("cell", sorted(_SERVE_CELL_SHAPES))
-def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
+def test_serve_programs_address_the_pool_in_place(
+    v5e_2x2, monkeypatch, cell, program
+):
     """The compiled decode and chunk-prefill programs move only the
     pages they name: no whole-pool relayout round the token write, no
     per-layer slice of the stacked pool materialised for the view
@@ -384,11 +386,17 @@ def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
     the chip, whatever was live -- and no test compiled them for the
     chip. Not ``slow``: 2-6 s a case with libtpu's own compiler.
 
-    ``flat-<share>`` (PR 30) is the decode program of the engine's
-    flat rung at that share of ``slots x pages a slot``: every K / V
-    gather moves the rung's pages and none moves every slot's
-    capacity, and what carries rows between pages and slots under
-    ``attention`` is products and reductions, never a scatter."""
+    ``decode`` reads K and V through the kernel that walks the page
+    tables (``sparse_paged_decode``, one Mosaic call a layer, compiled
+    as the chip would: the test answers for the described chip) and
+    holds no gathered view. ``flat-<share>`` is the decode
+    program of an int8 pool's flat rung at that share of ``slots x
+    pages a slot`` (such a pool's read gathers, and keeps the ladder):
+    every K / V gather moves the rung's pages and none moves every
+    slot's capacity, and what carries rows between pages and slots
+    under ``attention`` is products and reductions, never a
+    scatter."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     import jax.numpy as jnp
     from jax.sharding import SingleDeviceSharding
 
@@ -415,16 +423,21 @@ def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
     )
     layer_shape = (num_blocks, cfg.kv_heads, bs, cfg.head_dim)
     pool_shape = (cfg.n_layers, *layer_shape)
-    pool = sds(pool_shape, jnp.bfloat16)
     i32 = jnp.int32
     flat = None
+    quant = "none"
     if program.startswith("flat-"):
         share = float(fractions.Fraction(program[5:]))
         assert share in paging.FLAT_RUNGS
         flat = int(slots * mb * share)
+        quant = "int8"
+    dtype, spelling = (jnp.int8, "s8") if flat else (jnp.bfloat16, "bf16")
+    pool = (sds(pool_shape, dtype),) * 2
+    if flat:
+        pool += (sds((cfg.n_layers, num_blocks), jnp.float32),) * 2
     if program != "prefill":
         fn = paging.make_paged_decode_fn(
-            cfg, bs, mb, width, flat_pages=flat
+            cfg, bs, mb, width, kv_quant=quant, flat_pages=flat
         )
         vec = sds((slots,), i32)
         args = (vec, sds((len(paging.STEP_ROWS), slots), i32),
@@ -435,12 +448,12 @@ def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
         args = (sds((1, bucket), i32), sds((), i32), sds((), i32),
                 sds((width,), i32))
         view_pages = mb
-    compiled = jax.jit(fn, donate_argnums=(1, 2)).lower(
-        params, pool, pool, *args
-    ).compile()
+    compiled = jax.jit(
+        fn, donate_argnums=tuple(range(1, 1 + len(pool)))
+    ).lower(params, *pool, *args).compile()
 
     def spelled(shape):
-        return "bf16[" + ",".join(map(str, shape)) + "]"
+        return spelling + "[" + ",".join(map(str, shape)) + "]"
 
     rectangle = (slots, mb, *layer_shape[1:])
     view_shape = (flat, *layer_shape[1:]) if flat else rectangle
@@ -456,7 +469,7 @@ def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
         moved = [
             int(n) for line in entry.splitlines()
             if "/kv_read/gather" in line
-            for n in re.findall(rf"= bf16\[(\d+),{tail}\]", line)
+            for n in re.findall(rf"= {spelling}\[(\d+),{tail}\]", line)
         ]
         assert moved == [flat] * (2 * cfg.n_layers), moved
         assert spelled(rectangle) not in entry
@@ -491,6 +504,10 @@ def test_serve_programs_address_the_pool_in_place(v5e_2x2, cell, program):
     pool_bytes = cfg.n_layers * num_blocks * page_bytes
     temp = compiled.memory_analysis().temp_size_in_bytes
     assert temp < 2 * view_pages * page_bytes + pool_bytes // 2, temp
+    if program == "decode":
+        _walks_the_pool(
+            text, entry, cfg, slots, mb, bs, cfg.n_layers, temp
+        )
 
 
 @pytest.mark.parametrize("program", ["decode", "prefill"])
@@ -946,6 +963,233 @@ def test_parallel_programs_keep_the_pool_and_the_state_in_place(
         _walks_the_pool(text, entry, cfg, slots, mb, bs, cfg.n_layers, temp)
     else:
         assert "tpu_custom_call" not in text
+
+
+def _most_in_one_body(jaxpr, primitive) -> int:
+    """The most ``primitive`` equations in one straight-line stretch of
+    a kernel: those of one body (the kernel's own, a branch's, a
+    loop's), those of the bodies nested in it not counted."""
+    import jax.extend.core as jex
+
+    here, most = 0, 0
+    for eqn in jaxpr.eqns:
+        here += eqn.primitive.name == primitive
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) \
+                    else (value,):
+                if isinstance(sub, jex.ClosedJaxpr):
+                    sub = sub.jaxpr
+                if isinstance(sub, jex.Jaxpr):
+                    most = max(most, _most_in_one_body(sub, primitive))
+    return max(here, most)
+
+
+# (slots, capacity, KV heads, query rows a KV head) of the serve cells
+# whose row step walks K and V pages with no mask, and their pages'
+# KiB (16 rows x 128 numbers x KV heads, bf16).
+_WALKED_SHAPES = {
+    "mistral-32KiB": (96, 2304, 8, 4),
+    "granite-32KiB": (16, 30720, 8, 4),
+    "deepseek-128KiB": (32, 1536, 32, 1),
+    "falcon-16KiB": (16, 30720, 4, 5),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_WALKED_SHAPES))
+def test_a_walk_of_large_pages_issues_its_block_in_rounds(v5e_2x2, cell):
+    """The straight-line code ``sparse_paged_decode`` is lowered with at
+    every start. Where a page is 32 KiB or more, a whole block's copies
+    go ``ROUND_PAGES`` pages at a time in a loop, so the longest
+    straight line holds a round's K and V starts, not a block's (32
+    pages of 32 KiB in a block, 8 of 128 KiB); 16 KiB pages keep their
+    whole block in one line. A block's scores are unrolled over at most
+    ``HEAD_ROUND`` KV heads (two products each): deepseek's 32 go in
+    a loop over rounds of that many. Mosaic compiles each for the
+    v5e."""
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_hpc.kernels import sparse_paged_attention as spa
+
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+    slots, capacity, kv_heads, groups = _WALKED_SHAPES[cell]
+    bs, hd = 16, 128
+    mb = capacity // bs
+    width = mb + 512 // bs
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pool = sds((1, slots * mb + 1, kv_heads, bs, hd), jnp.bfloat16)
+    args = (sds((slots, kv_heads, groups, hd), jnp.bfloat16), pool, pool,
+            sds((), jnp.int32), sds((slots, width), jnp.int32),
+            sds((slots,), jnp.int32), sds((slots,), jnp.int32))
+
+    def walk(*args):
+        return spa.sparse_paged_decode(*args, scale=0.125)
+
+    page_bytes = kv_heads * bs * hd * 2
+    pages = min(spa.PAGES_PER_BLOCK, spa.BUFFER_BYTES // page_bytes)
+    line = pages if page_bytes < spa.ROUND_BYTES \
+        else min(pages, spa.ROUND_PAGES)
+    calls = [
+        eqn for eqn in jax.make_jaxpr(walk)(*args).jaxpr.eqns[0]
+        .params["jaxpr"].eqns if eqn.primitive.name == "pallas_call"
+    ]
+    assert len(calls) == 1
+    kernel = calls[0].params["jaxpr"]
+    assert _most_in_one_body(kernel, "dma_start") == 2 * line
+    assert (line < pages) == (page_bytes >= spa.ROUND_BYTES)
+    assert _most_in_one_body(kernel, "dot_general") \
+        == 2 * min(kv_heads, spa.HEAD_ROUND)
+    text = jax.jit(walk).lower(*args).compile().as_text()
+    assert text.count("tpu_custom_call") == 1
+
+
+def test_the_dense_walk_splits_over_kv_heads_on_a_tensor_axis(v5e_2x2):
+    """A dense decode program through ``gather`` on the serving mesh a
+    four-chip host builds for 7B (``{data: 1, model: 4}``, KV heads and
+    the pool over ``model``): the row step's walk runs under
+    ``shard_map`` a KV-head shard a chip, one Mosaic call a layer, and
+    no chip gathers another's pool (no all-gather of a pool-sized
+    operand)."""
+    import dataclasses
+
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from tpu_hpc.runtime import MeshSpec, build_mesh
+    from tpu_hpc.serve import paging
+    from tpu_hpc.serve.weights import serving_pspecs
+
+    cfg = dataclasses.replace(llama2.PRESETS["7b"], n_layers=2)
+    mesh = build_mesh(
+        MeshSpec(axes=tp.auto_mesh_axes(4, cfg.n_heads, cfg.kv_heads)),
+        devices=list(v5e_2x2.devices),
+    )
+    assert dict(mesh.shape) == {"data": 1, "model": 4}
+    rep = NamedSharding(mesh, P())
+
+    def sds(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    params = jax.tree.map(
+        lambda a, spec: sds(a.shape, a.dtype, NamedSharding(mesh, spec)),
+        jax.eval_shape(lambda: llama2.init_llama(jax.random.key(0), cfg)),
+        serving_pspecs(
+            jax.eval_shape(
+                lambda: llama2.init_llama(jax.random.key(0), cfg)
+            ), mesh,
+        ),
+    )
+    slots, bs, mb = 8, 16, 64
+    width = mb + 512 // bs
+    pool_shape = (cfg.n_layers, slots * mb + 1, cfg.kv_heads, bs,
+                  cfg.head_dim)
+    pool = sds(pool_shape, cfg.dtype, NamedSharding(
+        mesh, paging.paged_kv_cache_pspec(mesh, cfg.kv_heads)
+    ))
+    program = paging.make_paged_decode_fn(cfg, bs, mb, width, mesh=mesh)
+    text = jax.jit(program).lower(
+        params, pool, pool, sds((slots,), jnp.int32),
+        sds((len(paging.STEP_ROWS), slots), jnp.int32),
+        sds((slots, width), jnp.int32),
+    ).compile().as_text()
+    assert len(re.findall(
+        r"^\s*%sparse_paged_decode[.\d]* = [^\n]*tpu_custom_call", text,
+        re.M,
+    )) == cfg.n_layers
+    whole = "[" + ",".join(map(str, pool_shape)) + "]"
+    assert not [
+        line for line in text.splitlines()
+        if "all-gather" in line and whole in line
+    ]
+
+
+# The decode programs of the cells whose walk this rule leaves as it
+# was, at the cells' shapes (2 layers), as the commit before it traced
+# them with every kernel in its Mosaic form: the jaxpr, the kernels'
+# bodies included. (The lowered text carries the checkout's paths.)
+_UNMOVED_DECODE_JAXPRS = {
+    "keye": "3e878a6fe45e65bb",
+    "joyai": "d616d83a33d86642",
+    "falcon": "ab6ce5a3d8af2321",
+}
+
+
+@pytest.mark.parametrize("cell", sorted(_UNMOVED_DECODE_JAXPRS))
+def test_small_page_walks_trace_as_they_did(v5e_2x2, monkeypatch, cell):
+    """``serve-docqa-keye30b``'s (16 KiB pages under a mask),
+    ``serve-docqa-joyai-flash``'s (the latent walk) and
+    ``serve-docqa-falcon-h1-34b``'s (16 KiB, no mask) decode programs
+    trace to the text they had before a walk of large pages came to
+    issue its block in rounds."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    import dataclasses
+    import hashlib
+
+    import jax.numpy as jnp
+    from jax.sharding import SingleDeviceSharding
+
+    from tpu_hpc.models import hybrid_ssm_moe, latent_moe, sparse_moe
+    from tpu_hpc.serve import paging
+
+    one_chip = SingleDeviceSharding(v5e_2x2.devices[0])
+
+    def sds(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    slots, capacity, bs = 12 if cell == "keye" else 16, 30720, 16
+    mb = capacity // bs
+    width = mb + 512 // bs
+    pages = slots * mb + 1
+    bf16 = jnp.bfloat16
+    if cell == "keye":
+        cfg = dataclasses.replace(
+            sparse_moe.KEYE_VL2_30B_A3B, n_layers=2, max_seq_len=capacity,
+            dtype=bf16, param_dtype=bf16,
+        )
+        init = sparse_moe.init_sparse_moe
+        page = (2, pages, cfg.kv_heads, bs, cfg.head_dim)
+        state = [sds(page, bf16)] * 2 + [
+            sds((2, pages, bs, cfg.indexer_head_dim), bf16)
+        ]
+        counted = len(paging.SPARSE_COUNTERS)
+    elif cell == "joyai":
+        cfg = dataclasses.replace(
+            latent_moe.JOYAI_LLM_FLASH, n_layers=2, max_seq_len=capacity,
+            held_experts=tuple(range(64)), dtype=bf16, param_dtype=bf16,
+        )
+        init = latent_moe.init_latent_moe
+        pack = paging.rope_pack(cfg, bs)
+        state = [
+            sds((2, pages, bs // pack, pack * w), bf16)
+            for w in (cfg.kv_lora_rank, cfg.rope_dim)
+        ]
+        counted = len(paging.LATENT_COUNTERS)
+    else:
+        cfg = dataclasses.replace(
+            hybrid_ssm_moe.FALCON_H1_34B, n_layers=2, vocab_size=65280,
+            max_seq_len=capacity, dtype=bf16, param_dtype=bf16,
+        )
+        init = hybrid_ssm_moe.init_hybrid_ssm_moe
+        s_shape, rows_shape = cfg.state_shapes(slots)
+        page = (2, pages, cfg.kv_heads, bs, cfg.head_dim)
+        state = [sds(page, bf16)] * 2 + [
+            sds(s_shape, jnp.float32), sds(rows_shape, jnp.float32)
+        ]
+        counted = 0
+    params = jax.tree.map(
+        lambda a: sds(a.shape, a.dtype),
+        jax.eval_shape(lambda: init(jax.random.key(0), cfg)),
+    )
+    i32 = jnp.int32
+    jaxpr = jax.make_jaxpr(paging.make_paged_decode_fn(cfg, bs, mb, width))(
+        params, *state, sds((slots + counted,), i32),
+        sds((len(paging.STEP_ROWS), slots), i32), sds((slots, width), i32),
+    )
+    assert hashlib.sha256(str(jaxpr).encode()).hexdigest()[:16] \
+        == _UNMOVED_DECODE_JAXPRS[cell]
 
 
 def test_trained_expert_layer_and_narrow_flash_lower_for_v5e(

@@ -513,12 +513,13 @@ def params():
     )
 
 
-def _paged(params, mesh, kernel="gather"):
+def _paged(params, mesh, kernel="gather", kv_quant="none"):
     engine = PagedEngine(
         params, CFG,
         ServeConfig(slots=4, max_seq_len=64, prefill_buckets=(8, 16)),
         mesh, PagedConfig(block_size=4, num_blocks=4 * 16 + 1,
-                          prefill_chunk=8, kernel=kernel),
+                          prefill_chunk=8, kernel=kernel,
+                          kv_quant=kv_quant),
     )
     engine.warmup()
     return engine
@@ -561,7 +562,8 @@ def test_paged_engine_chunks_are_all_filed(params, mesh, ring, registry):
 
 
 def test_ladder_pair_counts_the_steps_no_rung_held(params, mesh, registry):
-    engine = _paged(params, mesh)
+    # An int8 pool's dense read gathers: the one engine with a ladder.
+    engine = _paged(params, mesh, kv_quant="int8")
     assert engine.decode_rungs == (24, 32)
     slots = engine.serve_cfg.slots
     for slot in range(slots):

@@ -764,7 +764,7 @@ def test_the_programs_lower_to_the_pinned_text(name):
 @pytest.mark.parametrize("pins,name", [
     ("test_sparse_moe", "gqa-decode-gather-none"),
     ("test_sparse_moe", "gqa-prefill-gather-none"),
-    ("test_sparse_moe", "mha-flat18-gather-none"),
+    ("test_sparse_moe", "mha-flat18-gather-int8"),
     ("test_latent_moe", "decode"),
     ("test_latent_moe", "prefill"),
 ])

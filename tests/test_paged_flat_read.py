@@ -1,26 +1,32 @@
-"""The decode step reads the pages that are live (PR 30): a flat list
-of every slot's live pages, in one of a few sizes the occupancy
-chooses, in place of the ``[slots, pages a slot]`` rectangle.
+"""The decode step reads the pages that are live, in place of the
+``[slots, pages a slot]`` rectangle: over a pool in the compute dtype
+its row step WALKS each slot's page table in a kernel
+(``kernels/sparse_paged_attention.py`` with no mask), over an int8 pool
+it gathers a flat list of every slot's live pages in one of a few sizes
+the occupancy chooses (the ladder).
 
 The oracle is the rectangle on the SAME pool, so every difference is
-the flat read's:
+the walk's or the flat read's:
 
-  * the attended rows agree to 1e-5 in float32, rung by rung, over
-    ragged lengths that cross page edges, a length exactly on an edge,
-    inactive slots between active ones, live pages that exactly fill a
-    rung, and nobody active (finite rows, only the scratch page
-    written), for MHA and GQA 4:1 and for float32 and int8 pages;
+  * the walk's attended rows agree to 1e-5 in float32 over ragged
+    lengths that cross page edges, a length exactly on an edge,
+    inactive slots between active ones, full slots and nobody active,
+    for MHA and GQA 4:1, with every page it must not read NaN;
+  * the flat read's agree the same way rung by rung, for float32 and
+    int8 pages (nobody active: finite rows, only the scratch page
+    written);
   * greedy tokens through ``PagedEngine.decode`` agree with an engine
-    that has no ladder, over 24 steps that climb through the rungs;
-  * the engine takes the smallest rung that holds a step's live pages,
-    compiles nothing after ``warmup``, counts what it read, and keeps
-    one step in flight across a change of rung;
-  * an indexer, a table-walking kernel and a speculative engine keep
-    the one decode program they had.
+    that reads the rectangle, over 24 steps that climb in occupancy
+    (through the rungs, for an int8 pool);
+  * a walking engine builds one decode program and nothing after
+    ``warmup``, and counts the live pages it read; a ladder engine
+    takes the smallest rung that holds a step's live pages, compiles
+    nothing after ``warmup``, counts what it read, and keeps one step
+    in flight across a change of rung;
+  * an indexer, a table-walking kernel, a walking dense engine and a
+    speculative engine keep the one decode program they had.
 
-CPU, float32, tiny sizes: values and counts, never a time. The slab
-oracle's token-exact parity (tests/test_serve.py, tests/test_paging.py)
-runs with the ladder on, as every engine there has it.
+CPU, float32, tiny sizes: values and counts, never a time.
 """
 import dataclasses
 
@@ -137,6 +143,41 @@ def test_flat_read_attends_as_the_rectangle_does(arch, quant, step, pages):
     assert not got[~on].any()
 
 
+@pytest.mark.parametrize("step", sorted(STEPS))
+@pytest.mark.parametrize("arch", sorted(CONFIGS))
+def test_the_walk_attends_as_the_rectangle_does(arch, step):
+    """A dense row step's read over a float32 pool: the kernel that
+    walks the tables (``PagedAttention._read_walked``, no mask: the
+    columns ``<= pos``) against the gathered rectangle on the same
+    pool. The walk reads a copy in which every page no active slot
+    reaches is NaN; an inactive slot's rows are zeros."""
+    cfg = CONFIGS[arch]
+    positions, active = STEPS[step]
+    state, tables = _pool(cfg, False)
+    q = jnp.asarray(np.random.default_rng(1).normal(
+        size=(SLOTS, 1, cfg.n_heads, cfg.head_dim)
+    ), jnp.float32)
+    want = _attended(cfg, False, state, tables, q, positions, active, None)
+    read = {
+        int(page) for s, (p, a) in enumerate(zip(positions, active)) if a
+        for page in np.asarray(tables)[s, :p // BLOCK + 1]
+    }
+    unread = sorted(set(range(VIEW + 1)) - read)
+    poisoned = [np.asarray(a).copy() for a in state]
+    for pool in poisoned:
+        pool[:, unread] = np.nan
+    pool = paging.PagedAttention(cfg, BLOCK, MAX_BLOCKS).on(
+        *map(jnp.asarray, poisoned)
+    )
+    pool.view(tables, jnp.asarray(positions, jnp.int32),
+              jnp.asarray(active, jnp.int32))
+    got = np.asarray(pool._read_walked(1, q))
+    on = np.asarray(active, bool)
+    assert np.isfinite(got).all()
+    np.testing.assert_allclose(got[on], want[on], rtol=0, atol=1e-5)
+    assert not got[~on].any()
+
+
 def test_the_cases_cover_what_they_name():
     live = {name: _live(*step) for name, step in STEPS.items()}
     assert live["fills_a_rung"] == RUNGS[0]
@@ -144,12 +185,16 @@ def test_the_cases_cover_what_they_name():
     assert 0 < live["ragged"] < RUNGS[0]
 
 
-@pytest.mark.parametrize("pages", [None, *RUNGS])
-@pytest.mark.parametrize("quant", [False, True], ids=["f32", "int8"])
+# A float32 pool's step walks (no rung); an int8 pool's reads the
+# rectangle or a rung.
+@pytest.mark.parametrize("quant,pages", [
+    (False, None), (True, None), *((True, pages) for pages in RUNGS),
+], ids=["f32-None", "int8-None", *(f"int8-{p}" for p in RUNGS)])
 @pytest.mark.parametrize("arch", sorted(CONFIGS))
 def test_nobody_active_writes_the_scratch_page_only(arch, quant, pages):
-    """Every entry of the list is nobody's: the step reads the scratch
-    page, its rows come out finite and no live page changes."""
+    """Nobody is active: the walk reads no page, every entry of a
+    rung's list is nobody's (it reads the scratch page); the rows come
+    out finite and no live page changes."""
     cfg = CONFIGS[arch]
     params = llama2.init_llama(jax.random.key(0), cfg)
     state, tables = _pool(cfg, quant)
@@ -175,9 +220,16 @@ def test_nobody_active_writes_the_scratch_page_only(arch, quant, pages):
 # -- through the engine ---------------------------------------------------
 class _Rectangle(PagedEngine):
     """The engine as it was: one decode program, every slot's whole
-    capacity a step."""
+    capacity a step (:func:`_engine` builds its programs with the
+    row step's walk swapped for the gathered read)."""
 
     decode_rungs = ()
+
+
+def _gathered(self, layer, q, mask=None):
+    """A row step's read as the rectangle ran it: the view's pages
+    gathered and attended over under the columns ``<= pos``."""
+    return self._read(layer, q, self.mask)
 
 
 def _engine(arch, mesh, quant=False, cls=PagedEngine):
@@ -187,7 +239,10 @@ def _engine(arch, mesh, quant=False, cls=PagedEngine):
         PagedConfig(block_size=BLOCK, num_blocks=VIEW + 1, prefill_chunk=16,
                     kv_quant="int8" if quant else "none"),
     )
-    eng.warmup()
+    with pytest.MonkeyPatch.context() as patch:
+        if cls is _Rectangle:
+            patch.setattr(paging.PagedAttention, "_read_walked", _gathered)
+        eng.warmup()
     return eng
 
 
@@ -200,10 +255,12 @@ def _decode_keys(engine):
 def test_greedy_tokens_agree_over_a_climb_through_the_rungs(
     arch, quant, mesh
 ):
-    """Four prompts of 3, 9, 14 and 6 tokens (10 live pages: the
-    first rung) decode 24 tokens each to 34 live pages (past the top
-    rung: the rectangle); a ladder engine and one without say the same
-    tokens at every step."""
+    """Four prompts of 3, 9, 14 and 6 tokens (10 live pages: an int8
+    pool's first rung) decode 24 tokens each to 34 live pages (past
+    the top rung: the rectangle); the engine and one that reads the
+    rectangle at every step say the same tokens at every step. Over a
+    float32 pool the engine walks the tables in its one decode
+    program."""
     rng = np.random.default_rng(5)
     prompts = [rng.integers(0, 128, n).tolist() for n in (3, 9, 14, 6)]
     streams, used = {}, None
@@ -226,13 +283,17 @@ def test_greedy_tokens_agree_over_a_climb_through_the_rungs(
         if cls is PagedEngine:
             used = [k for k in keys if k[0] == "decode"]
     assert streams[PagedEngine] == streams[_Rectangle]
+    if not quant:
+        assert set(used) == {("decode",)}
+        return
     assert used[0] == ("decode", 24) and used[-1] == ("decode",)
     assert ("decode", 32) in used
 
 
 @pytest.fixture(scope="module")
 def ladder(mesh):
-    return _engine("gqa", mesh)
+    # An int8 pool's dense read gathers: the one engine with a ladder.
+    return _engine("gqa", mesh, quant=True)
 
 
 def _positions(live_pages):
@@ -294,9 +355,35 @@ def test_one_step_stays_in_flight_across_a_change_of_rung(ladder):
     assert ladder.paged_stats[OVERLAPPED] - before == 2
 
 
+@pytest.mark.parametrize("arch", sorted(CONFIGS))
+def test_a_walking_engine_builds_one_decode_program(arch, mesh):
+    """A dense engine over a float32 pool: one decode program whatever
+    is live, nothing compiled after ``warmup`` over a climb in
+    occupancy and back, and each step counted by the live pages of
+    its active slots (what its walks read), no ladder counters."""
+    engine = _engine(arch, mesh)
+    assert engine.decode_rungs == ()
+    assert _decode_keys(engine) == [("decode",)]
+    assert not {n for n, _ in paging.LADDER_COUNTERS} & set(
+        engine.paged_stats
+    )
+    warmed = engine.compile_count_total
+    before = dict(engine.paged_stats)
+    climb = [4, 24, 25, 33, 64, 5]
+    for live in climb:
+        engine.decode_now([1] * SLOTS, _positions(live))
+    engine.decode_now([1] * SLOTS, [63, 63, 63, 3], [0, 0, 0, 1])
+    assert engine.compile_count_total == warmed
+    assert _decode_keys(engine) == [("decode",)]
+    grown = {k: v - before[k] for k, v in engine.paged_stats.items()}
+    assert grown[PAGES_READ] == sum(climb) + 1
+    assert grown[PAGES_TOTAL] == VIEW * (len(climb) + 1)
+
+
 def test_engines_that_keep_their_one_decode_program(devices, mesh):
-    """An indexer, a table-walking kernel and a speculative engine run
-    the program they had: no flat rung is built for them."""
+    """An indexer, a table-walking kernel, a dense engine whose row
+    step walks the tables and a speculative engine run the program
+    they had: no flat rung is built for them."""
     sparse_cfg = sparse_moe.SparseMoEConfig(
         name="tiny-sparse", dim=64, n_layers=1, n_heads=4, n_kv_heads=2,
         head_dim=32, vocab_size=128, max_seq_len=CAPACITY, n_experts=4,
@@ -317,19 +404,21 @@ def test_engines_that_keep_their_one_decode_program(devices, mesh):
         build_mesh(MeshSpec(axes={"data": 4, "model": 2})),
         dataclasses.replace(paged, kernel="pallas"),
     )
+    walking = PagedEngine(params, cfg, SERVE, mesh, paged)
     spec = PagedEngine(params, cfg, SERVE, mesh, paged)
     attach_spec(spec, SpecConfig(mode="ngram", k=2))
-    for engine in (sparse, pallas):
+    for engine in (sparse, pallas, walking):
         assert engine.decode_rungs == ()
         engine.warmup()
         assert _decode_keys(engine) == [("decode",)]
     assert spec.decode_rungs == ()
     spec.warmup()
     assert _decode_keys(spec) == []     # its step is the verify program
-    with pytest.raises(ValueError, match="flat_pages"):
-        paging.make_paged_decode_fn(
-            sparse_cfg, BLOCK, MAX_BLOCKS, MAX_BLOCKS + 4, flat_pages=8
-        )
+    for arch in (sparse_cfg, cfg):
+        with pytest.raises(ValueError, match="flat_pages"):
+            paging.make_paged_decode_fn(
+                arch, BLOCK, MAX_BLOCKS, MAX_BLOCKS + 4, flat_pages=8
+            )
 
 
 @pytest.mark.parametrize("name", [PAGES_READ, PAGES_TOTAL])

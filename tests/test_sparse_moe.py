@@ -527,9 +527,14 @@ def test_counts_come_back_with_the_tokens(params, mesh):
 # row step reads K and V through the kernel that walks the page tables
 # (``kernels/sparse_paged_attention.py``: interpreted here, so the
 # kernel's own text is part of the program's); ``sparse-prefill`` still
-# gathers its one view.
+# gathers its one view. The dense ``decode-gather-none`` pair was
+# re-pinned (from 81408bbaba39a8c9 / 9816f8bed450484a) when a dense row
+# step over a pool in the compute dtype came to read through the same
+# kernel, and no other; such a pool keeps no flat rung, so the
+# ``flat<pages>`` programs are those of an int8 pool, pinned as the
+# parent lowered them.
 PROGRAM_DIGESTS = {
-    "gqa-decode-gather-none": "81408bbaba39a8c9",
+    "gqa-decode-gather-none": "7d69c843f7e1f8cd",
     "gqa-prefill-gather-none": "8a8972db9fc5d25e",
     "gqa-decode-gather-int8": "da821b4063db53ed",
     "gqa-prefill-gather-int8": "2144b4b5b662fbda",
@@ -537,7 +542,7 @@ PROGRAM_DIGESTS = {
     "gqa-prefill-pallas-none": "76d6e8f95829fbe8",
     "gqa-decode-pallas-int8": "35d63b0063e32c5b",
     "gqa-prefill-pallas-int8": "4695e884e5b48fb4",
-    "mha-decode-gather-none": "9816f8bed450484a",
+    "mha-decode-gather-none": "73c3708328131e89",
     "mha-prefill-gather-none": "141949fcab431570",
     "mha-decode-gather-int8": "4ce500996804bdeb",
     "mha-prefill-gather-int8": "71101e9fc0221492",
@@ -555,10 +560,10 @@ PROGRAM_DIGESTS = {
     "sparse-decode": "b7a3b99c22315c58",
     "sparse-decode_probe": "1f367c6a19665171",
     "sparse-prefill": "98bb54d41635cafb",
-    "gqa-flat18-gather-none": "ba224ec5956db4d4",
-    "gqa-flat24-gather-none": "b21373b7aa6c8bf3",
-    "mha-flat18-gather-none": "c6d1fa9b094f9fbd",
-    "mha-flat24-gather-none": "be16c8b0efdbda89",
+    "gqa-flat18-gather-int8": "14d6a7965a8ac5b5",
+    "gqa-flat24-gather-int8": "2f17b8ed623eb0f0",
+    "mha-flat18-gather-int8": "2bd9c0aeb46712ef",
+    "mha-flat24-gather-int8": "fec9a8a51a235df5",
 }
 
 

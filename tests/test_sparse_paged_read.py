@@ -317,10 +317,11 @@ def test_without_a_mask_the_walk_attends_up_to_the_position(
 
 
 # -- without a selection: a state-space configuration's row step ----------
-# (KV heads, query rows a KV head) of the two cells that walk with no
-# mask: falcon-h1-34b's 4 x 5 and granite-4.0-h-small's 8 x 4. With
-# 128-wide rows of 16 tokens a bf16 page is 16 KiB and 32 KiB.
-UNMASKED = {"falcon": (4, 5), "granite": (8, 4)}
+# (KV heads, query rows a KV head) of the cells that walk with no mask:
+# falcon-h1-34b's 4 x 5, granite-4.0-h-small's and mistral-7b's 8 x 4,
+# deepseek-llm-7b's 32 x 1 (scored in rounds of HEAD_ROUND heads). With
+# 128-wide rows of 16 tokens a bf16 page is 16, 32 and 128 KiB.
+UNMASKED = {"falcon": (4, 5), "granite": (8, 4), "deepseek": (32, 1)}
 
 
 @pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
@@ -400,13 +401,16 @@ def test_the_unmasked_walk_attends_as_the_gathered_view_does(
 
 @pytest.mark.parametrize("kv_heads,dtype,pages", [
     (4, jnp.bfloat16, 64),   # keye's and falcon's 16 KiB pages
-    (8, jnp.bfloat16, 32),   # granite's 32 KiB
+    (8, jnp.bfloat16, 32),   # granite's and mistral's 32 KiB
     (8, jnp.float32, 16),    # 64 KiB
     (1, jnp.float32, 64),    # 8 KiB: no more than PAGES_PER_BLOCK
 ])
 def test_a_block_holds_a_buffer_of_pages(monkeypatch, kv_heads, dtype, pages):
     """The walk's block is chosen by the page's bytes: as many pages as
-    fill ``BUFFER_BYTES`` (1 MiB), at most ``PAGES_PER_BLOCK``."""
+    fill ``BUFFER_BYTES`` (1 MiB), at most ``PAGES_PER_BLOCK``; and so
+    are its rounds: pages of ``ROUND_BYTES`` (32 KiB) and up issue a
+    whole block's copies ``ROUND_PAGES`` at a time, smaller ones all
+    in one straight line."""
     seen = {}
     monkeypatch.setattr(
         sparse_paged_attention, "_walk", lambda *a, **kw: seen.update(kw)
@@ -417,6 +421,62 @@ def test_a_block_holds_a_buffer_of_pages(monkeypatch, kv_heads, dtype, pages):
         None, None, scale=1.0,
     )
     assert seen["pages"] == pages
+    page_bytes = kv_heads * 16 * 128 * jnp.dtype(dtype).itemsize
+    assert seen["round_pages"] == (
+        sparse_paged_attention.ROUND_PAGES
+        if page_bytes >= sparse_paged_attention.ROUND_BYTES else None
+    )
+
+
+@pytest.mark.parametrize("round_pages", [3, 8])
+def test_a_block_in_rounds_reads_as_one_straight_line(round_pages):
+    """32 KiB float32 pages (2 KV heads x 32 rows x 128) in blocks of 20
+    pages, their copies issued in rounds of 3 or 8 (6 rounds and 2
+    pages over, 2 rounds and 4 over): the same rows as the block's
+    copies in one straight line, and as the gathered view, over slots
+    of several whole blocks, a last block of a few pages, an inactive
+    slot and one on a page's first row; every page the walk must not
+    read is NaN."""
+    kv_heads, groups, bs, width, pages = 2, 2, 32, 128, 20
+    slots, per_slot = 4, 48
+    positions, active = [47 * bs + 5, 21 * bs, 30, 2 * pages * bs - 1], \
+        [1, 1, 0, 1]
+    rng = np.random.default_rng(11)
+    n_pages = slots * per_slot + 1
+    pools = [rng.normal(size=(1, n_pages, kv_heads, bs, width))
+             .astype(np.float32) for _ in range(2)]
+    tables = np.full((slots, per_slot), paging.SCRATCH_BLOCK, np.int32)
+    tables[:] = 1 + rng.permutation(n_pages - 1).reshape(slots, per_slot)
+    read = {
+        int(page) for s, (p, a) in enumerate(zip(positions, active)) if a
+        for page in tables[s, :p // bs + 1]
+    }
+    for pool in pools:
+        pool[:, sorted(set(range(n_pages)) - read)] = np.nan
+    q = jnp.asarray(rng.normal(size=(slots, kv_heads, groups, width)),
+                    jnp.float32)
+    args = (q, *map(jnp.asarray, pools), jnp.int32(0), jnp.asarray(tables),
+            jnp.asarray(positions, jnp.int32),
+            jnp.asarray(active, jnp.int32), None)
+    got, straight = (np.asarray(sparse_paged_attention._walk(
+        *args, scale=0.1, interpret=True, pages=pages, round_pages=r,
+    )) for r in (round_pages, None))
+    np.testing.assert_array_equal(got, straight)
+    clean = [np.nan_to_num(pool) for pool in pools]
+    valid = (
+        np.arange(per_slot * bs)[None, :] <= np.asarray(positions)[:, None]
+    )[:, None, None, None, :]
+    want = np.asarray(decoder._grouped_attention_paged(
+        q.reshape(slots, 1, kv_heads * groups, width),
+        *(jnp.asarray(pool)[0, tables] for pool in clean), valid,
+        types.SimpleNamespace(
+            kv_heads=kv_heads, n_heads=kv_heads * groups, head_dim=width,
+            dtype=jnp.float32, attention_multiplier=0.1,
+        ),
+    )).reshape(got.shape)
+    on = np.asarray(active, bool)
+    assert np.isfinite(got).all() and not got[~on].any()
+    np.testing.assert_allclose(got[on], want[on], rtol=0, atol=1e-5)
 
 
 # -- through the engine ---------------------------------------------------

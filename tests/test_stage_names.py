@@ -78,6 +78,10 @@ def programs(devices, tiny_abstract):
     cache = jax.ShapeDtypeStruct(
         (TINY.n_layers, 48, TINY.kv_heads, BLOCK, TINY.head_dim), f32
     )
+    # An int8 pool and its scales: the one whose dense read keeps the
+    # flat rungs.
+    cache_q = jax.ShapeDtypeStruct(cache.shape, jnp.int8)
+    scales = jax.ShapeDtypeStruct((TINY.n_layers, 48), f32)
     slab_cache = jax.ShapeDtypeStruct(
         (TINY.n_layers, slots, 48, TINY.kv_heads, TINY.head_dim), f32
     )
@@ -108,9 +112,10 @@ def programs(devices, tiny_abstract):
         ),
         "decode_flat": (
             paging.make_paged_decode_fn(
-                TINY, BLOCK, PER_SEQ, WIDTH, flat_pages=slots * PER_SEQ // 2
+                TINY, BLOCK, PER_SEQ, WIDTH, kv_quant="int8",
+                flat_pages=slots * PER_SEQ // 2,
             ),
-            (tiny_abstract, cache, cache, vec,
+            (tiny_abstract, cache_q, cache_q, scales, scales, vec,
              jax.ShapeDtypeStruct((len(paging.STEP_ROWS), slots), i32),
              tables),
         ),
@@ -530,16 +535,22 @@ def _ops_by_scope(fn, args) -> dict:
 # ``kv_read_ms`` or ``attention_ms.serve`` while the program stays the
 # same.
 OPS_BY_SCOPE = {
+    # Re-taken when a dense row step came to read through the kernel
+    # that walks the page tables: all of it under ``kv_read`` (the
+    # query's grouping and the call, one operation), ``attention`` the
+    # reshape of its result (44 before, ``kv_read`` 36; the rectangle's
+    # mask no longer counts among the unscoped, 61 before).
     "dense-decode": {
-        None: 61, "embed": 8, "qkv": 98, "kv_write": 116, "kv_read": 36,
-        "attention": 44, "attn_out": 6, "mlp": 32, "head": 13,
+        None: 56, "embed": 8, "qkv": 98, "kv_write": 116, "kv_read": 12,
+        "attention": 2, "attn_out": 6, "mlp": 32, "head": 13,
     },
-    # A flat rung (PR 30, as it came): the list of live pages is
-    # derived under ``kv_read`` (60 operations, once a step), the owner
-    # products and the combine run under ``attention``, and nothing
-    # joins the unscoped ones (the rectangle's mask leaves them).
+    # A flat rung of an int8 pool, the one that keeps a ladder,
+    # as the parent lowered it: the list of live pages is derived under
+    # ``kv_read`` once a step, beside the dequantized gathers, the
+    # page requantize under ``kv_write``, the owner products and the
+    # combine under ``attention``.
     "dense-decode_flat": {
-        None: 55, "embed": 8, "qkv": 98, "kv_write": 116, "kv_read": 96,
+        None: 60, "embed": 8, "qkv": 98, "kv_write": 268, "kv_read": 144,
         "attention": 80, "attn_out": 6, "mlp": 32, "head": 13,
     },
     "dense-prefill": {

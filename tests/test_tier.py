@@ -313,11 +313,11 @@ class TestHostTierEngine:
         self, tiered
     ):
         engine, warmed = tiered
-        # Buckets + the decode ladder (two flat rungs, the
-        # rectangle) + copy_block (the test_paging pin) plus the
-        # tier's spill gather + refill scatter -- same table, same
-        # counter, so the steady-state pins below cover the tier.
-        assert warmed == len(SERVE.prefill_buckets) + 2 + 2 + 2
+        # Buckets + the one decode program (its row step walks the
+        # tables: no flat rung) + copy_block (the test_paging pin)
+        # plus the tier's spill gather + refill scatter -- same table,
+        # same counter, so the steady-state pins below cover the tier.
+        assert warmed == len(SERVE.prefill_buckets) + 1 + 1 + 2
         assert engine.host_tier is not None
         assert engine.host_tier.group >= 1
         # "auto" sized the transfer group from the topology cost

@@ -12,14 +12,20 @@ runs its ``score`` over the blocks of the walk, each block's copies in
 flight while the block before is scored.
 
 **What the chip asked for** (TPU v5 lite; docs/guide/latent_moe.md has
-the table). What bounds such a walk is how fast its copies are ISSUED,
-not the bandwidth. So the copies of a whole block are straight-line
-code (a branch or a loop round between them costs more than the copy),
-a whole block is waited for with one wait an array, only a slot's first
-and last block go through a loop, and the loop over blocks runs two a
-round so that each block's buffer is known where the kernel is
-compiled: then the next block's copies are issued beside this block's
-products.
+the table). What bounds such a walk of small pages is how fast its
+copies are ISSUED, not the bandwidth. So the copies of a whole block are
+straight-line code (a branch or a loop round between them costs more
+than the copy), a whole block is waited for with one wait an array,
+only a slot's first and last block go through a loop, and the loop over
+blocks runs two a round so that each block's buffer is known where the
+kernel is compiled: then the next block's copies are issued beside this
+block's products. Where a page is large (32 KiB and up: a copy takes
+40 ns and more at 819 GB/s, where issuing one takes ~17) its caller may
+name ``round_pages``: a whole block's copies are then issued in rounds
+of that many pages, a loop over the rounds with each round's copies
+straight-line, which hides behind the bandwidth and keeps the code the
+kernel is lowered with (at every start) the size of a round, not of a
+block (docs/guide/sparse_moe.md has the sweep).
 """
 from __future__ import annotations
 
@@ -40,13 +46,16 @@ class Paged(NamedTuple):
     buffer: object
 
 
-def page_walk(s, n_live, tables_ref, width, pages, rows, arrays, sems):
+def page_walk(s, n_live, tables_ref, width, pages, rows, arrays, sems,
+              round_pages=None):
     """Start slot ``s``'s walk of its ``n_live`` leading pages
     (``tables_ref[s * width + ...]``) in blocks of ``pages`` pages of
     ``rows`` rows: the buffers are cleaned before the first slot, the
     first block's copies are started, and the walk's ``run(score,
     init)`` comes back. ``arrays`` is a :class:`Paged` a pool array;
     ``sems`` a DMA semaphore an array and buffer, ``[len(arrays), 2]``.
+    ``round_pages``: a whole block's copies go in rounds of that many
+    pages where the block holds more (None: all of them straight-line).
 
     ``run`` calls ``score(block, buf, carry) -> carry`` once a block
     in order (``init()`` makes the first carry; the last comes back),
@@ -84,24 +93,36 @@ def page_walk(s, n_live, tables_ref, width, pages, rows, arrays, sems):
             0, jnp.minimum(n_live - block * pages, pages), page, None
         )
 
+    def straight(block, buf, what, first, last):
+        """``what`` the copies of ``block``'s pages ``first .. last - 1``
+        (``first`` may be traced), no branch and no loop between them."""
+        for i in range(last):
+            for copy in copies(block, buf, first + i):
+                what(copy)
+
     def each_page(block, buf, what, whole=None):
         """``what`` every live page's copies of ``block``. Those of a
         WHOLE block are straight-line code, no branch and no loop
         between them (or ``whole``, if given, in their place): that is
         what lets the chip issue them beside the products of the block
         before (a branch a copy cost the v5e 0.4 ms a layer, a loop
-        round of eight copies as much). A slot's LAST block, the one
-        block that may hold fewer than ``pages`` live pages, takes the
-        loop."""
+        round of eight copies as much), or, with ``round_pages``, a
+        loop over rounds of that many, each round straight-line. A
+        slot's LAST block, the one block that may hold fewer than
+        ``pages`` live pages, takes the loop."""
         full = (block + 1) * pages <= n_live
 
         @pl.when(full)
         def _whole():
             if whole is not None:
                 return whole()
-            for i in range(pages):
-                for copy in copies(block, buf, i):
-                    what(copy)
+            if round_pages is None or pages <= round_pages:
+                return straight(block, buf, what, 0, pages)
+            rounds, rest = divmod(pages, round_pages)
+            jax.lax.fori_loop(0, rounds, lambda r, _: straight(
+                block, buf, what, r * round_pages, round_pages
+            ), None)
+            straight(block, buf, what, rounds * round_pages, rest)
 
         @pl.when(jnp.logical_not(full))
         def _last():

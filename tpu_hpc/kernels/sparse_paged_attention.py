@@ -23,7 +23,9 @@ head's group of query rows, float32 scores times ``scale``, the
 layer's selection applied BEFORE the softmax (the block's slice of the
 slot's mask row: column ``c`` of a view is page ``c // block_size``,
 row ``c % block_size``, so it is contiguous), a float32 running max,
-sum and attended value, the output written once at the slot's end. No
+sum and attended value, the output written once at the slot's end (more
+KV heads than :data:`HEAD_ROUND` are scored in a loop over rounds of
+that many, their running max, sum and value in fast memory). No
 view of the pool is written, no score leaves fast memory, pages past a
 slot's position and every page of an inactive slot are not read.
 
@@ -31,8 +33,10 @@ The selection is a subset of the columns ``<= pos`` (the indexer ranks
 only those), so the mask is the one limit on what is attended; a block
 none of whose columns is selected adds nothing, whatever it is the
 first. Without a mask (``mask=None``: a caller with no selection, the
-row step of a configuration with state-space layers) the columns ``<=
-pos`` are attended.
+row step of a dense configuration or of one with state-space layers)
+the columns ``<= pos`` are attended. Pages of :data:`ROUND_BYTES` and
+up issue a whole block's copies :data:`ROUND_PAGES` at a time
+(``page_walk``'s ``round_pages``).
 
 Products in the queries' dtype with float32 accumulation, a float32
 softmax over float32 scores (the gathered form rounds its scores to
@@ -61,14 +65,35 @@ PAGES_PER_BLOCK = 64
 # halves the straight-line copies the kernel is lowered with at every
 # start (docs/guide/sparse_moe.md).
 BUFFER_BYTES = 64 << 14
+# Pages of ROUND_BYTES and up issue a whole block's copies in rounds of
+# ROUND_PAGES (``page_walk``'s ``round_pages``): a 32 KiB copy takes
+# ~40 ns of bandwidth, so a loop round a few pages hides behind it, and
+# what the kernel is lowered with at every start no longer grows with
+# the pages in a block. Swept on the v5e (docs/guide/sparse_moe.md): at
+# serve-chat-mistral7b's and serve-docqa-granite4h-small's shapes the
+# kernel's time within 0.1 % of one straight line and its lowering 0.07
+# and 0.17 s shorter a start (that is all the rounds buy); at
+# serve-decode-deepseek7b's 8-page block 0.9 % slower.
+ROUND_BYTES = 32 << 10
+ROUND_PAGES = 4
+# KV heads a block's scoring unrolls. More (a multiple of it: 32 in
+# serve-decode-deepseek7b, one query row each) are scored in a loop over
+# rounds of this many, their running softmax in fast memory. Swept on
+# the v5e at that cell's shape: 32 unrolled (twice, once a buffer) read
+# 0.92 ms a layer against 1.22 in rounds of 16 and 1.28 in rounds of 8,
+# and made the cell's start 3.4 s longer than the ladder it replaced,
+# against 1.5 and 0.3 s (the kernel's trace and the decode program's
+# lowering, with every program a compile-cache hit): over setup_s's
+# bound unrolled, and 1 % of rate for 1.2 s at 16.
+HEAD_ROUND = 8
 
 
 def _kernel(
     layer_ref, tables_ref, pos_ref, active_ref,       # scalar prefetch
-    q_ref, *refs, block_size, width, pages, scale, masked,
+    q_ref, *refs, block_size, width, pages, scale, masked, round_pages,
 ):
     mask_ref = refs[0] if masked else None
-    ks_ref, vs_ref, o_ref, k_buf, v_buf, sems = refs[masked:]
+    ks_ref, vs_ref, o_ref, k_buf, v_buf, sems, *stats = refs[masked:]
     s = pl.program_id(0)
     layer = layer_ref[0]
     pos = pos_ref[s]
@@ -81,47 +106,78 @@ def _kernel(
     run = page_walk(s, n_live, tables_ref, width, pages, 1, (
         Paged(lambda page: ks_ref.at[layer, pl.ds(page, 1)], k_buf),
         Paged(lambda page: vs_ref.at[layer, pl.ds(page, 1)], v_buf),
-    ), sems)
+    ), sems, round_pages)
 
     nt = (((1,), (1,)), ((), ()))              # a @ b.T
 
+    def kept(block):
+        """The columns of ``block`` that are attended, ``[1, columns]``."""
+        if masked:
+            return mask_ref[pl.ds(block, 1), :] > 0
+        return block * columns + jax.lax.broadcasted_iota(
+            jnp.int32, (1, columns), 1
+        ) <= pos
+
+    def head(h, buf, keep, top, total, acc):
+        """KV head ``h``'s round of the online softmax: its ``groups``
+        query rows against the keys of the block in buffer ``buf``."""
+        q = q_ref[h]                           # [groups, head_dim]
+        # The head's rows of the block's pages, page after page: the
+        # block's tokens in order.
+        k = k_buf[buf, :, h].reshape(columns, head_dim).astype(q.dtype)
+        v = v_buf[buf, :, h].reshape(columns, head_dim).astype(q.dtype)
+        scores = jnp.where(keep, jax.lax.dot_general(
+            q, k, nt, preferred_element_type=jnp.float32
+        ) * scale, -jnp.inf)
+        new_top = jnp.maximum(top, jnp.max(scores, axis=1, keepdims=True))
+        # Nothing selected so far: any finite number, for exp(-inf - top)
+        # to be 0 and not exp(-inf + inf).
+        safe = jnp.where(new_top == -jnp.inf, 0.0, new_top)
+        shrink = jnp.exp(top - safe)
+        p = jnp.exp(scores - safe)
+        return (
+            new_top,
+            shrink * total + jnp.sum(p, axis=1, keepdims=True),
+            shrink * acc + jnp.dot(
+                p.astype(q.dtype), v, preferred_element_type=jnp.float32,
+            ),
+        )
+
+    if stats:
+        # Rounds of HEAD_ROUND heads, the running max, sum and attended
+        # value of every head in fast memory (``[kv_heads, groups, 1 |
+        # head_dim]``).
+        def score(block, buf, carry):
+            keep = kept(block)
+
+            def heads(r, _):
+                for j in range(HEAD_ROUND):
+                    h = r * HEAD_ROUND + j
+                    out = head(h, buf, keep, *(ref[h] for ref in stats))
+                    for ref, value in zip(stats, out):
+                        ref[h] = value
+
+            jax.lax.fori_loop(0, kv_heads // HEAD_ROUND, heads, None)
+            return carry
+
+        def init():
+            top, total, acc = stats
+            top[...] = jnp.full(top.shape, -jnp.inf, jnp.float32)
+            total[...] = jnp.zeros(total.shape, jnp.float32)
+            acc[...] = jnp.zeros(acc.shape, jnp.float32)
+
+        run(score, init)
+        _, total, acc = (ref[...] for ref in stats)
+        o_ref[...] = acc / jnp.where(total > 0, total, 1.0)
+        return
+
     def score(block, buf, carry):
         """One round of the online softmax over ``block``, landed in
-        buffer ``buf``: a KV head at a time, its ``groups`` query rows
-        against the block's keys."""
-        if masked:
-            keep = mask_ref[pl.ds(block, 1), :] > 0        # [1, columns]
-        else:
-            keep = block * columns + jax.lax.broadcasted_iota(
-                jnp.int32, (1, columns), 1
-            ) <= pos
-        out = []
-        for h, (top, total, acc) in enumerate(carry):
-            q = q_ref[h]                       # [groups, head_dim]
-            # The head's rows of the block's pages, page after page:
-            # the block's tokens in order.
-            k = k_buf[buf, :, h].reshape(columns, head_dim).astype(q.dtype)
-            v = v_buf[buf, :, h].reshape(columns, head_dim).astype(q.dtype)
-            scores = jnp.where(keep, jax.lax.dot_general(
-                q, k, nt, preferred_element_type=jnp.float32
-            ) * scale, -jnp.inf)
-            new_top = jnp.maximum(
-                top, jnp.max(scores, axis=1, keepdims=True)
-            )
-            # Nothing selected so far: any finite number, for exp(-inf
-            # - top) to be 0 and not exp(-inf + inf).
-            safe = jnp.where(new_top == -jnp.inf, 0.0, new_top)
-            shrink = jnp.exp(top - safe)
-            p = jnp.exp(scores - safe)
-            out.append((
-                new_top,
-                shrink * total + jnp.sum(p, axis=1, keepdims=True),
-                shrink * acc + jnp.dot(
-                    p.astype(q.dtype), v,
-                    preferred_element_type=jnp.float32,
-                ),
-            ))
-        return tuple(out)
+        buffer ``buf``: a KV head at a time."""
+        keep = kept(block)
+        return tuple(
+            head(h, buf, keep, *c) for h, c in enumerate(carry)
+        )
 
     heads = run(score, lambda: tuple((
         jnp.full((groups, 1), -jnp.inf, jnp.float32),
@@ -158,6 +214,7 @@ def sparse_paged_decode(
     pages = min(
         PAGES_PER_BLOCK, max(1, BUFFER_BYTES // page_bytes), tables.shape[1]
     )
+    round_pages = ROUND_PAGES if page_bytes >= ROUND_BYTES else None
     if mask is not None:
         # A row a block of the walk, whole blocks only (what a table
         # wider than the view names past it is selected by nobody).
@@ -167,7 +224,7 @@ def sparse_paged_decode(
         )).astype(jnp.int32).reshape(mask.shape[0], -1, block)
     return _walk(
         q, ks, vs, layer, tables, pos, active, mask, scale=scale,
-        interpret=interpret, pages=pages,
+        interpret=interpret, pages=pages, round_pages=round_pages,
     )
 
 
@@ -175,10 +232,10 @@ def sparse_paged_decode(
 # program (it names its layer in an operand): the decode program's
 # build is what ``setup_s`` pays.
 @functools.partial(
-    jax.jit, static_argnames=("scale", "interpret", "pages")
+    jax.jit, static_argnames=("scale", "interpret", "pages", "round_pages")
 )
 def _walk(q, ks, vs, layer, tables, pos, active, mask, *, scale,
-          interpret, pages):
+          interpret, pages, round_pages=None):
     slots, kv_heads, groups, head_dim = q.shape
     block_size = ks.shape[3]
     width = tables.shape[1]
@@ -194,10 +251,14 @@ def _walk(q, ks, vs, layer, tables, pos, active, mask, *, scale,
     anywhere = pl.BlockSpec(memory_space=pl.ANY)
     block = (pages, kv_heads, block_size, head_dim)   # one buffer
     buffers = 4 * pages * kv_heads * block_size * head_dim * itemsize
+    stats = []
+    if kv_heads > HEAD_ROUND and kv_heads % HEAD_ROUND == 0:
+        stats = [pltpu.VMEM((kv_heads, groups, n), jnp.float32)
+                 for n in (1, 1, head_dim)]
     return pl.pallas_call(
         functools.partial(
             _kernel, block_size=block_size, width=width, pages=pages,
-            scale=scale, masked=masked,
+            scale=scale, masked=masked, round_pages=round_pages,
         ),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=4,
@@ -212,6 +273,7 @@ def _walk(q, ks, vs, layer, tables, pos, active, mask, *, scale,
                 pltpu.VMEM((2, *block), ks.dtype),
                 pltpu.VMEM((2, *block), vs.dtype),
                 pltpu.SemaphoreType.DMA((2, 2)),
+                *stats,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),

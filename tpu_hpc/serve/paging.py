@@ -44,7 +44,10 @@ pool by layer and block table (``ks[layer, table]``; never
 ``ks[layer][table]``, whose per-layer slice the TPU compiler
 materialises before it gathers), the XLA-level reference formulation,
 correct on every backend and token-exact against the no-cache forward (the
-tests/test_serve.py oracle applies verbatim) -- or ``"pallas"`` -- the
+tests/test_serve.py oracle applies verbatim), which a chunk keeps and a
+one-row decode step over a pool in the compute dtype replaces with a
+kernel that walks each slot's table (``PagedAttention``) -- or
+``"pallas"`` -- the
 kernels/paged_attention.py kernels dropped into the SAME program
 slots: block table walked in-kernel as a scalar-prefetch operand, one
 HBM read per page, no gathered intermediate, run under ``shard_map``
@@ -113,11 +116,11 @@ DECODE_COUNTERS: Tuple[Tuple[str, str], ...] = (
      "Slot-steps computed past an end the host saw one step late "
      "(end of sequence) and dropped"),
     ("serve_decode_view_pages_read_total",
-     "KV pages the dispatched decode programs read a layer: the flat "
-     "rung's size, slots x pages a slot on the rectangle, or (a latent, "
-     "sparse-selection or state-space configuration, whose kernel "
-     "walks the tables) the live pages of the active slots, a shared "
-     "page once a slot"),
+     "KV pages the dispatched decode programs read a layer: the live "
+     "pages of the active slots where a kernel walks the tables (every "
+     "pool in the compute dtype read through 'gather'), a shared page "
+     "once a slot; else the flat rung's size, or slots x pages a slot "
+     "on the rectangle"),
     ("serve_decode_view_pages_total",
      "KV pages the rectangle would have gathered a layer (slots x "
      "pages a slot, a decode step); read over this is how much of "
@@ -136,14 +139,13 @@ LADDER_COUNTERS: Tuple[Tuple[str, str], ...] = (
      "the rectangle (every slot's whole capacity)"),
 )
 
-# The flat decode rungs, as shares of slots x pages a slot: a step
-# whose live pages fit one reads that many pages and no more; above the
-# last the rectangle reads every slot's whole capacity (at half of it
-# the two cost the same a page on the v5e). Two, because each is a
-# program to trace and an executable to load at start-up, 0.65 s on the
-# v5e's host, and a lower one (1/8 made a step at a tenth of the
-# capacity twice as fast again) waits for cheaper tracing (PERF.md,
-# PR 30).
+# The flat decode rungs of a dense engine over an int8 pool (the one
+# read that still gathers: a pool in the compute dtype is walked), as
+# shares of slots x pages a slot: a step whose live pages fit one reads
+# that many pages and no more; above the last the rectangle reads every
+# slot's whole capacity (at half of it the two cost the same a page on
+# the v5e). Two, because each is a program to trace and an executable
+# to load at start-up, 0.65 s on the v5e's host (PERF.md).
 FLAT_RUNGS = (3 / 8, 1 / 2)
 
 # What a decode step of a sparse-expert configuration counts: (the
@@ -493,6 +495,35 @@ def _on_mesh(kernel, mesh: Mesh, kv_heads: int, heads_dim: int, **static):
         )(q, k_pages, v_pages, scalars, scales)
 
     return call
+
+
+def _walk_on_mesh(walk, mesh: Optional[Mesh], kv_heads: int):
+    """A row step's table-walking read (``sparse_paged_decode`` with
+    its static arguments bound) where the program runs: as
+    ``sparse_moe._on_mesh`` has it, or, where the pool's KV heads are
+    split over ``model`` (:func:`paged_kv_cache_pspec`: a dense
+    configuration on a tensor-parallel mesh), under ``shard_map`` a
+    KV-head shard at a time: each chip walks its own heads' pages with
+    its share of the queries, tables, positions and the rest whole on
+    every chip. Interpreted when the mesh is not made of TPU
+    devices."""
+    model = None if mesh is None else paged_kv_cache_pspec(mesh, kv_heads)[2]
+    if model is None:
+        return sparse_moe._on_mesh(walk, mesh)
+    call = functools.partial(
+        walk, interpret=mesh.devices.flat[0].platform != "tpu"
+    )
+    heads = P(None, model, None, None)
+    pool = P(None, None, model, None, None)
+
+    def walk_shards(q, ks, vs, *rest):
+        return jax.shard_map(
+            lambda q, ks, vs, rest: call(q, ks, vs, *rest), mesh=mesh,
+            in_specs=(heads, pool, pool, P()), out_specs=heads,
+            check_vma=False,
+        )(q, ks, vs, rest)
+
+    return walk_shards
 
 
 # ---------------------------------------------------------------------
@@ -1174,16 +1205,23 @@ class PagedAttention:
       under the mask, page-major for row steps
       (:func:`_grouped_attention_paged`) and token-major for a chunk;
       so a chunk attends to every previously prefilled chunk and to the
-      shared prefix pages it never computed. A dense one-row step
-      whose caller knows how many pages are live gathers only those,
-      as one flat list over all slots (:meth:`live_pages`,
-      :func:`_grouped_attention_flat`), in place of every slot's whole
-      capacity. A ROW step of a configuration with state-space layers
-      (``models/hybrid_ssm_moe.py``) reads through the selected read's
-      kernel with no selection: the walk over every column ``<= pos``
-      at the configuration's score scale, under ``kv_read``, and the
-      rounding of its result under ``attention``; its chunk gathers its
-      one view as above. ``kernel="pallas"``: the
+      shared prefix pages it never computed. A ROW step handed ``pos,
+      active`` (:meth:`view`) over a pool in the compute dtype -- a
+      dense configuration's, and one with state-space layers
+      (``models/hybrid_ssm_moe.py``) -- reads through the selected
+      read's kernel with no selection (:meth:`walks` is the rule): the
+      walk over every column ``<= pos`` at the configuration's score
+      scale, each live page of K and V once, under ``kv_read``, and the
+      rounding of its result under ``attention``; pages of 32 KiB and
+      up have their block's copies issued in rounds of a few pages (the
+      kernel's page-bytes rule: ``sparse_paged_attention.ROUND_BYTES``).
+      On a mesh whose ``model`` axis splits the pool's KV heads the
+      walk runs a shard a chip (:func:`_walk_on_mesh`). A speculative
+      program's rows gather their view. A dense one-row step over an
+      int8 pool whose caller knows how many pages are live gathers only
+      those, dequantized, as one flat list over all slots
+      (:meth:`live_pages`, :func:`_grouped_attention_flat`), in place
+      of every slot's whole capacity. ``kernel="pallas"``: the
       table handed to kernels/paged_attention.py, which walks it
       in-kernel under ``shard_map`` over ``mesh`` (required for this
       kernel), all of it under ``kv_read``. Both read paths dequantize,
@@ -1236,6 +1274,7 @@ class PagedAttention:
         self.block_size, self.max_blocks = block_size, max_blocks
         self.chunk = chunk
         self.own = None                 # set by live_pages()
+        self.walk = None                # a row step's table-walking read
         self.quant = kv_quant == "int8"
         self.sparse = sparse_moe.is_sparse_moe(cfg)
         self.latent = latent_moe.is_latent_moe(cfg)
@@ -1247,13 +1286,13 @@ class PagedAttention:
             self.walk = sparse_moe._on_mesh(functools.partial(
                 latent_paged_decode, scale=cfg.qk_head_dim ** -0.5
             ), mesh)
-        elif self.walks(cfg) and not chunk:
+        elif self.walks(kernel, kv_quant) and not chunk:
             # A row step's read of K and V pages, under the selection or
             # over the columns ``<= pos`` (a chunk attends over its one
             # gathered view).
-            self.walk = sparse_moe._on_mesh(functools.partial(
+            self.walk = _walk_on_mesh(functools.partial(
                 sparse_paged_decode, scale=_score_scale(cfg)
-            ), mesh)
+            ), mesh, cfg.kv_heads)
         self.kernel = None
         if kernel == "pallas":
             self.kernel = _on_mesh(
@@ -1264,16 +1303,16 @@ class PagedAttention:
             )
 
     @staticmethod
-    def walks(cfg) -> bool:
-        """Whether a row step of ``cfg`` reads by walking its slots'
-        page tables in a kernel, and gathers no view: a latent, a
-        sparse-selection and a state-space configuration's does. The
-        engine counts such a read by the live pages and holds no flat
-        rung for it."""
-        return (
-            latent_moe.is_latent_moe(cfg) or sparse_moe.is_sparse_moe(cfg)
-            or hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
-        )
+    def walks(kernel: str, kv_quant: str) -> bool:
+        """Whether a row step reads by walking its slots' page tables in
+        a kernel, and gathers no view: every configuration's does under
+        ``kernel="gather"`` over a pool in the compute dtype (the only
+        read path a latent, a sparse-selection and a state-space
+        configuration have). An int8 pool's dense read gathers and
+        dequantizes; ``kernel="pallas"`` walks in
+        ``kernels/paged_attention.py``. The engine counts a walked read
+        by the live pages and holds no flat rung for it."""
+        return kernel == "gather" and kv_quant == "none"
 
     def on(self, ks, vs, ksc=None, vsc=None, xs=None):
         """This state over one call's arrays (a copy: the factory's
@@ -1388,8 +1427,10 @@ class PagedAttention:
             mask = self._select(layer, h, lp)
             if not self.chunk:
                 return self._read_walked(layer, q, mask)
-        elif self.hybrid and not self.chunk:
-            # No selection: the walk attends to the columns ``<= pos``.
+        elif self.walk is not None and self.where:
+            # A row step handed ``pos, active`` (a speculative program's
+            # rows are not: they gather their view), with no selection:
+            # the walk attends to the columns ``<= pos``.
             return self._read_walked(layer, q)
         return self._read(layer, q, mask)
 
@@ -1943,9 +1984,14 @@ def make_paged_decode_fn(
     selection ``[layers, slots, columns]``: the benchmark's check
     reads it, no serving path does.
 
-    ``flat_pages`` (a dense configuration through ``gather`` only):
-    the same program reading ``flat_pages`` pages in all, the live
-    pages of every slot end to end (:meth:`PagedAttention.live_pages`),
+    A dense configuration over a pool in the compute dtype reads K and
+    V through the same kernel with no mask, as one with state-space
+    layers does.
+
+    ``flat_pages`` (a dense configuration's int8 pool through
+    ``gather`` only): the same program reading ``flat_pages`` pages in
+    all, the live pages of every slot end to end
+    (:meth:`PagedAttention.live_pages`),
     where the plain one reads ``max_blocks`` pages of every slot
     whatever is live. Same arguments, same results; the caller
     promises that the step's live pages fit (``PagedEngine.decode``
@@ -1960,15 +2006,17 @@ def make_paged_decode_fn(
     recurrent = RecurrentState(cfg) \
         if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg) else None
     if flat_pages is not None and (
-        PagedAttention.walks(cfg) or attention.kernel is not None
+        PagedAttention.walks(kernel, kv_quant)
+        or attention.kernel is not None
     ):
         raise ValueError(
-            "flat_pages is the gather read of a dense configuration: an "
-            "indexer ranks the columns of each slot's own view, a latent "
-            "page is smaller than its owner's query, and a table-walking "
-            "kernel (kernel='pallas', and the row step of a latent, a "
-            "sparse-selection or a state-space configuration) reads no "
-            "view at all"
+            "flat_pages is the gather read of a dense configuration over "
+            "an int8 pool: an indexer ranks the columns of each slot's "
+            "own view, a latent page is smaller than its owner's query, "
+            "and a table-walking kernel (kernel='pallas', and the row "
+            "step of a dense, a sparse-selection, a latent or a "
+            "state-space configuration over a pool in the compute "
+            "dtype) reads no view at all"
         )
 
     def body(params, ks, vs, ksc, vsc, xs, rec, prev, step, tables):
@@ -2237,7 +2285,7 @@ class PagedEngine(Engine):
         self._recurrent = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
         # Whether the decode program's row step walks the tables in a
         # kernel, reading the live pages of the active slots and no view.
-        self._walks = PagedAttention.walks(cfg)
+        self._walks = PagedAttention.walks(paged.kernel, paged.kv_quant)
         if paged.kernel == "gather" and not self._walks:
             self._flat_rungs = tuple(sorted(
                 {int(self.view_pages * r) for r in FLAT_RUNGS} - {0}
@@ -2621,12 +2669,12 @@ class PagedEngine(Engine):
     def decode_rungs(self) -> Tuple[int, ...]:
         """The flat decode programs this engine holds below the
         rectangle, by the pages each reads (``FLAT_RUNGS`` of ``slots x
-        pages a slot``), smallest first. None where the read is not the
-        dense gather's: an indexer ranks each slot's own view, a latent
-        page is smaller than its owner's query (a flat rung ran slower
-        than the rectangle: PERF.md, PR 31), a table-walking kernel
-        reads no view, and a speculative engine's step is the verify
-        program."""
+        pages a slot``), smallest first: a dense engine over an int8
+        pool through ``gather`` only. None where the row step walks the
+        tables in a kernel and reads no view (every pool in the compute
+        dtype through ``gather``: :meth:`PagedAttention.walks`), under
+        ``kernel="pallas"``, and for a speculative engine, whose step
+        is the verify program."""
         return () if self.spec is not None else self._flat_rungs
 
     @property
@@ -3074,12 +3122,14 @@ class PagedEngine(Engine):
         the host's ``tokens``); :meth:`flush` takes them. With
         ``decode_lag`` 0 the step's own tokens come back at once.
 
-        The step runs the smallest program of the ladder that holds
-        its live pages (:attr:`decode_rungs`; the rectangle above the
-        last), counted here from ``positions`` and ``active`` as the
-        program counts them: what it reads follows the occupancy, and
-        ``serve_decode_view_pages_read_total`` over ``_total`` says how
-        far.
+        An engine whose row step walks the tables runs its one decode
+        program, which reads the live pages of the active slots; one
+        with a ladder runs the smallest program of it that holds them
+        (:attr:`decode_rungs`; the rectangle above the last). Either
+        way the live pages are counted here from ``positions`` and
+        ``active`` as the program counts them: what it reads follows
+        the occupancy, and ``serve_decode_view_pages_read_total`` over
+        ``_total`` says how far.
 
         ``active[s]`` False redirects slot ``s``'s write to the scratch
         page (free slots, and slots still mid-chunked-prefill, must not
