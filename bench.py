@@ -954,17 +954,12 @@ def bench_elastic(
 
 
 def _kv_metric_tag(summary: dict) -> str:
-    """Metric-family suffix for the paged read path
-    (tpu_hpc.kernels.paged_attention): '' for the default gather/fp
-    pool -- pre-existing banked histories continue untouched --
-    '_pallas', '_q8', or '_pallas_q8' otherwise, so each read-path
-    trajectory banks against its own high-water marks."""
-    tag = ""
-    if summary.get("kv_kernel", "gather") == "pallas":
-        tag += "_pallas"
-    if summary.get("kv_quant", "none") == "int8":
-        tag += "_q8"
-    return tag
+    """Metric-family suffix for the paged pool's storage
+    (tpu_hpc.kernels.paged_attention): '' for the default fp pool --
+    pre-existing banked histories continue untouched -- '_q8' for an
+    int8 one, so each trajectory banks against its own high-water
+    marks."""
+    return "_q8" if summary.get("kv_quant", "none") == "int8" else ""
 
 
 def serve_record(summary: dict, disagg: bool = False) -> dict:
@@ -990,7 +985,6 @@ def serve_record(summary: dict, disagg: bool = False) -> dict:
         rec_serve.update(
             kv_block_size=summary.get("kv_block_size"),
             kv_blocks=summary.get("kv_blocks"),
-            kv_kernel=summary.get("kv_kernel", "gather"),
             kv_quant=summary.get("kv_quant", "none"),
             prefix_hit_rate=round(
                 summary.get("prefix_hit_rate", 0.0), 4
@@ -1000,11 +994,11 @@ def serve_record(summary: dict, disagg: bool = False) -> dict:
                 "block_stalls", 0
             ),
         )
-        # Read path + storage dtype are part of the metric FAMILY
-        # (the kv_layout discipline): a pallas or int8 row banked
-        # under the gather/fp family would set high-water marks the
-        # next default row gets judged against. Default gather/none
-        # contributes no tag, so pre-ISSUE-20 histories continue.
+        # Storage dtype is part of the metric FAMILY (the kv_layout
+        # discipline): an int8 row banked under the fp family would
+        # set high-water marks the next default row gets judged
+        # against. The default contributes no tag, so older histories
+        # continue.
         kv_tag = _kv_metric_tag(summary)
     spec_mode = summary.get("spec_mode")
     acceptance = round(summary.get("acceptance_rate", 0.0), 4)
@@ -1064,7 +1058,7 @@ def serve_record(summary: dict, disagg: bool = False) -> dict:
 def _bench_paged_cfg(
     paged: bool, slots: int, max_seq: int, buckets,
     block_size=None, kv_blocks=None, prefill_chunk=None,
-    host_blocks=None, kernel=None, kv_quant=None,
+    host_blocks=None, kv_quant=None,
 ):
     """(PagedConfig | None, page-aligned max_seq) for the serve/
     loadgen rows. ONE derivation shared with server.py's CLI
@@ -1080,8 +1074,7 @@ def _bench_paged_cfg(
             slots, max_seq, buckets,
             block_size=block_size, num_blocks=kv_blocks,
             prefill_chunk=prefill_chunk, align_capacity=True,
-            host_blocks=host_blocks or 0,
-            kernel=kernel, kv_quant=kv_quant,
+            host_blocks=host_blocks or 0, kv_quant=kv_quant,
         )
     except ValueError as e:
         raise SystemExit(f"bench.py: {e}")
@@ -1106,7 +1099,7 @@ def bench_serve(
     prompt_lens=(96, 192, 384), buckets=(128, 256, 512),
     model_cfg=None, disagg: bool = False, paged: bool = False,
     block_size=None, kv_blocks=None, prefill_chunk=None,
-    host_blocks=None, kernel=None, kv_quant=None,
+    host_blocks=None, kv_quant=None,
     spec: str = "off", spec_k=None, draft_ckpt=None,
 ) -> dict:
     """Batched-inference throughput: the SAME ~170M bench architecture
@@ -1133,8 +1126,7 @@ def bench_serve(
     model_cfg = model_cfg or bench_model_cfg()
     paged_cfg, max_seq = _bench_paged_cfg(
         paged, slots, max(buckets) + max_new, buckets,
-        block_size, kv_blocks, prefill_chunk, host_blocks,
-        kernel, kv_quant,
+        block_size, kv_blocks, prefill_chunk, host_blocks, kv_quant,
     )
     spec_cfg = _bench_spec_cfg(spec, spec_k)
     serve_cfg = ServeConfig(
@@ -1152,7 +1144,6 @@ def bench_serve(
     print(
         f"serve{'-disagg' if disagg else ''}"
         f"{'-paged' if paged else ''}"
-        f"{f'-{kernel}' if kernel == 'pallas' else ''}"
         f"{f'-{kv_quant}' if kv_quant == 'int8' else ''}"
         f"{f'-spec:{spec}' if spec != 'off' else ''} | "
         f"{summary['mesh']} slots={slots} | "
@@ -1219,7 +1210,6 @@ def loadgen_record(summary: dict) -> dict:
         lg.update(
             kv_block_size=summary.get("kv_block_size"),
             kv_blocks=summary.get("kv_blocks"),
-            kv_kernel=summary.get("kv_kernel", "gather"),
             kv_quant=summary.get("kv_quant", "none"),
             prefix_hit_rate=round(
                 summary.get("prefix_hit_rate", 0.0), 4
@@ -1365,8 +1355,8 @@ def bench_loadgen(
     scenario: str = "multi_tenant", requests: int = 64,
     slots: int = 8, max_new: int = 32, seed: int = 0,
     paged: bool = False, block_size=None, kv_blocks=None,
-    prefill_chunk=None, host_blocks=None, kernel=None,
-    kv_quant=None, model: str = "bench",
+    prefill_chunk=None, host_blocks=None, kv_quant=None,
+    model: str = "bench",
     spec: str = "off", spec_k=None, draft_ckpt=None,
     fleet: int = 0, fleet_min: int = 1, fleet_swap_at=None,
     fleet_router: str = "affinity",
@@ -1407,8 +1397,7 @@ def bench_loadgen(
     buckets = (128, 256, 512)
     paged_cfg, max_seq = _bench_paged_cfg(
         paged, slots, max(buckets) + max_new, buckets,
-        block_size, kv_blocks, prefill_chunk, host_blocks,
-        kernel, kv_quant,
+        block_size, kv_blocks, prefill_chunk, host_blocks, kv_quant,
     )
     spec_cfg = _bench_spec_cfg(spec, spec_k)
     serve_cfg = ServeConfig(
@@ -1433,7 +1422,6 @@ def bench_loadgen(
     _attach_logit_rmse(rec, model_cfg, paged_cfg)
     print(
         f"loadgen {scenario}{' paged' if paged else ''}"
-        f"{f' {kernel}' if kernel == 'pallas' else ''}"
         f"{f' {kv_quant}' if kv_quant == 'int8' else ''}"
         f"{' tiered' if host_blocks else ''}"
         f"{f' fleet:{fleet}' if fleet else ''}"
@@ -1696,14 +1684,6 @@ def main(argv=None) -> int:
         "whole-prompt prefill)",
     )
     ap.add_argument(
-        "--serve-kernel", choices=("gather", "pallas"), default=None,
-        help="paged attention read path for --serve-paged "
-        "(tpu_hpc.kernels.paged_attention): 'gather' materializes "
-        "pages before a dense flash call (the oracle), 'pallas' "
-        "walks the block table in-kernel -- one HBM read per page; "
-        "pallas rows bank under their own _pallas metric family",
-    )
-    ap.add_argument(
         "--serve-kv-quant", choices=("none", "int8"), default=None,
         help="KV page storage for --serve-paged: 'int8' quantizes "
         "pages per page with fp32 scales -- half the bytes per "
@@ -1931,7 +1911,6 @@ def main(argv=None) -> int:
             ("--serve-kv-blocks", args.serve_kv_blocks),
             ("--serve-host-blocks", args.serve_host_blocks),
             ("--serve-prefill-chunk", args.serve_prefill_chunk),
-            ("--serve-kernel", args.serve_kernel),
             ("--serve-kv-quant", args.serve_kv_quant),
         ):
             if val is not None:
@@ -2206,7 +2185,6 @@ def main(argv=None) -> int:
             kv_blocks=args.serve_kv_blocks,
             prefill_chunk=args.serve_prefill_chunk,
             host_blocks=args.serve_host_blocks,
-            kernel=args.serve_kernel,
             kv_quant=args.serve_kv_quant,
             spec=args.serve_spec, spec_k=args.spec_k,
             draft_ckpt=args.serve_draft_ckpt,
@@ -2222,7 +2200,6 @@ def main(argv=None) -> int:
             kv_blocks=args.serve_kv_blocks,
             prefill_chunk=args.serve_prefill_chunk,
             host_blocks=args.serve_host_blocks,
-            kernel=args.serve_kernel,
             kv_quant=args.serve_kv_quant,
             model=args.serve_model,
             spec=args.serve_spec, spec_k=args.spec_k,

@@ -9,15 +9,12 @@ with only the depth cut -- dim 4096, 32 heads x 128, FFN 11008, vocab
   kernels  every Pallas kernel on the path, compiled by Mosaic (not
            interpreted) and checked against its XLA reference on the
            chip: flash forward + gradients vs ``attention_reference``,
-           both paged kernels vs the gather-then-dense oracle, bf16
-           and int8 pools;
+           the paged decode walk vs the gather-then-dense oracle over
+           a bf16 pool;
   serve    ``serve.server.run_replay`` through ``PagedEngine`` with
-           ``kernel="pallas"`` and chunked prefill: prompts of ~100 to
-           ~900 tokens, 32 new tokens, 4 slots; every request finishes
-           and nothing recompiles. The same replay through
-           ``kernel="gather"`` is reported next to it (random weights
-           make near-ties flip on rounding, so token agreement is
-           printed, not asserted);
+           chunked prefill (its decode steps walk the page tables):
+           prompts of ~100 to ~900 tokens, 32 new tokens, 4 slots;
+           every request finishes and nothing recompiles;
   train    the ``bench_llama`` assembly (``auto_mesh_axes`` mesh, Pallas
            flash attention forward and backward, ``Trainer.fit`` on
            ``TokenStream``): one compile step and a few timed ones,
@@ -56,13 +53,10 @@ SERVE_BUCKETS, SERVE_CHUNK, SERVE_SEQ = (128, 256), 256, 1024
 # bf16 carries 8 significand bits (2^-8 ~ 0.4% a rounding). The flash
 # kernel and its reference both round the probability tile to bf16
 # before the PV matmul and differ in summation order only; the paged
-# oracle keeps f32 probabilities, so its kernels sit one more bf16
-# rounding away. int8 pools are compared against the DEQUANTIZED pool
-# (same page bytes, same scales): the bound covers the kernel's bf16 q
-# against f32 page math, not the quantization error.
+# oracle keeps f32 probabilities, so the walk sits one more bf16
+# rounding away.
 TOL_FLASH_BF16 = 2e-2
 TOL_PAGED_BF16 = 2e-2
-TOL_PAGED_INT8 = 3e-2
 
 OUT_DIR = os.path.join(
     os.path.dirname(os.path.abspath(__file__)), "chiprun_out", "chip_smoke"
@@ -192,92 +186,61 @@ def check_flash() -> dict:
 
 
 def check_paged() -> dict:
-    """Both paged kernels vs the gather-then-dense oracle at the serve
-    phase's shapes (32 KV heads x 128, 4 slots, 1024-token views), on a
-    bf16 pool (16-row pages) and an int8 pool (32-row pages). Dead
-    table entries point at a NaN-poisoned page: a kernel that failed to
-    redirect them to scratch would poison its output."""
+    """The walk every serve cell's row step reads through
+    (``sparse_paged_decode`` with no mask) vs the gather-then-dense
+    oracle at the serve phase's shapes (32 KV heads x 128, 4 slots,
+    1024-token views, 16-row bf16 pages, the second layer of a
+    two-layer pool). Dead table entries point at a NaN-poisoned page:
+    a walk that read past a slot's live pages would poison its
+    output."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from tpu_hpc.kernels import paged_attention as pa
+    from tpu_hpc.kernels.sparse_paged_attention import sparse_paged_decode
 
-    hkv, g, d, slots, bucket = 32, 1, 128, SERVE_SLOTS, SERVE_CHUNK
-    errs = {}
-    for quant, bs, tol in (
-        (False, 16, TOL_PAGED_BF16), (True, 32, TOL_PAGED_INT8),
-    ):
-        rng = np.random.default_rng(7 + quant)
-        max_blocks = SERVE_SEQ // bs
-        nb = slots * max_blocks + 2
-        poison = nb - 1
-        k_pages, v_pages = (
-            jax.random.normal(key, (nb, hkv, bs, d), jnp.bfloat16)
-            .at[pa.SCRATCH_PAGE].set(0.0)
-            for key in jax.random.split(jax.random.key(2 + quant))
-        )
-        scales = {}
-        if quant:
-            k_pages, ksc = pa.quantize_pages_int8(k_pages)
-            v_pages, vsc = pa.quantize_pages_int8(v_pages)
-            scales = dict(k_scale=ksc, v_scale=vsc)
-        else:
-            k_pages = k_pages.at[poison].set(jnp.nan)
-            v_pages = v_pages.at[poison].set(jnp.nan)
-        kq, kp = jax.random.split(jax.random.key(4 + quant))
+    hkv, g, d, slots, bs = 32, 1, 128, SERVE_SLOTS, 16
+    rng = np.random.default_rng(7)
+    max_blocks = SERVE_SEQ // bs
+    nb = slots * max_blocks + 2
+    poison = nb - 1
+    ks, vs = (
+        jax.random.normal(key, (2, nb, hkv, bs, d), jnp.bfloat16)
+        .at[:, pa.SCRATCH_PAGE].set(0.0).at[:, poison].set(jnp.nan)
+        for key in jax.random.split(jax.random.key(2))
+    )
+    # Ragged positions, one inactive slot, disjoint tables.
+    pos = np.array([SERVE_SEQ - 1, 517, 99, 3], np.int32)[:slots]
+    active = np.array([1, 1, 1, 0], np.int32)[:slots]
+    tables = np.full((slots, max_blocks + 4), poison, np.int32)
+    for s in range(slots):
+        live = pos[s] // bs + 1 if active[s] else 0
+        tables[s, :live] = 1 + s * max_blocks + rng.permutation(
+            max_blocks
+        )[:live]
+    q = jax.random.normal(jax.random.key(4), (slots, hkv, g, d), jnp.bfloat16)
+    tables, pos, active = map(jnp.asarray, (tables, pos, active))
 
-        # decode: ragged positions, one inactive slot, disjoint tables
-        pos = np.array([SERVE_SEQ - 1, 517, 99, 3], np.int32)[:slots]
-        active = np.array([1, 1, 1, 0], np.int32)[:slots]
-        tables = np.full((slots, max_blocks + 4), poison, np.int32)
-        for s in range(slots):
-            live = pos[s] // bs + 1 if active[s] else 0
-            tables[s, :live] = 1 + s * max_blocks + rng.permutation(
-                max_blocks
-            )[:live]
-        q = jax.random.normal(kq, (slots, hkv, g, d), jnp.bfloat16)
-        args = (q, k_pages, v_pages, jnp.asarray(tables),
-                jnp.asarray(pos), jnp.asarray(active))
-
-        got = mosaic(
-            lambda *a: pa.paged_decode_attention(
-                *a, block_size=bs, max_blocks=max_blocks, **scales
-            ),
-            *args,
-        )
-        want = pa.paged_decode_reference(
-            *args, max_blocks=max_blocks, **scales
-        )
-        tag = "int8" if quant else "bf16"
-        errs[f"decode_{tag}"] = rel_err(got, want)
-        check(
-            not bool(jnp.any(got[active == 0])),
-            "inactive decode slot is not zeros",
-        )
-
-        # prefill: a continuation chunk that attends over earlier pages
-        start = 512
-        live = (start + bucket) // bs
-        table = np.full((max_blocks + 4,), poison, np.int32)
-        table[:live] = 1 + rng.permutation(max_blocks)[:live]
-        q = jax.random.normal(kp, (hkv, bucket, g, d), jnp.bfloat16)
-        args = (q, k_pages, v_pages, jnp.asarray(table),
-                jnp.asarray(start, jnp.int32))
-
-        got = mosaic(
-            lambda *a: pa.paged_prefill_attention(
-                *a, block_size=bs, max_blocks=max_blocks, **scales
-            ),
-            *args,
-        )
-        want = pa.paged_prefill_reference(
-            *args, max_blocks=max_blocks, **scales
-        )
-        errs[f"prefill_{tag}"] = rel_err(got, want)
-        for name in (f"decode_{tag}", f"prefill_{tag}"):
-            check(errs[name] < tol, f"paged {name} err {errs[name]}")
-    log(f"paged kernels vs gather-then-dense oracle | rel err {errs}")
+    got = mosaic(
+        lambda q, ks, vs, *a: sparse_paged_decode(
+            q, ks, vs, jnp.int32(1), *a, scale=d ** -0.5
+        ),
+        q, ks, vs, tables, pos, active,
+    )
+    want = pa.paged_decode_reference(
+        q, ks[1], vs[1], tables, pos, active, max_blocks=max_blocks
+    )
+    errs = {"decode_bf16": rel_err(got, want)}
+    check(
+        not bool(jnp.any(got[active == 0])),
+        "inactive decode slot is not zeros",
+    )
+    check(
+        errs["decode_bf16"] < TOL_PAGED_BF16,
+        f"paged walk err {errs['decode_bf16']}",
+    )
+    log(f"paged walk vs gather-then-dense oracle | rel err {errs}")
     return errs
 
 
@@ -291,75 +254,51 @@ def serve_phase(cfg) -> dict:
     from tpu_hpc.serve.paging import derive_paged_config
     from tpu_hpc.serve.server import run_replay
 
-    def replay(kernel):
-        paged, max_seq = derive_paged_config(
-            SERVE_SLOTS, SERVE_SEQ, SERVE_BUCKETS, block_size=16,
-            prefill_chunk=SERVE_CHUNK, kernel=kernel,
-        )
-        t0 = time.perf_counter()
-        summary = run_replay(
-            cfg,
-            ServeConfig(
-                slots=SERVE_SLOTS, max_seq_len=max_seq,
-                prefill_buckets=SERVE_BUCKETS,
-            ),
-            SERVE_REQUESTS, SERVE_PROMPT_LENS, SERVE_MAX_NEW,
-            paged=paged,
-        )
-        summary["wall_s_with_warmup"] = time.perf_counter() - t0
-        gc.collect()  # the engine's params and pool leave the chip
-        return summary
-
-    pallas = replay("pallas")
-    outputs = pallas["outputs"]
-    check(pallas["kv_kernel"] == "pallas", "engine did not run pallas")
+    paged, max_seq = derive_paged_config(
+        SERVE_SLOTS, SERVE_SEQ, SERVE_BUCKETS, block_size=16,
+        prefill_chunk=SERVE_CHUNK,
+    )
+    t0 = time.perf_counter()
+    summary = run_replay(
+        cfg,
+        ServeConfig(
+            slots=SERVE_SLOTS, max_seq_len=max_seq,
+            prefill_buckets=SERVE_BUCKETS,
+        ),
+        SERVE_REQUESTS, SERVE_PROMPT_LENS, SERVE_MAX_NEW,
+        paged=paged,
+    )
+    wall = time.perf_counter() - t0
+    gc.collect()  # the engine's params and pool leave the chip
+    outputs = summary["outputs"]
     check(
-        pallas["requests"] == SERVE_REQUESTS
+        summary["requests"] == SERVE_REQUESTS
         and len(outputs) == SERVE_REQUESTS
         and all(len(t) == SERVE_MAX_NEW for t in outputs.values()),
-        f"not every request finished: {pallas['requests']} of "
+        f"not every request finished: {summary['requests']} of "
         f"{SERVE_REQUESTS}",
     )
     check(
-        pallas["recompiles"] == 0,
-        f"{pallas['recompiles']} serving recompile(s)",
+        summary["recompiles"] == 0,
+        f"{summary['recompiles']} serving recompile(s)",
     )
     log(
-        f"serve pallas | mesh {pallas['mesh']} | "
-        f"{pallas['compiled_programs']} programs, 0 recompiles | "
-        f"{pallas['tokens']} tokens, {pallas['prefill_chunks']} prefill "
-        f"chunks | TTFT p50 {pallas['ttft_ms_p50']:.0f} ms, ITL p50 "
-        f"{pallas['itl_ms_p50']:.1f} ms (information)"
+        f"serve | mesh {summary['mesh']} | "
+        f"{summary['compiled_programs']} programs, 0 recompiles | "
+        f"{summary['tokens']} tokens, {summary['prefill_chunks']} "
+        f"prefill chunks | TTFT p50 {summary['ttft_ms_p50']:.0f} ms, "
+        f"ITL p50 {summary['itl_ms_p50']:.1f} ms (information)"
     )
     mem = memory("serve")
-
-    gather = replay("gather")
-    same = [
-        next(
-            (i for i, (a, b) in enumerate(zip(t, gather["outputs"][r]))
-             if a != b),
-            len(t),
-        )
-        for r, t in sorted(outputs.items())
-    ]
-    agree = sum(same) / (SERVE_REQUESTS * SERVE_MAX_NEW)
-    log(
-        f"serve gather | tokens equal to pallas up to the first "
-        f"difference, per request: {same} of {SERVE_MAX_NEW} "
-        f"({agree:.0%}); ITL p50 {gather['itl_ms_p50']:.1f} ms "
-        "(information)"
-    )
     return {
-        "mesh": pallas["mesh"],
-        "requests": pallas["requests"],
-        "tokens": pallas["tokens"],
-        "compiled_programs": pallas["compiled_programs"],
-        "recompiles": pallas["recompiles"],
-        "ttft_ms_p50": round(pallas["ttft_ms_p50"], 1),
-        "itl_ms_p50": round(pallas["itl_ms_p50"], 2),
-        "itl_ms_p50_gather": round(gather["itl_ms_p50"], 2),
-        "wall_s_with_warmup": round(pallas["wall_s_with_warmup"], 1),
-        "gather_token_agreement": round(agree, 3),
+        "mesh": summary["mesh"],
+        "requests": summary["requests"],
+        "tokens": summary["tokens"],
+        "compiled_programs": summary["compiled_programs"],
+        "recompiles": summary["recompiles"],
+        "ttft_ms_p50": round(summary["ttft_ms_p50"], 1),
+        "itl_ms_p50": round(summary["itl_ms_p50"], 2),
+        "wall_s_with_warmup": round(wall, 1),
         "memory": mem,
     }
 

@@ -80,16 +80,6 @@ SLOW_NODEIDS = frozenset(nodeid for nodeid, _ in [
     ("tests/test_pp.py::TestStashBackward::test_grads_match_oracle", "12s"),
     ("tests/test_pp.py::TestStashBackward::test_ppxdp_grads_match_oracle", "13s"),
     ("tests/test_pp.py::TestStashBackward::test_stash_ring_wraparound", "9s"),
-    # Pallas paged-attention sweep (tests/test_paged_kernels.py):
-    # tier-1 keeps the (block_size=4, float32) representative per
-    # kernel family; the rest of the block-size x dtype grid rides
-    # the slow tier under the ``kernels`` marker.
-    ("tests/test_paged_kernels.py::TestKernelSweep::test_decode_grid[4-bfloat16]", "1s"),
-    ("tests/test_paged_kernels.py::TestKernelSweep::test_decode_grid[8-float32]", "1s"),
-    ("tests/test_paged_kernels.py::TestKernelSweep::test_decode_grid[8-bfloat16]", "1s"),
-    ("tests/test_paged_kernels.py::TestKernelSweep::test_prefill_grid[4-bfloat16]", "1s"),
-    ("tests/test_paged_kernels.py::TestKernelSweep::test_prefill_grid[8-float32]", "1s"),
-    ("tests/test_paged_kernels.py::TestKernelSweep::test_prefill_grid[8-bfloat16]", "1s"),
     ("tests/test_overlap.py::TestTrainerCommMode::test_bucketed_with_grad_accum_matches_flat", "10s"),
     ("tests/test_overlap.py::TestTrainerCommMode::test_flat_mode_no_collective_creep", "14s"),
     ("tests/test_pp.py::test_grads_match_oracle[1f1b]", "10s"),

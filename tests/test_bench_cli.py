@@ -270,14 +270,13 @@ def test_serve_mode_routes_flags(bench, monkeypatch):
                          paged=False, block_size=None, kv_blocks=None,
                          prefill_chunk=None, spec="off", spec_k=None,
                          draft_ckpt=None, host_blocks=None,
-                         kernel=None, kv_quant=None):
+                         kv_quant=None):
         seen.update(requests=requests, slots=slots, max_new=max_new,
                     disagg=disagg, paged=paged,
                     block_size=block_size, kv_blocks=kv_blocks,
                     prefill_chunk=prefill_chunk, spec=spec,
                     spec_k=spec_k, draft_ckpt=draft_ckpt,
-                    host_blocks=host_blocks,
-                    kernel=kernel, kv_quant=kv_quant)
+                    host_blocks=host_blocks, kv_quant=kv_quant)
         return {"metric": "serve_tokens_per_s_per_chip", "value": 1,
                 "unit": "tokens/s/chip", "vs_baseline": None}
 
@@ -292,8 +291,7 @@ def test_serve_mode_routes_flags(bench, monkeypatch):
                     "block_size": None, "kv_blocks": None,
                     "prefill_chunk": None, "spec": "off",
                     "spec_k": None, "draft_ckpt": None,
-                    "host_blocks": None,
-                    "kernel": None, "kv_quant": None}
+                    "host_blocks": None, "kv_quant": None}
     seen.clear()
     assert bench.main(["--workload", "serve"]) == 0
     assert seen["requests"] == 32 and seen["slots"] == 8
@@ -312,9 +310,9 @@ def test_serve_mode_routes_flags(bench, monkeypatch):
     seen.clear()
     assert bench.main([
         "--workload", "serve", "--serve-paged",
-        "--serve-kernel", "pallas", "--serve-kv-quant", "int8",
+        "--serve-kv-quant", "int8",
     ]) == 0
-    assert seen["kernel"] == "pallas" and seen["kv_quant"] == "int8"
+    assert seen["kv_quant"] == "int8"
     seen.clear()
     assert bench.main([
         "--workload", "serve", "--serve-paged",
@@ -347,7 +345,7 @@ def test_loadgen_mode_routes_flags(bench, monkeypatch):
                            draft_ckpt=None, fleet=0, fleet_min=1,
                            fleet_swap_at=None,
                            fleet_router="affinity", host_blocks=None,
-                           kernel=None, kv_quant=None):
+                           kv_quant=None):
         seen.update(scenario=scenario, requests=requests, slots=slots,
                     max_new=max_new, paged=paged, spec=spec,
                     host_blocks=host_blocks)

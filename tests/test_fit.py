@@ -227,18 +227,19 @@ def v5e_2x2():
 def test_every_pallas_kernel_lowers_for_v5e(v5e_2x2):
     """Mosaic (libtpu's real compiler, no chip) must accept every
     Pallas kernel on the train and serve paths at the 7B head shape:
-    flash forward + dq + dkv, both paged kernels on bf16 and int8
-    pools, and the whole paged decode program on the four-chip serving
-    mesh (KV heads over ``model``, kernels under shard_map). PR 20's
-    paged kernels only ever ran interpreted and were refused outright
-    by this lowering; this is the test that would have said so."""
+    flash forward + dq + dkv, the row step's page walk on bf16 pages
+    at 32 KV heads, the grouped expert product, and the whole paged
+    decode program on the four-chip serving mesh (KV heads over
+    ``model``, the walk a KV-head shard a chip). A kernel that only
+    ever ran interpreted can be refused outright by this lowering;
+    this is the test that says so."""
     import dataclasses
 
     import jax.numpy as jnp
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-    from tpu_hpc.kernels import paged_attention as pa
     from tpu_hpc.kernels.attention import blockwise_attention
+    from tpu_hpc.kernels.sparse_paged_attention import sparse_paged_decode
     from tpu_hpc.runtime import MeshSpec, build_mesh
     from tpu_hpc.serve import paging
     from tpu_hpc.serve.weights import serving_pspecs
@@ -268,40 +269,24 @@ def test_every_pallas_kernel_lowers_for_v5e(v5e_2x2):
 
     assert mosaic_calls(flash_grads, qkv, qkv, qkv, qkv) == 3
 
-    # Paged kernels at 32 KV heads x 128.
+    # The row step's walk over bf16 pages at deepseek's 32 KV heads x
+    # 128, no mask (the columns <= pos).
     slots, hkv, d, bucket, cap = 4, 32, 128, 256, 1024
     i32 = jnp.int32
-    for dtype, bs in ((jnp.bfloat16, 16), (jnp.int8, 32)):
-        mb, nb = cap // bs, 64
-        pool = sds((nb, hkv, bs, d), dtype)
-        scales = (
-            dict(k_scale=sds((nb,), jnp.float32),
-                 v_scale=sds((nb,), jnp.float32))
-            if dtype == jnp.int8 else {}
+    bs = 16
+    mb = cap // bs
+    pool = sds((2, 64, hkv, bs, d), jnp.bfloat16)
+
+    def walk(q, ks, vs, layer, tables, pos, active):
+        return sparse_paged_decode(
+            q, ks, vs, layer, tables, pos, active, scale=d ** -0.5
         )
 
-        def decode(q, k, v, tables, pos, active, scales):
-            return pa.paged_decode_attention(
-                q, k, v, tables, pos, active, block_size=bs,
-                max_blocks=mb, **scales,
-            )
-
-        assert mosaic_calls(
-            decode, sds((slots, hkv, 1, d), jnp.bfloat16), pool, pool,
-            sds((slots, mb + 4), i32), sds((slots,), i32),
-            sds((slots,), i32), scales,
-        ) == 1
-
-        def prefill(q, k, v, table, start, scales):
-            return pa.paged_prefill_attention(
-                q, k, v, table, start, block_size=bs, max_blocks=mb,
-                **scales,
-            )
-
-        assert mosaic_calls(
-            prefill, sds((hkv, bucket, 1, d), jnp.bfloat16), pool, pool,
-            sds((mb + 4,), i32), sds((), i32), scales,
-        ) == 1
+    assert mosaic_calls(
+        walk, sds((slots, hkv, 1, d), jnp.bfloat16), pool, pool,
+        sds((), i32), sds((slots, mb + 4), i32), sds((slots,), i32),
+        sds((slots,), i32),
+    ) == 1
 
     # The grouped expert product at Keye's decode shape: 12 rows, a
     # list of 96 of 128 experts of 2048 x 768 (two whole experts in
@@ -333,7 +318,7 @@ def test_every_pallas_kernel_lowers_for_v5e(v5e_2x2):
         lambda a, spec: sds(a.shape, a.dtype, NamedSharding(mesh, spec)),
         params, serving_pspecs(params, mesh),
     )
-    bs, mb, nb = 16, cap // 16, 257
+    nb = 257
     width = mb + bucket // bs
     pool = sds(
         (cfg.n_layers, nb, cfg.kv_heads, bs, cfg.head_dim), cfg.dtype,
@@ -341,9 +326,7 @@ def test_every_pallas_kernel_lowers_for_v5e(v5e_2x2):
             mesh, paging.paged_kv_cache_pspec(mesh, cfg.kv_heads)
         ),
     )
-    program = paging.make_paged_decode_fn(
-        cfg, bs, mb, width, kernel="pallas", mesh=mesh
-    )
+    program = paging.make_paged_decode_fn(cfg, bs, mb, width, mesh=mesh)
     vec = sds((slots,), i32, rep)
     assert mosaic_calls(
         program, params, pool, pool, vec,

@@ -513,13 +513,12 @@ def params():
     )
 
 
-def _paged(params, mesh, kernel="gather", kv_quant="none"):
+def _paged(params, mesh, kv_quant="none"):
     engine = PagedEngine(
         params, CFG,
         ServeConfig(slots=4, max_seq_len=64, prefill_buckets=(8, 16)),
         mesh, PagedConfig(block_size=4, num_blocks=4 * 16 + 1,
-                          prefill_chunk=8, kernel=kernel,
-                          kv_quant=kv_quant),
+                          prefill_chunk=8, kv_quant=kv_quant),
     )
     engine.warmup()
     return engine
@@ -582,7 +581,7 @@ def test_ladder_pair_counts_the_steps_no_rung_held(params, mesh, registry):
 
 
 def test_an_engine_without_a_ladder_holds_neither_name(params, mesh):
-    engine = _paged(params, mesh, kernel="pallas")
+    engine = _paged(params, mesh)     # a walking engine
     assert engine.decode_rungs == ()
     assert not set(LADDER) & set(engine.paged_stats)
 

@@ -38,7 +38,6 @@ from jax.sharding import Mesh
 
 from tpu_hpc.kernels.paged_attention import quantize_pages_int8
 from tpu_hpc.models import llama2, sparse_moe
-from tpu_hpc.runtime import MeshSpec, build_mesh
 from tpu_hpc.serve import PagedConfig, PagedEngine, ServeConfig, paging
 from tpu_hpc.serve.spec import SpecConfig, attach_spec
 
@@ -380,10 +379,10 @@ def test_a_walking_engine_builds_one_decode_program(arch, mesh):
     assert grown[PAGES_TOTAL] == VIEW * (len(climb) + 1)
 
 
-def test_engines_that_keep_their_one_decode_program(devices, mesh):
-    """An indexer, a table-walking kernel, a dense engine whose row
-    step walks the tables and a speculative engine run the program
-    they had: no flat rung is built for them."""
+def test_engines_that_keep_their_one_decode_program(mesh):
+    """An indexer, a dense engine whose row step walks the tables and
+    a speculative engine run the program they had: no flat rung is
+    built for them."""
     sparse_cfg = sparse_moe.SparseMoEConfig(
         name="tiny-sparse", dim=64, n_layers=1, n_heads=4, n_kv_heads=2,
         head_dim=32, vocab_size=128, max_seq_len=CAPACITY, n_experts=4,
@@ -399,15 +398,10 @@ def test_engines_that_keep_their_one_decode_program(devices, mesh):
     )
     cfg = CONFIGS["mha"]
     params = llama2.init_llama(jax.random.key(0), cfg)
-    pallas = PagedEngine(
-        params, cfg, SERVE,
-        build_mesh(MeshSpec(axes={"data": 4, "model": 2})),
-        dataclasses.replace(paged, kernel="pallas"),
-    )
     walking = PagedEngine(params, cfg, SERVE, mesh, paged)
     spec = PagedEngine(params, cfg, SERVE, mesh, paged)
     attach_spec(spec, SpecConfig(mode="ngram", k=2))
-    for engine in (sparse, pallas, walking):
+    for engine in (sparse, walking):
         assert engine.decode_rungs == ()
         engine.warmup()
         assert _decode_keys(engine) == [("decode",)]

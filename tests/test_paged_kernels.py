@@ -1,31 +1,24 @@
-"""Pallas paged-attention kernels (kernels/paged_attention.py): direct
-kernel parity, int8 page quantization, and the engine-level contract.
+"""Paged reads and the page format (kernels/paged_attention.py): the
+walk's and the gathered read's parity, int8 page quantization, and the
+engine-level contract.
 
-Five invariant families:
-  * **direct kernel parity** -- property-style: random block tables
-    (ragged lengths, pages recycled across slots, inactive slots, dead
-    table entries pointing at a NaN-poisoned page) through
-    ``paged_decode_attention`` / ``paged_prefill_attention`` in
-    interpret mode match a dense gather-softmax reference. The poison
-    page proves the scalar-prefetch index map redirects every dead
-    read to the scratch page -- if the kernel touched it, NaN leaks.
+Four invariant families:
+  * **read parity** -- property-style: random block tables (ragged
+    lengths, pages recycled across slots, inactive slots, dead table
+    entries pointing at a NaN-poisoned page) through the row step's
+    walk (``sparse_paged_decode`` with no mask, interpreted) match a
+    dense gather-softmax reference; the poison page proves the walk
+    reads no page past a slot's position. An int8 row step's and a
+    chunk's gathered read (``PagedAttention``) match the same
+    reference over the dequantized pool.
   * **int8 quantization** -- per-page quantize/dequantize round trip
-    bounded by half a scale step, the all-zero-page scale floor, and
-    kernel-side in-register dequant matching the dequantized-pool
-    reference exactly (same math, different read path).
+    bounded by half a scale step, the all-zero-page scale floor.
   * **bounded divergence** -- the deterministic ``int8_logit_rmse``
     probe at TINY's attention dims stays under the pinned tolerance,
-    and greedy decode through an int8 pool is token-exact across
-    kernels (pallas vs gather on the SAME quantized pool -- the kernel
-    contract) and vs the fp oracle at this scale.
-  * **engine token exactness + compile discipline** -- a churn mix
-    (more requests than slots, a fully-cached prompt, a shared-prefix
-    CoW divergence, a chunk-stride crosser) through kernel="pallas"
-    matches kernel="gather" token for token and the no-cache oracle,
-    with ZERO new executables after warmup.
-  * **sweep** (``-m kernels``, slow) -- the block-size x dtype grid;
-    tier-1 keeps the (block_size=4, float32) representative per
-    kernel family above.
+    and greedy decode through an int8 pool stays token-exact vs the fp
+    oracle at this scale.
+  * **engine contract** -- one writer per page, the summary, the
+    configuration's refusals, zero recompiles under churn.
 """
 import jax
 import jax.numpy as jnp
@@ -38,11 +31,10 @@ from tpu_hpc.kernels.paged_attention import (
     SCRATCH_PAGE,
     dequantize_pages_int8,
     int8_logit_rmse,
-    paged_decode_attention,
-    paged_prefill_attention,
     quantize_pages_int8,
     write_tokens,
 )
+from tpu_hpc.kernels.sparse_paged_attention import sparse_paged_decode
 from tpu_hpc.models import llama2
 from tpu_hpc.runtime import MeshSpec, build_mesh
 from tpu_hpc.serve import (
@@ -54,7 +46,7 @@ from tpu_hpc.serve import (
     SpecConfig,
     attach_spec,
 )
-from tpu_hpc.serve.paging import SCRATCH_BLOCK
+from tpu_hpc.serve.paging import SCRATCH_BLOCK, PagedAttention
 
 
 TINY = llama2.LlamaConfig(
@@ -62,6 +54,8 @@ TINY = llama2.LlamaConfig(
     multiple_of=16, max_seq_len=64, dtype=jnp.float32,
 )
 SERVE = ServeConfig(slots=4, max_seq_len=48, prefill_buckets=(8, 16))
+# Page size and pages a view of the direct read checks.
+BS, MB = 4, 6
 
 # Bounded-divergence pin: int8_logit_rmse at TINY's attention dims
 # (head_dim=16, kv_heads=2, n_heads=4, block_size=4) measures ~0.007;
@@ -92,27 +86,13 @@ def _engine(tiny_params, serve_mesh, **kw):
 
 
 @pytest.fixture(scope="module")
-def gather_engine(tiny_params, serve_mesh):
+def engine(tiny_params, serve_mesh):
     return _engine(tiny_params, serve_mesh)
 
 
 @pytest.fixture(scope="module")
-def pallas_engine(tiny_params, serve_mesh):
-    return _engine(tiny_params, serve_mesh, kernel="pallas")
-
-
-@pytest.fixture(scope="module")
-def pallas_q8_engine(tiny_params, serve_mesh):
-    return _engine(
-        tiny_params, serve_mesh, kernel="pallas", kv_quant="int8"
-    )
-
-
-@pytest.fixture(scope="module")
-def gather_q8_engine(tiny_params, serve_mesh):
-    return _engine(
-        tiny_params, serve_mesh, kernel="gather", kv_quant="int8"
-    )
+def q8_engine(tiny_params, serve_mesh):
+    return _engine(tiny_params, serve_mesh, kv_quant="int8")
 
 
 _ORACLE_LEN = 48
@@ -223,8 +203,8 @@ def _random_case(
 ):
     """Random pool + tables. Page 0 is scratch (zeros, the engine
     contract); the LAST page is NaN-poisoned and never allocated --
-    every dead table entry points at it, so a kernel that fails to
-    redirect dead reads to scratch poisons its output."""
+    every dead table entry points at it, so a walk that reads past a
+    slot's live pages poisons its output."""
     pool = rng.standard_normal(
         (num_blocks, hkv, block_size, d)
     ).astype(dtype)
@@ -259,61 +239,125 @@ def _random_case(
 
 def _fresh_table_row(rng, num_blocks, max_blocks, ctx_pages):
     """A prefill table row: ``ctx_pages`` live pages, every later
-    entry pointed at the poison page (the engine pads dead entries
-    with scratch; poison proves the index map never reads them)."""
-    poison_page = num_blocks - 1
+    entry pointed at the scratch page, as the engine pads it (a
+    gathered view reads every entry; the mask keeps them out)."""
     row = rng.choice(
-        np.arange(1, poison_page), size=max_blocks, replace=False
+        np.arange(1, num_blocks - 1), size=max_blocks, replace=False
     ).astype(np.int32)
-    row[ctx_pages:] = poison_page
+    row[ctx_pages:] = SCRATCH_PAGE
     return row
 
 
+def _gathered(q, kp, vp, table, where, *, chunk, scales=None):
+    """What ``PagedAttention`` reads for layer 0 where it gathers: a
+    chunk's view (``q [hkv, bucket, g, d]``, ``table [width]``,
+    ``where`` the chunk's start) or an int8 row step's (``q [slots,
+    hkv, g, d]``, ``table [slots, width]``, ``where`` the positions),
+    in the reference's layout."""
+    state = [jnp.asarray(kp)[None], jnp.asarray(vp)[None]]
+    if scales is not None:
+        state += [sc[None] for sc in scales]
+    pool = PagedAttention(
+        TINY, BS, MB, chunk=chunk,
+        kv_quant="none" if scales is None else "int8",
+    ).on(*state)
+    pool.view(jnp.asarray(table))
+    cols = np.arange(MB * BS)
+    if chunk:
+        hkv, bucket, g, d = q.shape
+        qpos = where + np.arange(bucket)
+        mask = (cols[None, :] <= qpos[:, None])[None, None, None]
+        rows = np.transpose(q, (1, 0, 2, 3)).reshape(1, bucket, -1, d)
+        out = pool._read(0, jnp.asarray(rows), jnp.asarray(mask))
+        return np.transpose(
+            np.asarray(out).reshape(bucket, hkv, g, d), (1, 0, 2, 3)
+        )
+    slots, hkv, g, d = q.shape
+    mask = (cols[None, :] <= where[:, None])[:, None, None, None, :]
+    out = pool._read(
+        0, jnp.asarray(q.reshape(slots, 1, -1, d)), jnp.asarray(mask)
+    )
+    return np.asarray(out).reshape(q.shape)
+
+
 # ---------------------------------------------------------------------
-# Direct kernel parity
+# Read parity
 # ---------------------------------------------------------------------
 
 
 class TestDecodeKernelParity:
     def test_random_tables_match_dense_reference(self):
+        """The row step's walk over every column ``<= pos``: pages
+        recycled across slots, an inactive slot, every dead entry on
+        the poison page."""
         rng = np.random.default_rng(0)
         for trial in range(4):
             q, kp, vp, tables, pos, active = _random_case(rng)
-            out = np.asarray(paged_decode_attention(
-                jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+            out = np.asarray(sparse_paged_decode(
+                jnp.asarray(q), jnp.asarray(kp)[None],
+                jnp.asarray(vp)[None], jnp.int32(0),
                 jnp.asarray(tables), jnp.asarray(pos),
-                jnp.asarray(active),
-                block_size=4, max_blocks=6, interpret=True,
+                jnp.asarray(active), scale=q.shape[-1] ** -0.5,
+                interpret=True,
             ))
-            ref = _ref_decode(q, kp, vp, tables, pos, active, 4)
+            ref = _ref_decode(q, kp, vp, tables, pos, active, BS)
             assert np.isfinite(out).all(), trial  # poison stayed out
             np.testing.assert_allclose(
                 out, ref, atol=2e-5, rtol=2e-5, err_msg=f"trial {trial}"
             )
             assert not out[active == 0].any()  # dead slots exact zeros
 
+    @pytest.mark.parametrize("block_size,dtype", [
+        (4, "bfloat16"), (8, "float32"), (8, "bfloat16"),
+    ])
+    def test_walk_grid(self, block_size, dtype):
+        """The walk beyond the (4, float32) case above: bf16 pools, what
+        every serve cell holds, against the reference over the SAME
+        bf16-rounded pages; the tolerance covers the products in the
+        queries' dtype."""
+        rng = np.random.default_rng(6)
+        tol = 2e-5 if dtype == "float32" else 6e-2
+        for trial in range(2):
+            q, kp, vp, tables, pos, active = _random_case(
+                rng, block_size=block_size
+            )
+            qj, kj, vj = (jnp.asarray(a).astype(dtype) for a in (q, kp, vp))
+            out = np.asarray(sparse_paged_decode(
+                qj, kj[None], vj[None], jnp.int32(0), jnp.asarray(tables),
+                jnp.asarray(pos), jnp.asarray(active),
+                scale=q.shape[-1] ** -0.5, interpret=True,
+            ))
+            ref = _ref_decode(
+                *(np.asarray(a, np.float32) for a in (qj, kj, vj)),
+                tables, pos, active, block_size,
+            )
+            assert np.isfinite(out).all(), (trial, dtype)
+            np.testing.assert_allclose(
+                out, ref, atol=tol, rtol=tol,
+                err_msg=f"trial {trial} bs={block_size} {dtype}",
+            )
+
     def test_int8_pool_matches_dequantized_reference(self):
-        """In-register dequant is the same math as reading a
-        dequantized pool: parity is tight, not merely bounded."""
+        """An int8 row step gathers its view and dequantizes it: the
+        same math as reading a dequantized pool, so parity is tight,
+        not merely bounded."""
         rng = np.random.default_rng(1)
         q, kp, vp, tables, pos, active = _random_case(rng, poison=False)
         kq, ksc = quantize_pages_int8(jnp.asarray(kp))
         vq, vsc = quantize_pages_int8(jnp.asarray(vp))
-        out = np.asarray(paged_decode_attention(
-            jnp.asarray(q), kq, vq,
-            jnp.asarray(tables), jnp.asarray(pos), jnp.asarray(active),
-            block_size=4, max_blocks=6,
-            k_scale=ksc, v_scale=vsc, interpret=True,
-        ))
+        out = _gathered(
+            q, kq, vq, tables, pos, chunk=False, scales=(ksc, vsc)
+        )
         ref = _ref_decode(
             q, np.asarray(dequantize_pages_int8(kq, ksc)),
             np.asarray(dequantize_pages_int8(vq, vsc)),
-            tables, pos, active, 4,
+            tables, pos, active, BS,
         )
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
+        on = active > 0
+        np.testing.assert_allclose(out[on], ref[on], atol=2e-5, rtol=2e-5)
         # and bounded divergence vs the UNQUANTIZED pool
-        exact = _ref_decode(q, kp, vp, tables, pos, active, 4)
-        assert np.max(np.abs(out - exact)) < 0.05
+        exact = _ref_decode(q, kp, vp, tables, pos, active, BS)
+        assert np.max(np.abs(out[on] - exact[on])) < 0.05
 
 
 class TestPrefillKernelParity:
@@ -323,76 +367,31 @@ class TestPrefillKernelParity:
         start=0 is the first chunk, 8/16 are continuation chunks whose
         q rows attend across earlier pages."""
         rng = np.random.default_rng(2)
-        hkv, bucket, g, d, bs, mb = 2, 8, 2, 16, 4, 6
+        hkv, bucket, g, d = 2, 8, 2, 16
         _, kp, vp, _, _, _ = _random_case(rng)
-        ctx_pages = -(-(start + bucket) // bs)
-        table = _fresh_table_row(rng, kp.shape[0], mb, ctx_pages)
+        ctx_pages = -(-(start + bucket) // BS)
+        table = _fresh_table_row(rng, kp.shape[0], MB, ctx_pages)
         q = rng.standard_normal((hkv, bucket, g, d)).astype(np.float32)
-        out = np.asarray(paged_prefill_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(table), jnp.asarray(start, jnp.int32),
-            block_size=bs, max_blocks=mb, interpret=True,
-        ))
-        ref = _ref_prefill(q, kp, vp, table, start, bs)
-        assert np.isfinite(out).all()
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-    def test_odd_bucket_falls_back_to_one_q_block(self):
-        """bucket % block_q != 0 collapses to a single q block rather
-        than padding games -- the engine's odd trailing chunk."""
-        rng = np.random.default_rng(3)
-        hkv, bucket, g, d, bs, mb = 2, 6, 2, 16, 4, 6
-        _, kp, vp, _, _, _ = _random_case(rng)
-        table = _fresh_table_row(rng, kp.shape[0], mb, -(-bucket // bs))
-        q = rng.standard_normal((hkv, bucket, g, d)).astype(np.float32)
-        out = np.asarray(paged_prefill_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(table), jnp.asarray(0, jnp.int32),
-            block_size=bs, max_blocks=mb, block_q=4, interpret=True,
-        ))
-        ref = _ref_prefill(q, kp, vp, table, 0, bs)
-        np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
-
-    def test_multi_q_block_accumulators_reinit_per_block(self):
-        """bucket=8 at block_q=4 runs two q blocks over the same kv
-        walk: the VMEM accumulators must re-init at j==0 of EACH q
-        block, and the causal mask must track the block offset."""
-        rng = np.random.default_rng(8)
-        hkv, bucket, g, d, bs, mb = 2, 8, 2, 16, 4, 6
-        _, kp, vp, _, _, _ = _random_case(rng)
-        table = _fresh_table_row(
-            rng, kp.shape[0], mb, -(-(8 + bucket) // bs)
-        )
-        q = rng.standard_normal((hkv, bucket, g, d)).astype(np.float32)
-        out = np.asarray(paged_prefill_attention(
-            jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
-            jnp.asarray(table), jnp.asarray(8, jnp.int32),
-            block_size=bs, max_blocks=mb, block_q=4, interpret=True,
-        ))
-        ref = _ref_prefill(q, kp, vp, table, 8, bs)
+        out = _gathered(q, kp, vp, table, start, chunk=True)
+        ref = _ref_prefill(q, kp, vp, table, start, BS)
         assert np.isfinite(out).all()
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
     def test_int8_chunk_matches_dequantized_reference(self):
         rng = np.random.default_rng(4)
-        hkv, bucket, g, d, bs, mb = 2, 8, 2, 16, 4, 6
+        hkv, bucket, g, d = 2, 8, 2, 16
         _, kp, vp, _, _, _ = _random_case(rng, poison=False)
         table = _fresh_table_row(
-            rng, kp.shape[0], mb, -(-(8 + bucket) // bs)
+            rng, kp.shape[0], MB, -(-(8 + bucket) // BS)
         )
         kq, ksc = quantize_pages_int8(jnp.asarray(kp))
         vq, vsc = quantize_pages_int8(jnp.asarray(vp))
         q = rng.standard_normal((hkv, bucket, g, d)).astype(np.float32)
-        out = np.asarray(paged_prefill_attention(
-            jnp.asarray(q), kq, vq,
-            jnp.asarray(table), jnp.asarray(8, jnp.int32),
-            block_size=bs, max_blocks=mb,
-            k_scale=ksc, v_scale=vsc, interpret=True,
-        ))
+        out = _gathered(q, kq, vq, table, 8, chunk=True, scales=(ksc, vsc))
         ref = _ref_prefill(
             q, np.asarray(dequantize_pages_int8(kq, ksc)),
             np.asarray(dequantize_pages_int8(vq, vsc)),
-            table, 8, bs,
+            table, 8, BS,
         )
         np.testing.assert_allclose(out, ref, atol=2e-5, rtol=2e-5)
 
@@ -542,9 +541,9 @@ class TestOneWriterPerPage:
 
     @pytest.mark.parametrize("seed", [0, 1, 2])
     def test_active_slots_write_distinct_private_pages(
-        self, gather_engine, monkeypatch, seed
+        self, engine, monkeypatch, seed
     ):
-        eng = gather_engine
+        eng = engine
         bs = eng.paged.block_size
         rng = np.random.default_rng(260 + seed)
         # Shared prefixes of whole pages and of part of a page, exact
@@ -625,167 +624,45 @@ class TestEngineParity:
     def test_pick_block_sizes_single_source(self):
         assert pick_block_sizes(512, 512, 40, 200) == (128, 256)
 
-    def test_pallas_token_exact_vs_gather_and_oracle(
-        self, gather_engine, pallas_engine, greedy_oracle
-    ):
-        """The churn mix (slot churn, fully-cached prompt, CoW
-        divergence, chunk crosser) decodes identically through both
-        read paths, and both match the no-cache oracle."""
-        _, want = _drain(gather_engine, _churn_mix())
-        _, got = _drain(pallas_engine, _churn_mix())
-        assert got == want
-        for r in _churn_mix():
-            assert got[r.rid] == greedy_oracle(
-                r.prompt, r.max_new_tokens
-            ), r.rid
-
-    def test_pallas_prefix_hits_and_zero_recompiles(
-        self, pallas_engine, greedy_oracle
-    ):
-        """Replaying the mix hits the prefix trie (pages written by
-        the previous drain, read back through the Pallas kernels) with
-        ZERO new executables: tables, positions and chunk starts are
-        all data."""
-        n0 = pallas_engine.compile_count
-        hits0 = pallas_engine.paged_stats["prefix_hits"]
-        for _ in range(2):
-            reqs = _churn_mix()
-            _, got = _drain(pallas_engine, reqs)
-            for r in reqs:
-                assert got[r.rid] == greedy_oracle(
-                    r.prompt, r.max_new_tokens
-                ), r.rid
-        assert pallas_engine.compile_count == n0
-        assert pallas_engine.paged_stats["prefix_hits"] > hits0
-
-    def test_summary_reports_kernel_and_quant(
-        self, pallas_q8_engine, gather_engine
-    ):
-        s = pallas_q8_engine.paged_summary()
-        assert s["kv_kernel"] == "pallas"
+    def test_summary_reports_kernel_and_quant(self, q8_engine, engine):
+        s = q8_engine.paged_summary()
         assert s["kv_quant"] == "int8"
-        s = gather_engine.paged_summary()
-        assert s["kv_kernel"] == "gather"
+        s = engine.paged_summary()
         assert s["kv_quant"] == "none"
+        assert not [key for key in s if "kernel" in key]
 
     def test_config_validation(self):
         with pytest.raises(ValueError, match="kernel"):
             PagedConfig(block_size=4, num_blocks=8, kernel="triton")
+        # The Pallas serving kernels went with the option that picked
+        # them: the one legal value is what every engine reads with.
+        with pytest.raises(ValueError, match="removed"):
+            PagedConfig(block_size=4, num_blocks=8, kernel="pallas")
         with pytest.raises(ValueError, match="kv_quant"):
             PagedConfig(block_size=4, num_blocks=8, kv_quant="fp4")
-        # The one page size Mosaic refuses (bf16/int8 pools); every
-        # size from 2 up lowers and matched the oracle on the chip.
-        with pytest.raises(ValueError, match="block_size >= 2"):
-            PagedConfig(block_size=1, num_blocks=8, kernel="pallas")
-        PagedConfig(block_size=1, num_blocks=8)  # gather takes any
-        PagedConfig(block_size=2, num_blocks=8, kernel="pallas")
+        PagedConfig(block_size=1, num_blocks=8)  # any page size
+        PagedConfig(block_size=2, num_blocks=8, kernel="gather")
 
 
 class TestEngineInt8:
-    def test_int8_token_exact_across_kernels(
-        self, gather_q8_engine, pallas_q8_engine
-    ):
-        """The kernel contract under quantization: pallas and gather
-        read the SAME int8 pool, so their streams are token-exact
-        even where quantization drifts from fp."""
-        _, want = _drain(gather_q8_engine, _churn_mix())
-        _, got = _drain(pallas_q8_engine, _churn_mix())
-        assert got == want
-
     def test_int8_bounded_divergence_vs_fp_oracle(
-        self, pallas_q8_engine, greedy_oracle
+        self, q8_engine, greedy_oracle
     ):
         """int8 vs fp is a BOUNDED-divergence contract (the probe pin
         above); at TINY's scale the drift flips no greedy argmax, so
         the streams happen to stay token-exact -- pinned as such."""
         reqs = _churn_mix()
-        _, got = _drain(pallas_q8_engine, reqs)
+        _, got = _drain(q8_engine, reqs)
         for r in reqs:
             assert got[r.rid] == greedy_oracle(
                 r.prompt, r.max_new_tokens
             ), r.rid
 
-    def test_int8_zero_recompiles_under_churn(self, pallas_q8_engine):
-        n0 = pallas_q8_engine.compile_count
-        _drain(pallas_q8_engine, _churn_mix())
-        assert pallas_q8_engine.compile_count == n0
+    def test_int8_zero_recompiles_under_churn(self, q8_engine):
+        n0 = q8_engine.compile_count
+        _drain(q8_engine, _churn_mix())
+        assert q8_engine.compile_count == n0
 
-    def test_spec_rejects_quantized_pool(self, pallas_q8_engine):
+    def test_spec_rejects_quantized_pool(self, q8_engine):
         with pytest.raises(ValueError, match="quantized KV pool"):
-            attach_spec(pallas_q8_engine, SpecConfig(mode="ngram"))
-
-
-# ---------------------------------------------------------------------
-# Sweep: block-size x dtype grid (-m kernels; slowlisted)
-# ---------------------------------------------------------------------
-
-_SWEEP = [(4, "bfloat16"), (8, "float32"), (8, "bfloat16")]
-
-
-@pytest.mark.kernels
-class TestKernelSweep:
-    """The grid beyond tier-1's (block_size=4, float32)
-    representative. bf16 pools compare against an fp32 reference over
-    the SAME bf16-rounded pages; tolerance covers the p-matrix
-    bf16 cast in the flash inner loop."""
-
-    @pytest.mark.parametrize("block_size,dtype", _SWEEP)
-    def test_decode_grid(self, block_size, dtype):
-        rng = np.random.default_rng(6)
-        tol = 2e-5 if dtype == "float32" else 6e-2
-        for trial in range(2):
-            q, kp, vp, tables, pos, active = _random_case(
-                rng, block_size=block_size,
-                dtype=np.float32,
-            )
-            qj = jnp.asarray(q).astype(dtype)
-            kj = jnp.asarray(kp).astype(dtype)
-            vj = jnp.asarray(vp).astype(dtype)
-            out = np.asarray(paged_decode_attention(
-                qj, kj, vj,
-                jnp.asarray(tables), jnp.asarray(pos),
-                jnp.asarray(active),
-                block_size=block_size, max_blocks=6, interpret=True,
-            )).astype(np.float32)
-            ref = _ref_decode(
-                np.asarray(qj, np.float32), np.asarray(kj, np.float32),
-                np.asarray(vj, np.float32), tables, pos, active,
-                block_size,
-            )
-            assert np.isfinite(out).all(), (trial, dtype)
-            np.testing.assert_allclose(
-                out, ref, atol=tol, rtol=tol,
-                err_msg=f"trial {trial} bs={block_size} {dtype}",
-            )
-
-    @pytest.mark.parametrize("block_size,dtype", _SWEEP)
-    def test_prefill_grid(self, block_size, dtype):
-        rng = np.random.default_rng(7)
-        tol = 2e-5 if dtype == "float32" else 6e-2
-        hkv, bucket, g, d = 2, 8, 2, 16
-        _, kp, vp, _, _, _ = _random_case(
-            rng, block_size=block_size
-        )
-        for start in (0, 8):
-            ctx_pages = -(-(start + bucket) // block_size)
-            table = _fresh_table_row(rng, kp.shape[0], 6, ctx_pages)
-            q = rng.standard_normal(
-                (hkv, bucket, g, d)
-            ).astype(np.float32)
-            qj = jnp.asarray(q).astype(dtype)
-            kj = jnp.asarray(kp).astype(dtype)
-            vj = jnp.asarray(vp).astype(dtype)
-            out = np.asarray(paged_prefill_attention(
-                qj, kj, vj,
-                jnp.asarray(table), jnp.asarray(start, jnp.int32),
-                block_size=block_size, max_blocks=6, interpret=True,
-            )).astype(np.float32)
-            ref = _ref_prefill(
-                np.asarray(qj, np.float32), np.asarray(kj, np.float32),
-                np.asarray(vj, np.float32), table, start, block_size,
-            )
-            assert np.isfinite(out).all(), (start, dtype)
-            np.testing.assert_allclose(
-                out, ref, atol=tol, rtol=tol,
-                err_msg=f"start {start} bs={block_size} {dtype}",
-            )
+            attach_spec(q8_engine, SpecConfig(mode="ngram"))

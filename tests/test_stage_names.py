@@ -17,8 +17,10 @@ from jax.sharding import PartitionSpec as P
 
 from tpu_hpc import obs
 from tpu_hpc.config import TrainingConfig
-from tpu_hpc.kernels import paged_attention as pa
 from tpu_hpc.kernels.attention import blockwise_attention
+from tpu_hpc.kernels.grouped_experts import grouped_expert_ffn
+from tpu_hpc.kernels.latent_paged_attention import latent_paged_decode
+from tpu_hpc.kernels.sparse_paged_attention import sparse_paged_decode
 from tpu_hpc.models import datasets, llama2
 from tpu_hpc.obs import schema as schema_mod
 from tpu_hpc.obs.events import EventBus, set_bus
@@ -198,38 +200,53 @@ def _flash_jaxpr():
     return str(jax.make_jaxpr(jax.grad(loss, argnums=(0, 1, 2)))(q, q, q))
 
 
-def _paged_jaxpr(kernel):
-    slots, hkv, d, mb = 2, 2, 16, 4
-    pool = jnp.zeros((8, hkv, BLOCK, d), jnp.float32)
-    if kernel == "paged_decode":
+def _serving_jaxpr(kernel):
+    """The jaxpr of one of the kernels every serve cell runs:
+    interpreted, so the name on its ``pallas_call`` is in the text."""
+    slots, heads, d, mb, layers = 2, 2, 16, 4, 2
+    tables = jnp.zeros((slots, mb + 2), jnp.int32)
+    pos, active = jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), jnp.int32)
+    if kernel == "sparse_paged_decode":
+        pool = jnp.zeros((layers, 8, heads, BLOCK, d), jnp.float32)
         return str(jax.make_jaxpr(
-            lambda q, k, v, t, p, a: pa.paged_decode_attention(
-                q, k, v, t, p, a, block_size=BLOCK, max_blocks=mb,
-                interpret=True,
+            lambda q, k, v, *a: sparse_paged_decode(
+                q, k, v, *a, scale=d ** -0.5, interpret=True
             )
         )(
-            jnp.zeros((slots, hkv, 1, d)), pool, pool,
-            jnp.zeros((slots, mb + 2), jnp.int32),
-            jnp.zeros((slots,), jnp.int32), jnp.ones((slots,), jnp.int32),
+            jnp.zeros((slots, heads, 1, d)), pool, pool, jnp.int32(0),
+            tables, pos, active,
         ))
+    if kernel == "latent_paged_decode":
+        rank, rope, pack = 16, 8, 2
+        return str(jax.make_jaxpr(
+            lambda *a: latent_paged_decode(
+                *a, scale=d ** -0.5, interpret=True
+            )
+        )(
+            jnp.zeros((slots, heads, rank)),
+            jnp.zeros((slots, pack, heads, pack * rope)),
+            jnp.zeros((layers, 8, BLOCK // pack, pack * rank)),
+            jnp.zeros((layers, 8, BLOCK // pack, pack * rope)),
+            jnp.int32(0), tables, pos, active,
+        ))
+    experts, dim, hidden = 4, 16, 32
+    w = jnp.zeros((experts, dim, hidden))
     return str(jax.make_jaxpr(
-        lambda q, k, v, t, s: pa.paged_prefill_attention(
-            q, k, v, t, s, block_size=BLOCK, max_blocks=mb,
-            interpret=True,
-        )
+        lambda *a: grouped_expert_ffn(*a, interpret=True)
     )(
-        jnp.zeros((hkv, 8, 1, d)), pool, pool,
-        jnp.zeros((mb + 2,), jnp.int32), jnp.int32(0),
+        jnp.zeros((slots, dim)), jnp.zeros((slots, experts)),
+        jnp.arange(experts), jnp.int32(experts), w, w,
+        jnp.zeros((experts, hidden, dim)),
     ))
 
 
 @pytest.mark.parametrize("kernel", [
-    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "paged_decode",
-    "paged_prefill",
+    "flash_fwd", "flash_bwd_dq", "flash_bwd_dkv", "sparse_paged_decode",
+    "latent_paged_decode", "grouped_experts",
 ])
 def test_pallas_call_carries_its_name(kernel):
     text = _flash_jaxpr() if kernel.startswith("flash") \
-        else _paged_jaxpr(kernel)
+        else _serving_jaxpr(kernel)
     assert f"name={kernel}" in text
 
 

@@ -15,7 +15,7 @@ Two interchangeable implementations:
     blocks, fp32 accumulators in VMEM scratch, bf16 matmuls on the MXU,
     causal blocks above the diagonal skipped. Gradients come from a
     custom_vjp whose backward runs the hand-written Pallas dq and
-    dk/dv kernels below (``_flash_dq_kernel`` / ``_flash_dkv_kernel``),
+    dk/dv kernels below (``_flash_dq_kernel`` / ``_flash_dk_dv_kernel``),
     rematerialising p = softmax(qk) from the saved LSE instead of
     storing the attention matrix.
 
@@ -60,8 +60,7 @@ def pick_block_sizes(
     requested block -- a 37-token prompt under block 512 would burn
     ~14x the VMEM and MXU work on masked rows -- but blocks stay
     128-aligned so TPU lane tiling holds. The ONE selection rule for
-    every kernel in this package (forward, backward, and the paged
-    decode/prefill kernels in paged_attention.py); hand-synced copies
+    the flash kernels here, forward and backward; hand-synced copies
     drifted once already."""
     return (
         min(block_q, _round_up(sq, 128)),
@@ -453,7 +452,7 @@ def _flash_dq_kernel(
         dq_ref[0] = acc_ref[:].astype(dq_ref.dtype)
 
 
-def _flash_dkv_kernel(
+def _flash_dk_dv_kernel(
     qo_ref, ko_ref, q_ref, k_ref, v_ref, do_ref, lse_ref, dm_ref,
     dk_ref, dv_ref, dk_acc, dv_acc, *, sm_scale, causal, block_q, block_k,
     kv_len,
@@ -612,7 +611,7 @@ def _flash_backward(
 
     dk, dv = pl.pallas_call(
         functools.partial(
-            _flash_dkv_kernel, sm_scale=sm_scale, causal=causal,
+            _flash_dk_dv_kernel, sm_scale=sm_scale, causal=causal,
             block_q=block_q, block_k=block_k, kv_len=sk,
         ),
         grid=(b * h, sk_p // block_k, sq_p // block_q),

@@ -1,87 +1,53 @@
-"""Pallas paged-attention kernels over the serving engine's KV pool.
+"""The serving engine's KV page format, its int8 form, and the
+gather-then-dense read every table-walking kernel is held to.
 
 The paged engine (serve/paging.py) stores KV cache as a pool of
-fixed-size pages addressed through per-slot block tables. Its original
-programs express the table read as a data-indexed XLA gather that
-materialises each slot's whole KV view in HBM before a single FLOP of
-attention runs -- correct, and kept as the oracle + CPU path, but it
-costs one full extra copy of the working set per decode tick. This
-module is the vLLM PagedAttention insight (arXiv 2309.06180) done
-natively: the block table rides into the kernel as a scalar-prefetch
-operand, the BlockSpec index map resolves the page id per grid step, and
-each page is streamed HBM->VMEM exactly once with no gathered
-intermediate.
+fixed-size pages addressed through per-slot block tables. This module
+is the one definition of what a page is and how it is quantized; the
+kernels that read pages live beside it (``sparse_paged_attention.py``
+and ``latent_paged_attention.py``, over ``page_walk.py``).
 
-Two kernels, sharing the flash online-softmax core of
-``kernels/attention.py`` (fp32 VMEM accumulators, MASK_VALUE masking,
-``pick_block_sizes`` block selection):
+Page layout (``tokens_to_pages`` / ``pages_to_tokens`` /
+``write_tokens`` below are its only spellings): a page is ``[kv_heads,
+block_size, head_dim]``, heads AHEAD of rows, so one layer's pool is
+``[num_blocks, kv_heads, block_size, head_dim]`` and the engine stacks
+the layers in one array ahead of that and names layer and pages in ONE
+index, ``pool[layer, page_ids]`` -- a ``pool[layer]`` on its own is a
+copy of a layer's pool wherever the compiler cannot fuse it away. A
+K or V page is then one contiguous copy for a kernel that walks the
+tables.
 
-  * ``paged_decode_attention`` -- one query token per slot. Grid
-    (slot, kv_head, page); inactive slots and tail pages redirect to
-    scratch page 0 in the index map, exactly as the gather path does,
-    so the pool is never indexed out of bounds and dead programs cost
-    one dummy page read.
-  * ``paged_prefill_attention`` -- a chunked-prefill flash kernel that
-    takes the block-table *view* directly: q-block x table-indexed
-    kv-page grid, global causal mask built from the chunk ``start``
-    carried as data (no per-bucket mask tensors).
+int8 pages: one f32 scale a page, in a small side array allocated with
+the pool (``quantize_pages_int8`` is the single write-side definition),
+half the pool's HBM. Their read gathers and dequantizes. int8 mode is
+gated by a bounded-divergence oracle whose tolerance is pinned from
+the deterministic ``int8_logit_rmse`` probe.
 
-**Why these two have only lost on the chip** (PERF.md, PR 27 / PR 30:
-187 ms a decode tick at ``serve-decode-deepseek7b``'s shape where the
-gather path takes 23). Both take ONE page a grid step: the page is a
-``BlockSpec`` block, so every page of every (slot, KV head) pays a grid
-step's fixed cost (~0.35 us) to bring in ``block_size x head_dim``
-numbers (4 KiB at a page of 16) and multiply them against a handful of
-query rows, and neither was ever tiled for the chip. A kernel that
-wants to win there brings MANY pages of a slot into fast memory a step
-with copies of its own and runs the online softmax over the block:
-``kernels/latent_paged_attention.py`` does that for a latent
-configuration's headless page (PR 36); the per-head form of it (jax's
-``paged_attention``: ``pages_per_compute_block``) is what ROADMAP A3.2
-still asks for.
+``paged_decode_reference`` is what a one-row decode step over the pool
+must compute: each slot's view gathered dense, then masked softmax
+attention at full f32 precision. The walk
+(``sparse_paged_attention.sparse_paged_decode`` with no mask) is
+checked against it on the chip (``chip_smoke.py``).
 
-Both kernels optionally dequantize int8 pages in-register: per-page
-scales live in a small side array allocated with the pool
-(``quantize_pages_int8`` below is the single write-side definition),
-ride in through scalar prefetch, and multiply the page after the
-int8->f32 cast -- so int8 halves pool HBM *and* halves kernel read
-bytes. Quantize-on-write stays in the engine's XLA scatter; the kernels
-are read-only consumers.
-
-Page layout (the one definition; ``tokens_to_pages`` /
-``pages_to_tokens`` / ``write_tokens`` below are its only spellings):
-a page is ``[kv_heads, block_size, head_dim]``, heads AHEAD of rows, so
-one layer's pool is ``[num_blocks, kv_heads, block_size, head_dim]``
-(what a kernel here takes; the engine stacks the layers in one array
-ahead of that and names layer and pages in ONE index,
-``pool[layer, page_ids]`` -- a ``pool[layer]`` on its own is a copy of
-a layer's pool wherever the compiler cannot fuse it away) and one
-kernel block ``(1, 1, block_size, head_dim)`` spans the full last two
-dims of the array -- the shape Mosaic's block rule accepts (its last
-two block dims must divide by the (8, 128) tile or equal the array's).
-Rows-first pages ``[block_size, kv_heads, head_dim]`` put a size-1 head
-block in the second-minor dim and are refused by the TPU lowering.
-
-The caller says whether to interpret: the serving engine compiles for
-the devices of its mesh (Mosaic on TPU, real or virtual topology; the
-Pallas interpreter on the simulated CPU mesh, which lowers to plain XLA
-ops). Parity contract: greedy decode through these kernels is
-token-exact vs the gather oracle for fp16/bf16 pools (same
-online-softmax identity, fp32 accumulation); int8 mode is gated by a
-bounded-divergence oracle whose tolerance is pinned from the
-deterministic ``int8_logit_rmse`` probe.
+This module once held two Pallas kernels of its own, a decode and a
+chunked-prefill flash kernel, that took the block table as a
+scalar-prefetch operand and ONE page a grid step. They lost every
+reading on a TPU v5e: 187 ms a decode tick at
+``serve-decode-deepseek7b``'s shape where the gathered read took 23-39,
+and 12.2 against 8.7 ms at four slots (PERF.md). Every
+page of every (slot, KV head) paid a grid step's fixed cost (~0.35 us)
+to bring in ``block_size x head_dim`` numbers and multiply them
+against a handful of query rows. They went, with the option that
+picked them. A row step now reads through the walk, which brings many
+pages of a slot into fast memory a step with copies of its own; a
+chunk, a speculative step and an int8 row step gather their view.
 """
 from __future__ import annotations
 
-import functools
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
-
-from tpu_hpc.kernels.attention import MASK_VALUE, pick_block_sizes
 
 # Page 0 of the pool is the scratch page: never allocated, absorbs
 # writes/reads from inactive slots and dead table entries. Must match
@@ -188,309 +154,12 @@ def dequantize_pages_int8(q: jax.Array, sc: jax.Array) -> jax.Array:
 
 
 # ---------------------------------------------------------------------------
-# Decode kernel: one query token per slot, block table walked in-kernel
+# Gather-then-dense reference (the oracle the walk is checked against)
 # ---------------------------------------------------------------------------
 
-def _decode_kernel(
-    # scalar prefetch (SMEM)
-    tbl_ref,   # (slots, table_width) int32 block tables
-    pos_ref,   # (slots,) int32 position being written this tick
-    act_ref,   # (slots,) int32 active mask
-    *rest,
-    block_size: int,
-    n_pages: int,
-    sm_scale: float,
-    quant: bool,
-):
-    if quant:
-        ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        ksc_ref = vsc_ref = None
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    s_id = pl.program_id(0)
-    j = pl.program_id(2)
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    p_pos = pos_ref[s_id]
-    live = jnp.logical_and(j * block_size <= p_pos, act_ref[s_id] > 0)
-
-    @pl.when(live)
-    def _step():
-        q = q_ref[0, 0]                       # (g, d)
-        k = k_ref[0, 0]                       # (block_size, d)
-        v = v_ref[0, 0]
-        if quant:
-            page = tbl_ref[s_id, j]
-            k = k.astype(jnp.float32) * ksc_ref[page]
-            v = v.astype(jnp.float32) * vsc_ref[page]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                          # (g, block_size)
-        cols = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        s = jnp.where(cols <= p_pos, s, MASK_VALUE)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_safe = jnp.where(m_new <= MASK_VALUE * 0.5, 0.0, m_new)
-        p = jnp.where(s > MASK_VALUE * 0.5, jnp.exp(s - m_safe), 0.0)
-        alpha = jnp.exp(m_prev - m_safe)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[:] = alpha * acc_ref[:] + pv
-        m_ref[:] = m_new
-
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        o_ref[0, 0] = (acc_ref[:] / l_safe).astype(o_ref.dtype)
-
-
-def paged_decode_attention(
-    q: jax.Array,        # (slots, kv_heads, group, head_dim)
-    k_pages: jax.Array,  # (num_blocks, kv_heads, block_size, head_dim)
-    v_pages: jax.Array,
-    tables: jax.Array,   # (slots, table_width) int32
-    pos: jax.Array,      # (slots,) int32 position written this tick
-    active: jax.Array,   # (slots,) int32
-    *,
-    block_size: int,
-    max_blocks: int,
-    k_scale: Optional[jax.Array] = None,  # (num_blocks,) f32 (int8 pools)
-    v_scale: Optional[jax.Array] = None,
-    sm_scale: Optional[float] = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Single-token paged attention: returns (slots, kv_heads, group,
-    head_dim) context in q.dtype. Each grid program (slot, kv_head, j)
-    streams table[slot, j]'s page once; pages past pos and inactive
-    slots redirect to SCRATCH_PAGE in the index map and are skipped by
-    predication (inactive slots output zeros)."""
-    slots, hkv, g, d = q.shape
-    quant = k_scale is not None
-    if quant != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be passed together")
-    if sm_scale is None:
-        sm_scale = d ** -0.5
-    scalars = [tables.astype(jnp.int32), pos.astype(jnp.int32),
-               active.astype(jnp.int32)]
-    if quant:
-        scalars += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-
-    def kv_map(s, h, j, tbl, pos_r, act_r, *_):
-        live = jnp.logical_and(j * block_size <= pos_r[s], act_r[s] > 0)
-        page = jnp.where(live, tbl[s, j], SCRATCH_PAGE)
-        return page, h, 0, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
-        grid=(slots, hkv, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, 1, g, d), lambda s, h, j, *_: (s, h, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d), kv_map),
-            pl.BlockSpec((1, 1, block_size, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec((1, 1, g, d), lambda s, h, j, *_: (s, h, 0, 0)),
-        scratch_shapes=[
-            pltpu.VMEM((g, d), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-            pltpu.VMEM((g, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _decode_kernel,
-        block_size=block_size,
-        n_pages=max_blocks,
-        sm_scale=sm_scale,
-        quant=quant,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((slots, hkv, g, d), q.dtype),
-        interpret=interpret,
-        name="paged_decode",
-    )(*scalars, q, k_pages, v_pages)
-
-
-# ---------------------------------------------------------------------------
-# Chunked-prefill kernel: flash over the block-table view
-# ---------------------------------------------------------------------------
-
-def _prefill_kernel(
-    # scalar prefetch (SMEM)
-    tbl_ref,    # (table_width,) int32: this slot's table row
-    start_ref,  # (1,) int32: global position of the chunk's first token
-    *rest,
-    block_size: int,
-    block_q: int,
-    n_pages: int,
-    group: int,
-    sm_scale: float,
-    quant: bool,
-):
-    if quant:
-        ksc_ref, vsc_ref, q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    else:
-        ksc_ref = vsc_ref = None
-        q_ref, k_ref, v_ref, o_ref, acc_ref, m_ref, l_ref = rest
-    qi = pl.program_id(1)
-    j = pl.program_id(2)
-    start = start_ref[0]
-
-    @pl.when(j == 0)
-    def _init():
-        m_ref[:] = jnp.full_like(m_ref, MASK_VALUE)
-        l_ref[:] = jnp.zeros_like(l_ref)
-        acc_ref[:] = jnp.zeros_like(acc_ref)
-
-    # Causal page skip: the page's first key position is past the last
-    # query row of this block.
-    live = j * block_size <= start + (qi + 1) * block_q - 1
-
-    @pl.when(live)
-    def _step():
-        rows = block_q * group
-        q = q_ref[0].reshape(rows, q_ref.shape[-1])  # (bq*g, d), row-major
-        k = k_ref[0, 0]                              # (block_size, d)
-        v = v_ref[0, 0]
-        if quant:
-            page = tbl_ref[j]
-            k = k.astype(jnp.float32) * ksc_ref[page]
-            v = v.astype(jnp.float32) * vsc_ref[page]
-        s = jax.lax.dot_general(
-            q, k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        ) * sm_scale                                 # (bq*g, block_size)
-        # Global causal mask from data: q row r of this block sits at
-        # position start + qi*block_q + r//group.
-        qpos = start + qi * block_q + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 0
-        ) // group
-        cols = j * block_size + jax.lax.broadcasted_iota(
-            jnp.int32, s.shape, 1
-        )
-        s = jnp.where(cols <= qpos, s, MASK_VALUE)
-        m_prev = m_ref[:]
-        m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-        m_safe = jnp.where(m_new <= MASK_VALUE * 0.5, 0.0, m_new)
-        p = jnp.where(s > MASK_VALUE * 0.5, jnp.exp(s - m_safe), 0.0)
-        alpha = jnp.exp(m_prev - m_safe)
-        l_ref[:] = alpha * l_ref[:] + jnp.sum(p, axis=1, keepdims=True)
-        pv = jax.lax.dot_general(
-            p.astype(v.dtype), v,
-            (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32,
-        )
-        acc_ref[:] = alpha * acc_ref[:] + pv
-        m_ref[:] = m_new
-
-    @pl.when(j == n_pages - 1)
-    def _finish():
-        l = l_ref[:]
-        l_safe = jnp.where(l == 0.0, 1.0, l)
-        out = acc_ref[:] / l_safe
-        o_ref[0] = out.reshape(o_ref.shape[1:]).astype(o_ref.dtype)
-
-
-def paged_prefill_attention(
-    q: jax.Array,        # (kv_heads, bucket, group, head_dim)
-    k_pages: jax.Array,  # (num_blocks, kv_heads, block_size, head_dim)
-    v_pages: jax.Array,
-    table: jax.Array,    # (table_width,) int32: one slot's table row
-    start: jax.Array,    # scalar int32: chunk's first global position
-    *,
-    block_size: int,
-    max_blocks: int,
-    block_q: int = 128,
-    k_scale: Optional[jax.Array] = None,
-    v_scale: Optional[jax.Array] = None,
-    sm_scale: Optional[float] = None,
-    interpret: bool = False,
-) -> jax.Array:
-    """Chunked-prefill flash attention over the block-table view:
-    returns (kv_heads, bucket, group, head_dim) context in q.dtype.
-    The kv grid walks table[j] for j < max_blocks (the engine's full
-    view, trailing entries scratch-padded); the causal mask is global,
-    from ``start`` carried as data, so one compiled program serves
-    every chunk of every slot at this bucket."""
-    hkv, bucket, g, d = q.shape
-    quant = k_scale is not None
-    if quant != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be passed together")
-    if sm_scale is None:
-        sm_scale = d ** -0.5
-    block_q, _ = pick_block_sizes(block_q, block_size, bucket, block_size)
-    block_q = min(block_q, bucket)
-    if bucket % block_q:
-        block_q = bucket  # odd bucket: one q block, no padding games
-    scalars = [table.astype(jnp.int32),
-               jnp.asarray(start, jnp.int32).reshape(1)]
-    if quant:
-        scalars += [k_scale.astype(jnp.float32), v_scale.astype(jnp.float32)]
-
-    def kv_map(h, i, j, tbl, start_r, *_):
-        live = j * block_size <= start_r[0] + (i + 1) * block_q - 1
-        page = jnp.where(live, tbl[j], SCRATCH_PAGE)
-        return page, h, 0, 0
-
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=len(scalars),
-        grid=(hkv, bucket // block_q, max_blocks),
-        in_specs=[
-            pl.BlockSpec((1, block_q, g, d), lambda h, i, j, *_: (h, i, 0, 0)),
-            pl.BlockSpec((1, 1, block_size, d), kv_map),
-            pl.BlockSpec((1, 1, block_size, d), kv_map),
-        ],
-        out_specs=pl.BlockSpec(
-            (1, block_q, g, d), lambda h, i, j, *_: (h, i, 0, 0)
-        ),
-        scratch_shapes=[
-            pltpu.VMEM((block_q * g, d), jnp.float32),
-            pltpu.VMEM((block_q * g, 1), jnp.float32),
-            pltpu.VMEM((block_q * g, 1), jnp.float32),
-        ],
-    )
-    kernel = functools.partial(
-        _prefill_kernel,
-        block_size=block_size,
-        block_q=block_q,
-        n_pages=max_blocks,
-        group=g,
-        sm_scale=sm_scale,
-        quant=quant,
-    )
-    return pl.pallas_call(
-        kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((hkv, bucket, g, d), q.dtype),
-        interpret=interpret,
-        name="paged_prefill",
-    )(*scalars, q, k_pages, v_pages)
-
-
-# ---------------------------------------------------------------------------
-# Gather-then-dense references (the oracle the kernels are checked against)
-# ---------------------------------------------------------------------------
-
-def _gathered_view(pages, ids, scale):
-    """``pages[ids]`` as f32 token rows ``[..., T, kv_heads, d]``,
-    dequantized when the pool is int8."""
-    view = pages[ids]
-    if scale is not None:
-        view = dequantize_pages_int8(view, scale[ids])
-    return pages_to_tokens(view.astype(jnp.float32))
+def _gathered_view(pages, ids):
+    """``pages[ids]`` as f32 token rows ``[..., T, kv_heads, d]``."""
+    return pages_to_tokens(pages[ids].astype(jnp.float32))
 
 
 def _dense_attention(q, k, v, mask, eq_qk, eq_pv):
@@ -508,15 +177,15 @@ def _dense_attention(q, k, v, mask, eq_qk, eq_pv):
 
 def paged_decode_reference(
     q, k_pages, v_pages, tables, pos, active, *, max_blocks,
-    k_scale=None, v_scale=None,
 ):
-    """What :func:`paged_decode_attention` must compute, the way the
-    engine's ``kernel="gather"`` path does it: gather each slot's first
-    ``max_blocks`` table entries into a dense view, then masked softmax
-    attention. Inactive slots give zeros."""
+    """What a one-row decode step over the pool must compute: gather
+    each slot's first ``max_blocks`` table entries into a dense view,
+    then masked softmax attention over the columns ``<= pos``. q is
+    ``[slots, kv_heads, groups, head_dim]``; inactive slots give
+    zeros."""
     ids = tables[:, :max_blocks]
-    k = _gathered_view(k_pages, ids, k_scale)     # (slots, T, hkv, d)
-    v = _gathered_view(v_pages, ids, v_scale)
+    k = _gathered_view(k_pages, ids)     # (slots, T, hkv, d)
+    v = _gathered_view(v_pages, ids)
     live = jnp.arange(k.shape[1])[None, :] <= pos[:, None]
     v = jnp.where(live[:, :, None, None], v, 0.0)
     out = _dense_attention(
@@ -525,24 +194,6 @@ def paged_decode_reference(
     )
     return jnp.where(
         active[:, None, None, None] > 0, out, 0.0
-    ).astype(q.dtype)
-
-
-def paged_prefill_reference(
-    q, k_pages, v_pages, table, start, *, max_blocks,
-    k_scale=None, v_scale=None,
-):
-    """What :func:`paged_prefill_attention` must compute: one slot's
-    table row gathered dense, global causal mask from ``start``."""
-    ids = table[:max_blocks]
-    k = _gathered_view(k_pages, ids, k_scale)     # (T, hkv, d)
-    v = _gathered_view(v_pages, ids, v_scale)
-    qpos = start + jnp.arange(q.shape[1])
-    causal = jnp.arange(k.shape[0])[None, :] <= qpos[:, None]
-    v = jnp.where(causal[-1][:, None, None], v, 0.0)
-    return _dense_attention(
-        q, k, v, causal[None, :, None, :], "hqgd,thd->hqgt",
-        "hqgt,thd->hqgd",
     ).astype(q.dtype)
 
 

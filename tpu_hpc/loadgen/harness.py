@@ -176,25 +176,17 @@ class VirtualClock:
         self._t = float(t_s)
 
 
-# Modeled KV-traffic factors for the paged read paths
-# (tpu_hpc.kernels.paged_attention), relative to the gather/fp16
-# baseline the cost model was calibrated against. The gather path
-# materializes every slot's pages into a dense per-step copy before
-# the flash call (pool read + copy write + copy re-read, ~3 HBM
-# passes over the context); the pallas kernel walks the block table
-# in-kernel and touches each page once. int8 pages halve the bytes
-# the pool read moves (the fp32 scale side array is noise); under
-# gather the dense copy still moves at the activation dtype, so only
-# the pool-read pass shrinks. The (gather, none) entry MUST stay
-# exactly 1.0 -- every banked loadgen row before ISSUE 20 was charged
-# on that path, and the multiplier below is skipped at 1.0 so legacy
-# histories stay byte-identical.
-_KV_TRAFFIC = {
-    ("gather", "none"): 1.0,
-    ("pallas", "none"): 1 / 3,
-    ("gather", "int8"): 2 / 3,
-    ("pallas", "int8"): 1 / 6,
-}
+# Modeled KV-traffic factors by page storage
+# (tpu_hpc.kernels.paged_attention), relative to the fp16 baseline the
+# cost model was calibrated against, whose read materialized every
+# slot's pages into a dense per-step copy (pool read + copy write +
+# copy re-read, ~3 HBM passes over the context). int8 pages halve the
+# bytes the pool read moves (the fp32 scale side array is noise); the
+# dense copy still moves at the activation dtype, so only the
+# pool-read pass shrinks. The "none" entry MUST stay exactly 1.0 --
+# every banked loadgen row was charged on it, and the multiplier
+# below is skipped at 1.0 so legacy histories stay byte-identical.
+_KV_TRAFFIC = {"none": 1.0, "int8": 2 / 3}
 # How much of each charge is KV-bandwidth: decode is famously
 # KV-bound (one token of compute against the whole context's reads),
 # prefill is compute-bound with KV writes a small slice.
@@ -241,14 +233,10 @@ class _CostModelEngine:
         self._prefill_s_per_token = (
             prefill_ms_per_token / 1e3 * faults["prefill_delay"]
         )
-        # Kernel/quant read-path discount (_KV_TRAFFIC above): paged
-        # engines advertise kv_kernel/kv_quant (serve/paging.py);
-        # slab engines have neither attribute and charge the
-        # calibrated baseline untouched.
-        traffic = _KV_TRAFFIC[(
-            getattr(engine, "kv_kernel", "gather"),
-            getattr(engine, "kv_quant", "none"),
-        )]
+        # Page-storage discount (_KV_TRAFFIC above): paged engines
+        # advertise kv_quant (serve/paging.py); slab engines have no
+        # such attribute and charge the calibrated baseline untouched.
+        traffic = _KV_TRAFFIC[getattr(engine, "kv_quant", "none")]
         if traffic != 1.0:
             self._decode_s *= (
                 (1 - _KV_DECODE_FRAC) + _KV_DECODE_FRAC * traffic

@@ -358,7 +358,7 @@ def refuse(cfg: Any, who: str, why: str) -> None:
         raise NotImplementedError(
             f"{who} does not run {cfg.name!r} ({type(cfg).__name__}: "
             f"{what}): {why}. Serve it through "
-            "serve.paging.PagedEngine (kernel='gather', unquantised "
+            "serve.paging.PagedEngine (unquantised "
             "pages, no tensor axis)."
         )
 

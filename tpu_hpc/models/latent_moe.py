@@ -152,7 +152,7 @@ def refuse(cfg: Any, who: str, why: str) -> None:
             f"{who} does not run {cfg.name!r} ({type(cfg).__name__}: "
             f"latent attention, sigmoid-routed experts with a shared "
             f"expert, a leading dense layer): {why}. Serve it through "
-            "serve.paging.PagedEngine (kernel='gather', unquantised "
+            "serve.paging.PagedEngine (unquantised "
             "pages, no tensor axis)."
         )
 

@@ -137,8 +137,8 @@ _LOWER_IS_BETTER = (
     # banked logit_rmse side key pins the int8 quantizer's
     # pre-softmax score error -- a quantizer change that widens the
     # drift fails the gate even while the latency headline still
-    # rides within tolerance. ("kv_kernel"/"kv_quant" are identity,
-    # carried in the metric family name, never judged.)
+    # rides within tolerance. ("kv_quant" is identity, carried in the
+    # metric family name, never judged.)
     "rmse",
 )
 

@@ -10,10 +10,10 @@ AOT executable tables and token-exact oracles:
 
 * **cache** ``[layers, num_blocks, kv_heads, block_size, head_dim]``:
   one physical pool, heads ahead of rows inside a page (the layout
-  kernels/paged_attention.py defines and its Mosaic kernels need), KV
-  heads sharded over the ``model`` axis (pages are globally
-  addressable, so the block dim stays unsharded -- a multi-slice
-  deployment runs one pool per data-parallel replica);
+  kernels/paged_attention.py defines), KV heads sharded over the
+  ``model`` axis (pages are globally addressable, so the block dim
+  stays unsharded -- a multi-slice deployment runs one pool per
+  data-parallel replica);
 * **BlockAllocator** (host side): LIFO free list + refcounts. A block
   is shared when several owners (request tables, the prefix trie)
   hold references; it returns to the free list only at refcount zero.
@@ -38,22 +38,16 @@ AOT executable tables and token-exact oracles:
   runs through the same per-bucket program -- plain prefill is just
   the one-chunk case.
 
-Attention reads the logical sequence one of two ways, selected by
-``PagedConfig.kernel``: ``"gather"`` -- ONE gather over the stacked
-pool by layer and block table (``ks[layer, table]``; never
+Attention reads the logical sequence the way the program's kind and
+the pool's dtype decide (``PagedAttention``): a one-row decode step
+over a pool in the compute dtype walks each slot's table in a kernel;
+a chunk, a speculative step and an int8 row step take ONE gather over
+the stacked pool by layer and block table (``ks[layer, table]``; never
 ``ks[layer][table]``, whose per-layer slice the TPU compiler
 materialises before it gathers), the XLA-level reference formulation,
-correct on every backend and token-exact against the no-cache forward (the
-tests/test_serve.py oracle applies verbatim), which a chunk keeps and a
-one-row decode step over a pool in the compute dtype replaces with a
-kernel that walks each slot's table (``PagedAttention``) -- or
-``"pallas"`` -- the
-kernels/paged_attention.py kernels dropped into the SAME program
-slots: block table walked in-kernel as a scalar-prefetch operand, one
-HBM read per page, no gathered intermediate, run under ``shard_map``
-over the serving mesh (compiled by Mosaic for TPU meshes, interpreted
-on the simulated CPU mesh; token-exact vs gather by the parity suite
-in tests/test_paged_kernels.py). ``PagedConfig.kv_quant="int8"`` stores
+correct on every backend and token-exact against the no-cache forward
+(the tests/test_serve.py oracle applies verbatim).
+``PagedConfig.kv_quant="int8"`` stores
 the pool as per-page symmetric int8 with f32 scale side arrays
 (``k_scales``/``v_scales``, one scalar per page per layer): half the
 pool HBM, ~2x the resident context at equal bytes, gated by a
@@ -77,8 +71,6 @@ from tpu_hpc.kernels.latent_paged_attention import latent_paged_decode
 from tpu_hpc.kernels.paged_attention import (
     INT8_SCALE_FLOOR,
     dequantize_pages_int8,
-    paged_decode_attention,
-    paged_prefill_attention,
     pages_to_tokens,
     quantize_pages_int8,
     tokens_to_pages,
@@ -118,9 +110,8 @@ DECODE_COUNTERS: Tuple[Tuple[str, str], ...] = (
     ("serve_decode_view_pages_read_total",
      "KV pages the dispatched decode programs read a layer: the live "
      "pages of the active slots where a kernel walks the tables (every "
-     "pool in the compute dtype read through 'gather'), a shared page "
-     "once a slot; else the flat rung's size, or slots x pages a slot "
-     "on the rectangle"),
+     "pool in the compute dtype), a shared page once a slot; else the "
+     "flat rung's size, or slots x pages a slot on the rectangle"),
     ("serve_decode_view_pages_total",
      "KV pages the rectangle would have gathered a layer (slots x "
      "pages a slot, a decode step); read over this is how much of "
@@ -273,21 +264,16 @@ class PagedConfig:
     ``host_blocks``: host-DRAM page slots behind the HBM pool
     (serve/tier.py; 0 = no tier). Like ``num_blocks`` it INCLUDES a
     reserved scratch slot 0, so a non-zero tier needs >= 2 slots.
-    ``kernel``: how attention reads the pool -- ``"gather"`` (the XLA
-    data-indexed gather, the oracle) or ``"pallas"``
-    (kernels/paged_attention.py: block table walked in-kernel, one HBM
-    read per page). ``kv_quant``: pool storage -- ``"none"``
-    (cache_dtype as configured) or ``"int8"`` (per-page symmetric int8
-    with f32 scale side arrays; half the pool bytes).
+    ``kv_quant``: pool storage -- ``"none"`` (cache_dtype as
+    configured) or ``"int8"`` (per-page symmetric int8 with f32 scale
+    side arrays; half the pool bytes).
 
-    Legal page sizes for ``kernel="pallas"``: ``block_size >= 2``. A
-    one-row page fails the Mosaic lowering for bfloat16 and int8 pools
-    (the ``(1, 128)`` page leaves the matmul a degenerate operand);
-    every size from 2 to 128 lowers, and on a v5e matches the
-    gather-then-dense oracle, for float32, bfloat16 and int8 alike
-    (PERF.md, PR 21 sweep). HBM stores a page short of a tile
-    unpadded -- XLA narrows the tiling -- so no dtype needs a larger
-    minimum; which size is FASTEST is not measured.
+    ``kernel`` has one legal value, ``"gather"``, and no code reads it:
+    what reads a step is :class:`PagedAttention`'s to decide from the
+    program's kind, the configuration's kind and the pool's dtype. The
+    benchmark's workload files still name it; the Pallas serving
+    kernels that ``"pallas"`` picked were removed, having lost every
+    reading on the chip.
 
     ``ssm_snapshot_bytes``: what the prefix trie may hold in snapshots
     of a recurrent state (a configuration with state-space layers
@@ -333,16 +319,11 @@ class PagedConfig:
                 f"multiple of block_size {self.block_size} (chunks "
                 "start on page boundaries)"
             )
-        if self.kernel not in ("gather", "pallas"):
+        if self.kernel != "gather":
             raise ValueError(
-                f"kernel must be 'gather' or 'pallas', got "
-                f"{self.kernel!r}"
-            )
-        if self.kernel == "pallas" and self.block_size < 2:
-            raise ValueError(
-                f"kernel='pallas' needs block_size >= 2, got "
-                f"{self.block_size}: a one-row page does not lower "
-                "through Mosaic for bfloat16 and int8 pools"
+                f"kernel must be 'gather', got {self.kernel!r}: the "
+                "Pallas serving kernels that 'pallas' picked were "
+                "removed, and no other read is chosen by an option"
             )
         if self.kv_quant not in ("none", "int8"):
             raise ValueError(
@@ -371,7 +352,6 @@ def derive_paged_config(
     prefill_chunk: Optional[int] = None,
     align_capacity: bool = False,
     host_blocks: Optional[int] = None,
-    kernel: Optional[str] = None,
     kv_quant: Optional[str] = None,
 ) -> Tuple["PagedConfig", int]:
     """CLI-shared sizing: ``(PagedConfig, capacity)`` from the flag
@@ -409,7 +389,6 @@ def derive_paged_config(
         ),
         prefill_chunk=prefill_chunk or 0,
         host_blocks=host_blocks or 0,
-        kernel=kernel or "gather",
         kv_quant=kv_quant or "none",
     )
     return cfg, max_seq
@@ -457,44 +436,6 @@ def paged_kv_cache_pspec(mesh: Mesh, kv_heads: int) -> P:
         else None
     )
     return P(None, None, model, None, None)
-
-
-def _on_mesh(kernel, mesh: Mesh, kv_heads: int, heads_dim: int, **static):
-    """A paged kernel with its static arguments bound, run per KV-head
-    shard of the serving mesh. XLA has no SPMD partitioning rule for a
-    Mosaic call, so inside a GSPMD program the kernel runs under
-    ``shard_map`` (the tp.make_tp_flash_attn_fn pattern): q, the pool
-    and the output split over ``model`` on their KV-head dim exactly as
-    :func:`paged_kv_cache_pspec` lays the pool out; tables, positions
-    and scales are whole on every shard. ``heads_dim``: which dim of q
-    and of the output indexes KV heads. Interpreted when the mesh is
-    not made of TPU devices."""
-    if mesh is None:
-        raise ValueError("kernel='pallas' needs the serving mesh")
-    fn = functools.partial(
-        kernel,
-        interpret=mesh.devices.flat[0].platform != "tpu",
-        **static,
-    )
-    if mesh.size == 1:
-        return fn
-    model = paged_kv_cache_pspec(mesh, kv_heads)[2]
-    q_spec = P(*(model if i == heads_dim else None for i in range(4)))
-    pool_spec = P(None, model, None, None)
-
-    def call(q, k_pages, v_pages, *scalars, **scales):
-        # Tables/positions and the (possibly None) scales ride as two
-        # whole-on-every-shard pytrees.
-        return jax.shard_map(
-            lambda q, k, v, scalars, scales: fn(
-                q, k, v, *scalars, **scales
-            ),
-            mesh=mesh,
-            in_specs=(q_spec, pool_spec, pool_spec, P(), P()),
-            out_specs=q_spec, check_vma=False,
-        )(q, k_pages, v_pages, scalars, scales)
-
-    return call
 
 
 def _walk_on_mesh(walk, mesh: Optional[Mesh], kv_heads: int):
@@ -1116,33 +1057,27 @@ def _with_state(body, name: str, quant: bool, sparse: bool,
     return program
 
 
-def _check_read_path(cfg, kernel: str, kv_quant: str) -> None:
-    """A sparse-expert configuration reads under ``gather`` from a
-    pool in the compute dtype: ``kernels/paged_attention.py`` walks the
-    whole table (no selection) and the int8 page write has no indexer
-    key."""
-    if kernel != "gather" or kv_quant != "none":
+def _check_read_path(cfg, kv_quant: str) -> None:
+    """Only a dense configuration keeps an int8 pool: every other
+    reads a pool in the compute dtype."""
+    if kv_quant != "none":
         sparse_moe.refuse(
-            cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
-            "kernels/paged_attention.py applies no selection and the "
-            "int8 page write quantises no indexer key; the selected "
-            "decode step already walks the tables in a kernel of its "
-            "own (kernels/sparse_paged_attention.py) under 'gather', "
-            "over an unquantised pool",
+            cfg, f"kv_quant={kv_quant!r}",
+            "the int8 page write quantises no indexer key, and the "
+            "selected decode step walks the tables of a pool in the "
+            "compute dtype (kernels/sparse_paged_attention.py)",
         )
         latent_moe.refuse(
-            cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
-            "kernels/paged_attention.py contracts per-head K and V "
-            "pages and the int8 page write quantises them; a latent "
-            "page has neither, and its decode step already walks the "
-            "tables in a kernel of its own "
-            "(kernels/latent_paged_attention.py) under 'gather'",
+            cfg, f"kv_quant={kv_quant!r}",
+            "the int8 page write quantises per-head K and V pages; a "
+            "latent page has neither, and its decode step walks the "
+            "tables of a pool in the compute dtype "
+            "(kernels/latent_paged_attention.py)",
         )
         hybrid_ssm_moe.refuse(
-            cfg, f"kernel={kernel!r} / kv_quant={kv_quant!r}",
-            "the Pallas kernels scale scores by head_dim ** -0.5, not "
-            "by the configuration's multiplier, and neither they nor "
-            "the int8 pool have been held to this decoder's reference",
+            cfg, f"kv_quant={kv_quant!r}",
+            "the int8 pool has not been held to this decoder's "
+            "reference",
         )
 
 
@@ -1150,12 +1085,13 @@ class PagedAttention:
     """The attention state of every program over the page pool: the
     pool's arrays inside one call (``ks, vs``, an int8 pool's scales
     ``ksc, vsc``, a sparse-expert configuration's indexer keys ``xs``)
-    and the one place that knows how a page is written and read --
-    ``kv_quant``, ``kernel`` and the indexer's selection are decided
-    here and in no program. ``serve/decoder.py``'s layer loop calls it
-    once a layer; what stays the program's is what differs between
-    programs: positions and the mask, and which pages and rows this
-    step writes.
+    and the one place that knows how a page is written and read -- the
+    read, ``kv_quant`` and the indexer's selection are decided here and
+    in no program, from what it can observe: the program's kind, the
+    configuration's kind and the pool's dtype. ``serve/decoder.py``'s
+    layer loop calls it once a layer; what stays the program's is what
+    differs between programs: positions and the mask, and which pages
+    and rows this step writes.
 
     A factory builds one for its program's kind (``chunk``: one
     sequence's block-aligned run of tokens; else one row a slot, or a
@@ -1199,33 +1135,28 @@ class PagedAttention:
       layer's selection (``picked``, for the benchmark's probe) and
       counts what the indexer scored and what attention read
       (``counts``, by ``SPARSE_COUNTERS``' keys).
-    * ``kv_read`` + ``attention`` -- ``kernel="gather"``: ONE
-      data-indexed gather of the view's pages by layer and table
-      (dequantized from an int8 pool) and the model's dense attention
-      under the mask, page-major for row steps
-      (:func:`_grouped_attention_paged`) and token-major for a chunk;
-      so a chunk attends to every previously prefilled chunk and to the
-      shared prefix pages it never computed. A ROW step handed ``pos,
-      active`` (:meth:`view`) over a pool in the compute dtype -- a
-      dense configuration's, and one with state-space layers
-      (``models/hybrid_ssm_moe.py``) -- reads through the selected
-      read's kernel with no selection (:meth:`walks` is the rule): the
-      walk over every column ``<= pos`` at the configuration's score
-      scale, each live page of K and V once, under ``kv_read``, and the
-      rounding of its result under ``attention``; pages of 32 KiB and
-      up have their block's copies issued in rounds of a few pages (the
-      kernel's page-bytes rule: ``sparse_paged_attention.ROUND_BYTES``).
-      On a mesh whose ``model`` axis splits the pool's KV heads the
-      walk runs a shard a chip (:func:`_walk_on_mesh`). A speculative
-      program's rows gather their view. A dense one-row step over an
-      int8 pool whose caller knows how many pages are live gathers only
-      those, dequantized, as one flat list over all slots
-      (:meth:`live_pages`, :func:`_grouped_attention_flat`), in place
-      of every slot's whole capacity. ``kernel="pallas"``: the
-      table handed to kernels/paged_attention.py, which walks it
-      in-kernel under ``shard_map`` over ``mesh`` (required for this
-      kernel), all of it under ``kv_read``. Both read paths dequantize,
-      so they always see the identical pool state.
+    * ``kv_read`` + ``attention`` -- a ROW step handed ``pos, active``
+      (:meth:`view`) over a pool in the compute dtype -- a dense
+      configuration's, and one with state-space layers
+      (``models/hybrid_ssm_moe.py``) -- WALKS: the selected read's
+      kernel with no selection, over every column ``<= pos`` at the
+      configuration's score scale, each live page of K and V once,
+      under ``kv_read``, and the rounding of its result under
+      ``attention``; pages of 32 KiB and up have their block's copies
+      issued in rounds of a few pages (the kernel's page-bytes rule:
+      ``sparse_paged_attention.ROUND_BYTES``). On a mesh whose
+      ``model`` axis splits the pool's KV heads the walk runs a shard a
+      chip (:func:`_walk_on_mesh`). A CHUNK and a speculative program's
+      rows GATHER: ONE data-indexed gather of the view's pages by layer
+      and table and the model's dense attention under the mask,
+      token-major for a chunk (so it attends to every previously
+      prefilled chunk and to the shared prefix pages it never
+      computed) and page-major for rows
+      (:func:`_grouped_attention_paged`). A row step over an int8 pool
+      gathers its view dequantized, or, where its caller knows how many
+      pages are live, only those, as one flat list over all slots (a
+      flat rung: :meth:`live_pages`, :func:`_grouped_attention_flat`),
+      in place of every slot's whole capacity.
 
     A latent configuration (``models/latent_moe.py``) keeps ONE row a
     token and nothing per head, in two arrays without a head axis,
@@ -1267,9 +1198,9 @@ class PagedAttention:
     """
 
     def __init__(self, cfg, block_size: int, max_blocks: int,
-                 kernel: str = "gather", kv_quant: str = "none",
-                 mesh: Optional[Mesh] = None, chunk: bool = False):
-        _check_read_path(cfg, kernel, kv_quant)
+                 kv_quant: str = "none", mesh: Optional[Mesh] = None,
+                 chunk: bool = False):
+        _check_read_path(cfg, kv_quant)
         self.cfg = cfg
         self.block_size, self.max_blocks = block_size, max_blocks
         self.chunk = chunk
@@ -1286,33 +1217,13 @@ class PagedAttention:
             self.walk = sparse_moe._on_mesh(functools.partial(
                 latent_paged_decode, scale=cfg.qk_head_dim ** -0.5
             ), mesh)
-        elif self.walks(kernel, kv_quant) and not chunk:
+        elif not self.quant and not chunk:
             # A row step's read of K and V pages, under the selection or
             # over the columns ``<= pos`` (a chunk attends over its one
-            # gathered view).
+            # gathered view, an int8 row step over its dequantized one).
             self.walk = _walk_on_mesh(functools.partial(
                 sparse_paged_decode, scale=_score_scale(cfg)
             ), mesh, cfg.kv_heads)
-        self.kernel = None
-        if kernel == "pallas":
-            self.kernel = _on_mesh(
-                paged_prefill_attention if chunk
-                else paged_decode_attention,
-                mesh, cfg.kv_heads, 0 if chunk else 1,
-                block_size=block_size, max_blocks=max_blocks,
-            )
-
-    @staticmethod
-    def walks(kernel: str, kv_quant: str) -> bool:
-        """Whether a row step reads by walking its slots' page tables in
-        a kernel, and gathers no view: every configuration's does under
-        ``kernel="gather"`` over a pool in the compute dtype (the only
-        read path a latent, a sparse-selection and a state-space
-        configuration have). An int8 pool's dense read gathers and
-        dequantizes; ``kernel="pallas"`` walks in
-        ``kernels/paged_attention.py``. The engine counts a walked read
-        by the live pages and holds no flat rung for it."""
-        return kernel == "gather" and kv_quant == "none"
 
     def on(self, ks, vs, ksc=None, vsc=None, xs=None):
         """This state over one call's arrays (a copy: the factory's
@@ -1327,10 +1238,10 @@ class PagedAttention:
     def view(self, tables, *where):
         """What attention reads: the first ``max_blocks`` pages of each
         slot's table (``[slots, width]``), or of one sequence's
-        (``[width]``). ``where`` is what a table-walking kernel takes
-        after the table: ``pos, active`` of a row step, ``start`` of a
-        chunk."""
-        self.tables, self.where = tables, where
+        (``[width]``). ``where`` is what the walk takes after the
+        table: ``pos, active`` of a row step that walks (a speculative
+        program's rows and a chunk hand none, and gather)."""
+        self.where = where
         self.view_ids = tables[..., :self.max_blocks]
 
     def live_pages(self, tables, pos, active, n_pages):
@@ -1621,27 +1532,6 @@ class PagedAttention:
 
     def _read(self, layer, q, mask):
         cfg, quant = self.cfg, self.quant
-        b, s = q.shape[0], q.shape[1]
-        if self.kernel is not None:
-            with jax.named_scope("kv_read"):
-                # Queries grouped by KV head: [kv_heads, rows, ...] for
-                # one sequence's chunk, [slots, kv_heads, ...] for rows.
-                qg = q[0] if self.chunk else q[:, 0]
-                qg = qg.astype(cfg.dtype).reshape(
-                    -1, cfg.kv_heads, cfg.n_heads // cfg.kv_heads,
-                    cfg.head_dim,
-                )
-                if self.chunk:
-                    qg = qg.transpose(1, 0, 2, 3)
-                ctx = self.kernel(
-                    qg, self.ks[layer], self.vs[layer], self.tables,
-                    *self.where,
-                    k_scale=self.ksc[layer] if quant else None,
-                    v_scale=self.vsc[layer] if quant else None,
-                )
-                if self.chunk:
-                    ctx = ctx.transpose(1, 0, 2, 3)
-                return ctx.reshape(b, s, cfg.n_heads, cfg.head_dim)
         ids = self.view_ids if self.own is None else self.flat_ids
         with jax.named_scope("kv_read"):
             k_view = self.ks[layer, ids]
@@ -1803,7 +1693,6 @@ def make_chunk_logits_fn(
     block_size: int,
     max_blocks: int,
     table_width: int,
-    kernel: str = "gather",
     kv_quant: str = "none",
     mesh: Optional[Mesh] = None,
 ):
@@ -1820,8 +1709,8 @@ def make_chunk_logits_fn(
     K/V goes into the pages ``table[start/bs :]`` names, then
     attention runs over the WHOLE logical sequence view under the
     global causal mask ``key_pos <= start + q``
-    (:class:`PagedAttention` has the stages, and what ``kernel`` and
-    ``kv_quant`` change in them). The row at ``true_len - 1`` is
+    (:class:`PagedAttention` has the stages, and what ``kv_quant``
+    changes in them). The row at ``true_len - 1`` is
     meaningful on the final chunk only.
 
     The pool's arrays follow ``vs`` in the arguments and the results
@@ -1842,7 +1731,7 @@ def make_chunk_logits_fn(
     nb_chunk = bucket // block_size
     cache_cap = max_blocks * block_size
     attention = PagedAttention(
-        cfg, block_size, max_blocks, kernel, kv_quant, mesh, chunk=True
+        cfg, block_size, max_blocks, kv_quant, mesh, chunk=True
     )
     recurrent = RecurrentState(cfg, chunk=True) \
         if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg) else None
@@ -1864,7 +1753,7 @@ def make_chunk_logits_fn(
         blk_ids = jax.lax.dynamic_slice(
             table, (start // block_size,), (nb_chunk,)
         )
-        pool.view(table, start)
+        pool.view(table)
         pool.pages(blk_ids, qpos, mask)
         x, _ = decoder_layers(
             params, cfg, x, cos, sin, pool, recur=recur, mesh=mesh
@@ -1891,7 +1780,6 @@ def make_chunk_prefill_fn(
     block_size: int,
     max_blocks: int,
     table_width: int,
-    kernel: str = "gather",
     kv_quant: str = "none",
     mesh: Optional[Mesh] = None,
 ):
@@ -1899,7 +1787,7 @@ def make_chunk_prefill_fn(
     with the argmax token rule (meaningful on the final chunk only)."""
     inner = make_chunk_logits_fn(
         cfg, bucket, block_size, max_blocks, table_width,
-        kernel=kernel, kv_quant=kv_quant, mesh=mesh,
+        kv_quant=kv_quant, mesh=mesh,
     )
 
     # What follows the logits: a recurrent state's snapshot.
@@ -1922,7 +1810,6 @@ def make_paged_decode_fn(
     block_size: int,
     max_blocks: int,
     table_width: int,
-    kernel: str = "gather",
     kv_quant: str = "none",
     mesh: Optional[Mesh] = None,
     probe: bool = False,
@@ -1960,8 +1847,8 @@ def make_paged_decode_fn(
     tenant's chunk lands after it, and a row past a tenant's length is
     never read.
 
-    :class:`PagedAttention` has the stages, and what ``kernel`` and
-    ``kv_quant`` change in them; the pool's arrays follow ``vs``
+    :class:`PagedAttention` has the stages, and what ``kv_quant``
+    changes in them; the pool's arrays follow ``vs``
     (:func:`_with_state`). A sparse-expert configuration
     (``models/sparse_moe.py``) runs the SAME program with the
     indexer's selection inside attention and
@@ -1988,35 +1875,28 @@ def make_paged_decode_fn(
     V through the same kernel with no mask, as one with state-space
     layers does.
 
-    ``flat_pages`` (a dense configuration's int8 pool through
-    ``gather`` only): the same program reading ``flat_pages`` pages in
-    all, the live pages of every slot end to end
-    (:meth:`PagedAttention.live_pages`),
-    where the plain one reads ``max_blocks`` pages of every slot
-    whatever is live. Same arguments, same results; the caller
+    ``flat_pages`` (a dense configuration's int8 pool only): the same
+    program reading ``flat_pages`` pages in all, the live pages of
+    every slot end to end (:meth:`PagedAttention.live_pages`), where
+    the plain one reads ``max_blocks`` pages of every slot whatever is
+    live. Same arguments, same results; the caller
     promises that the step's live pages fit (``PagedEngine.decode``
     counts them from the positions it hands over and picks the
     program by them).
     """
     cache_cap = max_blocks * block_size
     counters = step_counters(cfg)
-    attention = PagedAttention(
-        cfg, block_size, max_blocks, kernel, kv_quant, mesh
-    )
+    attention = PagedAttention(cfg, block_size, max_blocks, kv_quant, mesh)
     recurrent = RecurrentState(cfg) \
         if hybrid_ssm_moe.is_hybrid_ssm_moe(cfg) else None
-    if flat_pages is not None and (
-        PagedAttention.walks(kernel, kv_quant)
-        or attention.kernel is not None
-    ):
+    if flat_pages is not None and not attention.quant:
         raise ValueError(
             "flat_pages is the gather read of a dense configuration over "
             "an int8 pool: an indexer ranks the columns of each slot's "
             "own view, a latent page is smaller than its owner's query, "
-            "and a table-walking kernel (kernel='pallas', and the row "
-            "step of a dense, a sparse-selection, a latent or a "
-            "state-space configuration over a pool in the compute "
-            "dtype) reads no view at all"
+            "and the walk (the row step of a dense, a sparse-selection, "
+            "a latent or a state-space configuration over a pool in the "
+            "compute dtype) reads no view at all"
         )
 
     def body(params, ks, vs, ksc, vsc, xs, rec, prev, step, tables):
@@ -2249,7 +2129,7 @@ class PagedEngine(Engine):
                 f"cache_dtype={serve_cfg.cache_dtype!r} (the scale "
                 "side arrays are always f32)"
             )
-        _check_read_path(cfg, paged.kernel, paged.kv_quant)
+        _check_read_path(cfg, paged.kv_quant)
         if "model" in mesh.axis_names and mesh.shape["model"] > 1:
             latent_moe.refuse(
                 cfg, "a serving mesh with a tensor axis",
@@ -2270,8 +2150,7 @@ class PagedEngine(Engine):
         # longer has to be provisioned for worst-case length).
         self.paged = paged
         # Read by the loadgen cost model and the bench metric-family
-        # suffixing; mirrors paged_summary()'s kv_kernel / kv_quant.
-        self.kv_kernel = paged.kernel
+        # suffixing; mirrors paged_summary()'s kv_quant.
         self.kv_quant = paged.kv_quant
         self.max_blocks_per_seq = per_seq
         # Table rows carry extra scratch entries past capacity so a
@@ -2284,9 +2163,10 @@ class PagedEngine(Engine):
         self._flat_rungs: Tuple[int, ...] = ()
         self._recurrent = hybrid_ssm_moe.is_hybrid_ssm_moe(cfg)
         # Whether the decode program's row step walks the tables in a
-        # kernel, reading the live pages of the active slots and no view.
-        self._walks = PagedAttention.walks(paged.kernel, paged.kv_quant)
-        if paged.kernel == "gather" and not self._walks:
+        # kernel, reading the live pages of the active slots and no view:
+        # every pool's but an int8 one, which gathers.
+        self._walks = paged.kv_quant == "none"
+        if not self._walks:
             self._flat_rungs = tuple(sorted(
                 {int(self.view_pages * r) for r in FLAT_RUNGS} - {0}
             ))
@@ -2573,8 +2453,7 @@ class PagedEngine(Engine):
             fn = make_chunk_prefill_fn(
                 self.cfg, bucket, self.paged.block_size,
                 self.max_blocks_per_seq, self.table_width,
-                kernel=self.paged.kernel, kv_quant=self.paged.kv_quant,
-                mesh=self.mesh,
+                kv_quant=self.paged.kv_quant, mesh=self.mesh,
             )
             tokens = jax.ShapeDtypeStruct(
                 (1, bucket), jnp.int32, sharding=self._rep
@@ -2593,8 +2472,8 @@ class PagedEngine(Engine):
             fn = make_paged_decode_fn(
                 self.cfg, self.paged.block_size,
                 self.max_blocks_per_seq, self.table_width,
-                kernel=self.paged.kernel, kv_quant=self.paged.kv_quant,
-                mesh=self.mesh, probe=key[0] == "decode_probe",
+                kv_quant=self.paged.kv_quant, mesh=self.mesh,
+                probe=key[0] == "decode_probe",
                 flat_pages=key[1] if len(key) > 1 else None,
             )
             prev = jax.ShapeDtypeStruct(
@@ -2670,11 +2549,9 @@ class PagedEngine(Engine):
         """The flat decode programs this engine holds below the
         rectangle, by the pages each reads (``FLAT_RUNGS`` of ``slots x
         pages a slot``), smallest first: a dense engine over an int8
-        pool through ``gather`` only. None where the row step walks the
-        tables in a kernel and reads no view (every pool in the compute
-        dtype through ``gather``: :meth:`PagedAttention.walks`), under
-        ``kernel="pallas"``, and for a speculative engine, whose step
-        is the verify program."""
+        pool only. None where the row step walks the tables in a kernel
+        and reads no view (every pool in the compute dtype), and for a
+        speculative engine, whose step is the verify program."""
         return () if self.spec is not None else self._flat_rungs
 
     @property
@@ -3372,7 +3249,6 @@ class PagedEngine(Engine):
         lookups = s["prefix_lookups"]
         return {
             "kv_layout": "paged",
-            "kv_kernel": self.paged.kernel,
             "kv_quant": self.paged.kv_quant,
             "kv_block_size": self.paged.block_size,
             "kv_blocks": self.paged.num_blocks,

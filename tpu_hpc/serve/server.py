@@ -589,7 +589,9 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         "--paged", action="store_true",
         help="paged KV cache (serve/paging.py): HBM carved into "
         "fixed-size pages with a block-table per slot, prefix reuse "
-        "over shared prompts, chunked prefill; composable with "
+        "over shared prompts, chunked prefill; a decode step walks "
+        "each slot's page table in a kernel, a prefill chunk gathers "
+        "its view; composable with "
         "--disagg (the KV hop then ships block tables + referenced "
         "pages only)",
     )
@@ -621,22 +623,13 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
         "the largest bucket are servable); requires --paged",
     )
     ap.add_argument(
-        "--kv-kernel", choices=("gather", "pallas"), default=None,
-        help="paged attention read path "
-        "(tpu_hpc.kernels.paged_attention): 'gather' materializes "
-        "each slot's pages with a take() before a dense flash call "
-        "(the oracle path), 'pallas' walks the block table inside "
-        "the kernel -- one HBM read per page, no gathered copy "
-        "(interpreted on CPU); token-exact vs gather under greedy; "
-        "requires --paged",
-    )
-    ap.add_argument(
         "--kv-quant", choices=("none", "int8"), default=None,
         help="KV page storage dtype: 'int8' stores pages quantized "
         "per page with a float32 scale side array -- half the bytes "
         "per token, ~2x resident context at equal HBM (size it with "
-        "python -m tpu_hpc.checks.fit --kv-quant int8); logits "
-        "drift within the pinned tolerance (tests/"
+        "python -m tpu_hpc.checks.fit --kv-quant int8); a decode "
+        "step gathers and dequantizes its pages where it would walk "
+        "them; logits drift within the pinned tolerance (tests/"
         "test_paged_kernels.py); requires --paged",
     )
     ap.add_argument(
@@ -796,7 +789,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
             ("--kv-blocks", args.kv_blocks),
             ("--kv-host-blocks", args.kv_host_blocks),
             ("--prefill-chunk", args.prefill_chunk),
-            ("--kv-kernel", args.kv_kernel),
             ("--kv-quant", args.kv_quant),
         ):
             if val is not None:
@@ -977,7 +969,6 @@ def main(argv: Optional[Sequence[str]] = None) -> int:
                 prefill_chunk=args.prefill_chunk,
                 align_capacity=args.max_seq_len is None,
                 host_blocks=args.kv_host_blocks,
-                kernel=args.kv_kernel,
                 kv_quant=args.kv_quant,
             )
         except ValueError as e:

@@ -728,7 +728,7 @@ class SpecRunner:
         inner = make_chunk_logits_fn(
             engine.cfg, bucket, engine.paged.block_size,
             engine.max_blocks_per_seq, engine.table_width,
-            kernel=engine.paged.kernel, mesh=engine.mesh,
+            mesh=engine.mesh,
         )
 
         def spec_prefill(params, ks, vs, tokens, start, true_len,
